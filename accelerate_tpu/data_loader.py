@@ -25,6 +25,7 @@ collate/workers) or with any python iterable yielding numpy/dict batches.
 
 from __future__ import annotations
 
+import logging
 import math
 from collections.abc import Mapping
 from typing import Any, Callable, Iterable, Iterator
@@ -42,6 +43,8 @@ from .utils.operations import (
     recursively_apply,
 )
 from .utils.random import get_rng_key, synchronize_rng_states
+
+logger = logging.getLogger(__name__)
 
 
 def _leaf_to_numpy(t: Any) -> Any:
@@ -540,10 +543,17 @@ class DataLoaderShard:
                         base_it, slot_bytes=self.prefetch_slot_bytes
                     )
                     base_it = iter(self._live_host_prefetcher)
+                    logger.info("prefetch=%r: native C++ staging ring", self.prefetch)
                 elif self.prefetch == "native":
                     raise RuntimeError(
                         f"prefetch='native' requested but {native_unavailable_reason()}"
                     )
+                else:
+                    # which path fed the step must be readable from the log:
+                    # the library builds on first use and a tree without a
+                    # toolchain runs the Python path
+                    logger.info("prefetch='auto': Python path (%s)",
+                                native_unavailable_reason())
             it = _PrefetchIterator(base_it, _mark_last)
             self._live_prefetch_it = it
             for idx, batch in enumerate(it):

@@ -112,6 +112,41 @@ def _dense(cfg: GPT2Config, features: int, name: str) -> nn.Module:
     )
 
 
+def _fused_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale_pools, sharding):
+    """`paged_decode_attention`, per shard when the engine serves on a mesh.
+
+    XLA cannot partition a Pallas call (a bare one inside a multi-device jit
+    fails to lower), and the kernel is row- and head-local: slot rows split
+    over the mesh's data axis, heads over its model axis, the block pool is
+    whole on the block dim. ``sharding`` is the engine's paged
+    `KVCacheSharding` — the layouts the arrays already carry — so the
+    shard_map moves nothing."""
+    from ..ops.flash_attention import paged_decode_attention
+
+    scales = scale_pools if scale_pools is not None else ()
+
+    def kernel(q, k_pool, v_pool, tables, lengths, *scales):
+        k_sp, v_sp = scales if scales else (None, None)
+        return paged_decode_attention(
+            q, k_pool, v_pool, tables, lengths, k_scale_pool=k_sp, v_scale_pool=v_sp
+        )
+
+    if sharding is None:
+        return kernel(q, k_pool, v_pool, block_tables, lengths, *scales)
+    from jax import shard_map
+
+    rows, _, heads, _ = sharding.gathered.spec
+    pool, scale = sharding.kv.spec, sharding.scale.spec
+    return shard_map(
+        kernel,
+        mesh=sharding.kv.mesh,
+        in_specs=(P(rows, heads, None), pool, pool, P(rows, None), P(rows))
+        + (scale,) * len(scales),
+        out_specs=P(rows, heads, None),
+        check_vma=False,
+    )(q, k_pool, v_pool, block_tables, lengths, *scales)
+
+
 class SelfAttention(nn.Module):
     config: GPT2Config
 
@@ -139,7 +174,6 @@ class SelfAttention(nn.Module):
             # (s > 1 / cache_write_len — speculative decoding) fall through
             # to the gather branch; s is static, so this costs nothing on the
             # one-token fast path.
-            from ..ops.flash_attention import paged_decode_attention
             from .kv_cache import paged_decode_write
 
             k_pool, v_pool, idx, is_init, scale_pools = paged_decode_write(
@@ -149,10 +183,9 @@ class SelfAttention(nn.Module):
                 sharding=cfg.kv_cache_sharding,
             )
             if is_init:
-                k_sp, v_sp = scale_pools if scale_pools is not None else (None, None)
-                out = paged_decode_attention(
+                out = _fused_paged_attention(
                     q[:, 0], k_pool, v_pool, block_tables, idx + 1,
-                    k_scale_pool=k_sp, v_scale_pool=v_sp,
+                    scale_pools, cfg.kv_cache_sharding,
                 )[:, None]  # [b, 1, n_head, head_dim]
             else:
                 # abstract shape-init trace: no pool yet, plain causal

@@ -7,7 +7,8 @@ TPU-native equivalent as an in-tree C++ component: `prefetch_ring.cpp`, a
 background gather-copy ring of 64-byte-aligned host staging buffers driven from
 `HostPrefetcher` (host_prefetcher.py) and `DataLoaderShard(prefetch=...)`.
 
-The shared library builds on first use with g++ (cached next to the source);
+The shared library builds on first use with g++ (cached next to the source,
+under a name derived from the source's content);
 every consumer degrades gracefully to the Python path when no toolchain is
 available, so the framework never hard-depends on the native build.
 """
@@ -15,6 +16,7 @@ available, so the framework never hard-depends on the native build.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,17 +24,24 @@ from pathlib import Path
 
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "prefetch_ring.cpp"
-_LIB = _HERE / "libprefetch_ring.so"
 _BUILD_LOCK = threading.Lock()
 _LOAD_FAILURE: str | None = None
 _lib: ctypes.CDLL | None = None
 
 
-def _build() -> bool:
+def _lib_path() -> Path:
+    """The binary is named after the source it was built from: a library left
+    in the tree by another checkout, or copied with fresher mtimes than the
+    source, can then never be loaded in place of this source's own build."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _HERE / f"libprefetch_ring.{digest}.so"
+
+
+def _build(lib_path: Path) -> bool:
     # compile to a process-unique temp path, then rename atomically: concurrent
     # processes (multi-host launch, parallel tests) must never dlopen a
     # partially-written .so
-    tmp = _LIB.with_suffix(f".so.tmp{os.getpid()}")
+    tmp = lib_path.with_suffix(f".so.tmp{os.getpid()}")
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
         str(_SRC), "-o", str(tmp),
@@ -46,7 +55,7 @@ def _build() -> bool:
         globals()["_LOAD_FAILURE"] = f"native build failed: {proc.stderr[-500:]}"
         tmp.unlink(missing_ok=True)
         return False
-    os.replace(tmp, _LIB)
+    os.replace(tmp, lib_path)
     return True
 
 
@@ -62,11 +71,11 @@ def _load() -> ctypes.CDLL | None:
             return _lib
         if _LOAD_FAILURE is not None:
             return None
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                return None
+        lib_path = _lib_path()
+        if not lib_path.exists() and not _build(lib_path):
+            return None
         try:
-            lib = ctypes.CDLL(str(_LIB))
+            lib = ctypes.CDLL(str(lib_path))
         except OSError as e:
             _LOAD_FAILURE = f"dlopen failed: {e}"
             return None

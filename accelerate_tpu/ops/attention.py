@@ -79,34 +79,23 @@ def dot_product_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-def _tp_shard_map(flash_fn, q, k):
-    """Under a live tensor-parallel mesh, run the Pallas kernel per head shard
-    via shard_map: XLA cannot partition a custom call, so without this it
-    all-gathers the sharded activations and computes attention replicated on
-    every device — correct but O(tp) redundant. Returns None when no TP mesh
-    is active or head counts don't divide the axis (caller runs unwrapped)."""
-    from ..parallel.mesh import active_batch_axes, inside_shard_map
-    from ..state import AcceleratorState
+def _mesh_shard_map(flash_fn, q, k):
+    """Under a live multi-device mesh, run the Pallas kernel per shard
+    (`parallel.mesh.pallas_shard_axes`). Attention is row- and head-local, so
+    the batch splits over the mesh's batch axes and heads over ``tensor``
+    when both head counts divide it (contiguous sharding then keeps whole GQA
+    groups per shard). Returns None when there is nothing to wrap."""
+    from ..parallel.mesh import pallas_shard_axes
 
-    if "mesh" not in AcceleratorState._shared_state:  # initialized check only:
-        return None  # a bare truthiness test could side-effect-init the singleton
-    mesh = AcceleratorState().mesh
-    tp = mesh.shape.get("tensor", 1)
-    if tp <= 1:
+    live = pallas_shard_axes(q.shape[0])
+    if live is None:
         return None
-    if inside_shard_map(mesh):
-        return None  # already per-shard (pipeline/ring region): nesting would fail
-    hq, hk = q.shape[2], k.shape[2]
-    if hq % tp or hk % tp:
-        return None  # heads don't divide the axis (contiguous sharding keeps
-        # whole GQA groups per shard whenever both counts divide)
     from jax import shard_map
 
-    batch_axes = active_batch_axes(mesh)
-    batch_div = math.prod(mesh.shape[a] for a in batch_axes) if batch_axes else 1
-    if q.shape[0] % batch_div:
-        return None  # e.g. batch-1 eval: keep the replicated (correct) path
-    spec = P(batch_axes if batch_axes else None, None, "tensor", None)
+    mesh, rows = live
+    tp = mesh.shape.get("tensor", 1)
+    heads = "tensor" if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    spec = P(rows, None, heads, None)
     return shard_map(
         flash_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )
@@ -160,7 +149,7 @@ def attention(
         flash = partial(
             flash_attention, causal=causal, window=window, block_q=block_q, block_kv=block_kv
         )
-        wrapped = _tp_shard_map(flash, q, k)
+        wrapped = _mesh_shard_map(flash, q, k)
         if wrapped is not None:
             return wrapped(q, k, v)
         return flash(q, k, v)

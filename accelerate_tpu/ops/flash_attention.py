@@ -731,6 +731,43 @@ def flash_attention(
 
 
 # ------------------------------------------------- paged decode (serving)
+# The fused kernel keeps a row's whole attended K/V span in two fp32 VMEM
+# scratch buffers ``[span, kv_heads, head_dim]``; Mosaic tiles the last two dims
+# (8, 128), so head_dim 64 occupies 128 lanes. At gpt2-medium (1024 positions,
+# 16 heads of 64) that is 2 x 8 MiB = exactly the 16 MiB a kernel gets by
+# default, so the call asks for what it needs (`vmem_limit_bytes`) and refuses
+# spans that cannot fit the core at all. 128 MiB is the v5e core's VMEM
+# (measured: XLA reports "Used 128.71M of 128.00M" one size past the cap).
+_VMEM_BYTES = 128 << 20
+PAGED_DECODE_VMEM_CAP = _VMEM_BYTES - (16 << 20)  # leave XLA's own share
+_PAGED_DECODE_HEADROOM = 8 << 20  # pipelined input blocks + flush temporaries
+
+
+def paged_decode_vmem_bytes(span: int, kv_heads: int, head_dim: int) -> int:
+    """VMEM the fused paged-decode kernel needs for one slot row: the two
+    lane/sublane-padded fp32 span buffers plus fixed headroom."""
+    padded = span * (-(-kv_heads // 8) * 8) * (-(-head_dim // 128) * 128) * 4
+    return 2 * padded + _PAGED_DECODE_HEADROOM
+
+
+def check_paged_decode_fits(span: int, kv_heads: int, head_dim: int) -> int:
+    """Raise with the sizes named when the kernel cannot hold ``span``
+    positions of ``kv_heads`` x ``head_dim`` in VMEM; returns the bytes it
+    will ask for. The serving engine calls this at construction, so
+    ``paged_attention="fused"`` fails there instead of at first decode."""
+    need = paged_decode_vmem_bytes(span, kv_heads, head_dim)
+    if need > PAGED_DECODE_VMEM_CAP:
+        raise ValueError(
+            f"fused paged decode keeps the whole attended span in VMEM: "
+            f"{span} positions x {kv_heads} kv heads x head_dim {head_dim} "
+            f"needs {need / 2**20:.0f} MiB (fp32, lane-padded), over the "
+            f"{PAGED_DECODE_VMEM_CAP / 2**20:.0f} MiB this kernel may use of "
+            f"the core's {_VMEM_BYTES / 2**20:.0f} MiB. Shorten n_positions, "
+            "shard heads over the model axis, or use paged_attention='gather'."
+        )
+    return need
+
+
 def _paged_decode_kernel(
     tables, lengths, q_ref, k_ref, v_ref, *rest,
     block_tokens, span, scale, groups, exact,
@@ -866,13 +903,13 @@ def paged_decode_attention(
     contribute is past the frontier and masked. GQA pools read kv head
     ``h // (n_heads // kv_heads)`` directly; K/V are never repeated in HBM.
 
-    VMEM cost per slot-row cell is ``2 * span * kv_heads * head_dim`` fp32 —
-    the attended K/V span lives in scratch so the flush runs a single
-    global-max softmax, bit-identical to the XLA gather oracle under the
-    interpreter (`docs/serving.md` "Fused paged decode"); spans beyond a few
-    thousand tokens should stay on the gather path until an online-softmax
-    variant exists. Returns ``[b, n_heads, head_dim]`` in ``q.dtype``. On
-    CPU (tests/CI) runs under the Pallas interpreter.
+    VMEM cost per slot-row cell is `paged_decode_vmem_bytes` — the attended
+    K/V span lives in fp32 scratch so the flush runs a single global-max
+    softmax, bit-identical to the XLA gather oracle under the interpreter
+    (`docs/serving.md` "Fused paged decode"). A span that cannot fit raises
+    (`check_paged_decode_fits`); an online-softmax variant is what lifts the
+    limit. Returns ``[b, n_heads, head_dim]`` in ``q.dtype``. On CPU
+    (tests/CI) runs under the Pallas interpreter.
 
     An int8 pool (`kv_cache_dtype=int8` paged serving) passes its fp32 absmax
     planes as ``k_scale_pool``/``v_scale_pool`` (``[num_blocks, block_tokens,
@@ -898,6 +935,7 @@ def paged_decode_attention(
     span = bps * block_tokens
     if interpret is None:
         interpret = not _on_tpu()
+    vmem_bytes = check_paged_decode_fits(span, kvh, d)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     # released slots park their whole table at the sentinel id num_blocks;
@@ -951,5 +989,6 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
     )(*inputs)

@@ -219,19 +219,8 @@ def _fused_bwd(vocab, block_r, block_v, interpret, res, g):
 _fused_head_lse.defvjp(_fused_fwd, _fused_bwd)
 
 
-def fused_cross_entropy(
-    hidden: jax.Array,  # [N, e] compute dtype
-    wte: jax.Array,  # [V, e]
-    labels: jax.Array,  # [N] int
-    ignore_index: int = -100,
-    block_r: int = 512,
-    block_v: int = 1024,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Mean CE over valid rows with the tied head fused in; the [N, V] logits
-    tensor never reaches HBM. Differentiable w.r.t. hidden and wte."""
-    if interpret is None:
-        interpret = not _on_tpu()
+def _loss_sum_count(hidden, wte, labels, ignore_index, block_r, block_v, interpret):
+    """Summed CE over the valid rows of one shard, and how many were valid."""
     n, e = hidden.shape
     v = wte.shape[0]
     mask = labels != ignore_index
@@ -249,5 +238,49 @@ def fused_cross_entropy(
     if vpad:
         wte = jnp.pad(wte, ((0, vpad), (0, 0)))
     lse, ll = _fused_head_lse(hidden, wte, safe, v, block_r, block_v, interpret)
-    per_row = (lse - ll) * mask
-    return per_row.sum() / jnp.maximum(mask.sum(), 1)
+    return ((lse - ll) * mask).sum(), mask.sum()
+
+
+def fused_cross_entropy(
+    hidden: jax.Array,  # [N, e] compute dtype
+    wte: jax.Array,  # [V, e]
+    labels: jax.Array,  # [N] int
+    ignore_index: int = -100,
+    block_r: int = 512,
+    block_v: int = 1024,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Mean CE over valid rows with the tied head fused in; the [N, V] logits
+    tensor never reaches HBM. Differentiable w.r.t. hidden and wte.
+
+    Under a live multi-device mesh the kernels run per shard
+    (`parallel.mesh.pallas_shard_axes`): rows split over the batch axes, every
+    shard holds the whole head, and the row sum and valid count are reduced
+    across shards before the division."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    local = functools.partial(
+        _loss_sum_count, ignore_index=ignore_index, block_r=block_r, block_v=block_v,
+        interpret=interpret,
+    )
+    from ..parallel.mesh import pallas_shard_axes
+
+    live = pallas_shard_axes(hidden.shape[0])
+    if live is None:
+        total, count = local(hidden, wte, labels)
+        return total / jnp.maximum(count, 1)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    mesh, rows = live
+
+    def per_shard(hidden, wte, labels):
+        total, count = local(hidden, wte, labels)
+        if rows is not None:
+            total, count = jax.lax.psum((total, count), rows)
+        return total / jnp.maximum(count, 1)
+
+    return shard_map(
+        per_shard, mesh=mesh, in_specs=(P(rows, None), P(None, None), P(rows)),
+        out_specs=P(), check_vma=False,
+    )(hidden, wte, labels)

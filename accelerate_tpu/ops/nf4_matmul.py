@@ -1,12 +1,19 @@
 """Fused NF4 dequant-matmul Pallas kernel (staged decode lever).
 
 Role: the reference's headline benchmark is big-model inference, and its 4-bit
-rows run bitsandbytes' fused CUDA dequant-GEMV. Here the default nf4 decode
-path dequantizes inside jit and lets XLA fuse (`utils/quantization.py`); this
-kernel is the escalation if the hardware measurement (`BENCH_INF_QUANT=nf4`
-vs fp16, queued in tools/relay_watch.py) shows dequant dominating decode: it
-reads the PACKED payload (4 bits/weight) straight from HBM and dequantizes in
-VMEM, so a memory-bound matvec moves ~4x fewer bytes than a bf16 weight read.
+rows run bitsandbytes' fused CUDA dequant-GEMV. Here every nf4 decode path
+that exists — `QuantizedModule.apply`, the serving engine's
+``weight_quant="nf4"`` — dequantizes inside jit and lets XLA fuse
+(`utils/quantization.py`); NONE of them calls this kernel. It needs the packed
+payload as a concrete array (the plane repack runs on the host), and inside a
+jitted decode loop the payload is a tracer, so `nf4_matmul` there IS the XLA
+dequant path. The kernel runs only when called with a concrete tensor
+(`tools/bench_nf4_kernel.py`, `chip_smoke.py`). It is the escalation if a chip
+measurement (`BENCH_INF_QUANT=nf4` vs fp16) shows dequant dominating decode:
+it reads the PACKED payload (4 bits/weight) straight from HBM and dequantizes
+in VMEM, so a memory-bound matvec moves ~4x fewer bytes than a bf16 weight
+read. Wiring it into the decode loop means making the packed planes jit
+arguments.
 
 Kernel design (TPU-first):
 - Plane packing: byte (k, j) holds element (k, j) in the high nibble and
@@ -65,8 +72,8 @@ def _kernel(x_ref, packed_ref, scales_ref, o_ref, *, code, bn):
             s_full = jnp.where(col == b, s_cols[:, b : b + 1], s_full)
         return vals * s_full
 
-    wl = dequant(hi, scales_ref[0])
-    wr = dequant(lo, scales_ref[1])
+    wl = dequant(hi, scales_ref[0, 0])
+    wr = dequant(lo, scales_ref[1, 0])
     x = x_ref[...].astype(jnp.float32)
     o_ref[0, ...] += jnp.dot(x, wl, preferred_element_type=jnp.float32)
     o_ref[1, ...] += jnp.dot(x, wr, preferred_element_type=jnp.float32)
@@ -158,16 +165,20 @@ def nf4_matmul(
         interpret = not _on_tpu()
     M = x2.shape[0]
     packed, scales2 = plane_pack(qt)
+    # one [K, bn/64] scale matrix per column tile: Mosaic wants a block's last
+    # dim to be a multiple of 128 or the whole axis, and bn/64 columns out of
+    # P/64 are neither
+    scales_tiled = scales2.reshape(2, K, P // bn, bn // 64).transpose(0, 2, 1, 3)
     out = pl.pallas_call(
         functools.partial(_kernel, code=[float(c) for c in NF4_CODE], bn=bn),
         grid=(P // bn, K // bk),
         in_specs=[
             pl.BlockSpec((M, bk), lambda j, k: (0, k)),
             pl.BlockSpec((bk, bn), lambda j, k: (k, j)),
-            pl.BlockSpec((2, bk, bn // 64), lambda j, k: (0, k, j)),
+            pl.BlockSpec((2, 1, bk, bn // 64), lambda j, k: (0, j, k, 0)),
         ],
         out_specs=pl.BlockSpec((2, M, bn), lambda j, k: (0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((2, M, P), jnp.float32),
         interpret=interpret,
-    )(x2, packed, scales2)
+    )(x2, packed, scales_tiled)
     return jnp.concatenate([out[0], out[1]], axis=-1).astype(x.dtype).reshape(*lead, N)

@@ -148,6 +148,27 @@ def active_batch_axes(mesh: Mesh) -> tuple[str, ...]:
     return tuple(n for n in ("data", "fsdp") if mesh.shape.get(n, 1) > 1)
 
 
+def pallas_shard_axes(batch: int) -> tuple[Mesh, tuple[str, ...] | None] | None:
+    """Where a Pallas call must be wrapped in shard_map: ``(mesh, rows)`` for
+    the live `AcceleratorState` mesh when it spans several devices and the
+    trace is not already inside a shard_map over it, else None. XLA cannot
+    partition a custom call — a bare one inside a multi-device jit fails to
+    lower ("Mosaic kernels cannot be automatically partitioned") — so every
+    kernel call site asks here. ``rows`` are the batch axes a leading dim of
+    ``batch`` splits over, or None when it does not divide them (batch-1
+    eval): the dim then stays whole in every shard, redundant but correct."""
+    from ..state import AcceleratorState
+
+    if "mesh" not in AcceleratorState._shared_state:  # initialized check only:
+        return None  # a bare truthiness test could side-effect-init the singleton
+    mesh = AcceleratorState().mesh
+    if mesh.size <= 1 or inside_shard_map(mesh):
+        return None  # nesting inside a pipeline/ring region would fail
+    axes = active_batch_axes(mesh)
+    divides = axes and batch % math.prod(mesh.shape[a] for a in axes) == 0
+    return mesh, (axes if divides else None)
+
+
 def inside_shard_map(mesh: Mesh) -> bool:
     """True when tracing inside a shard_map region that binds any of this
     mesh's axes — nesting another shard_map over the same mesh there would

@@ -86,6 +86,7 @@ from ..parallel.sharding import (
     shard_params,
 )
 from ..reliability.faults import ALL_SLOTS, active_injector
+from ..utils.environment import device_memory_stats
 from ..utils.quantization import (
     QuantizationConfig,
     QuantizedModule,
@@ -238,8 +239,11 @@ class WeightQuantConfig:
 
     ``mode`` picks the packed format: ``"int8"`` is per-channel absmax
     (`utils/quantization.QuantizationConfig(load_in_8bit=True)`), ``"nf4"``
-    is blockwise 4-bit NormalFloat over ``block_size``-element groups (the
-    `ops/nf4_matmul.py` codebook). Leaves smaller than ``min_weight_size``
+    is blockwise 4-bit NormalFloat over ``block_size``-element groups. Both
+    dequantize through XLA inside the trace: ``"nf4"`` shares the codebook of
+    the Pallas kernel in `ops/nf4_matmul.py` but never runs it (inside a
+    jitted program the payload is a tracer and that kernel needs a concrete
+    one). Leaves smaller than ``min_weight_size``
     elements (embeddings' peers: LayerNorm scales, biases) stay dense — the
     same eligibility rule `quantize_params` applies everywhere else.
 
@@ -527,6 +531,16 @@ class ServingEngine:
                 self._table_sharding = block_table_sharding(
                     self.mesh, slots=self.max_concurrency
                 )
+        if self.paged_attention == "fused":
+            # the kernel holds a row's whole attended span in VMEM; a model it
+            # cannot fit fails HERE with the sizes named — never at the first
+            # decode step, and never by quietly serving through "gather"
+            from ..ops.flash_attention import check_paged_decode_fits
+
+            check_paged_decode_fits(
+                int(cfg.n_positions), cfg.n_head // self._mesh_model,
+                cfg.n_embd // cfg.n_head,
+            )
         # contiguous slot ranges per data replica (the slot dim shards like any
         # leading batch dim: replica i owns rows [i*b/d, (i+1)*b/d)) — 1 when
         # the slot dim is replicated (b % data != 0, or no mesh)
@@ -1829,11 +1843,8 @@ class ServingEngine:
             for k, v in self.prefix_cache.memory_stats().items():
                 stats[f"block_pool/{k}"] = v
         for i, dev in enumerate(jax.local_devices()):
-            try:
-                dm = dev.memory_stats()
-            except Exception:  # backend without stats support
-                continue
-            if not dm:  # CPU returns None / {}
+            dm = device_memory_stats(dev)
+            if dm is None:  # the CPU keeps no stats
                 continue
             for key in ("bytes_in_use", "bytes_limit", "peak_bytes_in_use"):
                 if key in dm:

@@ -151,8 +151,16 @@ def _maybe_init_distributed(initialization_timeout: int | None = None) -> None:
             process_id=int(pid) if pid is not None else None,
             **extra,
         )
-    except (RuntimeError, ValueError) as e:  # already initialized or single-proc
-        logger.debug("jax.distributed.initialize skipped: %s", e)
+    except (RuntimeError, ValueError) as e:
+        # "already initialized" returned above; anything else means the
+        # launcher promised a world this process could not join. Carrying on
+        # as a world of one would run N duplicates that each claim
+        # main-process and overwrite each other's outputs.
+        raise RuntimeError(
+            f"the launcher set a coordinator contract (JAX_COORDINATOR_ADDRESS="
+            f"{coord!r}, num_processes={nproc!r}, JAX_PROCESS_ID={pid!r}) but "
+            f"jax.distributed.initialize failed: {e}"
+        ) from e
 
 
 class PartialState:

@@ -15,6 +15,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from .environment import device_memory_stats
+
 
 def dtype_byte_size(dtype: Any) -> float:
     if hasattr(dtype, "itemsize"):
@@ -93,11 +95,9 @@ def get_max_memory(max_memory: dict | None = None) -> dict[str, int]:
         return dict(max_memory)
     out: dict[str, int] = {}
     for i, dev in enumerate(jax.local_devices()):
-        try:
-            stats = dev.memory_stats()
-            free = stats["bytes_limit"] - stats["bytes_in_use"]
-        except Exception:
-            free = 8 * 1024**3
+        stats = device_memory_stats(dev)
+        # a backend without stats (host CPU devices) gets a nominal 8 GiB
+        free = stats["bytes_limit"] - stats["bytes_in_use"] if stats else 8 * 1024**3
         out[f"device:{i}"] = int(free * 0.9)
     try:
         with open("/proc/meminfo") as f:

@@ -16,8 +16,7 @@ is measured directly: host-blocked time per decode step (the seconds
 and inter-token latency p50/p99 ride along with TTFT/tokens-per-sec.
 
 Both sides run one warm pass first (compiles excluded) and count only the
-tokens requests actually asked for. Prints ONE machine-readable JSON line
-(`tools/bench_sweep.py` consumes it via a BENCH_SCRIPT overlay):
+tokens requests actually asked for. Prints ONE machine-readable JSON line:
 {"metric": "serving_tokens_per_sec", "value", "unit", "vs_baseline", "detail"}
 with vs_baseline = pipelined_tps / lockstep_tps (>1.0 = continuous batching
 wins); detail carries engine_depth1/engine_pipelined/lockstep breakdowns.
@@ -179,6 +178,9 @@ trace's event/drop/malformed counts. Tracing is off (the zero-overhead
 `NULL_TRACER`) unless the knob is set, so the headline numbers are untouched.
 
 Env knobs (defaults saturate an 8-slot engine on the host CPU in ~a minute):
+  BENCH_FORCE_CPU=1        run the labelled "cpu-host" rows on the host CPU;
+                           without it the bench needs a TPU and exits
+                           non-zero when JAX finds none
   BENCH_SERVE_REQUESTS     trace length (default 32; cluster mode: requests
                            PER REPLICA for the weak-scaling row, default 12)
   BENCH_SERVE_CONCURRENCY  engine slots == lockstep batch size (default 8)
@@ -282,6 +284,23 @@ SLO_BATCH = SLOSpec(name="batch")
 
 def _env_int(name: str, default: int) -> int:
     return int(os.environ.get(name, default))
+
+
+def _platform_or_exit(n_devices: int | None = None) -> None:
+    """This bench times a server, so it runs on the TPU or not at all: without
+    a chip it exits non-zero. ``BENCH_FORCE_CPU=1`` asks by name for the
+    "cpu-host" rows (counts and control flow; their seconds are the host's),
+    on ``n_devices`` forced host devices."""
+    from accelerate_tpu.utils.environment import configure_compile_cache, require_tpu
+
+    if os.environ.get("BENCH_FORCE_CPU", "0") == "1":
+        from accelerate_tpu.test_utils.platform import force_cpu_platform
+
+        force_cpu_platform(n_devices)
+    else:
+        require_tpu("benchmarks/bench_serving.py",
+                    rehearse="BENCH_FORCE_CPU=1 (the labelled cpu-host rows)")
+    configure_compile_cache()
 
 
 def _host_platform() -> str:
@@ -1174,7 +1193,7 @@ def main_mesh() -> None:
     ``ServingEngine(mesh=(d, m))`` for every requested shape. One JSON row per
     shape (tokens/sec, ITL p50/p99, per-step collective seconds from the
     blocking all-reduce probe, compile count + per-program compile seconds),
-    then the one summary line `tools/bench_sweep.py` consumes (value = the
+    then one summary line (value = the
     LAST shape's tokens/sec, vs_baseline = last / first — order the shapes so
     the first is the 1x1 reference)."""
     shapes: list[tuple[int, int]] = []
@@ -1184,12 +1203,9 @@ def main_mesh() -> None:
             shapes.append((int(d), int(m)))
     if not shapes:
         raise SystemExit("BENCH_SERVE_MESH set but no DxM shapes parsed")
-    if os.environ.get("JAX_PLATFORMS", "cpu").startswith("cpu"):
-        # mesh shapes need devices; on the host platform multiplex them BEFORE
-        # the backend initializes (the one audited defense — test_utils)
-        from accelerate_tpu.test_utils.platform import force_cpu_platform
-
-        force_cpu_platform(max(d * m for d, m in shapes))
+    # mesh shapes need devices; the cpu-host rows multiplex them BEFORE the
+    # backend initializes
+    _platform_or_exit(max(d * m for d, m in shapes))
 
     from accelerate_tpu.serving import ServingMetrics
 
@@ -1699,6 +1715,7 @@ def main() -> None:
     if os.environ.get("BENCH_SERVE_MESH"):
         main_mesh()
         return
+    _platform_or_exit()
     workload = os.environ.get("BENCH_SERVE_WORKLOAD", "ragged")
     if workload == "prefix":
         main_prefix()

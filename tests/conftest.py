@@ -10,23 +10,17 @@ from accelerate_tpu.test_utils.platform import force_cpu_platform
 
 force_cpu_platform(8)
 
-import os  # noqa: E402
-
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+from accelerate_tpu.utils.environment import configure_compile_cache  # noqa: E402
+
 # Persistent XLA compilation cache: tier-1 wall time is dominated by CPU
 # compiles of tiny test graphs, and the same programs recompile on every
-# pytest invocation. Caching them on disk (outside the repo) makes reruns of
-# an unchanged suite mostly compile-free. Opt out (or redirect) with
-# ACCELERATE_TPU_XLA_CACHE= / ACCELERATE_TPU_XLA_CACHE=/elsewhere.
-_xla_cache = os.environ.get(
-    "ACCELERATE_TPU_XLA_CACHE",
-    os.path.expanduser("~/.cache/accelerate_tpu/xla"),
-)
-if _xla_cache:
-    jax.config.update("jax_compilation_cache_dir", _xla_cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.25)
+# pytest invocation. The one helper places the cache (JAX_COMPILATION_CACHE_DIR
+# if set, else <checkout>/.jax_cache); the threshold makes tiny graphs count.
+configure_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.25)
 
 
 def pytest_configure(config):

@@ -88,8 +88,13 @@ def test_sagemaker_env_translates_to_jax_contract(monkeypatch):
 
     from accelerate_tpu.state import _sagemaker_env_to_contract
 
-    for k in ("JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES", "JAX_PROCESS_ID"):
-        monkeypatch.delenv(k, raising=False)
+    for k in ("JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES", "JAX_PROCESS_ID",
+              "ACCELERATE_TPU_NUM_PROCESSES"):
+        # set-then-delete registers the key with monkeypatch, so what the
+        # function writes into os.environ is undone after the test (a leaked
+        # contract makes every later PartialState() try to join a world)
+        monkeypatch.setenv(k, "")
+        monkeypatch.delenv(k)
     monkeypatch.setenv("ACCELERATE_TPU_USE_SAGEMAKER", "true")
     monkeypatch.setenv("SM_HOSTS", json.dumps(["algo-2", "algo-1"]))
     monkeypatch.setenv("SM_CURRENT_HOST", "algo-2")
@@ -224,16 +229,36 @@ def test_slurm_step_init_failure_fallback_opt_out(monkeypatch):
     st._maybe_init_distributed()  # must not raise
 
 
+def test_launcher_contract_init_failure_raises(monkeypatch):
+    """A launcher-supplied coordinator contract whose initialize fails must
+    not degrade to a world of one (it used to be logged at debug)."""
+    from accelerate_tpu import state as st
+
+    monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "127.0.0.1:1")
+    monkeypatch.setenv("JAX_NUM_PROCESSES", "2")
+    monkeypatch.setenv("JAX_PROCESS_ID", "1")
+    monkeypatch.setattr(st.jax.distributed, "is_initialized", lambda: False)
+
+    def boom(**kw):
+        raise RuntimeError("no coordinator")
+
+    monkeypatch.setattr(st.jax.distributed, "initialize", boom)
+    with pytest.raises(RuntimeError, match="coordinator contract.*no coordinator"):
+        st._maybe_init_distributed()
+    # an already-initialized world stays benign
+    monkeypatch.setattr(st.jax.distributed, "is_initialized", lambda: True)
+    st._maybe_init_distributed()
+
+
 def test_reregistering_deepspeed_plugins_resets_stale_active(monkeypatch):
     """Re-registering under new names must re-point the active plugin at the
     new dict's first entry, not leave deepspeed_plugin silently None."""
     from accelerate_tpu import state as st
     from accelerate_tpu.state import AcceleratorState
 
-    # this jax version lacks jax.distributed.is_initialized (the construction
-    # path probes it); stub it so the test exercises the registry, not the env
-    monkeypatch.setattr(st.jax.distributed, "is_initialized", lambda: True,
-                        raising=False)
+    # the construction path probes jax.distributed.is_initialized; stub it so
+    # the test exercises the registry, not the env
+    monkeypatch.setattr(st.jax.distributed, "is_initialized", lambda: True)
     AcceleratorState._reset_state()
     st = AcceleratorState()
     a, b, c = object(), object(), object()

@@ -1,0 +1,192 @@
+"""Compile the Pallas kernels for a TPU v5e without one.
+
+The CPU suite runs every kernel under the Pallas interpreter, which lowers a
+`pallas_call` to ordinary XLA ops: it can hide a kernel Mosaic rejects (VMEM
+limits, layouts) and a call XLA cannot partition over a mesh. libtpu can
+compile for a chip that is not there (`jax.experimental.topologies`), so this
+file compiles each kernel at gpt2-medium shapes with ``interpret=False`` — on
+one device and inside a four-device jit. It checks that the program builds;
+only a chip run (`chip_smoke.py`) checks what it computes. Marked slow.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+pytestmark = pytest.mark.slow
+
+B, S, H, D = 8, 1024, 16, 64  # gpt2-medium attention at the bench shape
+E, V = 1024, 50257
+BLOCK_TOKENS = 16
+
+
+@pytest.fixture(scope="module")
+def topology():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot build a v5e:2x2 topology here: {type(e).__name__}: {e}")
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels default to interpret mode off-TPU; the host here is a CPU."""
+    from accelerate_tpu.utils import environment
+
+    monkeypatch.setattr(environment, "on_tpu_platform", lambda: True)
+
+
+def _one_device(topology):
+    return NamedSharding(Mesh(np.array(topology.devices[:1]), ("x",)), P())
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _paged_args(heads, sharding_for, quant):
+    bps = S // BLOCK_TOKENS
+    blocks = B * bps
+    pool_dtype = jnp.int8 if quant else jnp.bfloat16
+    args = [
+        _sds((B, heads, D), jnp.bfloat16, sharding_for("q")),
+        _sds((blocks, BLOCK_TOKENS, heads, D), pool_dtype, sharding_for("pool")),
+        _sds((blocks, BLOCK_TOKENS, heads, D), pool_dtype, sharding_for("pool")),
+        _sds((B, bps), jnp.int32, sharding_for("tables")),
+        _sds((B,), jnp.int32, sharding_for("lengths")),
+    ]
+    if quant:
+        args += [_sds((blocks, BLOCK_TOKENS, heads), jnp.float32, sharding_for("scale"))] * 2
+    return args
+
+
+# ------------------------------------------------------------------ one device
+@pytest.mark.parametrize("window", [None, 256])
+def test_flash_fwd_bwd_one_device(topology, window):
+    from accelerate_tpu.ops.flash_attention import flash_attention
+
+    s = _one_device(topology)
+    qkv = [_sds((B, S, H, D), jnp.bfloat16, s)] * 3
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+
+
+def test_fused_ce_fwd_bwd_one_device(topology):
+    from accelerate_tpu.ops.fused_ce import fused_cross_entropy
+
+    s = _one_device(topology)
+    loss = functools.partial(fused_cross_entropy, interpret=False)
+    _compile(
+        jax.grad(loss, argnums=(0, 1)),
+        _sds((B * S, E), jnp.bfloat16, s), _sds((V, E), jnp.bfloat16, s),
+        _sds((B * S,), jnp.int32, s),
+    )
+
+
+@pytest.mark.parametrize("heads", [12, 16, 20])  # small, medium, large
+@pytest.mark.parametrize("quant", [False, True])
+def test_paged_decode_one_device(topology, heads, quant):
+    """gpt2-medium is where the two lane-padded fp32 span buffers reach the
+    default 16 MiB scoped-VMEM limit; the call must ask for what it needs."""
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    s = _one_device(topology)
+
+    def decode(q, k, v, tables, lengths, *scales):
+        k_sp, v_sp = scales if scales else (None, None)
+        return paged_decode_attention(
+            q, k, v, tables, lengths, k_scale_pool=k_sp, v_scale_pool=v_sp, interpret=False
+        )
+
+    _compile(decode, *_paged_args(heads, lambda _: s, quant))
+
+
+@pytest.mark.parametrize("shape", [(1024, 3072), (1024, 4096), (4096, 1024)])  # qkv, up, down
+def test_nf4_matmul_one_device(topology, shape):
+    """The per-tile scale block must be a whole trailing axis: Mosaic rejects
+    a (.., bk, 2) block cut out of a (.., K, N/128) array."""
+    from accelerate_tpu.ops.nf4_matmul import nf4_matmul
+    from accelerate_tpu.utils.quantization import QuantizationConfig, quantize
+
+    weight = np.random.default_rng(0).normal(size=shape).astype(np.float32)
+    qt = quantize(weight, QuantizationConfig(
+        load_in_4bit=True, quant_type="nf4", compute_dtype=jnp.bfloat16))
+    _compile(lambda x: nf4_matmul(x, qt, interpret=False),
+             _sds((B, shape[0]), jnp.bfloat16, _one_device(topology)))
+
+
+# ------------------------------------------------- inside a four-device jit
+@pytest.fixture
+def data_mesh(topology, monkeypatch):
+    """The training mesh over the four chips (data=4), installed as the live
+    `AcceleratorState` mesh the kernel call sites read."""
+    from accelerate_tpu.state import AcceleratorState
+    from accelerate_tpu.utils.constants import MESH_AXIS_NAMES
+
+    shape = tuple(4 if name == "data" else 1 for name in MESH_AXIS_NAMES)
+    mesh = Mesh(np.array(topology.devices).reshape(shape), MESH_AXIS_NAMES)
+    monkeypatch.setitem(AcceleratorState._shared_state, "mesh", mesh)
+    return mesh
+
+
+def test_flash_fwd_bwd_four_devices(data_mesh, compiled_kernels):
+    from accelerate_tpu.ops.attention import attention
+
+    s = NamedSharding(data_mesh, P("data", None, None, None))
+    qkv = [_sds((4 * B, S, H, D), jnp.bfloat16, s)] * 3
+
+    def loss(q, k, v):
+        out = attention(q, k, v, causal=True, implementation="flash")
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+
+
+def test_fused_ce_fwd_bwd_four_devices(data_mesh, compiled_kernels):
+    from accelerate_tpu.ops.fused_ce import fused_cross_entropy
+
+    rows = NamedSharding(data_mesh, P("data"))
+    _compile(
+        jax.grad(fused_cross_entropy, argnums=(0, 1)),
+        _sds((4 * B * S, E), jnp.bfloat16, NamedSharding(data_mesh, P("data", None))),
+        _sds((V, E), jnp.bfloat16, NamedSharding(data_mesh, P())),
+        _sds((4 * B * S,), jnp.int32, rows),
+    )
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_paged_decode_on_serving_mesh(topology, compiled_kernels, quant):
+    """The engine's ``mesh=(2, 2)``: slot rows over data, heads over tensor."""
+    from accelerate_tpu.models.gpt2 import _fused_paged_attention
+    from accelerate_tpu.parallel.mesh import serving_mesh
+    from accelerate_tpu.parallel.sharding import block_table_sharding, kv_cache_sharding
+
+    mesh = serving_mesh(data=2, model=2, devices=list(topology.devices))
+    sharding = kv_cache_sharding(mesh, slots=B, paged=True)
+    named = {
+        "q": NamedSharding(mesh, P("data", "tensor", None)),
+        "pool": sharding.kv,
+        "scale": sharding.scale,
+        "tables": block_table_sharding(mesh, slots=B),
+        "lengths": sharding.index,
+    }
+
+    def decode(q, k, v, tables, lengths, *scales):
+        return _fused_paged_attention(q, k, v, tables, lengths, scales or None, sharding)
+
+    compiled = _compile(decode, *_paged_args(H, named.__getitem__, quant))
+    assert compiled.output_shardings.spec == P("data", "tensor", None)

@@ -1865,7 +1865,7 @@ def main() -> None:
     if os.environ.get("CHAOS_MESH"):
         d, m = os.environ["CHAOS_MESH"].lower().replace(" ", "").split("x")
         mesh = (int(d), int(m))
-        if os.environ.get("JAX_PLATFORMS", "cpu").startswith("cpu"):
+        if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
             # must run before the backend initializes (the import of jax
             # inside run() is what first touches it)
             from accelerate_tpu.test_utils.platform import force_cpu_platform
