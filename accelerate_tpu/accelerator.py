@@ -1287,7 +1287,13 @@ class Accelerator:
                 state_box["count"] += 1
             return loss
 
-        return step
+        def annotated(batch: Any) -> jax.Array:
+            # host time to enqueue one step, on a profile's host plane beside
+            # the loader's `train.input_wait` (`utils/spans.py`)
+            with jax.profiler.TraceAnnotation("train.dispatch"):
+                return step(batch)
+
+        return annotated
 
     # -------------------------------------------------------- pipeline training
     def prepare_pipeline(

@@ -42,6 +42,7 @@ from .utils.operations import (
     find_batch_size,
     recursively_apply,
 )
+from .utils import spans
 from .utils.random import get_rng_key, synchronize_rng_states
 
 logger = logging.getLogger(__name__)
@@ -556,11 +557,21 @@ class DataLoaderShard:
                                 native_unavailable_reason())
             it = _PrefetchIterator(base_it, _mark_last)
             self._live_prefetch_it = it
-            for idx, batch in enumerate(it):
-                if idx < self.skip_batches:
-                    continue
-                self.batches_seen_in_epoch = idx + 1
-                yield self._to_global(batch)
+            feed = enumerate(it)
+            while True:
+                # what one `__next__` costs the step loop: producing the host
+                # batch (skipped ones included) and placing it on the mesh
+                with spans.span("train.input_wait") as wait:
+                    for idx, batch in feed:
+                        if idx >= self.skip_batches:
+                            break
+                    else:
+                        wait.drop()  # exhausted: no batch was waited for
+                        return
+                    wait.attrs["batch"] = idx  # its place in the epoch
+                    self.batches_seen_in_epoch = idx + 1
+                    placed = self._to_global(batch)
+                yield placed
         finally:
             self.gradient_state._remove_dataloader(self)
             self.skip_batches = 0

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import time
 from collections import deque
@@ -76,7 +77,7 @@ from ..models.kv_cache import (
     tree_bytes_by_dtype,
     tree_nbytes,
 )
-from ..parallel.mesh import ParallelismConfig, mesh_axis_size, serving_mesh
+from ..parallel.mesh import ParallelismConfig, serving_mesh
 from ..parallel.sharding import (
     block_table_sharding,
     infer_block_pool_shardings,
@@ -86,6 +87,7 @@ from ..parallel.sharding import (
     shard_params,
 )
 from ..reliability.faults import ALL_SLOTS, active_injector
+from ..utils import spans
 from ..utils.environment import device_memory_stats
 from ..utils.quantization import (
     QuantizationConfig,
@@ -164,8 +166,9 @@ class _Inflight:
     arrays: tuple
     slots: tuple[int, ...]
     gens: tuple[int, ...]
-    # trace pairing handle (serving/trace.py): the EV_DISPATCH sequence number
-    # this entry was stamped with, echoed by its EV_FETCH; -1 when untraced
+    # pairing handle: the sequence number `_dispatch` drew for the program
+    # whose results these are, carried by its `serve.dispatch` span and its
+    # EV_DISPATCH event and echoed by `serve.fetch` / EV_FETCH
     seq: int = -1
     # decode iterations this dispatch ran (tokens_per_sync); the fetched
     # arrays are stacked [tokens, b] when > 1, plain [b] when 1
@@ -187,6 +190,9 @@ class StepTimings:
     ``device_get``; ``deliver_s`` detokenize/retire/SLO accounting net of
     journal writes; ``journal_s`` journal appends incl. fsync; ``telemetry_s``
     the telemetry poll. The phases partition ``total_s`` up to clock jitter.
+    ``total_s``, ``draft_s``, ``dispatch_s``, ``fetch_blocked_s`` and
+    ``telemetry_s`` are the lengths of the step's ``serve.*`` spans in
+    `utils.spans.RING`: one set of stamps feeds both.
     """
 
     schedule_s: float = 0.0
@@ -365,9 +371,7 @@ class ServingEngine:
     replicas, which then decode disjoint slot ranges. Token streams are
     bit-identical to ``mesh=None`` (tests/test_serving_sharded.py proves the
     matrix); the scheduler, pipelining, and all host-side bookkeeping are
-    mesh-oblivious. ``collective_probe_every=N`` times a tiny blocking
-    all-reduce every N steps into ``metrics.collective_s`` (benches only —
-    the block serializes the dispatch pipeline).
+    mesh-oblivious.
 
     ``tracer=`` attaches a `serving.trace.Tracer`: every request lifecycle
     edge and every jitted dispatch/fetch pair is recorded as a span event,
@@ -405,7 +409,6 @@ class ServingEngine:
         metrics: ServingMetrics | None = None,
         mesh: Any = None,
         param_rules: Any = None,
-        collective_probe_every: int = 0,
         journal: Any = None,
         tracer: Any = None,
         telemetry: Any = None,
@@ -707,9 +710,10 @@ class ServingEngine:
         # anomaly detection + flight recorder (serving/anomaly.py): one
         # attribute check per step, NULL_ANOMALY default — zero-overhead off
         self.anomaly = anomaly if anomaly is not None else NULL_ANOMALY
-        # (key, compiled, wall_s) of the most recent jitted dispatch — the
-        # compile-vs-replay flag EV_DISPATCH events carry
-        self._last_dispatch: tuple[str, bool, float] = ("", False, 0.0)
+        # the `serve.dispatch` span of the most recent jitted dispatch: its
+        # `_Inflight` entry takes the sequence number from it, and EV_DISPATCH
+        # its key, compile-vs-replay flag and wall time
+        self._last_dispatch: spans.span | None = None
         # per-step host phase breakdown (docs/observability.md "Latency
         # attribution"): reset at each step() entry, folded into the
         # step_phase_* histograms at step exit
@@ -892,29 +896,7 @@ class ServingEngine:
         # timed (the python call blocks through trace+compile; execution stays
         # async, so the first-call wall time is compile-dominated) under a
         # ``kind[pb{N}b{M}]@mesh{D}x{T}`` key — see ServingMetrics.record_compile
-        self._compile_seen: set[str] = set()
-        # optional per-step collective probe: a tiny all-reduce over every
-        # non-trivial mesh axis, dispatched and BLOCKED right after the decode
-        # dispatch — an upper-bound measure of the mesh's per-step collective /
-        # straggler latency. Blocking serializes the dispatch pipeline, so it
-        # is opt-in (benches turn it on; production leaves it 0).
-        self.collective_probe_every = int(collective_probe_every)
-        self._probe_fn = None
-        self._probe_x = None
-        if self.mesh is not None and self.collective_probe_every > 0:
-            axes = tuple(n for n in ("data", "tensor") if self.mesh.shape[n] > 1)
-            if axes:
-                n = mesh_axis_size(self.mesh, *axes)
-                self._probe_x = jax.device_put(
-                    jnp.arange(n, dtype=jnp.float32),
-                    NamedSharding(self.mesh, PartitionSpec(axes)),
-                )
-                self._probe_fn = jax.jit(
-                    jnp.sum,
-                    out_shardings=NamedSharding(self.mesh, PartitionSpec()),
-                )
-                # warm up now so the first observation is a collective, not a compile
-                jax.block_until_ready(self._probe_fn(self._probe_x))
+        self._compile_seen: dict[str, str] = {}  # compile key -> program kind
 
     # ------------------------------------------------------------------- mesh
     @staticmethod
@@ -946,13 +928,11 @@ class ServingEngine:
         return f"{kind}@{tag}" if pb is None else f"{kind}[pb{pb}b{bb}]@{tag}"
 
     def _dispatch(self, key: str, fn, *args):
-        """Call a jitted serving program, recording the first dispatch per key
-        as one compile (count + wall seconds) in the metrics. With a tracer
-        attached the call is additionally timed for the EV_DISPATCH
-        compile-vs-replay flag and (optionally) wrapped in a
-        ``jax.profiler.TraceAnnotation`` so the host span lines up with
-        device traces; with the default NULL_TRACER a replay dispatch is the
-        bare ``fn(*args)`` it always was."""
+        """Call a jitted serving program inside a ``serve.dispatch`` span
+        (host time to enqueue; the program's sequence number, kind, compile
+        key and compile-vs-replay flag ride on it), recording the first
+        dispatch per key as one compile (count + wall seconds) in the
+        metrics."""
         injector = active_injector()
         if injector is not None:
             # the serving.dispatch fault point: an active injector may wedge
@@ -960,41 +940,43 @@ class ServingEngine:
             # exactly what the supervisor's watchdog/restart ladder is proven
             # against. Production cost stays the one active_injector() load.
             injector.dispatch_faults()
-        compiled = key not in self._compile_seen
-        t0 = time.perf_counter()
-        if not compiled and not self.tracer.enabled:
+        kind = self._compile_seen.get(key)
+        compiled = kind is None
+        if compiled:
+            kind = key.partition("@")[0].partition("[")[0]
+        with spans.span("serve.dispatch", seq=spans.next_seq(), kind=kind,
+                        key=key, compiled=compiled) as sp:
             out = fn(*args)
-            self._timings.dispatch_s += time.perf_counter() - t0
-            return out
-        with self.tracer.annotation(key):
-            out = fn(*args)
-        dt = time.perf_counter() - t0
+        dt = sp.end - sp.start
         self._timings.dispatch_s += dt
         if compiled:
-            self._compile_seen.add(key)
+            self._compile_seen[key] = kind
             self.metrics.record_compile(key, dt)
-        self._last_dispatch = (key, compiled, dt)
+        self._last_dispatch = sp
         return out
 
     def _trace_dispatch(self, entry: _Inflight, what: str, **extra) -> None:
-        """Stamp a just-enqueued `_Inflight` with a dispatch sequence number
-        and emit its EV_DISPATCH span: which jitted program ran (compile or
-        replay), the pipeline depth it joined at, and every (slot, rid, gen)
-        riding it — the handle `trace.validate` balances against EV_FETCH.
-        ``extra`` attrs ride along verbatim (e.g. ``drafted`` on spec)."""
+        """Stamp a just-enqueued `_Inflight` with its program's dispatch
+        sequence number and emit its EV_DISPATCH event, built from the
+        program's ``serve.dispatch`` span: which jitted program ran (compile
+        or replay), the pipeline depth it joined at, and every (slot, rid,
+        gen) riding it — the handle `trace.validate` balances against
+        EV_FETCH. ``extra`` attrs ride along verbatim (e.g. ``drafted`` on
+        spec)."""
+        sp = self._last_dispatch
+        ran = sp.attrs
+        entry.seq = ran["seq"]
         tr = self.tracer
         if not tr.enabled:
             return
-        entry.seq = tr.next_seq()
-        key, compiled, dt = self._last_dispatch
         reqs = tuple(
             (int(slot), self._slot_req[slot].request_id, int(gen))
             for slot, gen in zip(entry.slots, entry.gens)
             if self._active[slot] and self._slot_req[slot] is not None
             and self._slot_gen[slot] == gen
         )
-        tr.emit(EV_DISPATCH, None, seq=entry.seq, what=what, key=key,
-                compiled=compiled, dispatch_s=round(dt, 6),
+        tr.emit(EV_DISPATCH, None, seq=entry.seq, what=what, key=ran["key"],
+                compiled=ran["compiled"], dispatch_s=round(sp.end - sp.start, 6),
                 depth=len(self._inflight), step=self._step_count, reqs=reqs,
                 tokens=entry.tokens, **extra)
 
@@ -1698,6 +1680,7 @@ class ServingEngine:
             request_id=request.request_id, prompt_len=plen,
             tokens=list(rec.tokens), finish_reason="",
             arrival_time=request.arrival_time,
+            token_times=list(rec.token_times),
         )
         out.first_token_time = rec.first_token_time
         self._slot_out[slot] = out
@@ -1980,10 +1963,26 @@ class ServingEngine:
         slot, fetch results lagging by up to ``pipeline_depth`` dispatches,
         and return the requests whose completion was OBSERVED during this
         call (at depth > 1 a finish surfaces when its fetch lands, up to
-        ``pipeline_depth - 1`` calls after the device produced it)."""
+        ``pipeline_depth - 1`` calls after the device produced it).
+
+        The call is one ``serve.step`` span in `utils.spans.RING`, numbered
+        with the count `ServingMetrics.step_total_s` reports once the step is
+        observed; its dispatches, fetches, draft, journal appends and
+        telemetry poll are spans that name it as their parent."""
         tm = self._timings
         tm.reset()
-        t_start = time.perf_counter()
+        with spans.span("serve.step", is_step=True,
+                        step=self.metrics.step_total_s.count + 1) as sp:
+            finished = self._step(sp.start, tm)
+        tm.total_s = sp.end - sp.start
+        self.metrics.observe_step_phases(tm)
+        self._last_step_timings = tm.as_dict()
+        if self.anomaly.enabled:
+            self.anomaly.observe(self)
+        return finished
+
+    def _step(self, t_start: float, tm: StepTimings) -> list[RequestOutput]:
+        """The body of `step`, entered at ``t_start``."""
         journal = self.journal
         j_start = journal.append_s if journal is not None else 0.0
         finished: list[RequestOutput] = []
@@ -2019,9 +2018,9 @@ class ServingEngine:
                 # host drafting happens at dispatch time, from the host's
                 # (possibly pipeline-lagged) view of each slot's tokens —
                 # staleness costs acceptance only, verification is exact
-                t_draft = time.perf_counter()
-                drafts = jnp.asarray(self._propose_drafts())
-                tm.draft_s = time.perf_counter() - t_draft
+                with spans.span("serve.draft") as sp:
+                    drafts = jnp.asarray(self._propose_drafts())
+                tm.draft_s = sp.end - sp.start
                 step_args += (drafts,)
             if self.paged:
                 # tables ride as data (not donated): decode reads through
@@ -2074,11 +2073,6 @@ class ServingEngine:
                                      **extra)
             else:
                 self._trace_dispatch(entry, "step", **extra)
-            if (self._probe_fn is not None
-                    and self._step_count % self.collective_probe_every == 0):
-                t0 = time.perf_counter()
-                jax.block_until_ready(self._probe_fn(self._probe_x))
-                self.metrics.collective_s.observe(time.perf_counter() - t0)
             self._drain_to(self.pipeline_depth - 1, finished)
         if not self._active.any():
             # nothing left to overlap with — flush the lagged tail so every
@@ -2088,16 +2082,11 @@ class ServingEngine:
                 and self._step_count % self.metrics_log_every == 0):
             self.metrics.log_to(self.tracker, step=self._step_count)
         if self.telemetry.enabled:
-            t_tel = time.perf_counter()
-            self.telemetry.poll(self)
-            tm.telemetry_s = time.perf_counter() - t_tel
+            with spans.span("serve.telemetry") as sp:
+                self.telemetry.poll(self)
+            tm.telemetry_s = sp.end - sp.start
         tm.journal_s = ((journal.append_s - j_start)
                         if journal is not None else 0.0)
-        tm.total_s = time.perf_counter() - t_start
-        self.metrics.observe_step_phases(tm)
-        self._last_step_timings = tm.as_dict()
-        if self.anomaly.enabled:
-            self.anomaly.observe(self)
         return finished
 
     def run(self, requests: Iterable[Request], max_steps: int | None = None
@@ -2186,6 +2175,7 @@ class ServingEngine:
                 return RequestOutput(
                     request_id=request_id, prompt_len=len(rec.request.prompt),
                     tokens=list(rec.tokens), finish_reason=FINISH_ABORTED,
+                    token_times=list(rec.token_times),
                     arrival_time=rec.request.arrival_time, finish_time=now,
                 )
         for slot, req in enumerate(self._slot_req):
@@ -2278,6 +2268,7 @@ class ServingEngine:
                 aborted.append(RequestOutput(
                     request_id=rid, prompt_len=len(rec.request.prompt),
                     tokens=list(rec.tokens), finish_reason=reason,
+                    token_times=list(rec.token_times),
                     arrival_time=rec.request.arrival_time, finish_time=now,
                 ))
         for slot in np.flatnonzero(self._active):
@@ -2607,13 +2598,15 @@ class ServingEngine:
         entry = self._inflight.popleft()
         tm = self._timings
         journal = self.journal
-        blocked_t = time.perf_counter()
-        fetched = jax.device_get(entry.arrays)
-        blocked = time.perf_counter() - blocked_t
+        # the span ends when this program's result is on the host: `now`,
+        # the delivery stamp of every token the entry brings
+        with spans.span("serve.fetch", seq=entry.seq, kind=entry.kind) as sp:
+            fetched = jax.device_get(entry.arrays)
+        blocked = sp.end - sp.start
         tm.fetch_blocked_s += blocked
         self.metrics.host_blocked_s.observe(blocked)
         j0 = journal.append_s if journal is not None else 0.0
-        now = time.perf_counter()
+        now = sp.end
         if entry.kind == "admit":
             self._process_admit(entry, fetched, now, finished)
         elif entry.kind == "spec":
@@ -2646,6 +2639,7 @@ class ServingEngine:
             out = self._slot_out[slot]
             request = self._slot_req[slot]
             out.first_token_time = now
+            out.token_times.append(now)
             if request.arrival_time is not None:
                 ttft = max(0.0, now - request.arrival_time)
                 self.metrics.ttft_s.observe(ttft)
@@ -2714,6 +2708,7 @@ class ServingEngine:
                     continue
                 out = self._slot_out[slot]
                 out.tokens.append(token)
+                out.token_times.append(now)
                 appended += 1
                 self.metrics.tokens_generated.inc()
                 gap = gaps.get(slot, now - self._slot_last_token_t[slot])
@@ -2811,6 +2806,7 @@ class ServingEngine:
                     continue
                 out = self._slot_out[slot]
                 out.tokens.append(token)
+                out.token_times.append(now)
                 appended += 1
                 self.metrics.tokens_generated.inc()
                 gap = gaps.get(slot, now - self._slot_last_token_t[slot])
@@ -3220,6 +3216,7 @@ class ServingEngine:
                 # decode appends from token k+1
                 tokens=list(request.resume_tokens), finish_reason="",
                 arrival_time=request.arrival_time,
+                token_times=[math.nan] * len(request.resume_tokens),
             )
             # the recovered prefix came FROM the journal/snapshot — only
             # tokens past it need (re-)journaling

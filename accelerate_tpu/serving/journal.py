@@ -42,6 +42,8 @@ import zlib
 from pathlib import Path
 from typing import Any
 
+from ..utils import spans
+
 MAGIC = b"ATSJRNL1"
 _FRAME = struct.Struct("<II")  # payload length, crc32(payload)
 # sanity bound: a frame longer than this is garbage, not a record (the
@@ -195,17 +197,17 @@ class RequestJournal:
 
     # ------------------------------------------------------------- appending
     def _append(self, rec: dict[str, Any]) -> None:
-        t0 = time.perf_counter()
-        rec.setdefault("ts", time.time())
-        payload = json.dumps(rec, separators=(",", ":")).encode()
-        frame = _FRAME.pack(len(payload), zlib.crc32(payload)) + payload
-        self._f.write(frame)
-        self._f.flush()
-        if self.fsync == FSYNC_ALWAYS or (
-            self.fsync == FSYNC_ACCEPT and rec["t"] in _DURABLE_TYPES
-        ):
-            os.fsync(self._f.fileno())
-        self.append_s += time.perf_counter() - t0
+        with spans.span("serve.journal", record=rec["t"]) as sp:
+            rec.setdefault("ts", time.time())
+            payload = json.dumps(rec, separators=(",", ":")).encode()
+            frame = _FRAME.pack(len(payload), zlib.crc32(payload)) + payload
+            self._f.write(frame)
+            self._f.flush()
+            if self.fsync == FSYNC_ALWAYS or (
+                self.fsync == FSYNC_ACCEPT and rec["t"] in _DURABLE_TYPES
+            ):
+                os.fsync(self._f.fileno())
+        self.append_s += sp.end - sp.start
         self.bytes_written += len(frame)
         self._size += len(frame)
         if self.metrics is not None:

@@ -256,6 +256,7 @@ class HibernatedRequest:
 
     request: Any
     tokens: list[int]
+    token_times: list[float]  # `RequestOutput.token_times`, one per token
     blocks: HostBlocks
     n_content: int            # leading table blocks the host copy covers
     first_token_time: float | None
@@ -590,7 +591,8 @@ class KVTier:
         hb = self._gather(ids)
         wall = max(time.perf_counter() - t0, 1e-9)
         rec = HibernatedRequest(
-            request=request, tokens=list(out.tokens), blocks=hb,
+            request=request, tokens=list(out.tokens),
+            token_times=list(out.token_times), blocks=hb,
             n_content=n_content, first_token_time=out.first_token_time,
             hit=bool(eng._slot_hit[slot]), t_hibernated=self.clock(),
         )

@@ -198,14 +198,10 @@ class ServingMetrics:
         self.supervisor_brownouts = Counter()
         self.supervisor_brownout_active = 0
         self.supervisor_time_in_brownout_s = 0.0
-        # mesh-sharded serving telemetry (engine ``mesh=``): per-step wall
-        # seconds of the cross-device sync probe (a tiny jitted all-reduce
-        # over every mesh axis, dispatched+blocked right after the decode
-        # dispatch — an upper-bound measure of per-step collective/straggler
-        # latency the mesh adds), and per-replica slot occupancy (one
-        # observation per data-axis replica per step, so imbalance between
-        # the disjoint slot ranges is visible as p50-vs-min spread)
-        self.collective_s = Histogram()
+        # mesh-sharded serving telemetry (engine ``mesh=``): per-replica slot
+        # occupancy (one observation per data-axis replica per step, so
+        # imbalance between the disjoint slot ranges is visible as
+        # p50-vs-min spread)
         self.replica_occupancy = Histogram()
         # compile telemetry: every first dispatch of a jitted serving program
         # — decode step, plain/cached admission per (prompt_bucket,
@@ -481,7 +477,6 @@ class ServingMetrics:
         for p, n in sorted(self.class_shed.items()):
             out[f"serving/class/{p}/shed"] = n
         for name, hist in (
-            ("collective_s", self.collective_s),
             ("replica_occupancy", self.replica_occupancy),
             ("compile_s", self.compile_s),
             ("ttft_s", self.ttft_s),

@@ -142,7 +142,18 @@ class Request:
 class RequestOutput:
     """Tokens generated for one request, with host-clock latency marks
     (`metrics.ServingMetrics` aggregates these into TTFT / inter-token
-    histograms)."""
+    histograms).
+
+    ``token_times[i]`` is the ``time.perf_counter()`` stamp at which
+    ``tokens[i]`` reached the host: the end of the ``serve.fetch`` span that
+    brought it, so the tokens of one dispatch (``tokens_per_sync``,
+    speculation) share a stamp. ``token_times[0] == first_token_time``, and
+    for a request that ended on a token (`FINISH_EOS`, `FINISH_LENGTH`)
+    ``token_times[-1] == finish_time``. Tokens that this process did not
+    deliver carry ``nan``: the prefix a stream re-admitted through
+    `Request.resume_tokens` starts from (crash recovery, replica migration, a
+    hibernated stream woken by re-prefill). An output rebuilt from a journal
+    alone has no stamps at all (``token_times == []``)."""
 
     request_id: int
     prompt_len: int
@@ -151,6 +162,7 @@ class RequestOutput:
     arrival_time: float | None = None
     first_token_time: float | None = None
     finish_time: float | None = None
+    token_times: list[float] = field(default_factory=list)
 
 
 @dataclass(frozen=True)

@@ -37,11 +37,10 @@ stream rides along under the ``accelerateTpuTrace`` key (unknown top-level
 keys are ignored by trace viewers) so `tools/trace_report.py` can re-validate
 and summarize a trace file without the live tracer.
 
-With ``annotate=True`` every jitted dispatch is additionally wrapped in a
-``jax.profiler.TraceAnnotation``, so on a real TPU run (with
-``jax.profiler.trace`` active) these host spans line up with device traces
-in the same Perfetto UI. The import is lazy and failure-tolerant: tracing
-never *requires* the profiler.
+Host spans that line up with a device profile are not this module's: the
+engine's always-on ``serve.*`` spans (`utils/spans.py`) open a
+``jax.profiler.TraceAnnotation`` each, and dispatch sequence numbers are drawn
+from that module's one counter, so an exported trace and the span ring pair up.
 """
 
 from __future__ import annotations
@@ -50,10 +49,11 @@ import json
 import math
 import time
 from collections import deque
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Callable, Iterable
+
+from ..utils import spans
 
 # ----------------------------------------------------------------- event kinds
 # Request lifecycle edges (``rid`` is set):
@@ -129,9 +129,6 @@ class NullTracer:
     def events(self) -> list[TraceEvent]:
         return []
 
-    def annotation(self, name: str):
-        return nullcontext()
-
     def export(self, path: str | Path) -> dict[str, Any]:
         raise RuntimeError("cannot export from the disabled NullTracer; "
                            "pass a serving.Tracer to the engine")
@@ -144,9 +141,7 @@ class Tracer:
     """Bounded, deterministic event recorder.
 
     ``capacity`` caps the ring buffer (oldest events drop first, counted in
-    ``dropped``); ``clock`` must be monotonic (injectable for tests);
-    ``annotate=True`` wraps engine dispatches in
-    ``jax.profiler.TraceAnnotation`` so host spans appear in device profiles.
+    ``dropped``); ``clock`` must be monotonic (injectable for tests).
     """
 
     enabled = True
@@ -156,7 +151,6 @@ class Tracer:
         capacity: int = 1 << 16,
         *,
         clock: Callable[[], float] = time.perf_counter,
-        annotate: bool = False,
     ):
         if capacity < 1:
             raise ValueError("tracer capacity must be >= 1")
@@ -164,9 +158,6 @@ class Tracer:
         self._clock = clock
         self._events: deque[TraceEvent] = deque()
         self.dropped = 0
-        self.annotate = bool(annotate)
-        self._annotation_cls = None
-        self._seq = 0
 
     # ------------------------------------------------------------- recording
     def emit(self, kind: str, rid: int | None = None, **data: Any) -> None:
@@ -177,10 +168,10 @@ class Tracer:
 
     def next_seq(self) -> int:
         """Monotonic dispatch sequence number; pairs EV_DISPATCH with the
-        EV_FETCH that later drains it."""
-        seq = self._seq
-        self._seq += 1
-        return seq
+        EV_FETCH that later drains it. Drawn from the process-wide counter
+        the engine numbers its ``serve.dispatch`` spans from
+        (`utils.spans.next_seq`)."""
+        return spans.next_seq()
 
     def events(self) -> list[TraceEvent]:
         return list(self._events)
@@ -188,24 +179,6 @@ class Tracer:
     def clear(self) -> None:
         self._events.clear()
         self.dropped = 0
-
-    # ------------------------------------------- device-profile interleaving
-    def annotation(self, name: str):
-        """A context manager wrapping one jitted dispatch. With
-        ``annotate=False`` (default) this is a shared ``nullcontext``; with
-        ``annotate=True`` it is a ``jax.profiler.TraceAnnotation`` so the
-        host-side span shows up alongside device traces when a
-        ``jax.profiler.trace`` capture is active."""
-        if not self.annotate:
-            return nullcontext()
-        if self._annotation_cls is None:
-            try:
-                from jax.profiler import TraceAnnotation
-            except Exception:  # profiler unavailable: degrade, don't fail
-                self.annotate = False
-                return nullcontext()
-            self._annotation_cls = TraceAnnotation
-        return self._annotation_cls(name)
 
     # -------------------------------------------------------------- analysis
     def validate(self) -> dict[str, Any]:

@@ -231,10 +231,9 @@ Env knobs (defaults saturate an 8-slot engine on the host CPU in ~a minute):
                            shapes, e.g. "1x1,2x1,1x2,2x2" — the ragged trace
                            runs once per shape through `ServingEngine(mesh=...)`
                            and each shape prints its own machine-readable row
-                           (tokens/sec, ITL p50/p99, per-step collective
-                           seconds, compile stats) before the final summary
+                           (tokens/sec, ITL p50/p99, compile stats) before
+                           the final summary
                            line; on CPU the needed virtual devices are forced
-  BENCH_SERVE_PROBE_EVERY  mesh mode: collective-probe period in steps (1)
   BENCH_SERVE_TRACE        path: export the pipelined timed run's trace-event
                            JSON here (default: tracing off entirely)
   BENCH_SERVE_TELEMETRY    path: attach a `serving.telemetry.TelemetryExporter`
@@ -1191,9 +1190,8 @@ def main_cluster() -> None:
 def main_mesh() -> None:
     """Per-mesh-shape serving rows: the SAME ragged trace through
     ``ServingEngine(mesh=(d, m))`` for every requested shape. One JSON row per
-    shape (tokens/sec, ITL p50/p99, per-step collective seconds from the
-    blocking all-reduce probe, compile count + per-program compile seconds),
-    then one summary line (value = the
+    shape (tokens/sec, ITL p50/p99, compile count + per-program compile
+    seconds), then one summary line (value = the
     LAST shape's tokens/sec, vs_baseline = last / first — order the shapes so
     the first is the 1x1 reference)."""
     shapes: list[tuple[int, int]] = []
@@ -1215,7 +1213,6 @@ def main_mesh() -> None:
     seed = _env_int("BENCH_SERVE_SEED", 0)
     depth = _env_int("BENCH_SERVE_DEPTH", 2)
     admit = _env_int("BENCH_SERVE_ADMIT", 4)
-    probe_every = _env_int("BENCH_SERVE_PROBE_EVERY", 1)
 
     cfg = GPT2Config(vocab_size=2048, n_positions=128, n_embd=512, n_layer=6,
                      n_head=8, dtype=jnp.float32, param_dtype=jnp.float32)
@@ -1229,15 +1226,12 @@ def main_mesh() -> None:
             module, params, max_concurrency=concurrency,
             prompt_buckets=BUCKETS, max_queue=len(trace) + 1,
             pipeline_depth=depth, admit_batch=admit, mesh=(d, m),
-            collective_probe_every=probe_every,
         )
         _run_engine(engine, trace)  # warm pass: every compile lands here
         compiles = dict(engine.metrics.compiles)
         compile_count = engine.metrics.compile_count.value
         engine.metrics = ServingMetrics()  # timed pass starts clean
         tps, dt, detail = _run_engine(engine, trace)
-        mm = engine.metrics
-        steps = max(mm.steps.value, 1)
         row = {
             "row": "serving_mesh",
             "mesh": f"{d}x{m}",
@@ -1245,12 +1239,6 @@ def main_mesh() -> None:
             "wall_s": round(dt, 3),
             "itl_p50_s": detail["itl_p50_s"],
             "itl_p99_s": detail["itl_p99_s"],
-            # per-step cost of the cross-device sync probe (upper bound on the
-            # mesh's per-step collective/straggler latency; 0.0 when probing
-            # is off or the mesh is 1x1 — no non-trivial axis to reduce over)
-            "collective_per_step_s": round(mm.collective_s.sum / steps, 6),
-            "collective_p50_s": round(mm.collective_s.quantile(0.5), 6),
-            "collective_p99_s": round(mm.collective_s.quantile(0.99), 6),
             "compile_count": compile_count,
             "compile_s": compiles,
             "ttft_p50_s": detail["ttft_p50_s"],
@@ -1275,7 +1263,6 @@ def main_mesh() -> None:
             "poisson_rate": rate,
             "pipeline_depth": depth,
             "admit_batch": admit,
-            "collective_probe_every": probe_every,
             "shapes": rows,
         },
     }), flush=True)
