@@ -58,10 +58,10 @@ class GPT2Config:
     # then be a [b] vector too.
     kv_cache_per_slot: bool = False
     # paged KV: decode KV lives in a shared [kv_num_blocks, kv_block_tokens,
-    # ...] block pool instead of per-slot rows, and each row attends through
-    # its block table (models/kv_cache.paged_decode_update — the serving
-    # engine's paged_kv mode, docs/serving.md "Paged KV"). Implies the
-    # per-slot write-cursor semantics; block_tables must be threaded into
+    # kv_heads * head_dim] block pool instead of per-slot rows, and each row
+    # attends through its block table (models/kv_cache.paged_decode_update —
+    # the serving engine's paged_kv mode, docs/serving.md "Paged KV"). Implies
+    # the per-slot write-cursor semantics; block_tables must be threaded into
     # __call__ on every decode step.
     kv_cache_paged: bool = False
     kv_num_blocks: int = 0
@@ -118,7 +118,9 @@ def _fused_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale_pools
     XLA cannot partition a Pallas call (a bare one inside a multi-device jit
     fails to lower), and the kernel is row- and head-local: slot rows split
     over the mesh's data axis, heads over its model axis, the block pool is
-    whole on the block dim. ``sharding`` is the engine's paged
+    whole on the block dim and split on its folded ``kv_heads * head_dim`` dim
+    (a shard is whole heads: heads are contiguous runs of ``head_dim``, and the
+    model axis divides them). ``sharding`` is the engine's paged
     `KVCacheSharding` — the layouts the arrays already carry — so the
     shard_map moves nothing."""
     from ..ops.flash_attention import paged_decode_attention

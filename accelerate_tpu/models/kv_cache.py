@@ -224,6 +224,10 @@ def _paged_frontier_write(
     segment can never write into blocks the row's reservation does not own.
     """
     b, s = news[0].shape[:2]
+    # K/V rows arrive [b, s, kv_heads, head_dim]; the pool keeps the two merged
+    # (`_paged_pool_step`): fold the few KB of new rows, never the pool
+    news = tuple(new.reshape((b, s) + pool.shape[2:])
+                 for pool, new in zip(pools, news))
     if write_len is None:
         bids = block_tables[jnp.arange(b), idx // block_tokens]  # [b]
         bids = jnp.where(mask, bids, num_blocks)  # frozen rows: dropped write
@@ -258,7 +262,16 @@ def _paged_pool_step(
 ) -> tuple[tuple[jax.Array, ...], jax.Array, bool]:
     """Shared body of `paged_decode_update` / `paged_decode_write`: create the
     pool variables (int8 payload + fp32 scale planes when quantized), run the
-    append-at-frontier write, pin shardings, commit. Returns
+    append-at-frontier write, pin shardings, commit.
+
+    The K/V leaves are ``[num_blocks, block_tokens, kv_heads * head_dim]``,
+    heads folded into the last dim: a TPU tiles an array's two trailing dims
+    (bf16: 16 x 128), so ``block_tokens x (kv_heads*head_dim)`` is compact as
+    plain row-major and the scatter, the Pallas kernel's DMA and the donated
+    buffer all address the same bytes. Trailing ``kv_heads, head_dim`` would
+    pad (20 x 64 to 32 x 128, 3.2x), and XLA then keeps the pool block-minor
+    at each program's boundary and rewrites all of it on the way in and out.
+    Returns
     ``(pool_leaves, write_index, is_init)`` where ``pool_leaves`` is
     ``(k_pool, v_pool)`` at full precision or
     ``(k_pool, v_pool, k_scale_pool, v_scale_pool)`` under int8."""
@@ -271,9 +284,9 @@ def _paged_pool_step(
     store_dtype = jnp.int8 if quant else k.dtype
     is_init = mod.has_variable("cache", "cached_key")
     cached_k = mod.variable("cache", "cached_key", jnp.zeros,
-                            (num_blocks, block_tokens, kv_heads, head_dim), store_dtype)
+                            (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
     cached_v = mod.variable("cache", "cached_value", jnp.zeros,
-                            (num_blocks, block_tokens, kv_heads, head_dim), store_dtype)
+                            (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
     if quant:
         k_scale = mod.variable("cache", "key_scale", jnp.zeros,
                                (num_blocks, block_tokens, kv_heads), jnp.float32)
@@ -335,11 +348,13 @@ def paged_decode_update(
     sharding: Any = None,  # KVCacheSharding with pool kv / scale / index / gathered
 ) -> tuple[jax.Array, jax.Array, jax.Array, bool]:
     """Paged variant of `decode_cache_update`: the cache collection holds ONE
-    shared ``[num_blocks, block_tokens, ...]`` block pool (per layer) plus the
-    per-slot ``[b]`` write cursor, and each row's KV lives wherever its block
-    table says. Returns ``(k_all, v_all, write_index, is_init)`` exactly like
-    the slot-pool path, with ``k_all``/``v_all`` the gathered
-    ``[b, blocks_per_slot * block_tokens, ...]`` attended view.
+    shared ``[num_blocks, block_tokens, kv_heads * head_dim]`` block pool (per
+    layer; heads folded into the last dim so the stored layout tiles, see
+    `_paged_pool_step`) plus the per-slot ``[b]`` write cursor, and each row's
+    KV lives wherever its block table says. Returns ``(k_all, v_all,
+    write_index, is_init)`` exactly like the slot-pool path, with
+    ``k_all``/``v_all`` the gathered ``[b, blocks_per_slot * block_tokens,
+    kv_heads, head_dim]`` attended view (heads unfolded after the gather).
 
     Append-at-frontier write: row ``i``'s new entry lands in pool block
     ``block_tables[i, idx[i] // block_tokens]`` at offset
@@ -353,7 +368,7 @@ def paged_decode_update(
     cannot perturb a stream (the parity bar of `docs/serving.md`).
 
     ``kv_cache_dtype=int8`` stores the pool quantized: the int8 payload rides
-    the usual ``[num_blocks, block_tokens, kv_heads, head_dim]`` leaves and
+    the same ``[num_blocks, block_tokens, kv_heads * head_dim]`` leaves and
     the fp32 absmax scales ride sibling ``key_scale``/``value_scale`` pool
     leaves of shape ``[num_blocks, block_tokens, kv_heads]`` — per-block
     planes addressed through the SAME block table, mirroring the slot path's
@@ -375,16 +390,18 @@ def paged_decode_update(
     blocks_per_slot = block_tables.shape[1]
     span = blocks_per_slot * block_tokens
 
-    def _view(pool):
-        return pool[block_tables].reshape((b, span) + pool.shape[2:])
+    def _view(pool, *tail):
+        # heads unfold on the gathered [b, span, ...] copy, not on the pool
+        return pool[block_tables].reshape((b, span) + tail)
 
     if kv_cache_dtype is not None:
         new_k, new_v, new_ks, new_vs = new_pools
-        k_all = _dq(_view(new_k), _view(new_ks), k.dtype)
-        v_all = _dq(_view(new_v), _view(new_vs), v.dtype)
+        k_all = _dq(_view(new_k, kv_heads, head_dim), _view(new_ks, kv_heads), k.dtype)
+        v_all = _dq(_view(new_v, kv_heads, head_dim), _view(new_vs, kv_heads), v.dtype)
     else:
         new_k, new_v = new_pools
-        k_all, v_all = _view(new_k), _view(new_v)
+        k_all = _view(new_k, kv_heads, head_dim)
+        v_all = _view(new_v, kv_heads, head_dim)
     if sharding is not None and getattr(sharding, "gathered", None) is not None:
         k_all = jax.lax.with_sharding_constraint(k_all, sharding.gathered)
         v_all = jax.lax.with_sharding_constraint(v_all, sharding.gathered)
@@ -406,8 +423,9 @@ def paged_decode_write(
     """Write-only variant of `paged_decode_update` for the fused attention
     path: identical append-at-frontier write and cursor semantics, but returns
     the UPDATED POOL leaves — ``(k_pool, v_pool, write_index, is_init,
-    scale_pools)`` with the pool still ``[num_blocks, block_tokens, ...]`` —
-    instead of gathering the contiguous ``[b, span, ...]`` attended view. The
+    scale_pools)`` with the pool still ``[num_blocks, block_tokens,
+    kv_heads * head_dim]``, updated in place under donation — instead of
+    gathering the contiguous ``[b, span, ...]`` attended view. The
     Pallas kernel (`ops.flash_attention.paged_decode_attention`) then reads
     the blocks in place through the block table, so no per-layer per-step
     gather copy is ever materialized. Frozen rows (``write_mask`` False) still
@@ -591,6 +609,7 @@ def gather_block_rows(
     block_tables: jax.Array,  # [nb, blocks_per_row] int32 pool block ids
     cache_index: jax.Array,  # [nb] int32 resume index (the cached prefix length)
     shardings: Any = None,  # congruent NamedShardings for the assembled rows
+    like: Any = None,  # congruent [1, n_positions, ...] row shapes to unfold into
 ) -> Any:
     """Assemble ``nb`` cache rows from pool blocks in ONE gather per leaf: row
     ``i`` is ``block_tables[i]``'s blocks concatenated along the token axis
@@ -600,16 +619,23 @@ def gather_block_rows(
     suffix prefill or masked out of attention before anything reads them.
     ``cache_index`` leaves are set to ``cache_index`` so the suffix prefill
     writes (and attends) from each row's cached-prefix end.
+
+    ``like`` (the admit module's cache shapes) gives each assembled row its
+    trailing dims: the paged pool folds ``kv_heads * head_dim`` and the
+    contiguous cache it is gathered into does not. Without it the rows keep
+    the pool's.
     """
 
-    def gather(path, leaf):
+    def gather(path, leaf, row_like=None):
         if _is_index_leaf(path):
             return cache_index.astype(leaf.dtype)
         rows = leaf[block_tables]  # [nb, blocks_per_row, block_tokens, ...]
-        return rows.reshape((rows.shape[0], rows.shape[1] * rows.shape[2]) + rows.shape[3:])
+        tail = rows.shape[3:] if row_like is None else row_like.shape[2:]
+        return rows.reshape((rows.shape[0], rows.shape[1] * rows.shape[2]) + tail)
 
+    trees = (block_pool,) if like is None else (block_pool, like)
     return _constrain_tree(
-        jax.tree_util.tree_map_with_path(gather, block_pool), shardings
+        jax.tree_util.tree_map_with_path(gather, *trees), shardings
     )
 
 
@@ -699,7 +725,9 @@ def scatter_rows_to_blocks(
             new_leaf = jnp.pad(
                 new_leaf, [(0, 0), (0, pad)] + [(0, 0)] * (new_leaf.ndim - 2)
             )
-        pieces = new_leaf.reshape((nb * n_blk, block_tokens) + new_leaf.shape[2:])
+        # the fresh rows are [nb, bucket, kv_heads, head_dim]; the pool folds
+        # the last two (`_paged_pool_step`): fold the rows, a few MB
+        pieces = new_leaf.reshape((nb * n_blk, block_tokens) + pool_leaf.shape[2:])
         return pool_leaf.at[dest_blocks.reshape(-1)].set(
             pieces.astype(pool_leaf.dtype), mode="drop"
         )

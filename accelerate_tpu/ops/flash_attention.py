@@ -732,21 +732,23 @@ def flash_attention(
 
 # ------------------------------------------------- paged decode (serving)
 # The fused kernel keeps a row's whole attended K/V span in two fp32 VMEM
-# scratch buffers ``[span, kv_heads, head_dim]``; Mosaic tiles the last two dims
-# (8, 128), so head_dim 64 occupies 128 lanes. At gpt2-medium (1024 positions,
-# 16 heads of 64) that is 2 x 8 MiB = exactly the 16 MiB a kernel gets by
-# default, so the call asks for what it needs (`vmem_limit_bytes`) and refuses
-# spans that cannot fit the core at all. 128 MiB is the v5e core's VMEM
-# (measured: XLA reports "Used 128.71M of 128.00M" one size past the cap).
+# scratch buffers ``[span, kv_heads * head_dim]``, the pool's own folded row
+# (`models/kv_cache._paged_pool_step`); Mosaic tiles the last two dims (8, 128),
+# so only a merged width that is no multiple of 128 pads. At gpt2-large (1024
+# positions, 20 heads of 64) that is 2 x 5 MiB, at gpt2-medium 2 x 4 MiB; the
+# call asks for what it needs (`vmem_limit_bytes`) and refuses spans that
+# cannot fit the core at all. 128 MiB is the v5e core's VMEM (measured: XLA
+# reports "Used 128.71M of 128.00M" one size past the cap).
 _VMEM_BYTES = 128 << 20
 PAGED_DECODE_VMEM_CAP = _VMEM_BYTES - (16 << 20)  # leave XLA's own share
 _PAGED_DECODE_HEADROOM = 8 << 20  # pipelined input blocks + flush temporaries
 
 
 def paged_decode_vmem_bytes(span: int, kv_heads: int, head_dim: int) -> int:
-    """VMEM the fused paged-decode kernel needs for one slot row: the two
-    lane/sublane-padded fp32 span buffers plus fixed headroom."""
-    padded = span * (-(-kv_heads // 8) * 8) * (-(-head_dim // 128) * 128) * 4
+    """VMEM the fused paged-decode kernel needs for one slot row: the two fp32
+    span buffers, ``ceil8(span) x ceil128(kv_heads * head_dim) x 4`` bytes
+    each, plus fixed headroom."""
+    padded = (-(-span // 8) * 8) * (-(-kv_heads * head_dim // 128) * 128) * 4
     return 2 * padded + _PAGED_DECODE_HEADROOM
 
 
@@ -760,7 +762,8 @@ def check_paged_decode_fits(span: int, kv_heads: int, head_dim: int) -> int:
         raise ValueError(
             f"fused paged decode keeps the whole attended span in VMEM: "
             f"{span} positions x {kv_heads} kv heads x head_dim {head_dim} "
-            f"needs {need / 2**20:.0f} MiB (fp32, lane-padded), over the "
+            f"needs {need / 2**20:.0f} MiB (two fp32 [span, kv_heads*head_dim] "
+            f"buffers + headroom), over the "
             f"{PAGED_DECODE_VMEM_CAP / 2**20:.0f} MiB this kernel may use of "
             f"the core's {_VMEM_BYTES / 2**20:.0f} MiB. Shorten n_positions, "
             "shard heads over the model axis, or use paged_attention='gather'."
@@ -789,12 +792,17 @@ def _paged_decode_kernel(
     which is the parity bar the serving tests hold (docs/serving.md). On TPU
     the flush unrolls per head into MXU-friendly 2-D dots instead.
 
+    K/V blocks and the scratch are ``[block_tokens | span, kv_heads * d]``,
+    head ``h`` in lanes ``h*d:(h+1)*d``: the pool's stored row, so a block is
+    one contiguous DMA and nothing relays the pool in front of the call.
+
     An int8 pool rides two extra refs — the fp32 absmax scale planes
     (``[1, block_tokens, kv_heads]`` per block) — and each block dequantizes
-    AT STAGING into the fp32 VMEM scratch (value × scale, round-tripped
-    through the compute dtype exactly like the gather oracle's `_dq`), so the
-    quantized pool is never materialized at full precision in HBM and the
-    flush math below is byte-for-byte the same in both modes."""
+    AT STAGING into the fp32 VMEM scratch (value × its head's scale over that
+    head's ``d`` lanes, round-tripped through the compute dtype exactly like
+    the gather oracle's `_dq`), so the quantized pool is never materialized at
+    full precision in HBM and the flush math below is byte-for-byte the same
+    in both modes."""
     if len(rest) == 5:
         ks_ref, vs_ref, o_ref, k_scr, v_scr = rest
     else:
@@ -805,18 +813,24 @@ def _paged_decode_kernel(
     nj = pl.num_programs(1)
     length = lengths[b_]  # valid kv span for this row (frontier cursor + 1)
     window = pl.ds(j * block_tokens, block_tokens)
+    hq, d = q_ref.shape[1], q_ref.shape[2]
+    kvh = k_scr.shape[1] // d
+
+    def head(h):  # kv head h's lanes of a folded row
+        return slice(h * d, (h + 1) * d)
 
     @pl.when(j * block_tokens < length)
     def _():
         if ks_ref is None:
-            k_scr[window] = k_ref[0].astype(jnp.float32)  # [bt, kv_heads, d]
+            k_scr[window] = k_ref[0].astype(jnp.float32)  # [bt, kv_heads * d]
             v_scr[window] = v_ref[0].astype(jnp.float32)
         else:
             cdt = q_ref.dtype
-            k_scr[window] = (k_ref[0].astype(jnp.float32)
-                             * ks_ref[0][..., None]).astype(cdt).astype(jnp.float32)
-            v_scr[window] = (v_ref[0].astype(jnp.float32)
-                             * vs_ref[0][..., None]).astype(cdt).astype(jnp.float32)
+            for src, sc, dst in ((k_ref, ks_ref, k_scr), (v_ref, vs_ref, v_scr)):
+                for h in range(kvh):
+                    dst[window, head(h)] = (
+                        src[0, :, head(h)].astype(jnp.float32) * sc[0, :, h:h + 1]
+                    ).astype(cdt).astype(jnp.float32)
 
     @pl.when(j * block_tokens >= length)
     def _():
@@ -829,8 +843,6 @@ def _paged_decode_kernel(
 
     @pl.when(j == nj - 1)
     def _():
-        hq, d = q_ref.shape[1], q_ref.shape[2]
-        kvh = k_scr.shape[1]
         neg = jnp.finfo(jnp.float32).min
         if exact:
             q4 = q_ref[...].astype(jnp.float32).reshape(1, 1, hq, d)  # [b,q,h,d]
@@ -860,7 +872,7 @@ def _paged_decode_kernel(
         else:
             for hh in range(hq):
                 q2 = q_ref[0, hh].astype(jnp.float32).reshape(1, d)
-                k2 = k_scr[:, hh // groups, :]  # [span, d]
+                k2 = k_scr[:, head(hh // groups)]  # [span, d]
                 s = jax.lax.dot_general(
                     q2, k2, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -871,7 +883,7 @@ def _paged_decode_kernel(
                 p = jnp.exp(s - m)
                 w = p / jnp.sum(p, axis=-1, keepdims=True)
                 o = jax.lax.dot_general(
-                    w, v_scr[:, hh // groups, :], (((1,), (0,)), ((), ())),
+                    w, v_scr[:, head(hh // groups)], (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )  # [1, d]
                 o_ref[0, hh] = o.reshape(d).astype(o_ref.dtype)
@@ -879,7 +891,7 @@ def _paged_decode_kernel(
 
 def paged_decode_attention(
     q: jax.Array,  # [b, n_heads, head_dim] — ONE decode query per slot row
-    k_pool: jax.Array,  # [num_blocks, block_tokens, kv_heads, head_dim]
+    k_pool: jax.Array,  # [num_blocks, block_tokens, kv_heads * head_dim]
     v_pool: jax.Array,
     block_tables: jax.Array,  # [b, blocks_per_slot] int32 pool block ids
     lengths: jax.Array,  # [b] int32 valid kv positions (frontier cursor + 1)
@@ -895,6 +907,13 @@ def paged_decode_attention(
     materializes a contiguous ``[b, span, heads, head_dim]`` copy per layer
     per decode step.
 
+    The pool folds heads into its last dim (``kv_heads = k_pool.shape[-1] //
+    head_dim``, head ``h`` in ``[..., h*head_dim:(h+1)*head_dim]``): a block is
+    then one contiguous, tile-aligned ``[block_tokens, kv_heads * head_dim]``
+    DMA of the array as the engine stores it. A four-dim pool's trailing
+    ``20 x 64`` would pad to ``32 x 128`` on a TPU, and XLA would rewrite each
+    layer's whole pool (84 MB to ~270 MB) in front of this call and back.
+
     Row ``i`` attends positions ``0..lengths[i]-1`` of its logical sequence;
     position ``p`` lives in pool block ``block_tables[i, p // block_tokens]``
     at offset ``p % block_tokens`` (the paged admission/decode layout).
@@ -903,9 +922,10 @@ def paged_decode_attention(
     contribute is past the frontier and masked. GQA pools read kv head
     ``h // (n_heads // kv_heads)`` directly; K/V are never repeated in HBM.
 
-    VMEM cost per slot-row cell is `paged_decode_vmem_bytes` — the attended
-    K/V span lives in fp32 scratch so the flush runs a single global-max
-    softmax, bit-identical to the XLA gather oracle under the interpreter
+    VMEM cost per slot-row cell is `paged_decode_vmem_bytes` (two fp32
+    ``[span, kv_heads * head_dim]`` buffers: 10.5 MB at gpt2-large's 1024
+    positions) — the attended K/V span lives in fp32 scratch so the flush
+    runs a single global-max softmax, bit-identical to the XLA gather oracle under the interpreter
     (`docs/serving.md` "Fused paged decode"). A span that cannot fit raises
     (`check_paged_decode_fits`); an online-softmax variant is what lifts the
     limit. Returns ``[b, n_heads, head_dim]`` in ``q.dtype``. On CPU
@@ -917,9 +937,16 @@ def paged_decode_attention(
     dequantized in VMEM scratch at staging time, so the quantized pool is
     never materialized at full precision."""
     b, hq, d = q.shape
-    num_blocks, block_tokens, kvh, dk = k_pool.shape
-    if dk != d:
-        raise ValueError(f"q head_dim {d} != pool head_dim {dk}")
+    if k_pool.ndim != 3 or v_pool.shape != k_pool.shape:
+        raise ValueError(
+            f"pools must be [num_blocks, block_tokens, kv_heads * head_dim], "
+            f"got {k_pool.shape} and {v_pool.shape}"
+        )
+    num_blocks, block_tokens, width = k_pool.shape
+    if width % d:
+        raise ValueError(
+            f"pool row width {width} is no multiple of q head_dim {d}")
+    kvh = width // d
     if hq % kvh:
         raise ValueError(f"q heads ({hq}) must be a multiple of kv heads ({kvh})")
     if (k_scale_pool is None) != (v_scale_pool is None):
@@ -947,12 +974,12 @@ def paged_decode_attention(
     in_specs = [
         pl.BlockSpec((1, hq, d), lambda b_, j, t, l: (b_, 0, 0)),
         pl.BlockSpec(
-            (1, block_tokens, kvh, d),
-            lambda b_, j, t, l: (t[b_, j], 0, 0, 0),
+            (1, block_tokens, width),
+            lambda b_, j, t, l: (t[b_, j], 0, 0),
         ),
         pl.BlockSpec(
-            (1, block_tokens, kvh, d),
-            lambda b_, j, t, l: (t[b_, j], 0, 0, 0),
+            (1, block_tokens, width),
+            lambda b_, j, t, l: (t[b_, j], 0, 0),
         ),
     ]
     inputs = [tables, lengths, q, k_pool, v_pool]
@@ -977,8 +1004,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hq, d), lambda b_, j, t, l: (b_, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((span, kvh, d), jnp.float32),
-            pltpu.VMEM((span, kvh, d), jnp.float32),
+            pltpu.VMEM((span, width), jnp.float32),
+            pltpu.VMEM((span, width), jnp.float32),
         ],
     )
     kernel = functools.partial(

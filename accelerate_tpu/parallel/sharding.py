@@ -215,20 +215,27 @@ class KVCacheSharding:
     down to `models/kv_cache.decode_cache_update`'s in-jit constraints).
 
     In paged mode (`kv_cache_sharding(..., paged=True)`) ``kv`` describes the
-    shared ``[num_blocks, block_tokens, ...]`` block pool instead of slot
-    rows, and ``gathered`` carries the layout of the per-slot attended view
+    shared ``[num_blocks, block_tokens, kv_heads * head_dim]`` block pool
+    instead of slot rows: heads are folded into the last dim so the stored
+    layout tiles (20 x 64 trailing dims pad to 32 x 128 on a TPU, 3.2x), and a
+    model-axis shard of that dim is whole heads, each a contiguous run of
+    ``head_dim``. ``gathered`` carries the layout of the per-slot attended view
     the paged update assembles (`models/kv_cache.paged_decode_update`) — the
     slot-pool layout, so attention math shards identically in both modes.
     """
 
-    kv: NamedSharding  # [slots, max_len, kv_heads, head_dim] buffers (or the block pool)
+    kv: NamedSharding  # [slots, max_len, kv_heads, head_dim] buffers, or the 3-dim block pool
     scale: NamedSharding  # [slots, max_len, kv_heads] int8 absmax scales
     index: NamedSharding  # [slots] write cursor
     gathered: NamedSharding | None = None  # paged: [slots, span, kv_heads, head_dim] view
 
 
+def _leaf_name(path) -> str | None:
+    return getattr(path[-1], "key", getattr(path[-1], "name", None))
+
+
 def _is_cache_index(path) -> bool:
-    return getattr(path[-1], "key", getattr(path[-1], "name", None)) == "cache_index"
+    return _leaf_name(path) == "cache_index"
 
 
 def kv_cache_sharding(
@@ -248,7 +255,8 @@ def kv_cache_sharding(
     ``paged=True`` describes the paged-KV layout instead: the block pool
     replicates blocks across the data axis (any replica's slot may own or
     alias any block — block ids ride as data, the table gather must be able
-    to reach the whole pool) and shards heads on the model axis; the per-slot
+    to reach the whole pool) and shards heads on the model axis, which is the
+    pool's folded last dim ``kv_heads * head_dim``; the per-slot
     write cursor and the gathered attended view keep the slot-dim rules.
     """
     batch_axes = tuple(n for n in batch_axes if mesh.shape.get(n, 1) > 1)
@@ -257,7 +265,7 @@ def kv_cache_sharding(
     head = head_axis if mesh.shape.get(head_axis, 1) > 1 else None
     if paged:
         return KVCacheSharding(
-            kv=NamedSharding(mesh, P(None, None, head, None)),
+            kv=NamedSharding(mesh, P(None, None, head)),
             scale=NamedSharding(mesh, P(None, None, head)),
             index=NamedSharding(mesh, P(row)),
             gathered=NamedSharding(mesh, P(row, None, head, None)),
@@ -291,9 +299,11 @@ def infer_cache_shardings(cache: Any, sharding: KVCacheSharding) -> Any:
     every donated cache argument."""
 
     def pick(path, leaf):
-        if _is_cache_index(path):
+        # by name: a paged K/V leaf is 3-dim like a scale plane
+        name = _leaf_name(path)
+        if name == "cache_index":
             return sharding.index
-        return sharding.kv if getattr(leaf, "ndim", len(leaf.shape)) == 4 else sharding.scale
+        return sharding.scale if name in ("key_scale", "value_scale") else sharding.kv
 
     return jax.tree_util.tree_map_with_path(pick, cache)
 

@@ -1489,7 +1489,7 @@ class ServingEngine:
         the trie's pinned blocks for the prefix and its own fresh blocks for
         the rest: the zero-copy sharing the slot path's `gather` +
         `scatter_cache_slots` round trip paid a pool-to-slot copy for."""
-        module = self._admit_module
+        module, fresh_shapes = self._admit_module, self._fresh_shapes
         cache_shardings = self._cache_shardings
         fresh_shardings = self._fresh_shardings
         bt = self._block_tokens
@@ -1503,7 +1503,8 @@ class ServingEngine:
             # or the num_blocks sentinel clamped by the gather) read garbage
             # the suffix write overwrites or the causal mask never admits
             fresh = gather_block_rows(pool_cache, gather_tables, cached_lens,
-                                      shardings=fresh_shardings)
+                                      shardings=fresh_shardings,
+                                      like=fresh_shapes)
             logits, mutated = module.apply(
                 {"params": params, "cache": fresh}, suffix_rows, decode=True,
                 position_offset=cached_lens, mutable=["cache"],

@@ -248,6 +248,10 @@ def phase_kernels(run: Smoke) -> None:
     pool_shape = (blocks, bt, z.heads, z.head_dim)
     k_pool, v_pool = rand(pool_shape), rand(pool_shape)
 
+    def fold(pool):
+        # the engine's stored layout: heads folded into the last dim
+        return pool.reshape(blocks, bt, z.heads * z.head_dim)
+
     def paged_ref(q, k_view, v_view):
         # the gather oracle: pool[table] laid out contiguously, frontier mask
         gathered = [p[jnp.minimum(tables_j, blocks - 1)].reshape(
@@ -256,7 +260,7 @@ def phase_kernels(run: Smoke) -> None:
         return dot_product_attention(q[:, None], *gathered, mask=mask)[:, 0]
 
     got = jax.jit(lambda *a: paged_decode_attention(*a, interpret=interpret))(
-        qd, k_pool, v_pool, tables_j, lengths_j)
+        qd, fold(k_pool), fold(v_pool), tables_j, lengths_j)
     ref = exact(paged_ref, *_f32(qd, k_pool, v_pool))
     compare(f"paged_decode {z.heads}x{z.head_dim} pos={z.positions} bt={bt}", got, ref, z.tol)
 
@@ -268,7 +272,7 @@ def phase_kernels(run: Smoke) -> None:
     (k8, ks), (v8, vs) = quantize_pool(k_pool), quantize_pool(v_pool)
     got = jax.jit(lambda q, k, v, t, l, ks_, vs_: paged_decode_attention(
         q, k, v, t, l, k_scale_pool=ks_, v_scale_pool=vs_, interpret=interpret
-    ))(qd, k8, v8, tables_j, lengths_j, ks, vs)
+    ))(qd, fold(k8), fold(v8), tables_j, lengths_j, ks, vs)
     # dequantized through the compute dtype, as both serving paths do
     deq = [(p.astype(jnp.float32) * s[..., None]).astype(dtype).astype(jnp.float32)
            for p, s in ((k8, ks), (v8, vs))]

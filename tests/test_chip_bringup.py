@@ -150,20 +150,40 @@ def test_notebook_launcher_refuses_to_share_tpu_chips(monkeypatch):
 
 
 # ------------------------------------------- fused paged decode: fit, or refuse
-def test_fused_kernel_vmem_model():
+@pytest.mark.parametrize("span, heads, head_dim, buffers", [
+    (1024, 16, 64, 8 << 20),  # gpt2-medium: two 4 MiB [1024, 1024] fp32 buffers
+    (1024, 20, 64, 10 << 20),  # gpt2-large: 1280 lanes, no padding (was 25 MB)
+    (1024, 12, 64, 6 << 20),  # gpt2-small
+    (128, 2, 32, 2 * 128 * 128 * 4),  # tiny: 64 merged lanes pad to one 128 tile
+    (8192, 16, 64, 64 << 20),  # refused before the pool folded its heads
+])
+def test_fused_kernel_vmem_model(span, heads, head_dim, buffers):
+    """Two fp32 ``[span, ceil128(kv_heads * head_dim)]`` buffers plus headroom."""
     from accelerate_tpu.ops.flash_attention import (
         PAGED_DECODE_VMEM_CAP,
         check_paged_decode_fits,
         paged_decode_vmem_bytes,
     )
 
-    # gpt2-medium at 1024 positions: two 8 MiB buffers (head_dim 64 pads to
-    # 128 lanes) — exactly the 16 MiB default the kernel used to run into
-    assert paged_decode_vmem_bytes(1024, 16, 64) - paged_decode_vmem_bytes(0, 16, 64) == 16 << 20
-    for heads in (12, 16, 20):  # every preset at its own n_positions
-        assert check_paged_decode_fits(1024, heads, 64) <= PAGED_DECODE_VMEM_CAP
-    with pytest.raises(ValueError, match=r"8192 positions x 16 kv heads x head_dim 64"):
-        check_paged_decode_fits(8192, 16, 64)
+    headroom = paged_decode_vmem_bytes(0, heads, head_dim)
+    assert paged_decode_vmem_bytes(span, heads, head_dim) - headroom == buffers
+    assert check_paged_decode_fits(span, heads, head_dim) == buffers + headroom
+    assert buffers + headroom <= PAGED_DECODE_VMEM_CAP
+
+
+def test_fused_kernel_refuses_the_first_span_that_cannot_fit():
+    """gpt2-medium heads: 13,312 positions fill the cap to the byte, the next
+    block of 16 is refused, and so is the next power of two."""
+    from accelerate_tpu.ops.flash_attention import (
+        PAGED_DECODE_VMEM_CAP,
+        check_paged_decode_fits,
+    )
+
+    assert check_paged_decode_fits(13312, 16, 64) == PAGED_DECODE_VMEM_CAP
+    with pytest.raises(ValueError, match=r"13328 positions x 16 kv heads x head_dim 64"):
+        check_paged_decode_fits(13328, 16, 64)
+    with pytest.raises(ValueError, match=r"needs 136 MiB \(two fp32 \[span, kv_heads\*head_dim\]"):
+        check_paged_decode_fits(16384, 16, 64)
 
 
 def test_engine_refuses_a_fused_kernel_that_cannot_fit():
@@ -173,7 +193,7 @@ def test_engine_refuses_a_fused_kernel_that_cannot_fit():
     from accelerate_tpu.serving import ServingEngine
 
     module = GPT2LMHead(GPT2Config(
-        vocab_size=64, n_positions=8192, n_embd=1024, n_layer=1, n_head=16))
+        vocab_size=64, n_positions=16384, n_embd=1024, n_layer=1, n_head=16))
     params = jax.eval_shape(lambda: module.init_params(jax.random.key(0)))
     with pytest.raises(ValueError, match="needs 136 MiB.*paged_attention='gather'"):
         ServingEngine(module, params, max_concurrency=2, paged_kv=True, paged_attention="fused")

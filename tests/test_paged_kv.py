@@ -232,6 +232,91 @@ def test_quarantine_mid_scan_replays_token_identical(model, fault_injection):
         assert o.tokens == refs[o.request_id]
 
 
+def test_paged_parity_with_a_merged_width_that_tiles_nothing():
+    """3 heads of 16: the pool's folded last dim is 48 lanes, no multiple of
+    128 nor of 64, with an odd head count. Fused == gather == slot, and the
+    pool leaves really are ``[num_blocks, block_tokens, kv_heads * head_dim]``."""
+    cfg = GPT2Config.tiny(dtype=jnp.float32, n_embd=48, n_head=3)
+    module = GPT2LMHead(cfg)
+    params = module.init_params(jax.random.key(1))
+    prompts = _prompts(11, (5, 23, 40, 9))
+
+    def serve(**kw):
+        engine = ServingEngine(module, params, max_concurrency=4,
+                               prompt_buckets=(16, 64), admit_batch=2, **kw)
+        tokens = {o.request_id: o.tokens for o in engine.run(_requests(prompts))}
+        return tokens, engine
+
+    slot, _ = serve()
+    gather, _ = serve(paged_kv=True)
+    fused, engine = serve(paged_kv=True, paged_attention="fused")
+    assert fused == gather == slot
+    kv_shapes = {leaf.shape for path, leaf in
+                 jax.tree_util.tree_leaves_with_path(engine._cache)
+                 if path[-1].key in ("cached_key", "cached_value")}
+    assert kv_shapes == {(4 * 128 // BT, BT, 48)}
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim, quant", [
+    (2, 2, 32, False),  # the tiny config: 64 merged lanes, half a tile
+    (4, 2, 32, False),  # GQA, groups of 2
+    (6, 2, 16, True),  # GQA, groups of 3, int8 pool: per-head scale lanes
+    (8, 8, 16, False),  # 128 merged lanes: exactly one tile
+    (3, 3, 16, True),  # odd head count, 48 lanes, int8
+])
+def test_fused_kernel_reads_the_folded_pool_bit_for_bit(heads, kv_heads, head_dim, quant):
+    """`paged_decode_attention` on a ``[num_blocks, block_tokens, kv_heads *
+    head_dim]`` pool against XLA attention over the gathered, unfolded view
+    (what `paged_decode_update` hands the gather path): the same bits."""
+    from accelerate_tpu.models.kv_cache import _dq, _q
+    from accelerate_tpu.ops.attention import attention
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    rows, bps, num_blocks = 3, 4, 16
+    span = bps * BT
+    rng = np.random.default_rng(heads * 100 + head_dim)
+    dtype = jnp.float32  # the parity bar is the engine tests': float32 compute
+    q = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+    k4, v4 = (jnp.asarray(rng.normal(size=(num_blocks, BT, kv_heads, head_dim)), dtype)
+              for _ in range(2))
+    tables = jnp.asarray(rng.permutation(num_blocks)[: rows * bps].reshape(rows, bps), jnp.int32)
+    tables = tables.at[2, 2:].set(num_blocks)  # a released tail: the clamped sentinel
+    lengths = jnp.asarray([span, 21, 32], jnp.int32)  # full, mid-block, block-exact
+
+    def fold(x):
+        return x.reshape(num_blocks, BT, kv_heads * head_dim)
+
+    def view(pool, *tail):
+        return pool[jnp.minimum(tables, num_blocks - 1)].reshape((rows, span) + tail)
+
+    if quant:
+        (kq, ks), (vq, vs) = _q(k4), _q(v4)
+        got = paged_decode_attention(q, fold(kq), fold(vq), tables, lengths,
+                                     k_scale_pool=ks, v_scale_pool=vs)
+        k_all = _dq(view(kq, kv_heads, head_dim), view(ks, kv_heads), dtype)
+        v_all = _dq(view(vq, kv_heads, head_dim), view(vs, kv_heads), dtype)
+    else:
+        got = paged_decode_attention(q, fold(k4), fold(v4), tables, lengths)
+        k_all, v_all = view(k4, kv_heads, head_dim), view(v4, kv_heads, head_dim)
+    mask = (jnp.arange(span)[None, :] < lengths[:, None])[:, None, None, :]
+    want = attention(q[:, None], k_all, v_all, causal=False, mask=mask,
+                     implementation="xla")[:, 0]
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+def test_fused_kernel_refuses_an_unfolded_pool():
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    q = jnp.zeros((2, 2, 32))
+    tables, lengths = jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32)
+    with pytest.raises(ValueError, match=r"kv_heads \* head_dim"):
+        paged_decode_attention(q, jnp.zeros((4, BT, 2, 32)), jnp.zeros((4, BT, 2, 32)),
+                               tables, lengths)
+    with pytest.raises(ValueError, match="no multiple of q head_dim"):
+        paged_decode_attention(q, jnp.zeros((4, BT, 48)), jnp.zeros((4, BT, 48)),
+                               tables, lengths)
+
+
 def test_paged_frontier_partial_fill_masking(model):
     """Prompt lengths straddling the block quantum — mid-block frontier
     (21), exactly-full block (16, 32), one-short (15, 31) — decode appends

@@ -278,6 +278,36 @@ def test_kv_cache_sharding_slot_and_head_rules():
     assert s_dp.kv.spec == P(("data",), None, None, None)
 
 
+def test_paged_pool_sharding_splits_the_folded_head_dim():
+    """The paged pool is ``[num_blocks, block_tokens, kv_heads * head_dim]``:
+    blocks replicate, the folded last dim shards on "tensor" (whole heads),
+    and a K/V leaf is told from a scale plane by its name, both being 3-dim."""
+    mesh = serving_mesh(data=2, model=2)
+    paged = kv_cache_sharding(mesh, slots=4, paged=True)
+    assert paged.kv.spec == P(None, None, "tensor")
+    assert paged.scale.spec == P(None, None, "tensor")
+    assert paged.index.spec == P(("data",))
+    assert paged.gathered.spec == P(("data",), None, "tensor", None)
+    cache = {
+        "cached_key": jax.ShapeDtypeStruct((12, 16, 2 * 8), jnp.int8),
+        "cached_value": jax.ShapeDtypeStruct((12, 16, 2 * 8), jnp.int8),
+        "key_scale": jax.ShapeDtypeStruct((12, 16, 2), jnp.float32),
+        "value_scale": jax.ShapeDtypeStruct((12, 16, 2), jnp.float32),
+        "cache_index": jax.ShapeDtypeStruct((4,), jnp.int32),
+    }
+    tree = infer_cache_shardings(cache, paged)
+    assert tree["cached_key"] is paged.kv and tree["cached_value"] is paged.kv
+    assert tree["key_scale"] is paged.scale and tree["value_scale"] is paged.scale
+    assert tree["cache_index"] is paged.index
+    # by name, not by rank: a slot cache's 4-dim K/V and 3-dim scale still part
+    slot = kv_cache_sharding(mesh, slots=4)
+    tree = infer_cache_shardings({
+        "cached_value": jax.ShapeDtypeStruct((4, 16, 2, 8), jnp.int8),
+        "value_scale": jax.ShapeDtypeStruct((4, 16, 2), jnp.float32),
+    }, slot)
+    assert tree["cached_value"] is slot.kv and tree["value_scale"] is slot.scale
+
+
 # ------------------------------------------------------------------- validation
 def test_engine_rejects_indivisible_heads(model):
     """tiny n_head=2 cannot split over a model axis of 4: loud ValueError at

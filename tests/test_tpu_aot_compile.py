@@ -6,10 +6,12 @@ limits, layouts) and a call XLA cannot partition over a mesh. libtpu can
 compile for a chip that is not there (`jax.experimental.topologies`), so this
 file compiles each kernel at gpt2-medium shapes with ``interpret=False`` — on
 one device and inside a four-device jit. It checks that the program builds;
-only a chip run (`chip_smoke.py`) checks what it computes. Marked slow.
+only a chip run (`chip_smoke.py`) checks what it computes. Marked slow, all
+but the paged pool's layout guard at the end (two compiles, about 3 s).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-pytestmark = pytest.mark.slow
+slow = pytest.mark.slow
 
 B, S, H, D = 8, 1024, 16, 64  # gpt2-medium attention at the bench shape
 E, V = 1024, 50257
@@ -60,8 +62,8 @@ def _paged_args(heads, sharding_for, quant):
     pool_dtype = jnp.int8 if quant else jnp.bfloat16
     args = [
         _sds((B, heads, D), jnp.bfloat16, sharding_for("q")),
-        _sds((blocks, BLOCK_TOKENS, heads, D), pool_dtype, sharding_for("pool")),
-        _sds((blocks, BLOCK_TOKENS, heads, D), pool_dtype, sharding_for("pool")),
+        _sds((blocks, BLOCK_TOKENS, heads * D), pool_dtype, sharding_for("pool")),
+        _sds((blocks, BLOCK_TOKENS, heads * D), pool_dtype, sharding_for("pool")),
         _sds((B, bps), jnp.int32, sharding_for("tables")),
         _sds((B,), jnp.int32, sharding_for("lengths")),
     ]
@@ -71,6 +73,7 @@ def _paged_args(heads, sharding_for, quant):
 
 
 # ------------------------------------------------------------------ one device
+@slow
 @pytest.mark.parametrize("window", [None, 256])
 def test_flash_fwd_bwd_one_device(topology, window):
     from accelerate_tpu.ops.flash_attention import flash_attention
@@ -85,6 +88,7 @@ def test_flash_fwd_bwd_one_device(topology, window):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
 
 
+@slow
 def test_fused_ce_fwd_bwd_one_device(topology):
     from accelerate_tpu.ops.fused_ce import fused_cross_entropy
 
@@ -97,11 +101,12 @@ def test_fused_ce_fwd_bwd_one_device(topology):
     )
 
 
+@slow
 @pytest.mark.parametrize("heads", [12, 16, 20])  # small, medium, large
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_decode_one_device(topology, heads, quant):
-    """gpt2-medium is where the two lane-padded fp32 span buffers reach the
-    default 16 MiB scoped-VMEM limit; the call must ask for what it needs."""
+    """The two fp32 span buffers (8-10 MiB at these widths) plus headroom pass
+    the default 16 MiB scoped-VMEM limit; the call must ask for what it needs."""
     from accelerate_tpu.ops.flash_attention import paged_decode_attention
 
     s = _one_device(topology)
@@ -115,6 +120,7 @@ def test_paged_decode_one_device(topology, heads, quant):
     _compile(decode, *_paged_args(heads, lambda _: s, quant))
 
 
+@slow
 @pytest.mark.parametrize("shape", [(1024, 3072), (1024, 4096), (4096, 1024)])  # qkv, up, down
 def test_nf4_matmul_one_device(topology, shape):
     """The per-tile scale block must be a whole trailing axis: Mosaic rejects
@@ -143,6 +149,7 @@ def data_mesh(topology, monkeypatch):
     return mesh
 
 
+@slow
 def test_flash_fwd_bwd_four_devices(data_mesh, compiled_kernels):
     from accelerate_tpu.ops.attention import attention
 
@@ -156,6 +163,7 @@ def test_flash_fwd_bwd_four_devices(data_mesh, compiled_kernels):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
 
 
+@slow
 def test_fused_ce_fwd_bwd_four_devices(data_mesh, compiled_kernels):
     from accelerate_tpu.ops.fused_ce import fused_cross_entropy
 
@@ -168,6 +176,7 @@ def test_fused_ce_fwd_bwd_four_devices(data_mesh, compiled_kernels):
     )
 
 
+@slow
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_decode_on_serving_mesh(topology, compiled_kernels, quant):
     """The engine's ``mesh=(2, 2)``: slot rows over data, heads over tensor."""
@@ -190,3 +199,77 @@ def test_paged_decode_on_serving_mesh(topology, compiled_kernels, quant):
 
     compiled = _compile(decode, *_paged_args(H, named.__getitem__, quant))
     assert compiled.output_shardings.spec == P("data", "tensor", None)
+
+
+# --------------------------------------- the paged pool's layout (not slow)
+# The serving cell's pool leaf: gpt2-large, 2048 blocks of 16 tokens, 20 heads
+# of 64 folded into the last dim, 32 slot rows with 64 table blocks each.
+POOL_BLOCKS, LARGE_HEADS, ROWS, ROW_BLOCKS = 2048, 20, 32, 64
+POOL_SHAPE = (POOL_BLOCKS, BLOCK_TOKENS, LARGE_HEADS * D)
+
+
+def _assert_pool_is_not_relaid(compiled, pool_in, pool_out):
+    """A donated pool leaf goes in, is updated in place and comes out: the
+    same row-major layout on both sides, no pool-shaped ``copy`` between them
+    and no pool-sized temporary. With ``kv_heads, head_dim`` as trailing dims
+    (20 x 64 pads to 32 x 128) each leaf cost two whole-pool copies a program."""
+    row_major = tuple(range(len(POOL_SHAPE)))
+    for fmt in (*pool_in, *pool_out):
+        assert fmt.layout.major_to_minor == row_major, fmt
+    assert [f.layout for f in pool_in] == [f.layout for f in pool_out]
+    dims = ",".join(map(str, POOL_SHAPE))
+    copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(", compiled.as_text())
+    assert not copies, copies
+    leaf_bytes = int(np.prod(POOL_SHAPE)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
+def test_decode_write_and_kernel_leave_the_pool_in_place(topology):
+    from accelerate_tpu.models.kv_cache import _paged_frontier_write
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    s = _one_device(topology)
+    pool = _sds(POOL_SHAPE, jnp.bfloat16, s)
+    new = _sds((ROWS, 1, LARGE_HEADS, D), jnp.bfloat16, s)
+
+    def decode(k_pool, v_pool, q, k, v, tables, idx):
+        (k_pool, v_pool), _ = _paged_frontier_write(
+            (k_pool, v_pool), (k, v), idx, jnp.ones((ROWS,), bool), None,
+            POOL_BLOCKS, BLOCK_TOKENS, tables)
+        out = paged_decode_attention(q, k_pool, v_pool, tables, idx + 1, interpret=False)
+        return k_pool, v_pool, out
+
+    compiled = jax.jit(decode, donate_argnums=(0, 1)).lower(
+        pool, pool, _sds((ROWS, LARGE_HEADS, D), jnp.bfloat16, s), new, new,
+        _sds((ROWS, ROW_BLOCKS), jnp.int32, s), _sds((ROWS,), jnp.int32, s),
+    ).compile()
+    _assert_pool_is_not_relaid(
+        compiled, compiled.input_formats[0][:2], compiled.output_formats[:2])
+
+
+@pytest.mark.parametrize("bucket", [128, S])  # a prompt bucket; the n_positions rows the engine's admits prefill
+def test_admit_scatter_leaves_the_pool_in_place(topology, bucket):
+    from accelerate_tpu.models.kv_cache import scatter_rows_to_blocks
+
+    s = _one_device(topology)
+    nb = 4
+
+    def tree(kv, index):
+        return {"cached_key": kv, "cached_value": kv, "cache_index": index}
+
+    cache = tree(_sds(POOL_SHAPE, jnp.bfloat16, s), _sds((ROWS,), jnp.int32, s))
+    fresh = tree(_sds((nb, bucket, LARGE_HEADS, D), jnp.bfloat16, s), _sds((nb,), jnp.int32, s))
+
+    def admit(cache, fresh, slots, dest_blocks, prompt_lens):
+        return scatter_rows_to_blocks(cache, fresh, slots, dest_blocks, prompt_lens, BLOCK_TOKENS)
+
+    compiled = jax.jit(admit, donate_argnums=(0,)).lower(
+        cache, fresh, _sds((nb,), jnp.int32, s),
+        _sds((nb, bucket // BLOCK_TOKENS), jnp.int32, s), _sds((nb,), jnp.int32, s),
+    ).compile()
+    kv = ("cached_key", "cached_value")
+    _assert_pool_is_not_relaid(
+        compiled,
+        [compiled.input_formats[0][0][name] for name in kv],
+        [compiled.output_formats[name] for name in kv],
+    )
