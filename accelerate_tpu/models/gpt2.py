@@ -82,6 +82,13 @@ class GPT2Config:
     # matmuls; scaling state rides the mutable fp8_meta collection)
     fp8_recipe: Any = None
 
+    def cache_contract(self):
+        """Keys and values only, every head its own (`kv_cache.CacheContract`)."""
+        from .kv_cache import CacheContract
+
+        return CacheContract(kv_heads=self.n_head, head_dim=self.n_embd // self.n_head,
+                             param_rules=gpt2_sharding_rules)
+
     @classmethod
     def small(cls, **kw) -> "GPT2Config":
         return cls(**{**dict(n_embd=768, n_layer=12, n_head=12), **kw})

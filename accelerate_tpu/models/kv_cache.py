@@ -13,12 +13,42 @@ integration quantizes weights only.
 
 from __future__ import annotations
 
+import dataclasses
 from collections import deque
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheContract:
+    """What a causal LM declares about its decode cache, for the serving
+    engine (``module.config.cache_contract()``; `docs/serving.md` "The cache
+    contract"). The config also carries the engine's cache switches
+    (``kv_cache_per_slot``, ``kv_cache_paged``, ``kv_num_blocks``,
+    ``kv_block_tokens``, ``kv_paged_attention``, ``kv_cache_sharding``,
+    ``n_positions``) and the module takes `GPT2LMHead`'s decode arguments.
+
+    Leaves of the ``cache`` collection are told apart by name:
+    ``cache_index`` is the per-slot write cursor, names in ``state_leaves``
+    are per-slot recurrent state ``[slots, ...]`` (never block-addressable:
+    admission writes a slot's whole state, a finished slot's is frozen by
+    ``cache_write_mask``), everything else is keys/values (and their int8
+    scales) in the per-slot or paged layout. A model with state leaves gets
+    ``cache_write_len`` = each row's true prompt length in its admit program.
+
+    ``step_counters`` names int32 scalars the module sows into a ``counters``
+    collection (summed over layers); the decode step returns them beside its
+    tokens and `ServingMetrics` accumulates them. ``param_rules`` gives the
+    parameter sharding rules for a serving mesh."""
+
+    kv_heads: int
+    head_dim: int
+    state_leaves: tuple[str, ...] = ()
+    step_counters: tuple[str, ...] = ()
+    param_rules: Callable[[], Any] | None = None
 
 
 def _q(x):
@@ -449,8 +479,19 @@ def paged_decode_write(
     return new_k, new_v, idx, True, None
 
 
+def leaf_name(path) -> str | None:
+    """The variable name a cache (or sown) leaf was declared under."""
+    return getattr(path[-1], "key", None)
+
+
 def _is_index_leaf(path) -> bool:
-    return getattr(path[-1], "key", None) == "cache_index"
+    return leaf_name(path) == "cache_index"
+
+
+def state_nbytes(cache: Any, state_leaves: tuple[str, ...]) -> int:
+    """Device bytes of the per-slot recurrent-state leaves of a cache tree."""
+    flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return sum(int(leaf.nbytes) for path, leaf in flat if leaf_name(path) in state_leaves)
 
 
 def rewind_frontier(cache: Any, new_index: jax.Array) -> Any:
@@ -700,6 +741,7 @@ def scatter_rows_to_blocks(
     cache_index: jax.Array,  # [nb] int32 per-row resume index (true prefill length)
     block_tokens: int,
     shardings: Any = None,  # congruent NamedShardings keeping the pool's layout
+    state_leaves: tuple[str, ...] = (),  # names of per-slot recurrent-state leaves
 ) -> Any:
     """Paged admission: carve each freshly prefilled contiguous row into
     ``block_tokens``-sized pieces and scatter them into the row's allocated
@@ -712,12 +754,15 @@ def scatter_rows_to_blocks(
 
     The ``cache_index`` leaf rows ``slots`` are stamped with ``cache_index``
     (the true prefill length — decode's append frontier), exactly like the
-    slot-pool admission scatter.
+    slot-pool admission scatter. ``state_leaves`` are not paged: their fresh
+    ``[nb, ...]`` rows overwrite the slots' whole state.
     """
 
     def scatter(path, pool_leaf, new_leaf):
         if _is_index_leaf(path):
             return pool_leaf.at[slots].set(cache_index.astype(pool_leaf.dtype))
+        if leaf_name(path) in state_leaves:
+            return pool_leaf.at[slots].set(new_leaf.astype(pool_leaf.dtype))
         nb, bucket = new_leaf.shape[:2]
         n_blk = dest_blocks.shape[1]
         pad = n_blk * block_tokens - bucket

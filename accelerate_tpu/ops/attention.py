@@ -141,6 +141,14 @@ def attention(
 
             if band_block_default(q.shape[1]) is None:
                 implementation = "xla"
+        elif implementation == "flash":
+            # so does the rectangular grid: a length its blocks do not divide
+            # (1536 under the 1024 default) would raise in the kernel
+            from .flash_attention import rect_blocks
+
+            bq, bkv = rect_blocks(q.shape[1], k.shape[1], block_q, block_kv)
+            if q.shape[1] % bq or k.shape[1] % bkv:
+                implementation = "xla"
     if implementation == "flash":
         from .flash_attention import flash_attention
 

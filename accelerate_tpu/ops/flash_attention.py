@@ -615,6 +615,17 @@ def band_block_default(sq: int) -> int | None:
     return best if best >= 8 else None
 
 
+def rect_blocks(sq: int, skv: int, block_q: int | None = None,
+                block_kv: int | None = None) -> tuple[int, int]:
+    """The rectangular grid's block sizes: the given ones, else the env-tunable
+    defaults, shrunk to the sequence. The kernel and the dispatcher's auto
+    routing share them: a length they do not divide raises in the kernel, so
+    auto routes it to xla."""
+    block_q = _env_block("ACCELERATE_TPU_FLASH_BLOCK_Q", 1024) if block_q is None else block_q
+    block_kv = _env_block("ACCELERATE_TPU_FLASH_BLOCK_KV", 1024) if block_kv is None else block_kv
+    return min(block_q, sq), min(block_kv, skv)
+
+
 def flash_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,
@@ -716,10 +727,7 @@ def flash_attention(
         # whole (b,h) attention runs in ONE grid cell, and the [block_q, block_kv]
         # fp32 logits tile (4 MB) still fits VMEM comfortably; longer sequences
         # fall back to 1024-wide tiles.
-        block_q = _env_block("ACCELERATE_TPU_FLASH_BLOCK_Q", 1024) if block_q is None else block_q
-        block_kv = _env_block("ACCELERATE_TPU_FLASH_BLOCK_KV", 1024) if block_kv is None else block_kv
-        block_q = min(block_q, sq)
-        block_kv = min(block_kv, skv)
+        block_q, block_kv = rect_blocks(sq, skv, block_q, block_kv)
         if sq % block_q or skv % block_kv:
             raise ValueError(
                 f"seq lengths ({sq}, {skv}) must divide block sizes ({block_q}, {block_kv})"

@@ -17,6 +17,15 @@ the native TPU design. Switch/GShard-style top-k routing with static capacity:
 
 Dropped tokens (over capacity) pass through the residual stream untouched, as in
 GShard/Switch.
+
+`held_experts_mlp` is the other kind of expert layer: one chip's share of
+many small experts under expert parallelism. It is told which experts it
+holds, routes over all of them, drops no token, and computes the held experts'
+part of the result with a grouped matrix product over the picks sorted by
+expert (`grouped_product`: `jax.lax.ragged_dot`, or the Pallas grouped matmul
+that ships with JAX for a prefill's rows on the TPU). `shared_expert_mlp` is
+the always-on expert behind a sigmoid gate that such models put beside the
+routed ones.
 """
 
 from __future__ import annotations
@@ -142,6 +151,94 @@ class MoEMLP(nn.Module):
         aux = cfg.aux_loss_weight * E * jnp.sum(me * ce)
         sow_aux_loss(self, aux)
         return out.reshape(b, s, e).astype(x.dtype)
+
+
+def route_top_k(x: jax.Array, router: jax.Array, top_k: int) -> tuple[jax.Array, jax.Array]:
+    """Softmax over ALL of the router's experts in float32, the ``top_k``
+    largest, renormalised to sum 1: ``(weights [T, k], expert ids [T, k])``."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top, idx = jax.lax.top_k(probs, top_k)
+        return top / jnp.sum(top, axis=-1, keepdims=True), idx
+
+
+GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small groups share one
+GMM_MIN_ROWS = 4096  # from a 512-token prefill's picks up
+GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
+
+
+def grouped_product(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
+    """``rows [R, K]`` sorted by group times each group's ``w [G, K, N]``,
+    ``sizes [G]`` rows a group, float32 ``[R, N]``; rows past the last group
+    hold anything. A prefill's rows on the TPU go through the Pallas grouped
+    matmul that ships with JAX (megablox): with a dozen rows a group XLA's
+    `ragged_dot` takes 2.5 to 3 times the weights' read there, and a turn that
+    admits a request is what a decoding slot waits on. Any other backend and
+    a row count the tile does not divide keep `jax.lax.ragged_dot`; so, for
+    now, do a decode step's few rows, though the kernel measured faster there
+    too (ROADMAP S10): that step's roofline reader looks for `ragged_dot`."""
+    from ..utils.environment import on_tpu_platform
+
+    n_rows, (_, k, n) = rows.shape[0], w.shape
+    if n_rows >= GMM_MIN_ROWS and n_rows % GMM_ROW_TILE == 0 and on_tpu_platform():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        tile_k = min(k, 2048)
+        tile_n = min(n, GMM_WEIGHT_TILE // tile_k)  # the whole width of both of an expert's matrices
+        return gmm(rows, w, sizes, jnp.float32, tiling=(GMM_ROW_TILE, tile_k, tile_n))
+    return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=jnp.float32)
+
+
+def held_experts_mlp(
+    x: jax.Array,  # [T, hidden] tokens, compute dtype
+    weights: jax.Array,  # [T, k] float32 combine weights (`route_top_k`)
+    expert_idx: jax.Array,  # [T, k] int32 ids over the router's full width
+    w_gate_up: jax.Array,  # [E_held, hidden, 2 * F]: gate columns, then up
+    w_down: jax.Array,  # [E_held, F, hidden]
+    first_expert: int = 0,  # this share holds experts [first, first + E_held)
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``sum_e p_e * down_e(silu(gate_e x) * up_e x)`` over each token's chosen
+    experts that are held here; a pick that falls on an absent expert adds
+    nothing (its chip adds it, and the exchange sums the parts). No capacity:
+    the ``T * k`` picks are sorted by expert, absent ones last, and the held
+    ones run through two grouped products whose group sizes are the experts'
+    pick counts. Returns ``(out [T, hidden] float32, picks_held, experts_touched)``
+    with the two int32 counts of this call."""
+    n_tokens, k = expert_idx.shape
+    n_held, _, two_f = w_gate_up.shape
+    # picks place by place, pick j of token t at j * T + t: the weighted sum
+    # over a token's picks is then over the leading axis of [k, T, hidden],
+    # which no tiling pads (k = 10 as a second-minor axis is relaid to 16)
+    local = expert_idx.T.reshape(-1) - first_expert
+    held = (local >= 0) & (local < n_held)
+    group = jnp.where(held, local, n_held).astype(jnp.int32)  # absent picks sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=n_held + 1)[:n_held].astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        rows = x[order % n_tokens]
+        gate_up = grouped_product(rows, w_gate_up.astype(x.dtype), sizes)
+        act = (jax.nn.silu(gate_up[:, : two_f // 2]) * gate_up[:, two_f // 2:]).astype(x.dtype)
+        y = grouped_product(act, w_down.astype(x.dtype), sizes)
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+        # rows past the last group belong to absent experts: whatever the
+        # grouped product left there is dropped, not weighted
+        scale = jnp.where(held, weights.T.reshape(-1), 0.0)[:, None]
+        out = jnp.where(held[:, None], y[inverse] * scale, 0.0).reshape(k, n_tokens, -1).sum(axis=0)
+    return out, jnp.sum(held).astype(jnp.int32), jnp.sum(sizes > 0).astype(jnp.int32)
+
+
+def shared_expert_mlp(x: jax.Array, gate: jax.Array, w_gate_up: jax.Array,
+                      w_down: jax.Array) -> jax.Array:
+    """``sigmoid(x . gate) * down(silu(gate_proj x) * up_proj x)``: the expert
+    every token passes through. ``w_gate_up`` is ``[hidden, 2 * F]``. float32."""
+    f = w_gate_up.shape[-1] // 2
+    gu = jnp.matmul(x, w_gate_up.astype(x.dtype), preferred_element_type=jnp.float32)
+    act = (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(x.dtype)
+    y = jnp.matmul(act, w_down.astype(x.dtype), preferred_element_type=jnp.float32)
+    on = jax.nn.sigmoid(jnp.sum(x.astype(jnp.float32) * gate.astype(jnp.float32), -1, keepdims=True))
+    return on * y
 
 
 def collect_aux_losses(extra_state: Any) -> jax.Array:

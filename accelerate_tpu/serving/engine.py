@@ -65,15 +65,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..models.gpt2 import gpt2_sharding_rules
 from ..models.kv_cache import (
     BlockAllocator,
     _is_index_leaf,
     gather_block_rows,
+    leaf_name,
     make_cache,
     rewind_frontier,
     scatter_cache_slots,
     scatter_rows_to_blocks,
+    state_nbytes,
     tree_bytes_by_dtype,
     tree_nbytes,
 )
@@ -352,10 +353,15 @@ class RecoveryReport:
 class ServingEngine:
     """Request-level continuous batching over a fixed pool of decode slots.
 
-    ``module`` is any causal LM whose config supports ``kv_cache_per_slot``
-    (GPT-2 today); the engine re-instantiates it with the flag on, so callers
-    pass the same module they would hand to ``generate``. ``params`` is the
-    matching param tree. The context length is the config's ``n_positions``.
+    ``module`` is any causal LM whose config declares a cache contract
+    (``config.cache_contract()``, `models/kv_cache.CacheContract`: GPT-2's keys
+    and values, or keys and values beside per-slot recurrent state); the
+    engine re-instantiates it with its cache switches on, so callers pass the
+    same module they would hand to ``generate``. ``params`` is the matching
+    param tree. The context length is the config's ``n_positions``. A model
+    that declares recurrent state is refused ``prefix_cache``, ``kv_tier``,
+    ``speculation`` and ``mesh`` at construction: each of them addresses or
+    rewinds the cache by token position, which a recurrent state has not.
 
     ``pipeline_depth`` bounds how many decode dispatches may be in flight
     before the host blocks on the oldest fetch (1 = fully synchronous, the
@@ -421,12 +427,27 @@ class ServingEngine:
         weight_quant: WeightQuantConfig | str | None = None,
     ):
         cfg = getattr(module, "config", None)
-        if cfg is None or not hasattr(cfg, "kv_cache_per_slot"):
+        if cfg is None or not hasattr(cfg, "cache_contract"):
             raise TypeError(
-                f"{type(module).__name__} has no kv_cache_per_slot config flag; "
-                "the serving engine needs the per-slot cache variant "
-                "(models/kv_cache.py) — GPT2LMHead supports it."
+                f"{type(module).__name__}'s config declares no cache contract; "
+                "the serving engine needs `config.cache_contract()` and the "
+                "per-slot cache switches it describes (models/kv_cache.py "
+                "CacheContract) — GPT2LMHead and Qwen3NextForCausalLM have them."
             )
+        contract = self._contract = cfg.cache_contract()
+        if contract.state_leaves:
+            # per-slot recurrent state is no function of a token range: it can
+            # be neither shared by prefix, nor spilled and restored by block,
+            # nor rewound after a rejected draft. Refuse here, never answer
+            # wrongly; a mesh layout for it (and the experts' exchange) is
+            # not written either.
+            for name, on in (("prefix_cache", prefix_cache), ("kv_tier", kv_tier),
+                             ("speculation", speculation is not None),
+                             ("mesh", mesh is not None)):
+                if on:
+                    raise ValueError(
+                        f"{type(module).__name__} keeps per-slot recurrent state "
+                        f"{contract.state_leaves}; {name} is not supported for it")
         self.max_concurrency = int(max_concurrency)
         if self.max_concurrency < 1:
             raise ValueError(f"max_concurrency must be >= 1, got {max_concurrency}")
@@ -517,10 +538,10 @@ class ServingEngine:
                     f"the serving engine shards over (data, tensor) only; "
                     f"mesh has extra non-trivial axes {extra}"
                 )
-            if self._mesh_model > 1 and cfg.n_head % self._mesh_model:
+            if self._mesh_model > 1 and contract.kv_heads % self._mesh_model:
                 raise ValueError(
                     f"model-axis degree {self._mesh_model} must divide "
-                    f"n_head={cfg.n_head} (attention is sharded over heads)"
+                    f"n_head={contract.kv_heads} (attention is sharded over heads)"
                 )
             self._slot_sharding = kv_cache_sharding(
                 self.mesh, slots=self.max_concurrency, paged=self.paged
@@ -541,8 +562,8 @@ class ServingEngine:
             from ..ops.flash_attention import check_paged_decode_fits
 
             check_paged_decode_fits(
-                int(cfg.n_positions), cfg.n_head // self._mesh_model,
-                cfg.n_embd // cfg.n_head,
+                int(cfg.n_positions), contract.kv_heads // self._mesh_model,
+                contract.head_dim,
             )
         # contiguous slot ranges per data replica (the slot dim shards like any
         # leading batch dim: replica i owns rows [i*b/d, (i+1)*b/d)) — 1 when
@@ -604,7 +625,7 @@ class ServingEngine:
             # serving a non-GPT-2 model pass their own ``param_rules``);
             # unmatched / scalar / 1-D leaves come out replicated. Derived
             # over the DENSE tree — packed leaves re-derive below.
-            rules = param_rules if param_rules is not None else gpt2_sharding_rules()
+            rules = param_rules if param_rules is not None else contract.param_rules()
             dense_shardings = infer_param_shardings(
                 params, self.mesh, rules=rules
             )
@@ -981,6 +1002,27 @@ class ServingEngine:
                 tokens=entry.tokens, **extra)
 
     # ------------------------------------------------------------- jitted fns
+    def _step_mutable(self):
+        """``(mutable collections, counters(mutated) -> tuple)`` for the
+        one-token decode programs: a model whose contract names step counters
+        sows them into ``counters``, and the step returns them summed over
+        layers as ONE int32 vector beside its tokens (no transfer of its
+        own). A model without them gets ``["cache"]`` and ``()``: its program
+        is what it was."""
+        names = self._contract.step_counters
+        if not names:
+            return ["cache"], lambda mutated: ()
+
+        def counters(mutated):
+            flat = jax.tree_util.tree_flatten_with_path(mutated.get("counters", {}))[0]
+            sums = {name: jnp.zeros((), jnp.int32) for name in names}
+            for path, leaf in flat:
+                if leaf_name(path) in sums:
+                    sums[leaf_name(path)] += jnp.sum(leaf).astype(jnp.int32)
+            return (jnp.stack([sums[name] for name in names]),)
+
+        return ["cache", "counters"], counters
+
     def _build_step_fn(self):
         if self.draft_tokens:
             return self._build_spec_step_fn()
@@ -989,6 +1031,7 @@ class ServingEngine:
         if self.paged:
             return self._build_paged_step_fn()
         module = self.module
+        mutable, counters = self._step_mutable()
 
         def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
                     finished, remaining, poison, eos_id):
@@ -999,7 +1042,7 @@ class ServingEngine:
             # lags, a finished slot's state is bit-stable until re-admission
             logits, mutated = module.apply(
                 {"params": params, "cache": cache}, tokens[:, None], decode=True,
-                position_offset=pos, mutable=["cache"], cache_write_mask=live,
+                position_offset=pos, mutable=mutable, cache_write_mask=live,
             )
             last = logits[:, -1]
             # fault injection rides INSIDE the compiled step (poison is a [b]
@@ -1025,7 +1068,7 @@ class ServingEngine:
             # the host decides to quarantine it
             new_finished = finished | (live & (~ok | hit_eos | (new_remaining <= 0)))
             return (mutated["cache"], nxt, new_pos, new_remaining, new_finished,
-                    jax.random.key_data(new_rngs), ok | finished)
+                    jax.random.key_data(new_rngs), ok | finished) + counters(mutated)
 
         if self.mesh is None:
             return _shared_jit(module, "step",
@@ -1044,6 +1087,7 @@ class ServingEngine:
     def _build_admit_fn(self):
         module, fresh_shapes = self._admit_module, self._fresh_shapes
         cache_shardings = self._cache_shardings
+        state_leaves = self._contract.state_leaves
 
         def admit_fn(pool_cache, params, prompt_rows, slots, prompt_lens, temps,
                      top_ks, rng_batch, budgets, d_tokens, d_pos, d_temps,
@@ -1060,6 +1104,10 @@ class ServingEngine:
             logits, mutated = module.apply(
                 {"params": params, "cache": fresh}, prompt_rows, decode=True,
                 position_offset=0, mutable=["cache"],
+                # a model with recurrent state must know each row's true
+                # length inside the padded bucket: pad tokens leave the state
+                # untouched. Keys-and-values models get no argument at all.
+                **({"cache_write_len": prompt_lens} if state_leaves else {}),
             )
             last = jax.vmap(
                 lambda row, n: jax.lax.dynamic_slice(
@@ -1181,6 +1229,7 @@ class ServingEngine:
         (`kv_cache.paged_decode_update` — same token layout, same frontier
         mask, so logits match the slot path bit-for-bit)."""
         module = self.module
+        mutable, counters = self._step_mutable()
 
         def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
                     finished, remaining, poison, eos_id, tables):
@@ -1190,7 +1239,7 @@ class ServingEngine:
             # num_blocks, so even a stale dispatch's write cannot land
             logits, mutated = module.apply(
                 {"params": params, "cache": cache}, tokens[:, None], decode=True,
-                position_offset=pos, mutable=["cache"], cache_write_mask=live,
+                position_offset=pos, mutable=mutable, cache_write_mask=live,
                 block_tables=tables,
             )
             last = logits[:, -1]
@@ -1207,7 +1256,7 @@ class ServingEngine:
             hit_eos = (eos_id >= 0) & (nxt == eos_id)
             new_finished = finished | (live & (~ok | hit_eos | (new_remaining <= 0)))
             return (mutated["cache"], nxt, new_pos, new_remaining, new_finished,
-                    jax.random.key_data(new_rngs), ok | finished)
+                    jax.random.key_data(new_rngs), ok | finished) + counters(mutated)
 
         if self.mesh is None:
             return _shared_jit(module, "step",
@@ -1426,6 +1475,7 @@ class ServingEngine:
         module, fresh_shapes = self._admit_module, self._fresh_shapes
         cache_shardings = self._cache_shardings
         bt = self._block_tokens
+        state_leaves = self._contract.state_leaves
 
         def admit_fn(pool_cache, params, prompt_rows, slots, prompt_lens,
                      temps, top_ks, rng_batch, budgets, dest_blocks,
@@ -1438,6 +1488,8 @@ class ServingEngine:
             logits, mutated = module.apply(
                 {"params": params, "cache": fresh}, prompt_rows, decode=True,
                 position_offset=0, mutable=["cache"],
+                # true lengths for a model with recurrent state (`_build_admit_fn`)
+                **({"cache_write_len": prompt_lens} if state_leaves else {}),
             )
             last = jax.vmap(
                 lambda row, n: jax.lax.dynamic_slice(
@@ -1451,6 +1503,7 @@ class ServingEngine:
             new_pool = scatter_rows_to_blocks(
                 pool_cache, mutated["cache"], slots, dest_blocks, prompt_lens,
                 bt, shardings=cache_shardings,
+                state_leaves=state_leaves,
             )
             d_tables = d_tables.at[slots].set(group_tables)
             rem0 = budgets - 1
@@ -1792,6 +1845,13 @@ class ServingEngine:
         }
         for dtype, n in tree_bytes_by_dtype(self._cache).items():
             stats[f"slot_pool_bytes/{dtype}"] = n
+        state_bytes = 0
+        if self._contract.state_leaves:
+            # per-slot recurrent state rides in the same cache tree: its
+            # bytes are part of slot_pool_bytes and are broken out here
+            state_bytes = state_nbytes(self._cache, self._contract.state_leaves)
+            stats["slot_state_bytes"] = state_bytes
+            stats["slot_state_bytes_per_slot"] = state_bytes // self.max_concurrency
         for k, v in self.quant_stats().items():
             stats[f"quant/{k}"] = v
         if self.paged:
@@ -1804,7 +1864,7 @@ class ServingEngine:
                     if self.prefix_cache is not None else {})
             resident = int(base.get("blocks_resident", 0))
             for k, v in {
-                "pool_bytes": stats["slot_pool_bytes"],
+                "pool_bytes": stats["slot_pool_bytes"] - state_bytes,
                 "block_tokens": self._block_tokens,
                 "blocks_total": alloc.num_blocks,
                 "blocks_free": alloc.free_count,
@@ -2037,11 +2097,13 @@ class ServingEngine:
                 self.metrics.spec_forwards.inc()
                 kind, tokens_attr = "spec", self.draft_tokens + 1
             elif self.tokens_per_sync == 1:
+                # a model with step counters returns them as one more small
+                # array, fetched with the tokens (`_step_mutable`)
                 (self._cache, nxt, self._d_pos, self._d_remaining, fin,
-                 self._rng_data, ok) = self._dispatch(
+                 self._rng_data, ok, *counted) = self._dispatch(
                     self._compile_key("step"), self._step_fn, *step_args)
                 self._d_tokens, self._d_finished = nxt, fin
-                arrays = (nxt, fin, ok)
+                arrays = (nxt, fin, ok, *counted)
                 kind, tokens_attr = "step", 1
             else:
                 # one scan dispatch advances the device state k iterations;
@@ -2665,7 +2727,10 @@ class ServingEngine:
 
     def _process_step(self, entry: _Inflight, fetched: tuple, now: float,
                       finished: list[RequestOutput]) -> None:
-        tokens, fins, healthy = (np.asarray(a) for a in fetched)
+        tokens, fins, healthy, *counted = (np.asarray(a) for a in fetched)
+        if counted:
+            self.metrics.observe_step_counters(
+                self._contract.step_counters, counted[0])
         if tokens.ndim == 1:
             # single-token dispatch: normalize to the stacked [k, b] layout
             # the multi-token walk below expects (k == 1)

@@ -212,6 +212,13 @@ class ServingMetrics:
         self.compile_count = Counter()
         self.compile_s = Histogram()
         self.compiles: dict[str, float] = {}
+        # counters a model sums on the device inside its decode step and the
+        # engine fetches with the step's tokens (`CacheContract.step_counters`
+        # — e.g. `moe_picks_held`, `moe_experts_touched`), summed over every
+        # fetched step; `counted_steps` is how many steps brought them. Empty
+        # for a model that declares none (snapshot then has no such key).
+        self.step_counters: dict[str, int] = {}
+        self.counted_steps = Counter()
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -394,6 +401,12 @@ class ServingMetrics:
         self.step_phase_telemetry_s.observe(t.telemetry_s)
         self.step_total_s.observe(t.total_s)
 
+    def observe_step_counters(self, names, values) -> None:
+        """One fetched decode step's device-side counters, in ``names``' order."""
+        self.counted_steps.inc()
+        for name, value in zip(names, values):
+            self.step_counters[name] = self.step_counters.get(name, 0) + int(value)
+
     def record_compile(self, key: str, seconds: float) -> None:
         """First dispatch of a jitted serving program: one compile, keyed by
         ``kind[pb{prompt_bucket}b{batch_bucket}]@mesh{data}x{model}``."""
@@ -474,6 +487,10 @@ class ServingMetrics:
                 out[f"serving/slo/{name}/{stat}"] = stats[stat]
         for key, seconds in self.compiles.items():
             out[f"serving/compile/{key}"] = seconds
+        for name, total in self.step_counters.items():
+            out[f"serving/step_counters/{name}"] = total
+        if self.step_counters:
+            out["serving/step_counters/steps"] = self.counted_steps.value
         for p, n in sorted(self.class_shed.items()):
             out[f"serving/class/{p}/shed"] = n
         for name, hist in (

@@ -1,0 +1,21 @@
+"""Whole serving step's share of the chip's bf16 peak for Qwen3-Next: forward
+FLOPs of the chip's share (`flops_qwen3_next.serve_request_flops`: the held
+experts' part of each token's picks under even routing, the sliced head, the
+delta rule's products with its state) of the requests finished in the traced
+run's window, per second of that window, over chips times peak. In percent.
+Requests in flight at either edge of the window stand in for each other. A
+decode-bound cell reads a few percent: the step is bound by bytes
+(`step_hbm_roofline.serve.qwen3next`)."""
+
+import flops_qwen3_next as flops
+import peaks
+
+
+def read(run):
+    cell, window = run["cell"], run.get("window")
+    if cell.rehearsal or not window or not window["done"]:
+        return None
+    total = sum(flops.serve_request_flops(cell.config, len(item["prompt"]), len(out.tokens))
+                for item, out in window["done"])
+    peak = peaks.peaks_for(run["peaks_kind"])["bf16_flops_per_s"]
+    return 100.0 * total / window["seconds"] / (run["chips"] * peak)

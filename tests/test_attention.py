@@ -400,3 +400,20 @@ def _run_tp_shard_assertions(out, f, q, k, v, qs, ks, vs):
     ref1 = dot_product_attention(
         q1, jnp.repeat(k1, 2, axis=2), jnp.repeat(v1, 2, axis=2), causal=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref1), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq,want", [(1024, "flash"), (2048, "flash"), (1536, "xla"), (512, "xla")])
+def test_auto_routes_a_length_the_default_blocks_do_not_divide_to_xla(monkeypatch, seq, want):
+    """On a TPU `auto` takes the flash kernel from 1024 tokens on; a length its
+    1024-wide rectangular blocks do not divide (a 1536 prompt bucket) raised
+    in the kernel and now goes to xla."""
+    from accelerate_tpu.ops import flash_attention as fa
+    from accelerate_tpu.ops.attention import attention
+    from accelerate_tpu.utils import environment
+
+    monkeypatch.setattr(environment, "on_tpu_platform", lambda: True)
+    took = []
+    monkeypatch.setattr(fa, "flash_attention", lambda q, k, v, **kw: took.append("flash") or q)
+    q = k = v = _rand((1, seq, 2, 8), 0)
+    out = attention(q, k, v, causal=True)
+    assert out.shape == q.shape and (took or ["xla"]) == [want]

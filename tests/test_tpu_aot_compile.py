@@ -273,3 +273,22 @@ def test_admit_scatter_leaves_the_pool_in_place(topology, bucket):
         [compiled.input_formats[0][0][name] for name in kv],
         [compiled.output_formats[name] for name in kv],
     )
+
+
+# ------------------------------------- the expert layer's grouped products
+@pytest.mark.parametrize("tokens, pallas", [(512, True), (1536, True), (128, False)])
+def test_held_experts_grouped_product(topology, compiled_kernels, tokens, pallas):
+    """One expert-parallel chip's share at the Qwen3-Next cell's widths (256
+    held of 512 experts of width 512, hidden 2048, 10 picks a token): a prompt
+    bucket's picks go through the Pallas grouped matmul inside its 16 MiB of
+    VMEM, a decode step's 1,280 keep XLA's `ragged_dot`."""
+    from accelerate_tpu.ops.moe import held_experts_mlp
+
+    s = _one_device(topology)
+    hidden, width, held, k = 2048, 512, 256, 10
+    compiled = _compile(
+        lambda x, p, idx, wgu, wd: held_experts_mlp(x, p, idx, wgu, wd)[0],
+        _sds((tokens, hidden), jnp.bfloat16, s), _sds((tokens, k), jnp.float32, s),
+        _sds((tokens, k), jnp.int32, s), _sds((held, hidden, 2 * width), jnp.bfloat16, s),
+        _sds((held, width, hidden), jnp.bfloat16, s))
+    assert (compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2) is pallas
