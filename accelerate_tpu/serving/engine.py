@@ -52,6 +52,7 @@ so a request served here emits the SAME tokens as a solo ``generate`` with
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -133,23 +134,61 @@ from .trace import (
 
 
 def _sample_slot(logits: jax.Array, key: jax.Array, temperature: jax.Array,
-                 top_k: jax.Array) -> jax.Array:
+                 top_k: jax.Array, *, mask_top_k: bool = True) -> jax.Array:
     """Sample one slot's next token from ``[vocab]`` logits.
 
     Value-matches `models/generation._sample` on a single row with the same
     key (the parity contract), but temperature/top_k are DATA here — the
     static python branches become jnp.where so every slot can carry its own
     settings inside one compiled step. top_k == 0 disables the top-k mask.
+    ``mask_top_k=False`` is the same body for rows known to carry
+    ``top_k == 0``: the mask's condition holds ``top_k > 0``, so ``masked ==
+    scaled`` and the vocabulary-wide sort that feeds it is left out.
     """
     greedy = jnp.argmax(logits, axis=-1)
     vocab = logits.shape[-1]
     safe_t = jnp.where(temperature > 0, temperature, jnp.ones_like(temperature))
     scaled = logits / safe_t
-    ordered = jnp.sort(scaled, axis=-1)  # ascending, like _sample's kth lookup
-    kth = jnp.take(ordered, vocab - jnp.clip(top_k, 1, vocab))
-    masked = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
-    sampled = jax.random.categorical(key, masked, axis=-1)
+    if mask_top_k:
+        ordered = jnp.sort(scaled, axis=-1)  # ascending, like _sample's kth lookup
+        kth = jnp.take(ordered, vocab - jnp.clip(top_k, 1, vocab))
+        scaled = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
+    sampled = jax.random.categorical(key, scaled, axis=-1)
     return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+
+
+def _sample_rows(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
+                 top_k: jax.Array, live: jax.Array) -> jax.Array:
+    """Sample every row's next token from ``[rows, vocab]`` logits, paying
+    only for what the ``live`` rows ask for.
+
+    `_sample_slot` under `jax.vmap` charges every row, every step, for the
+    heaviest setting a row could carry: the vocabulary-wide sort whose k-th
+    value is thrown away at ``top_k == 0`` and the random draw thrown away at
+    ``temperature == 0``. Here ONE `lax.switch`, outside the vmap so XLA
+    emits a real ``conditional`` (a per-row predicate inside a vmap turns
+    back into a select that runs both sides), picks for the whole batch:
+
+    0. no live row draws: ``argmax`` only;
+    1. some live row draws, none with top-k: `_sample_slot` without the sort;
+    2. some live row draws with top-k: `_sample_slot` for every row.
+
+    Live rows' tokens are bit-equal to ``jax.vmap(_sample_slot)``'s in every
+    case (tests/test_sample_tail.py); a row that is not ``live`` (a finished
+    or vacant slot still carrying its last tenant's settings) may get a
+    lighter branch's token, which its caller discards. The keys are split by
+    the caller, outside the branches: the rng chain is one split a token
+    whichever branch runs.
+    """
+    draws = live & (temperature > 0)
+    branch = (jnp.any(draws).astype(jnp.int32)
+              + jnp.any(draws & (top_k > 0)).astype(jnp.int32))
+    return jax.lax.switch(
+        branch,
+        (lambda logits, *_: jnp.argmax(logits, axis=-1).astype(jnp.int32),
+         jax.vmap(functools.partial(_sample_slot, mask_top_k=False)),
+         jax.vmap(_sample_slot)),
+        logits, keys, temperature, top_k)
 
 
 @dataclasses.dataclass
@@ -824,6 +863,11 @@ class ServingEngine:
         # carries an SLO with an ITL bound (None otherwise — the common path
         # appends nothing); retired into per-class attainment via observe_slo
         self._slot_itl: list[list[float] | None] = [None] * b
+        # held slots whose tenant samples (temperature > 0), and those of them
+        # with a top-k mask: kept at admit and release, read at each decode
+        # dispatch for the `serving/sample_tail/*` counters
+        self._draw_slots = 0
+        self._top_k_slots = 0
         self._free: deque[int] = deque(range(b))
         self._inflight: deque[_Inflight] = deque()
         self._next_id = 0
@@ -1056,7 +1100,7 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_data)
             split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            sampled = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            sampled = _sample_rows(last, keys, temps, top_ks, live)
             healthy = live & ok
             nxt = jnp.where(healthy, sampled, tokens)
             new_pos = jnp.where(healthy, pos + 1, pos)
@@ -1117,7 +1161,8 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_batch)
             split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            first = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            first = _sample_rows(last, keys, temps, top_ks,
+                                 jnp.ones_like(temps, bool))
             new_pool = scatter_cache_slots(
                 pool_cache, mutated["cache"], slots, prompt_lens,
                 shardings=cache_shardings,
@@ -1187,7 +1232,8 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_batch)
             split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            first = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            first = _sample_rows(last, keys, temps, top_ks,
+                                 jnp.ones_like(temps, bool))
             # decode resumes from the FULL prompt end: cached prefix + suffix
             prompt_lens = cached_lens + suffix_lens
             new_pool = scatter_cache_slots(
@@ -1248,7 +1294,7 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_data)
             split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            sampled = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            sampled = _sample_rows(last, keys, temps, top_ks, live)
             healthy = live & ok
             nxt = jnp.where(healthy, sampled, tokens)
             new_pos = jnp.where(healthy, pos + 1, pos)
@@ -1305,7 +1351,7 @@ class ServingEngine:
                 rngs = jax.random.wrap_key_data(rng_data)
                 split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
                 new_rngs, keys = split[:, 0], split[:, 1]
-                sampled = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+                sampled = _sample_rows(last, keys, temps, top_ks, live)
                 healthy = live & ok
                 nxt = jnp.where(healthy, sampled, tokens)
                 new_pos = jnp.where(healthy, pos + 1, pos)
@@ -1362,7 +1408,7 @@ class ServingEngine:
           ``new_pos`` per slot — the unaccepted suffix becomes dead weight
           past the cursor that the next dispatch simply overwrites. Frozen
           and poisoned slots rewind to their untouched pre-step ``pos``.
-        - **Parity.** Position 0 samples through the same `_sample_slot` and
+        - **Parity.** Position 0 samples through the same `_sample_rows` and
           the same split chain as the plain step; positions 1..n-1 are the
           target's own greedy choices at exactly the logits a sequential
           decode would have produced (the drafts they extend matched those
@@ -1412,7 +1458,7 @@ class ServingEngine:
                     key0 = sp[:, 1]
                 states.append(jax.random.key_data(cur))
             states = jnp.stack(states, axis=1)  # [b, s, *key]
-            sampled0 = jax.vmap(_sample_slot)(logits[:, 0], key0, temps, top_ks)
+            sampled0 = _sample_rows(logits[:, 0], key0, temps, top_ks, live)
             out_tokens = jnp.concatenate(
                 [sampled0[:, None], greedy[:, 1:]], axis=1)  # [b, s]
             matches = drafts == greedy[:, :k_draft]
@@ -1499,7 +1545,8 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_batch)
             split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            first = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            first = _sample_rows(last, keys, temps, top_ks,
+                                 jnp.ones_like(temps, bool))
             new_pool = scatter_rows_to_blocks(
                 pool_cache, mutated["cache"], slots, dest_blocks, prompt_lens,
                 bt, shardings=cache_shardings,
@@ -1570,7 +1617,8 @@ class ServingEngine:
             rngs = jax.random.wrap_key_data(rng_batch)
             split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
-            first = jax.vmap(_sample_slot)(last, keys, temps, top_ks)
+            first = _sample_rows(last, keys, temps, top_ks,
+                                 jnp.ones_like(temps, bool))
             # decode resumes from the FULL prompt end: cached prefix + suffix
             prompt_lens = cached_lens + suffix_lens
             new_pool = scatter_rows_to_blocks(
@@ -1730,6 +1778,7 @@ class ServingEngine:
         # not a new admission; TTFT was already paid)
         self._slot_gen[slot] += 1
         self._slot_req[slot] = request
+        self._count_sample_tail(sp, 1)
         out = RequestOutput(
             request_id=request.request_id, prompt_len=plen,
             tokens=list(rec.tokens), finish_reason="",
@@ -2067,6 +2116,7 @@ class ServingEngine:
             )
         self._step_count += 1
         if n_active:
+            self.metrics.observe_sample_tail(self._draw_slots, self._top_k_slots)
             poison = self._poison_mask()
             step_args = (
                 self._cache, self.params, self._d_tokens, self._d_pos,
@@ -3276,6 +3326,7 @@ class ServingEngine:
             self._slot_gen[slot] += 1
             gens.append(int(self._slot_gen[slot]))
             self._slot_req[slot] = request
+            self._count_sample_tail(request.params, 1)
             self._slot_out[slot] = RequestOutput(
                 request_id=request.request_id, prompt_len=len(request.prompt),
                 # a resumed stream's recovered prefix is part of the output;
@@ -3416,6 +3467,13 @@ class ServingEngine:
         self._release_slot(slot)
         finished.append(out)
 
+    def _count_sample_tail(self, params: SamplingParams, sign: int) -> None:
+        """A slot took (+1) or gave up (-1) a tenant with these params."""
+        if params.temperature > 0:
+            self._draw_slots += sign
+            if (params.top_k or 0) > 0:
+                self._top_k_slots += sign
+
     def _release_slot(self, slot: int) -> None:
         """Return a slot to the free pool. Device state needs no touch-up:
         the slot is frozen by its on-device finished mask (or, for a cancel,
@@ -3441,6 +3499,7 @@ class ServingEngine:
             # scatter can land.
             self._d_tables = self._d_tables.at[slot].set(
                 jnp.int32(self._allocator.num_blocks))
+        self._count_sample_tail(self._slot_req[slot].params, -1)
         self._slot_match[slot] = None
         self._slot_hit[slot] = False
         self._slot_itl[slot] = None

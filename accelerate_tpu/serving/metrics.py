@@ -219,6 +219,16 @@ class ServingMetrics:
         # for a model that declares none (snapshot then has no such key).
         self.step_counters: dict[str, int] = {}
         self.counted_steps = Counter()
+        # which branch of the sampling tail (`engine._sample_rows`) each
+        # dispatched decode step was sent to, by the HOST's view at dispatch:
+        # the params of the slots it holds. The device decides from its own
+        # `finished` mask, so for a turn or two this can read one branch
+        # heavier than the device ran (a slot finished on the device and not
+        # yet retired here) or lighter (a cancelled slot burns out its budget
+        # on the device after the host released it).
+        self.sample_tail_greedy_steps = Counter()
+        self.sample_tail_draw_steps = Counter()
+        self.sample_tail_top_k_steps = Counter()
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -407,6 +417,16 @@ class ServingMetrics:
         for name, value in zip(names, values):
             self.step_counters[name] = self.step_counters.get(name, 0) + int(value)
 
+    def observe_sample_tail(self, draw_slots: int, top_k_slots: int) -> None:
+        """One dispatched decode step: ``draw_slots`` held slots sample
+        (temperature > 0), ``top_k_slots`` of them with a top-k mask."""
+        if top_k_slots:
+            self.sample_tail_top_k_steps.inc()
+        elif draw_slots:
+            self.sample_tail_draw_steps.inc()
+        else:
+            self.sample_tail_greedy_steps.inc()
+
     def record_compile(self, key: str, seconds: float) -> None:
         """First dispatch of a jitted serving program: one compile, keyed by
         ``kind[pb{prompt_bucket}b{batch_bucket}]@mesh{data}x{model}``."""
@@ -463,6 +483,11 @@ class ServingMetrics:
             "serving/accepted_tokens_per_forward": (
                 self.spec_tokens.value / self.spec_forwards.value
                 if self.spec_forwards.value else 0.0),
+            "serving/sample_tail/greedy_steps": (
+                self.sample_tail_greedy_steps.value),
+            "serving/sample_tail/draw_steps": self.sample_tail_draw_steps.value,
+            "serving/sample_tail/top_k_steps": (
+                self.sample_tail_top_k_steps.value),
             "serving/streams_opened": self.streams_opened.value,
             "serving/streams_finished": self.streams_finished.value,
             "serving/stream_events": self.stream_events.value,

@@ -7,7 +7,8 @@ compile for a chip that is not there (`jax.experimental.topologies`), so this
 file compiles each kernel at gpt2-medium shapes with ``interpret=False`` — on
 one device and inside a four-device jit. It checks that the program builds;
 only a chip run (`chip_smoke.py`) checks what it computes. Marked slow, all
-but the paged pool's layout guard at the end (two compiles, about 3 s).
+but the guards at the end: the paged pool's layout (two compiles, about 3 s),
+the expert layer's grouped products, and the sampling tail's conditional.
 """
 
 import functools
@@ -292,3 +293,97 @@ def test_held_experts_grouped_product(topology, compiled_kernels, tokens, pallas
         _sds((tokens, k), jnp.int32, s), _sds((held, hidden, 2 * width), jnp.bfloat16, s),
         _sds((held, width, hidden), jnp.bfloat16, s))
     assert (compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2) is pallas
+
+
+# ------------------------------------------- the sampling tail's conditional
+# `engine._sample_rows` branches once for the batch, so a decode step or an
+# admit program whose rows are all greedy runs no vocabulary-wide sort. A
+# `cond` moved back under the `vmap` lowers to a select that runs every side:
+# these fail then, where otherwise only the benchmark would show it.
+TAIL_VOCAB = 1000  # a sort this wide compiles in a second; 8,192 wide takes 20
+
+
+def _always_run_wide_sorts(hlo, width):
+    """``(sorts, branch_sorts)``: the ``sort`` instructions over ``[..., width]``
+    in computations the program runs on every call (reached from ``ENTRY``
+    through fusions, calls and loop bodies), and those reached only through a
+    ``conditional``'s branch computations."""
+    comps, entry, name = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    wide = re.compile(rf"\[(\d+,)*{width}\]\S* sort\(")  # the result, or a tuple result's last part
+    todo, seen, always = [entry], set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            if wide.search(line):
+                always.append(line.strip()[:160])
+            if " conditional(" not in line:
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+    elsewhere = [line.strip()[:160] for comp in comps.keys() - seen
+                 for line in comps[comp] if wide.search(line)]
+    return always, elsewhere
+
+
+@pytest.fixture(scope="module")
+def serving_programs():
+    """The paged engine's decode step and admit program with the shapes of
+    the arguments it dispatched them with, from one tiny request served here
+    on the CPU (kernels interpreted; the tests below trace them again)."""
+    from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+    from accelerate_tpu.serving import Request, SamplingParams, ServingEngine
+
+    module = GPT2LMHead(GPT2Config(vocab_size=TAIL_VOCAB, n_positions=256, n_embd=2 * D,
+                                   n_layer=1, n_head=2, dtype=jnp.bfloat16))
+    engine = ServingEngine(module, module.init_params(jax.random.key(0)), max_concurrency=8,
+                           prompt_buckets=(128,), paged_kv=True, paged_attention="fused")
+    programs, dispatch = {}, engine._dispatch
+
+    def record(key, fn, *args):
+        programs.setdefault(key.partition("@")[0].partition("[")[0],
+                            (fn.__wrapped__, jax.tree.map(lambda a: (a.shape, a.dtype), args)))
+        return dispatch(key, fn, *args)
+
+    engine._dispatch = record
+    engine.run([Request([1, 2, 3], SamplingParams(max_new_tokens=2))])
+    return programs
+
+
+@pytest.mark.parametrize("kind", ["step", "admit"])
+def test_sampling_tail_sorts_only_inside_a_conditional(topology, compiled_kernels,
+                                                       serving_programs, kind):
+    s = _one_device(topology)
+    fn, shapes = serving_programs[kind]
+    args = jax.tree.map(lambda shape_dtype: _sds(*shape_dtype, s), shapes,
+                        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+    hlo = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().as_text()
+    # the decode step holds the fused kernel, compiled and not interpreted
+    assert ('custom_call_target="tpu_custom_call"' in hlo) is (kind == "step")
+    always, in_branches = _always_run_wide_sorts(hlo, TAIL_VOCAB)
+    assert not always, always
+    assert in_branches, "the top-k branch's sort is gone: this guard sees nothing"
+
+
+def test_sampling_tail_guard_sees_a_sort_under_the_vmap(topology):
+    """What the guard above is for: the per-row body under `vmap`, as every
+    step ran it before `_sample_rows`, puts the sort where every call runs it."""
+    from accelerate_tpu.serving.engine import _sample_slot
+
+    s = _one_device(topology)
+    hlo = _compile(jax.vmap(_sample_slot), _sds((8, TAIL_VOCAB), jnp.float32, s),
+                   _sds((8,), jax.random.key(0).dtype, s), _sds((8,), jnp.float32, s),
+                   _sds((8,), jnp.int32, s)).as_text()
+    always, in_branches = _always_run_wide_sorts(hlo, TAIL_VOCAB)
+    assert always and not in_branches
