@@ -54,7 +54,7 @@ class GPT2Config:
     kv_cache_dtype: Any = None  # None | jnp.int8 (see models/kv_cache.py)
     # per-slot [b]-vector cache write index instead of one scalar shared by the
     # batch: every row decodes at its own position (the serving engine's
-    # continuous-batching slot pool — serving/engine.py). position_offset may
+    # continuous-batching slots — serving/engine.py). position_offset may
     # then be a [b] vector too.
     kv_cache_per_slot: bool = False
     # paged KV: decode KV lives in a shared [kv_num_blocks, kv_block_tokens,

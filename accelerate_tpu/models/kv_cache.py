@@ -88,8 +88,8 @@ def decode_cache_update(
 
     ``per_slot=True`` replaces the scalar write index shared by the whole batch
     with a ``[b]`` vector: row ``i`` writes its new entries at its own
-    ``cache_index[i]`` (the serving engine's slot pool, where every slot sits at
-    a different position in an independent sequence — `serving/engine.py`).
+    ``cache_index[i]`` (the serving engine's admission rows, where every row sits
+    at a different position in an independent sequence — `serving/engine.py`).
     ``write_index`` is then the ``[b]`` vector and row starts clamp into range
     exactly like ``dynamic_update_slice``.
 
@@ -120,7 +120,7 @@ def decode_cache_update(
     if write_len is not None and not per_slot:
         raise ValueError(
             "write_len requires per_slot=True (per-row segment clamping is a "
-            "slot-pool decode concept)"
+            "per-slot decode concept)"
         )
     quant = kv_cache_dtype is not None
     b, s, kv_heads, head_dim = k.shape
@@ -382,7 +382,7 @@ def paged_decode_update(
     layer; heads folded into the last dim so the stored layout tiles, see
     `_paged_pool_step`) plus the per-slot ``[b]`` write cursor, and each row's
     KV lives wherever its block table says. Returns ``(k_all, v_all,
-    write_index, is_init)`` exactly like the slot-pool path, with
+    write_index, is_init)`` exactly like `decode_cache_update`, with
     ``k_all``/``v_all`` the gathered ``[b, blocks_per_slot * block_tokens,
     kv_heads, head_dim]`` attended view (heads unfolded after the gather).
 
@@ -401,8 +401,8 @@ def paged_decode_update(
     the same ``[num_blocks, block_tokens, kv_heads * head_dim]`` leaves and
     the fp32 absmax scales ride sibling ``key_scale``/``value_scale`` pool
     leaves of shape ``[num_blocks, block_tokens, kv_heads]`` — per-block
-    planes addressed through the SAME block table, mirroring the slot path's
-    per-(batch, position, kv-head) scheme. The gathered attended view is
+    planes addressed through the SAME block table, mirroring the contiguous
+    cache's per-(batch, position, kv-head) scheme. The gathered attended view is
     dequantized here (scales gathered alongside the payload), so attention
     sees compute-dtype K/V either way.
     """
@@ -415,8 +415,8 @@ def paged_decode_update(
         return k, v, idx, False
     # the attended view: each row's table blocks concatenated in token order —
     # position p of row i sits at gathered index p (block p // block_tokens,
-    # offset p % block_tokens), the same layout the slot-pool cache has, so
-    # the caller's frontier mask is identical in both modes
+    # offset p % block_tokens), the same layout a contiguous per-slot cache
+    # has, so the caller's frontier mask is the same for both
     blocks_per_slot = block_tables.shape[1]
     span = blocks_per_slot * block_tokens
 
@@ -500,10 +500,10 @@ def rewind_frontier(cache: Any, new_index: jax.Array) -> Any:
     (`serving/engine.py`). A rejected draft's KV entries stay behind in the
     slot buffer / block pool, but the cursor retreat makes them dead state:
     the next write lands on top of them and the frontier mask keeps attention
-    from ever reading past the cursor. Works unchanged for the slot-pool,
-    paged-gather, and paged-fused layouts because all three share the ``[b]``
-    cursor leaf — in paged mode this is the promised block-table rollback
-    with no pool copy."""
+    from ever reading past the cursor. Works unchanged for the contiguous
+    per-slot, paged-gather, and paged-fused layouts because all three share
+    the ``[b]`` cursor leaf — for the paged pool this is the promised
+    block-table rollback with no pool copy."""
 
     def stamp(path, leaf):
         if _is_index_leaf(path):
@@ -517,7 +517,8 @@ class BlockAllocator:
     """Host-side free-list over a device block pool's ids (paged KV serving,
     `docs/serving.md` "Paged KV").
 
-    The pool itself is device state (`make_block_pool` leaves); this tracks
+    The pool itself is device state (the paged decode module's cache leaves,
+    `make_cache`); this tracks
     which block ids are owned — by a slot's private frontier or by the prefix
     trie — purely on the host, so admission never round-trips the device to
     find space. Allocation is all-or-nothing: a request that cannot get every
@@ -585,10 +586,11 @@ def tree_bytes_by_dtype(tree: Any) -> dict[str, int]:
 
 
 def make_cache(module: Any, batch: int, shardings: Any = None) -> Any:
-    """Allocate the zeroed ``[batch, n_positions, ...]`` per-slot decode cache
-    pytree for ``module`` (the serving engine's slot pool) without running a
-    real forward: shapes come from `jax.eval_shape` over ``module.init``, so
-    no throwaway init compute touches the device.
+    """Allocate ``module``'s zeroed decode cache pytree for ``batch`` slots
+    (the serving engine's block pool and per-slot cursor when the module's
+    config is paged, ``[batch, n_positions, ...]`` rows when it is not)
+    without running a real forward: shapes come from `jax.eval_shape` over
+    ``module.init``, so no throwaway init compute touches the device.
 
     ``shardings`` is an optional congruent pytree of NamedShardings
     (`parallel.sharding.infer_cache_shardings`): each leaf is then allocated
@@ -614,35 +616,6 @@ def _constrain_tree(tree: Any, shardings: Any) -> Any:
     if shardings is None:
         return tree
     return jax.tree.map(jax.lax.with_sharding_constraint, tree, shardings)
-
-
-def make_block_pool(cache: Any, num_blocks: int, block_tokens: int,
-                    shardings: Any = None) -> Any:
-    """Allocate the device-resident block pool for prefix KV reuse
-    (`serving/prefix_cache.py`): a pytree mirroring a per-slot cache, but with
-    every KV leaf carved into ``[num_blocks, block_tokens, ...]`` fixed-size
-    blocks instead of ``[B, n_positions, ...]`` slot rows.
-
-    ``cache_index`` leaves become per-block placeholders (the pool has no
-    write cursor — block occupancy lives in the host-side radix trie); they
-    exist only so the pool shares the cache's treedef and one ``tree_map``
-    drives every gather/scatter.
-
-    ``shardings`` (a congruent pytree of NamedShardings,
-    `parallel.sharding.infer_block_pool_shardings`) allocates each block leaf
-    straight into its mesh placement — heads sharded on the model axis, blocks
-    replicated across replicas so any replica can reuse any cached prefix.
-    """
-
-    def alloc(path, leaf):
-        if _is_index_leaf(path):
-            return jnp.zeros((num_blocks,), leaf.dtype)
-        return jnp.zeros((num_blocks, block_tokens) + leaf.shape[2:], leaf.dtype)
-
-    pool = jax.tree_util.tree_map_with_path(alloc, cache)
-    if shardings is not None:
-        pool = jax.tree.map(jax.device_put, pool, shardings)
-    return pool
 
 
 def gather_block_rows(
@@ -680,59 +653,6 @@ def gather_block_rows(
     )
 
 
-def scatter_block_rows(
-    block_pool: Any,  # [num_blocks, block_tokens, ...] pool pytree
-    cache: Any,  # the [B, n_positions, ...] slot-pool cache pytree
-    slot: jax.Array,  # scalar int32 slot row to donate from
-    dest_blocks: jax.Array,  # [n_positions // block_tokens] int32 pool ids; >= num_blocks drops
-    shardings: Any = None,  # congruent NamedShardings keeping the pool's layout
-) -> Any:
-    """Donate one slot row's KV into pool blocks in ONE scatter per leaf (the
-    prefix cache's retire-time donation). ``dest_blocks[j]`` is where the
-    row's ``j``-th block lands; entries pointing past the pool (``num_blocks``)
-    are dropped — that is how already-present trie blocks and the region past
-    the donated prefix are skipped without a second compile."""
-
-    def scatter(path, pool_leaf, cache_leaf):
-        if _is_index_leaf(path):
-            return pool_leaf
-        row = cache_leaf[slot]  # [n_positions, ...]
-        n_blocks = dest_blocks.shape[0]
-        blocks = row.reshape((n_blocks, row.shape[0] // n_blocks) + row.shape[1:])
-        return pool_leaf.at[dest_blocks].set(blocks, mode="drop")
-
-    return _constrain_tree(
-        jax.tree_util.tree_map_with_path(scatter, block_pool, cache), shardings
-    )
-
-
-def scatter_cache_slots(
-    pool_cache: Any,  # the [B, ...] slot-pool cache pytree
-    new_cache: Any,  # an [nb, ...] freshly prefilled cache pytree
-    slots: jax.Array,  # [nb] int32 distinct pool rows to write
-    cache_index: jax.Array,  # [nb] int32 per-row resume index (unpadded length)
-    shardings: Any = None,  # congruent NamedShardings keeping the pool's layout
-) -> Any:
-    """Scatter an ``nb``-row prefill cache into pool rows ``slots`` in ONE
-    jitted op per leaf (the serving engine's batched admission: `pipeline
-    decode dispatch`, `serving/engine.py`).
-
-    Every leaf's rows land at ``pool_leaf[slots[i]]``. The ``cache_index``
-    leaf is OVERWRITTEN with ``cache_index`` — the prefill advanced it to the
-    padded bucket length, but decode must resume (and overwrite the pad
-    entries) from each row's true prompt end.
-    """
-
-    def insert(path, pool_leaf, new_leaf):
-        if getattr(path[-1], "key", None) == "cache_index":
-            return pool_leaf.at[slots].set(cache_index.astype(pool_leaf.dtype))
-        return pool_leaf.at[slots].set(new_leaf.astype(pool_leaf.dtype))
-
-    return _constrain_tree(
-        jax.tree_util.tree_map_with_path(insert, pool_cache, new_cache), shardings
-    )
-
-
 def scatter_rows_to_blocks(
     paged_cache: Any,  # paged cache pytree: KV [num_blocks, block_tokens, ...], cache_index [B]
     new_cache: Any,  # an [nb, bucket, ...] freshly prefilled cache pytree
@@ -745,16 +665,17 @@ def scatter_rows_to_blocks(
 ) -> Any:
     """Paged admission: carve each freshly prefilled contiguous row into
     ``block_tokens``-sized pieces and scatter them into the row's allocated
-    pool blocks in ONE op per leaf (the paged counterpart of
-    `scatter_cache_slots`). ``dest_blocks[i, j]`` is where row ``i``'s
+    pool blocks in ONE op per leaf. ``dest_blocks[i, j]`` is where row ``i``'s
     ``j``-th piece lands; entries pointing past the pool (``num_blocks``)
     are dropped — that is how a cache hit's ALIASED prefix blocks (already
     resident, trie-pinned, shared zero-copy through the block table) and the
     pad region past a short bucket are skipped without a second compile.
 
     The ``cache_index`` leaf rows ``slots`` are stamped with ``cache_index``
-    (the true prefill length — decode's append frontier), exactly like the
-    slot-pool admission scatter. ``state_leaves`` are not paged: their fresh
+    (the true prefill length — decode's append frontier: the prefill advanced
+    the fresh rows' cursor to the padded bucket length, but decode must
+    resume, and overwrite the pad entries, from each row's true prompt end).
+    ``state_leaves`` are not paged: their fresh
     ``[nb, ...]`` rows overwrite the slots' whole state.
     """
 

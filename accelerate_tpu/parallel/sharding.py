@@ -196,16 +196,18 @@ def constrain(x: Any, mesh: Mesh, spec: PartitionSpec) -> Any:
 
 
 # --------------------------------------------------------------------------- KV
-# Serving-side sharding rules: the engine's slot-pool KV cache and the prefix
-# block pool are pytrees of a known leaf zoo (models/kv_cache.py):
-#   cached_key / cached_value  [slots, max_len, kv_heads, head_dim]
-#   key_scale  / value_scale   [slots, max_len, kv_heads]        (int8 storage)
+# Serving-side sharding rules: the engine's block pool and the contiguous rows
+# an admission prefills are pytrees of a known leaf zoo (models/kv_cache.py):
+#   cached_key / cached_value  [slots, max_len, kv_heads, head_dim] rows, or
+#                              [num_blocks, block_tokens, kv_heads * head_dim]
+#   key_scale  / value_scale   [slots, max_len, kv_heads] or
+#                              [num_blocks, block_tokens, kv_heads]  (int8 storage)
 #   cache_index                [slots]
 # Tensor parallelism shards the HEAD dim (attention is embarrassingly parallel
 # over heads — the collectives stay in the proj/down matmuls, exactly where the
 # training-mesh rules already put them); data parallelism shards the SLOT dim
-# so replicas decode disjoint slot ranges. Block pools shard heads only — a
-# block is one shared prefix, readable by every replica.
+# so replicas decode disjoint slot ranges. The block pool shards heads only —
+# any replica's slot may own or alias any block.
 
 
 @dataclass(frozen=True)
@@ -214,14 +216,14 @@ class KVCacheSharding:
     can ride inside a frozen model config — `GPT2Config.kv_cache_sharding` —
     down to `models/kv_cache.decode_cache_update`'s in-jit constraints).
 
-    In paged mode (`kv_cache_sharding(..., paged=True)`) ``kv`` describes the
+    With ``paged=True`` (`kv_cache_sharding(..., paged=True)`) ``kv`` describes the
     shared ``[num_blocks, block_tokens, kv_heads * head_dim]`` block pool
     instead of slot rows: heads are folded into the last dim so the stored
     layout tiles (20 x 64 trailing dims pad to 32 x 128 on a TPU, 3.2x), and a
     model-axis shard of that dim is whole heads, each a contiguous run of
     ``head_dim``. ``gathered`` carries the layout of the per-slot attended view
     the paged update assembles (`models/kv_cache.paged_decode_update`) — the
-    slot-pool layout, so attention math shards identically in both modes.
+    contiguous rows' layout, so attention math shards identically for both.
     """
 
     kv: NamedSharding  # [slots, max_len, kv_heads, head_dim] buffers, or the 3-dim block pool
@@ -234,10 +236,6 @@ def _leaf_name(path) -> str | None:
     return getattr(path[-1], "key", getattr(path[-1], "name", None))
 
 
-def _is_cache_index(path) -> bool:
-    return _leaf_name(path) == "cache_index"
-
-
 def kv_cache_sharding(
     mesh: Mesh,
     *,
@@ -246,7 +244,7 @@ def kv_cache_sharding(
     head_axis: str = "tensor",
     paged: bool = False,
 ) -> KVCacheSharding:
-    """Build the `KVCacheSharding` for a slot-pool cache on ``mesh``.
+    """Build the `KVCacheSharding` for a per-slot cache on ``mesh``.
 
     The slot dim is sharded over ``batch_axes`` only when ``slots`` divides
     their total degree (pass ``slots=None`` to force replication of the slot
@@ -294,7 +292,7 @@ def block_table_sharding(
 
 
 def infer_cache_shardings(cache: Any, sharding: KVCacheSharding) -> Any:
-    """Pytree of NamedShardings congruent with a slot-pool cache pytree (or its
+    """Pytree of NamedShardings congruent with a decode cache pytree (or its
     `jax.eval_shape` ShapeDtypeStructs) — the engine's jit in/out_shardings for
     every donated cache argument."""
 
@@ -306,20 +304,3 @@ def infer_cache_shardings(cache: Any, sharding: KVCacheSharding) -> Any:
         return sharding.scale if name in ("key_scale", "value_scale") else sharding.kv
 
     return jax.tree_util.tree_map_with_path(pick, cache)
-
-
-def infer_block_pool_shardings(pool: Any, mesh: Mesh, *, head_axis: str = "tensor") -> Any:
-    """NamedShardings for a prefix block pool: heads sharded like the slot
-    cache, blocks replicated across the data axis (any replica may gather any
-    cached prefix block — prefix reuse must not depend on which replica's slot
-    donated it)."""
-    head = head_axis if mesh.shape.get(head_axis, 1) > 1 else None
-
-    def pick(path, leaf):
-        if _is_cache_index(path):
-            return NamedSharding(mesh, P(None))
-        ndim = getattr(leaf, "ndim", len(leaf.shape))
-        return NamedSharding(mesh, P(None, None, head, None) if ndim == 4
-                             else P(None, None, head))
-
-    return jax.tree_util.tree_map_with_path(pick, pool)

@@ -42,7 +42,7 @@ from .frontend import (
 from .journal import JournalError, JournalScan, RequestJournal
 from .kv_tier import KVTier, KVTierConfig, choose_wake
 from .metrics import Counter, Histogram, ServingMetrics
-from .prefix_cache import PrefixCache, PrefixCacheConfig
+from .prefix_cache import PrefixCache
 from .request import (
     FINISH_ABORTED,
     FINISH_EOS,
@@ -109,7 +109,6 @@ __all__ = [
     "KVTierConfig",
     "choose_wake",
     "PrefixCache",
-    "PrefixCacheConfig",
     "ServingMetrics",
     "Counter",
     "Histogram",

@@ -227,7 +227,7 @@ class ServingCluster:
 
         cluster = ServingCluster(
             lambda **kw: ServingEngine(module, params, max_concurrency=4,
-                                       prefix_cache=PrefixCacheConfig(), **kw),
+                                       prefix_cache=True, **kw),
             workdir, replicas=2,
             supervisor_config=SupervisorConfig(max_restarts=1),
         )

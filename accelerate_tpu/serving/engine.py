@@ -6,19 +6,22 @@ every row decodes until the slowest finishes, nobody joins mid-flight. This
 engine multiplexes independent requests through ONE jitted, static-shape
 decode step instead (the serving half of the ROADMAP north star):
 
-  - a pre-allocated per-slot KV cache pool, ``[max_concurrency, n_positions,
-    ...]`` fixed buffers in the `models/kv_cache.py` layout with the per-slot
-    ``[b]`` write-index variant (int8 storage supported via the model config's
-    ``kv_cache_dtype``);
+  - ONE pre-allocated KV store, the paged block pool: ``[num_blocks,
+    block_tokens, kv_heads * head_dim]`` buffers (`models/kv_cache.py`; int8
+    storage via the model config's ``kv_cache_dtype``) that every slot reaches
+    through its row of a ``[max_concurrency, n_positions / block_tokens]``
+    block table, sized by ``paged_kv=PagedKVConfig(...)``;
   - admission prefills up to ``admit_batch`` queued requests of one prompt
     bucket in a SINGLE jitted call (one compile per ``(prompt_bucket,
-    batch_bucket)`` pair), samples their first tokens, and scatters all the
-    new slots into the pool at once (`kv_cache.scatter_cache_slots`);
-  - with ``prefix_cache=`` enabled, admission first reuses any cached prompt
-    prefix from a device-resident block pool (`serving/prefix_cache.py`):
-    matched blocks are gathered into the slot's rows and only the uncached
-    suffix is prefilled (re-bucketed, so compiles stay bounded); retirement
-    donates finished prompts back. Token streams are identical either way;
+    batch_bucket)`` pair) into fresh contiguous rows, samples their first
+    tokens, and scatters the rows into the slots' reserved blocks at once
+    (`kv_cache.scatter_rows_to_blocks`);
+  - with ``prefix_cache=True``, admission first reuses any cached prompt
+    prefix (`serving/prefix_cache.py`): the matched blocks of the pool are
+    aliased into the slot's block table and only the uncached suffix is
+    prefilled (re-bucketed, so compiles stay bounded); retirement hands the
+    finished prompt's blocks to the trie. Token streams are identical either
+    way;
   - ``step()`` decodes ALL slots in one jitted call with donated cache
     buffers; per-slot positions, sampling params, rng keys, remaining budget,
     and the finished mask are DEVICE-RESIDENT ``[max_concurrency]`` arrays,
@@ -31,14 +34,14 @@ step N's results completes asynchronously, up to ``pipeline_depth`` dispatches
 in flight (depth 1 reproduces fully synchronous dispatch bit-for-bit). An
 on-device finished mask — EOS hit, token budget, context limit, or watchdog
 health — freezes a slot inside the compiled step (token/position/cache writes
-all stop, `kv_cache.decode_cache_update(write_mask=...)`), so host-side
+all stop, `kv_cache.paged_decode_update(write_mask=...)`), so host-side
 retirement/backfill lagging by up to ``pipeline_depth`` steps can never
 corrupt a stream: the host simply truncates the lagged tail at the finish
 point, token-identical to a solo ``generate``. A per-slot generation counter
 discards fetched results that postdate a retirement/cancel/quarantine.
 
 Static-shape invariant (the whole point): the decode step's shapes depend only
-on ``(max_concurrency, n_positions, model config)`` and admission's only on
+on ``(max_concurrency, num_blocks, n_positions, model config)`` and admission's only on
 ``(prompt_bucket, batch_bucket)``. Everything request-specific is data, not
 shape.
 
@@ -73,7 +76,6 @@ from ..models.kv_cache import (
     leaf_name,
     make_cache,
     rewind_frontier,
-    scatter_cache_slots,
     scatter_rows_to_blocks,
     state_nbytes,
     tree_bytes_by_dtype,
@@ -82,7 +84,6 @@ from ..models.kv_cache import (
 from ..parallel.mesh import ParallelismConfig, serving_mesh
 from ..parallel.sharding import (
     block_table_sharding,
-    infer_block_pool_shardings,
     infer_cache_shardings,
     infer_param_shardings,
     kv_cache_sharding,
@@ -103,7 +104,7 @@ from .journal import MAGIC as JOURNAL_MAGIC
 from .journal import JournalScan, RequestJournal, request_record
 from .kv_tier import KVTier, KVTierConfig
 from .metrics import ServingMetrics
-from .prefix_cache import NO_MATCH, PrefixCache, PrefixCacheConfig, PrefixMatch
+from .prefix_cache import NO_MATCH, PrefixCache, PrefixMatch
 from .request import (
     FINISH_ABORTED,
     FINISH_EOS,
@@ -191,6 +192,37 @@ def _sample_rows(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
         logits, keys, temperature, top_k)
 
 
+def _decode_tail(last, live, tokens, pos, temps, top_ks, rng_data, finished,
+                 remaining, poison, eos_id):
+    """One decode turn after the model, shared by the step and the scan
+    body: from the ``[b, vocab]`` last-position logits to ``(next tokens,
+    positions, budgets, finished mask, rng key data, health)``."""
+    # fault injection rides INSIDE the compiled step (poison is a [b] data
+    # mask, all-False in production): NaN logits flow through the real
+    # sampler so the watchdog sees exactly what a numerically poisoned model
+    # step would produce
+    last = jnp.where(poison[:, None], jnp.asarray(jnp.nan, last.dtype), last)
+    # watchdog health flag: a non-finite logit row means this slot's sampled
+    # token is garbage, whatever index it lands on
+    ok = jnp.all(jnp.isfinite(last), axis=-1)
+    rngs = jax.random.wrap_key_data(rng_data)
+    split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
+    new_rngs, keys = split[:, 0], split[:, 1]
+    sampled = _sample_rows(last, keys, temps, top_ks, live)
+    healthy = live & ok
+    nxt = jnp.where(healthy, sampled, tokens)
+    new_pos = jnp.where(healthy, pos + 1, pos)
+    new_remaining = jnp.where(healthy, remaining - 1, remaining)
+    hit_eos = (eos_id >= 0) & (nxt == eos_id)
+    # the on-device finish sources: EOS, token budget (which already encodes
+    # the context limit), and watchdog health — a poisoned slot freezes
+    # immediately so it stops mutating its cache while the host decides to
+    # quarantine it
+    new_finished = finished | (live & (~ok | hit_eos | (new_remaining <= 0)))
+    return (nxt, new_pos, new_remaining, new_finished,
+            jax.random.key_data(new_rngs), ok | finished)
+
+
 @dataclasses.dataclass
 class _Inflight:
     """One dispatched-but-unfetched device computation.
@@ -260,19 +292,19 @@ SNAPSHOT_FORMAT = "accelerate_tpu/serving-snapshot-v1"
 
 @dataclasses.dataclass(frozen=True)
 class PagedKVConfig:
-    """Knobs for the engine's ``paged_kv=`` argument (`docs/serving.md`
-    "Paged KV").
+    """The size of the engine's KV store, its ``paged_kv=`` argument
+    (`docs/serving.md` "Paged KV").
 
-    ``block_tokens`` is the allocation granularity: smaller blocks waste less
-    of the last partially-filled block per request (internal fragmentation
-    bounded by ``block_tokens - 1`` tokens) but mean a bigger table and more
+    ``block_tokens`` is the allocation granularity, and the prefix cache's
+    reuse granularity (its trie aliases these blocks): smaller blocks waste
+    less of the last partially-filled block per request (internal
+    fragmentation bounded by ``block_tokens - 1`` tokens) and reuse more of a
+    shared prefix, but mean a bigger table, more trie nodes and more
     allocator work per admission. Must be a power of two dividing
-    ``n_positions``, and must MATCH the prefix cache's ``block_tokens`` when
-    both are configured (the trie aliases pool blocks directly). ``num_blocks``
-    sizes the shared pool; None derives ``max_concurrency * (n_positions /
-    block_tokens)`` — byte-for-byte the slot pool's KV footprint, so any
-    concurrency gain is pure ragged-occupancy win, measured not assumed
-    (`benchmarks/bench_serving.py`'s ragged workload)."""
+    ``n_positions``. ``num_blocks`` sizes the shared pool; None derives
+    ``max_concurrency * (n_positions / block_tokens)``: every slot can hold
+    a full context at once, and what ragged requests leave free is the
+    prefix trie's room."""
 
     block_tokens: int = 16
     num_blocks: int | None = None
@@ -410,9 +442,10 @@ class ServingEngine:
 
     ``mesh`` shards the whole engine over a ``(data, model)`` device mesh
     (a `jax.sharding.Mesh`, a `ParallelismConfig`, or a ``(data, model)``
-    tuple): params by the Megatron-style TP rules, the KV pools on heads
-    along the model axis (which must divide ``n_head``), and — when
-    ``max_concurrency`` divides the data degree — the slot dim across
+    tuple): params by the Megatron-style TP rules, the block pool on heads
+    along the model axis (which must divide ``n_head``) with its blocks
+    replicated over ``data``, and — when the data degree divides
+    ``max_concurrency`` — the per-slot state and block tables across
     replicas, which then decode disjoint slot ranges. Token streams are
     bit-identical to ``mesh=None`` (tests/test_serving_sharded.py proves the
     matrix); the scheduler, pipelining, and all host-side bookkeeping are
@@ -447,8 +480,8 @@ class ServingEngine:
         eos_token_id: int | None = None,
         pipeline_depth: int = 2,
         admit_batch: int = 4,
-        prefix_cache: PrefixCacheConfig | bool = False,
-        paged_kv: PagedKVConfig | bool = False,
+        prefix_cache: bool = False,
+        paged_kv: PagedKVConfig | bool = True,
         tracker: Any = None,
         metrics_log_every: int = 0,
         metrics: ServingMetrics | None = None,
@@ -490,43 +523,44 @@ class ServingEngine:
         self.max_concurrency = int(max_concurrency)
         if self.max_concurrency < 1:
             raise ValueError(f"max_concurrency must be >= 1, got {max_concurrency}")
-        # paged KV (docs/serving.md "Paged KV"): KV lives ONLY in a shared
-        # device-resident block pool — per-slot block tables replace the
-        # contiguous [b, n_positions] slot rows, admission reserves blocks on
-        # demand, and prefix-cache hits become zero-copy table aliasing. Off
-        # by default: the slot-pool path stays bit-for-bit what it was.
-        self.paged = bool(paged_kv)
-        self._allocator: BlockAllocator | None = None
-        self._block_tokens = 0
-        self._blocks_per_slot = 0
-        if self.paged:
-            pk = (paged_kv if isinstance(paged_kv, PagedKVConfig)
-                  else PagedKVConfig())
-            bt = int(pk.block_tokens)
-            n_pos = int(cfg.n_positions)
-            if bt < 1 or (bt & (bt - 1)) or n_pos % bt:
-                raise ValueError(
-                    f"paged_kv block_tokens must be a power of two dividing "
-                    f"n_positions={n_pos}, got {bt}"
-                )
-            # kv_cache_dtype=int8 composes with paging: the block pool stores
-            # the int8 payload and carries the fp32 absmax scales as sibling
-            # [num_blocks, block_tokens, kv_heads] pool leaves addressed
-            # through the same block table (models/kv_cache.py
-            # `paged_decode_write`) — no rejection, no special casing here.
-            self._block_tokens = bt
-            self._blocks_per_slot = n_pos // bt
-            # default pool: byte-for-byte the slot pool's KV footprint, so a
-            # paged-vs-slot comparison at equal bytes needs no sizing math
-            n_blocks = (int(pk.num_blocks) if pk.num_blocks is not None
-                        else self.max_concurrency * self._blocks_per_slot)
-            if n_blocks < self._blocks_per_slot:
-                raise ValueError(
-                    f"num_blocks={n_blocks} cannot seat even one full-context "
-                    f"request ({self._blocks_per_slot} blocks of "
-                    f"{bt} tokens) — admission would backpressure forever"
-                )
-            self._allocator = BlockAllocator(n_blocks)
+        # the KV store (docs/serving.md "Paged KV"): keys and values live ONLY
+        # in a shared device-resident block pool — per-slot block tables
+        # address it, admission reserves blocks on demand, and prefix-cache
+        # hits are zero-copy table aliasing. ``paged_kv`` sizes it: True is
+        # `PagedKVConfig()`. The bool form is what the benchmark's workload
+        # files pass (ROADMAP.md D4).
+        if isinstance(paged_kv, PagedKVConfig):
+            pk = paged_kv
+        elif paged_kv is True:
+            pk = PagedKVConfig()
+        else:
+            raise ValueError(
+                f"paged_kv={paged_kv!r}: the per-slot contiguous KV store was "
+                "removed; the paged block pool is the engine's only store. "
+                "Pass True (the default) or a PagedKVConfig that sizes it")
+        bt = int(pk.block_tokens)
+        n_pos = int(cfg.n_positions)
+        if bt < 1 or (bt & (bt - 1)) or n_pos % bt:
+            raise ValueError(
+                f"paged_kv block_tokens must be a power of two dividing "
+                f"n_positions={n_pos}, got {bt}"
+            )
+        # kv_cache_dtype=int8 composes with paging: the block pool stores
+        # the int8 payload and carries the fp32 absmax scales as sibling
+        # [num_blocks, block_tokens, kv_heads] pool leaves addressed
+        # through the same block table (models/kv_cache.py
+        # `paged_decode_write`) — no rejection, no special casing here.
+        self._block_tokens = bt
+        self._blocks_per_slot = n_pos // bt
+        n_blocks = (int(pk.num_blocks) if pk.num_blocks is not None
+                    else self.max_concurrency * self._blocks_per_slot)
+        if n_blocks < self._blocks_per_slot:
+            raise ValueError(
+                f"num_blocks={n_blocks} cannot seat even one full-context "
+                f"request ({self._blocks_per_slot} blocks of "
+                f"{bt} tokens) — admission would backpressure forever"
+            )
+        self._allocator = BlockAllocator(n_blocks)
         # fused paged decode (docs/serving.md "Fused paged decode"): "fused"
         # makes decode attention read K/V blocks in place through the block
         # table (the Pallas kernel `ops.flash_attention.paged_decode_attention`)
@@ -539,32 +573,26 @@ class ServingEngine:
                 f"paged_attention must be 'gather' or 'fused', "
                 f"got {paged_attention!r}"
             )
-        if self.paged_attention == "fused":
-            if not self.paged:
-                raise ValueError(
-                    "paged_attention='fused' requires paged_kv — the fused "
-                    "kernel reads the block pool through the block tables"
-                )
-            if not hasattr(cfg, "kv_paged_attention"):
-                raise ValueError(
-                    f"{type(module).__name__} has no kv_paged_attention config "
-                    "flag; the fused paged decode path needs it (models/gpt2.py)"
-                )
+        if self.paged_attention == "fused" and not hasattr(cfg, "kv_paged_attention"):
+            raise ValueError(
+                f"{type(module).__name__} has no kv_paged_attention config "
+                "flag; the fused paged decode path needs it (models/gpt2.py)"
+            )
         # mesh-sharded serving (docs/serving.md "Sharded serving"): ``mesh`` is
         # a Mesh, a ParallelismConfig, or a (data, model) tuple. The model axis
         # is the standard ``tensor`` axis — params shard by the training-path
-        # TP rules, the KV pools shard on heads, and (when divisible) the slot
-        # dim shards on ``data`` so replicas decode disjoint slot ranges. None
+        # TP rules, the block pool shards on heads (blocks replicated over
+        # ``data``), and (when divisible) the per-slot state and block tables
+        # shard on ``data`` so replicas decode disjoint slot ranges. None
         # keeps the single-device engine bit-for-bit: no sharding objects are
         # created and every jit call below is exactly the unsharded one.
         self.mesh = self._resolve_mesh(mesh)
         self._mesh_data = self.mesh.shape.get("data", 1) if self.mesh is not None else 1
         self._mesh_model = self.mesh.shape.get("tensor", 1) if self.mesh is not None else 1
-        self._slot_sharding = None    # KVCacheSharding for the [b, ...] slot pool
+        self._slot_sharding = None    # KVCacheSharding for the block pool + cursor
         self._fresh_sharding = None   # head-only variant for admission's nb rows
         self._cache_shardings = None  # NamedSharding pytrees congruent with ...
-        self._fresh_shardings = None  # ... the pool / fresh-rows / block-pool trees
-        self._pool_shardings = None
+        self._fresh_shardings = None  # ... the pool / fresh-rows trees
         self._param_shardings = None
         self._row_sharding = None     # [max_concurrency] per-slot state vectors
         self._rep_sharding = None     # replicated scalars / [nb] admission inputs
@@ -583,17 +611,16 @@ class ServingEngine:
                     f"n_head={contract.kv_heads} (attention is sharded over heads)"
                 )
             self._slot_sharding = kv_cache_sharding(
-                self.mesh, slots=self.max_concurrency, paged=self.paged
+                self.mesh, slots=self.max_concurrency, paged=True
             )
             self._fresh_sharding = kv_cache_sharding(self.mesh, slots=None)
             self._row_sharding = self._slot_sharding.index
             self._rep_sharding = NamedSharding(self.mesh, PartitionSpec())
-            if self.paged:
-                # block tables follow the slot dim's layout (each replica
-                # indexes the replicated pool through its own slots' rows)
-                self._table_sharding = block_table_sharding(
-                    self.mesh, slots=self.max_concurrency
-                )
+            # block tables follow the slot dim's layout (each replica
+            # indexes the replicated pool through its own slots' rows)
+            self._table_sharding = block_table_sharding(
+                self.mesh, slots=self.max_concurrency
+            )
         if self.paged_attention == "fused":
             # the kernel holds a row's whole attended span in VMEM; a model it
             # cannot fit fails HERE with the sizes named — never at the first
@@ -612,43 +639,36 @@ class ServingEngine:
             if self._mesh_data > 1 and self.max_concurrency % self._mesh_data == 0
             else 1
         )
-        updates: dict[str, Any] = {}
-        if not cfg.kv_cache_per_slot:
-            updates["kv_cache_per_slot"] = True
-        if self.paged:
-            # the DECODE module owns the block pool; its cache collection is
-            # the [num_blocks, block_tokens, ...] pool plus the per-slot
-            # cursor, and every decode step attends through the block table
-            updates["kv_cache_paged"] = True
-            updates["kv_num_blocks"] = self._allocator.num_blocks
-            updates["kv_block_tokens"] = self._block_tokens
-            # "gather" is the config default — adding nothing keeps the
-            # gather engine's module (and its shared-jit entry) byte-identical
-            if self.paged_attention == "fused":
-                updates["kv_paged_attention"] = "fused"
+        # the DECODE module owns the block pool; its cache collection is
+        # the [num_blocks, block_tokens, ...] pool plus the per-slot
+        # cursor, and every decode step attends through the block table
+        updates: dict[str, Any] = {
+            "kv_cache_per_slot": True,
+            "kv_cache_paged": True,
+            "kv_num_blocks": self._allocator.num_blocks,
+            "kv_block_tokens": self._block_tokens,
+        }
+        # "gather" is the config default — adding nothing keeps the
+        # gather engine's module (and its shared-jit entry) byte-identical
+        if self.paged_attention == "fused":
+            updates["kv_paged_attention"] = "fused"
         if self.mesh is not None and hasattr(cfg, "kv_cache_sharding"):
             updates["kv_cache_sharding"] = self._slot_sharding
-        if updates:
-            module = type(module)(dataclasses.replace(cfg, **updates))
-        self.module = module
+        self.module = module = type(module)(dataclasses.replace(cfg, **updates))
         # admission prefills a FRESH nb-row cache (nb = batch bucket, not b):
         # its in-jit cache constraints must be the head-only layout — slot-dim
         # specs applied to nb rows would be a different (often indivisible)
         # partitioning, so admission traces a config carrying ``_fresh_sharding``.
-        # Paged admission ALSO prefills contiguous rows (numerics identical to
-        # slot-mode admission, the parity anchor) — only the post-prefill
-        # scatter targets the block pool — so the admit module always carries
-        # the contiguous per-slot cache layout.
-        admit_updates: dict[str, Any] = {}
-        if self.paged:
-            admit_updates["kv_cache_paged"] = False
+        # Those rows are contiguous (the numerics of a solo ``generate``'s
+        # prefill, the parity anchor) — only the post-prefill scatter targets
+        # the block pool — so the admit module carries the contiguous
+        # per-slot cache layout.
+        admit_updates: dict[str, Any] = {"kv_cache_paged": False}
         if self.mesh is not None and hasattr(cfg, "kv_cache_sharding"):
             admit_updates["kv_cache_sharding"] = self._fresh_sharding
-        self._admit_module = module
-        if admit_updates:
-            self._admit_module = type(module)(dataclasses.replace(
-                module.config, **admit_updates
-            ))
+        self._admit_module = type(module)(dataclasses.replace(
+            module.config, **admit_updates
+        ))
         # quantized weights (docs/serving.md "Quantized serving"): quantize
         # the param tree ONCE here and hand every jitted program the packed
         # leaves directly — the `QuantizedModule` wrapper dequantizes inside
@@ -781,7 +801,7 @@ class ServingEngine:
         self._last_step_timings: dict[str, float] = {}
 
         b = self.max_concurrency
-        # device state: the slot-pool cache (donated through every step) plus
+        # device state: the block pool (donated through every step) plus
         # ALL per-slot decode state — last token, position, sampling params,
         # rng chain (raw key data so slot updates are plain scatters), token
         # budget, and the finished mask. The decode loop never uploads any of
@@ -797,12 +817,6 @@ class ServingEngine:
             )
             self._cache_shardings = infer_cache_shardings(
                 cache_shapes, self._slot_sharding
-            )
-            # the prefix cache's standalone pool exists only in slot mode —
-            # paged mode's trie aliases the engine's own pool blocks
-            self._pool_shardings = (
-                None if self.paged
-                else infer_block_pool_shardings(cache_shapes, self.mesh)
             )
         self._cache = make_cache(self.module, b, shardings=self._cache_shardings)
         kd = jax.random.key_data(jax.random.key(0))
@@ -826,22 +840,17 @@ class ServingEngine:
                  self._no_poison)
             )
             self._d_eos = jax.device_put(self._d_eos, self._rep_sharding)
-        # paged: per-slot block tables, the ONLY indirection decode follows.
+        # per-slot block tables, the ONLY indirection decode follows.
         # A free slot's row points at num_blocks (out of range): a lagged
         # step's write for a cancelled tenant DROPS instead of landing in a
         # freed — possibly re-allocated — block (see _release_slot)
-        self._d_tables = None
-        if self.paged:
-            self._d_tables = jnp.full(
-                (b, self._blocks_per_slot), self._allocator.num_blocks,
-                jnp.int32,
-            )
-            if self.mesh is not None:
-                self._d_tables = jax.device_put(
-                    self._d_tables, self._table_sharding)
-        # fresh-row shapes come from the ADMIT module: in paged mode the
-        # decode module's cache is the pool, not the contiguous per-row
-        # layout admission prefills into
+        self._d_tables = jnp.full(
+            (b, self._blocks_per_slot), self._allocator.num_blocks, jnp.int32)
+        if self.mesh is not None:
+            self._d_tables = jax.device_put(self._d_tables, self._table_sharding)
+        # fresh-row shapes come from the ADMIT module: the decode module's
+        # cache is the pool, not the contiguous per-row layout admission
+        # prefills into
         self._fresh_shapes = jax.eval_shape(
             lambda: self._admit_module.init(
                 jax.random.key(0), jnp.zeros((1, 1), jnp.int32), decode=True
@@ -890,12 +899,12 @@ class ServingEngine:
         self._slot_logged = np.zeros(b, np.int64)
         # prefix KV reuse (serving/prefix_cache.py): admission skips prefill
         # of prompt prefixes already resident in the block pool, retirement
-        # donates finished prompts back. Off by default — the cache-off
-        # engine's compiled programs are bit-for-bit the pre-PR-4 ones.
+        # hands finished prompts' blocks to the trie. Off by default — the
+        # cache-off engine never builds the cached admission program.
         self.prefix_cache: PrefixCache | None = None
         self._slot_match: list[PrefixMatch | None] = [None] * b
         self._slot_hit = np.zeros(b, bool)
-        # paged per-slot bookkeeping: the host copy of the slot's block table
+        # per-slot block bookkeeping: the host copy of the slot's block table
         # (what _retire donates from), the slot's PRIVATE block ids (freed at
         # release — aliased prefix blocks belong to the trie, pinned via
         # _slot_match), and how many leading table entries are aliased
@@ -903,51 +912,26 @@ class ServingEngine:
         self._slot_table_host: list[np.ndarray | None] = [None] * b
         self._slot_aliased = np.zeros(b, np.int32)
         if prefix_cache:
-            pc_cfg = (prefix_cache if isinstance(prefix_cache, PrefixCacheConfig)
-                      else PrefixCacheConfig())
-            if self.paged:
-                if int(pc_cfg.block_tokens) != self._block_tokens:
-                    raise ValueError(
-                        f"prefix_cache block_tokens={pc_cfg.block_tokens} must "
-                        f"equal paged_kv block_tokens={self._block_tokens}: "
-                        f"the trie aliases the engine's pool blocks directly"
-                    )
-                # paged trie: no standalone pool — entries pin blocks of the
-                # engine's own block pool (zero-copy hits, adopt-not-copy
-                # donation); num_blocks/shardings are the engine's
-                self.prefix_cache = PrefixCache(
-                    None, max_len=self.max_len,
-                    block_tokens=self._block_tokens,
-                    metrics=self.metrics, allocator=self._allocator,
-                )
-            else:
-                self.prefix_cache = PrefixCache(
-                    self._cache, max_len=self.max_len,
-                    block_tokens=pc_cfg.block_tokens, num_blocks=pc_cfg.num_blocks,
-                    metrics=self.metrics, shardings=self._pool_shardings,
-                )
+            # the trie owns no device state: its entries pin blocks of the
+            # engine's own pool (zero-copy hits, adopt-not-copy donation)
+            self.prefix_cache = PrefixCache(
+                self._allocator, max_len=self.max_len,
+                block_tokens=self._block_tokens, metrics=self.metrics,
+            )
             self.scheduler.prefill_len_fn = self._prefill_len
-            self._cached_admit_fn = (self._build_paged_cached_admit_fn()
-                                     if self.paged
-                                     else self._build_cached_admit_fn())
-        if self.paged:
-            # admission is gated on BLOCKS, not just free slots: the scheduler
-            # shrinks each front run to what the pool can actually seat
-            self.scheduler.capacity_fn = self._paged_capacity
+            self._cached_admit_fn = self._build_paged_cached_admit_fn()
+        # admission is gated on BLOCKS, not just free slots: the scheduler
+        # shrinks each front run to what the pool can actually seat
+        self.scheduler.capacity_fn = self._paged_capacity
         self._step_fn = self._build_step_fn()
-        self._admit_fn = (self._build_paged_admit_fn() if self.paged
-                          else self._build_admit_fn())
+        self._admit_fn = self._build_paged_admit_fn()
         # host-RAM KV tier + request hibernation (serving/kv_tier.py,
         # docs/serving.md "KV tiering & hibernation"): a host-memory block
-        # tier behind the paged pool, so concurrency outgrows device HBM.
+        # tier behind the block pool, so concurrency outgrows device HBM.
         # Default off — tier-off programs and host paths stay bit-for-bit.
         self.kv_tier: KVTier | None = None
         self._tier_wake_fn = None
         if kv_tier:
-            if not self.paged:
-                raise ValueError(
-                    "kv_tier requires paged_kv — the host tier spills and "
-                    "restores pool blocks through the block tables")
             if self.mesh is not None:
                 raise ValueError(
                     "kv_tier does not support mesh-sharded serving yet")
@@ -1072,241 +1056,40 @@ class ServingEngine:
             return self._build_spec_step_fn()
         if self.tokens_per_sync > 1:
             return self._build_scan_step_fn()
-        if self.paged:
-            return self._build_paged_step_fn()
-        module = self.module
-        mutable, counters = self._step_mutable()
-
-        def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
-                    finished, remaining, poison, eos_id):
-            live = ~finished
-            # finished slots are frozen INSIDE the compiled step: their cache
-            # rows are not written (write_mask), and below their token/pos/
-            # budget are carried unchanged — so however far host retirement
-            # lags, a finished slot's state is bit-stable until re-admission
-            logits, mutated = module.apply(
-                {"params": params, "cache": cache}, tokens[:, None], decode=True,
-                position_offset=pos, mutable=mutable, cache_write_mask=live,
-            )
-            last = logits[:, -1]
-            # fault injection rides INSIDE the compiled step (poison is a [b]
-            # data mask, all-False in production): NaN logits flow through the
-            # real sampler so the watchdog sees exactly what a numerically
-            # poisoned model step would produce
-            last = jnp.where(poison[:, None], jnp.asarray(jnp.nan, last.dtype), last)
-            # watchdog health flag: a non-finite logit row means this slot's
-            # sampled token is garbage, whatever index it lands on
-            ok = jnp.all(jnp.isfinite(last), axis=-1)
-            rngs = jax.random.wrap_key_data(rng_data)
-            split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
-            new_rngs, keys = split[:, 0], split[:, 1]
-            sampled = _sample_rows(last, keys, temps, top_ks, live)
-            healthy = live & ok
-            nxt = jnp.where(healthy, sampled, tokens)
-            new_pos = jnp.where(healthy, pos + 1, pos)
-            new_remaining = jnp.where(healthy, remaining - 1, remaining)
-            hit_eos = (eos_id >= 0) & (nxt == eos_id)
-            # the on-device finish sources: EOS, token budget (which already
-            # encodes the context limit), and watchdog health — a poisoned
-            # slot freezes immediately so it stops mutating its cache while
-            # the host decides to quarantine it
-            new_finished = finished | (live & (~ok | hit_eos | (new_remaining <= 0)))
-            return (mutated["cache"], nxt, new_pos, new_remaining, new_finished,
-                    jax.random.key_data(new_rngs), ok | finished) + counters(mutated)
-
-        if self.mesh is None:
-            return _shared_jit(module, "step",
-                               lambda: jax.jit(step_fn, donate_argnums=(0,)))
-        # explicit shardings pin the hot loop's layout: the donated cache keeps
-        # its pool placement through every step (in == out, no resharding) and
-        # each [b] state vector rides the slot dim's layout
-        row, rep = self._row_sharding, self._rep_sharding
-        return jax.jit(
-            step_fn, donate_argnums=(0,),
-            in_shardings=(self._cache_shardings, self._param_shardings,
-                          row, row, row, row, row, row, row, row, rep),
-            out_shardings=(self._cache_shardings, row, row, row, row, row, row),
-        )
-
-    def _build_admit_fn(self):
-        module, fresh_shapes = self._admit_module, self._fresh_shapes
-        cache_shardings = self._cache_shardings
-        state_leaves = self._contract.state_leaves
-
-        def admit_fn(pool_cache, params, prompt_rows, slots, prompt_lens, temps,
-                     top_ks, rng_batch, budgets, d_tokens, d_pos, d_temps,
-                     d_topks, d_finished, d_remaining, rng_data, eos_id):
-            # prefill ALL nb (right-padded) rows of one prompt bucket in one
-            # pass into a fresh nb-slot cache; the causal mask keeps pad
-            # positions from reaching each row's last real token's logits, and
-            # the cache_index reset in the scatter keeps decode from ever
-            # attending the stale pad entries
-            nb = prompt_rows.shape[0]
-            fresh = jax.tree.map(
-                lambda s: jnp.zeros((nb,) + s.shape[1:], s.dtype), fresh_shapes
-            )
-            logits, mutated = module.apply(
-                {"params": params, "cache": fresh}, prompt_rows, decode=True,
-                position_offset=0, mutable=["cache"],
-                # a model with recurrent state must know each row's true
-                # length inside the padded bucket: pad tokens leave the state
-                # untouched. Keys-and-values models get no argument at all.
-                **({"cache_write_len": prompt_lens} if state_leaves else {}),
-            )
-            last = jax.vmap(
-                lambda row, n: jax.lax.dynamic_slice(
-                    row, (n - 1, 0), (1, row.shape[-1])
-                )[0]
-            )(logits, prompt_lens)
-            rngs = jax.random.wrap_key_data(rng_batch)
-            split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
-            new_rngs, keys = split[:, 0], split[:, 1]
-            first = _sample_rows(last, keys, temps, top_ks,
-                                 jnp.ones_like(temps, bool))
-            new_pool = scatter_cache_slots(
-                pool_cache, mutated["cache"], slots, prompt_lens,
-                shardings=cache_shardings,
-            )
-            # first token rides out of the prefill itself; budget-1 tokens
-            # remain for the decode loop (a 1-token budget or first-token EOS
-            # is finished on arrival)
-            rem0 = budgets - 1
-            fin0 = (rem0 <= 0) | ((eos_id >= 0) & (first == eos_id))
-            d_tokens = d_tokens.at[slots].set(first)
-            d_pos = d_pos.at[slots].set(prompt_lens)
-            d_temps = d_temps.at[slots].set(temps)
-            d_topks = d_topks.at[slots].set(top_ks)
-            d_finished = d_finished.at[slots].set(fin0)
-            d_remaining = d_remaining.at[slots].set(rem0)
-            rng_data = rng_data.at[slots].set(jax.random.key_data(new_rngs))
-            return (new_pool, first, fin0, d_tokens, d_pos, d_temps, d_topks,
-                    d_finished, d_remaining, rng_data)
-
-        if self.mesh is None:
-            return _shared_jit(module, "admit",
-                               lambda: jax.jit(admit_fn, donate_argnums=(0,)))
-        # the [nb] admission inputs (padded prompts, lens, sampling params,
-        # seeds) are replicated — nb is small and the prefill's activations
-        # shard over heads via the param/TP rules; the [b] per-slot vectors
-        # keep the slot layout through the scatter
-        row, rep = self._row_sharding, self._rep_sharding
-        return jax.jit(
-            admit_fn, donate_argnums=(0,),
-            in_shardings=(self._cache_shardings, self._param_shardings,
-                          rep, rep, rep, rep, rep, rep, rep,
-                          row, row, row, row, row, row, row, rep),
-            out_shardings=(self._cache_shardings, rep, rep,
-                           row, row, row, row, row, row, row),
-        )
-
-    def _build_cached_admit_fn(self):
-        """Admission with prefix reuse: gather each row's matched blocks out
-        of the prefix pool into its cache rows, prefill ONLY the uncached
-        suffix (each row resuming at its own ``cached_len`` via the [nb]
-        ``position_offset`` vector), and scatter into the slot pool exactly
-        like plain admission. One compile per ``(suffix_bucket, batch_bucket)``
-        pair — the same bounded set as plain admission, because the scheduler
-        re-buckets the SUFFIX (`FIFOScheduler.prefill_bucket_for`)."""
-        module = self._admit_module
-        cache_shardings = self._cache_shardings
-        fresh_shardings = self._fresh_shardings
-
-        def admit_fn(pool_cache, params, block_pool, block_tables, cached_lens,
-                     suffix_rows, suffix_lens, slots, temps, top_ks, rng_batch,
-                     budgets, d_tokens, d_pos, d_temps, d_topks, d_finished,
-                     d_remaining, rng_data, eos_id):
-            # rows assembled from pool blocks; table entries past a row's real
-            # prefix fill positions the suffix write overwrites or the causal
-            # mask (kv_pos <= cached_len + j) never lets a query read
-            fresh = gather_block_rows(block_pool, block_tables, cached_lens,
-                                      shardings=fresh_shardings)
-            logits, mutated = module.apply(
-                {"params": params, "cache": fresh}, suffix_rows, decode=True,
-                position_offset=cached_lens, mutable=["cache"],
-            )
-            last = jax.vmap(
-                lambda row, n: jax.lax.dynamic_slice(
-                    row, (n - 1, 0), (1, row.shape[-1])
-                )[0]
-            )(logits, suffix_lens)
-            rngs = jax.random.wrap_key_data(rng_batch)
-            split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
-            new_rngs, keys = split[:, 0], split[:, 1]
-            first = _sample_rows(last, keys, temps, top_ks,
-                                 jnp.ones_like(temps, bool))
-            # decode resumes from the FULL prompt end: cached prefix + suffix
-            prompt_lens = cached_lens + suffix_lens
-            new_pool = scatter_cache_slots(
-                pool_cache, mutated["cache"], slots, prompt_lens,
-                shardings=cache_shardings,
-            )
-            rem0 = budgets - 1
-            fin0 = (rem0 <= 0) | ((eos_id >= 0) & (first == eos_id))
-            d_tokens = d_tokens.at[slots].set(first)
-            d_pos = d_pos.at[slots].set(prompt_lens)
-            d_temps = d_temps.at[slots].set(temps)
-            d_topks = d_topks.at[slots].set(top_ks)
-            d_finished = d_finished.at[slots].set(fin0)
-            d_remaining = d_remaining.at[slots].set(rem0)
-            rng_data = rng_data.at[slots].set(jax.random.key_data(new_rngs))
-            return (new_pool, first, fin0, d_tokens, d_pos, d_temps, d_topks,
-                    d_finished, d_remaining, rng_data)
-
-        if self.mesh is None:
-            return _shared_jit(module, "cached_admit",
-                               lambda: jax.jit(admit_fn, donate_argnums=(0,)))
-        # block pool: heads sharded, blocks replicated across replicas (any
-        # replica gathers any cached prefix); everything else as plain admission
-        row, rep = self._row_sharding, self._rep_sharding
-        return jax.jit(
-            admit_fn, donate_argnums=(0,),
-            in_shardings=(self._cache_shardings, self._param_shardings,
-                          self._pool_shardings,
-                          rep, rep, rep, rep, rep, rep, rep, rep, rep,
-                          row, row, row, row, row, row, row, rep),
-            out_shardings=(self._cache_shardings, rep, rep,
-                           row, row, row, row, row, row, row),
-        )
+        return self._build_paged_step_fn()
 
     def _build_paged_step_fn(self):
-        """Decode through the block table: identical sampling tail to the
-        slot-pool step (the parity anchor), but the cache rides as the shared
-        block pool and each row attends the gathered view its table describes
-        (`kv_cache.paged_decode_update` — same token layout, same frontier
-        mask, so logits match the slot path bit-for-bit)."""
+        """Decode through the block table: the cache rides as the shared
+        block pool and each row attends the span its table describes
+        (`kv_cache.paged_decode_update` — the token layout and frontier mask
+        of a contiguous per-row cache, so logits match a solo ``generate``)."""
         module = self.module
         mutable, counters = self._step_mutable()
 
         def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
                     finished, remaining, poison, eos_id, tables):
             live = ~finished
-            # finished slots freeze exactly as in slot mode; paged adds one
-            # more drop layer — a released slot's table row points at
+            # finished slots are frozen INSIDE the compiled step: their blocks
+            # are not written (write_mask), and `_decode_tail` carries their
+            # token/pos/budget unchanged — so however far host retirement
+            # lags, a finished slot's state is bit-stable until re-admission.
+            # One more drop layer: a released slot's table row points at
             # num_blocks, so even a stale dispatch's write cannot land
             logits, mutated = module.apply(
                 {"params": params, "cache": cache}, tokens[:, None], decode=True,
                 position_offset=pos, mutable=mutable, cache_write_mask=live,
                 block_tables=tables,
             )
-            last = logits[:, -1]
-            last = jnp.where(poison[:, None], jnp.asarray(jnp.nan, last.dtype), last)
-            ok = jnp.all(jnp.isfinite(last), axis=-1)
-            rngs = jax.random.wrap_key_data(rng_data)
-            split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
-            new_rngs, keys = split[:, 0], split[:, 1]
-            sampled = _sample_rows(last, keys, temps, top_ks, live)
-            healthy = live & ok
-            nxt = jnp.where(healthy, sampled, tokens)
-            new_pos = jnp.where(healthy, pos + 1, pos)
-            new_remaining = jnp.where(healthy, remaining - 1, remaining)
-            hit_eos = (eos_id >= 0) & (nxt == eos_id)
-            new_finished = finished | (live & (~ok | hit_eos | (new_remaining <= 0)))
-            return (mutated["cache"], nxt, new_pos, new_remaining, new_finished,
-                    jax.random.key_data(new_rngs), ok | finished) + counters(mutated)
+            return (mutated["cache"],) + _decode_tail(
+                logits[:, -1], live, tokens, pos, temps, top_ks, rng_data,
+                finished, remaining, poison, eos_id) + counters(mutated)
 
         if self.mesh is None:
             return _shared_jit(module, "step",
                                lambda: jax.jit(step_fn, donate_argnums=(0,)))
+        # explicit shardings pin the hot loop's layout: the donated pool keeps
+        # its placement through every step (in == out, no resharding) and
+        # each [b] state vector rides the slot dim's layout
         row, rep = self._row_sharding, self._rep_sharding
         return jax.jit(
             step_fn, donate_argnums=(0,),
@@ -1319,49 +1102,35 @@ class ServingEngine:
     def _build_scan_step_fn(self):
         """``tokens_per_sync`` = k > 1: k decode iterations inside ONE jitted
         `lax.scan` between host syncs. The scan body is token-for-token the
-        single-step program — same apply, same rng split per iteration, same
-        finish sources — so iteration t of one scan is bit-identical to the
-        t-th of k separate dispatches. The carry is exactly the device state
-        the host round-trips today (cache/tokens/pos/remaining/finished/rng);
-        the per-iteration ``(nxt, finished, healthy)`` triple stacks into
-        ``[k, b]`` arrays the existing fetch path walks token-by-token.
-        Finished (and poisoned — health is a finish source) slots freeze
-        inside the scan, so EOS/budget/quarantine landing mid-scan just
-        carries the row unchanged for the remaining iterations."""
+        single-step program — same apply, same `_decode_tail` — so iteration
+        t of one scan is bit-identical to the t-th of k separate dispatches.
+        The carry is exactly the device state the host round-trips today
+        (cache/tokens/pos/remaining/finished/rng); the per-iteration ``(nxt,
+        finished, healthy)`` triple stacks into ``[k, b]`` arrays the
+        existing fetch path walks token-by-token. Finished (and poisoned —
+        health is a finish source) slots freeze inside the scan, so
+        EOS/budget/quarantine landing mid-scan just carries the row unchanged
+        for the remaining iterations."""
         module = self.module
         k_iters = self.tokens_per_sync
-        paged = self.paged
 
         def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
-                    finished, remaining, poison, eos_id, *tables):
+                    finished, remaining, poison, eos_id, tables):
 
             def body(carry, _):
                 cache, tokens, pos, remaining, finished, rng_data = carry
                 live = ~finished
-                extra = {"block_tables": tables[0]} if paged else {}
                 logits, mutated = module.apply(
                     {"params": params, "cache": cache}, tokens[:, None],
                     decode=True, position_offset=pos, mutable=["cache"],
-                    cache_write_mask=live, **extra,
+                    cache_write_mask=live, block_tables=tables,
                 )
-                last = logits[:, -1]
-                last = jnp.where(poison[:, None],
-                                 jnp.asarray(jnp.nan, last.dtype), last)
-                ok = jnp.all(jnp.isfinite(last), axis=-1)
-                rngs = jax.random.wrap_key_data(rng_data)
-                split = jax.vmap(jax.random.split)(rngs)  # [b, 2] keys
-                new_rngs, keys = split[:, 0], split[:, 1]
-                sampled = _sample_rows(last, keys, temps, top_ks, live)
-                healthy = live & ok
-                nxt = jnp.where(healthy, sampled, tokens)
-                new_pos = jnp.where(healthy, pos + 1, pos)
-                new_remaining = jnp.where(healthy, remaining - 1, remaining)
-                hit_eos = (eos_id >= 0) & (nxt == eos_id)
-                new_finished = finished | (
-                    live & (~ok | hit_eos | (new_remaining <= 0)))
+                nxt, new_pos, new_remaining, new_finished, new_rng, ok = _decode_tail(
+                    logits[:, -1], live, tokens, pos, temps, top_ks, rng_data,
+                    finished, remaining, poison, eos_id)
                 carry = (mutated["cache"], nxt, new_pos, new_remaining,
-                         new_finished, jax.random.key_data(new_rngs))
-                return carry, (nxt, new_finished, ok | finished)
+                         new_finished, new_rng)
+                return carry, (nxt, new_finished, ok)
 
             carry = (cache, tokens, pos, remaining, finished, rng_data)
             carry, (toks, fins, oks) = jax.lax.scan(
@@ -1377,13 +1146,11 @@ class ServingEngine:
         # stacked [k, b] per-iteration outputs: iteration dim replicated, the
         # slot dim keeps its layout
         srow = NamedSharding(self.mesh, PartitionSpec(None, *row.spec))
-        in_shardings = (self._cache_shardings, self._param_shardings,
-                        row, row, row, row, row, row, row, row, rep)
-        if paged:
-            in_shardings += (self._table_sharding,)
         return jax.jit(
             step_fn, donate_argnums=(0,),
-            in_shardings=in_shardings,
+            in_shardings=(self._cache_shardings, self._param_shardings,
+                          row, row, row, row, row, row, row, row, rep,
+                          self._table_sharding),
             out_shardings=(self._cache_shardings, row, row, row, row, row,
                            srow, srow, srow),
         )
@@ -1423,20 +1190,18 @@ class ServingEngine:
         module = self.module
         k_draft = self.draft_tokens
         s = k_draft + 1
-        paged = self.paged
 
         def step_fn(cache, params, tokens, pos, temps, top_ks, rng_data,
-                    finished, remaining, poison, eos_id, drafts, *tables):
+                    finished, remaining, poison, eos_id, drafts, tables):
             b = tokens.shape[0]
             rows = jnp.arange(b)
             live = ~finished
             seq = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [b, s]
             write_len = jnp.clip(remaining + 1, 0, s) * live.astype(jnp.int32)
-            extra = {"block_tables": tables[0]} if paged else {}
             logits, mutated = module.apply(
                 {"params": params, "cache": cache}, seq, decode=True,
                 position_offset=pos, mutable=["cache"], cache_write_mask=live,
-                cache_write_len=write_len, **extra,
+                cache_write_len=write_len, block_tables=tables,
             )  # [b, s, vocab]
             logits = jnp.where(poison[:, None, None],
                                jnp.asarray(jnp.nan, logits.dtype), logits)
@@ -1499,60 +1264,56 @@ class ServingEngine:
         # dim keeps its layout; drafts [b, k] ride the slot layout with the
         # position dim replicated (trailing dims of a short spec replicate)
         srow = NamedSharding(self.mesh, PartitionSpec(None, *row.spec))
-        in_shardings = (self._cache_shardings, self._param_shardings,
-                        row, row, row, row, row, row, row, row, rep, row)
-        if paged:
-            in_shardings += (self._table_sharding,)
         return jax.jit(
             step_fn, donate_argnums=(0,),
-            in_shardings=in_shardings,
+            in_shardings=(self._cache_shardings, self._param_shardings,
+                          row, row, row, row, row, row, row, row, rep, row,
+                          self._table_sharding),
             out_shardings=(self._cache_shardings, row, row, row, row, row,
                            srow, srow, row, row),
         )
 
-    def _build_paged_admit_fn(self):
-        """Plain admission, paged pool: prefill the group into a FRESH
-        contiguous nb-row cache — byte-identical numerics to slot-mode
-        admission — then one scatter moves each row's newly written blocks
-        into the pool at the slot's reserved block ids and stamps the device
-        block tables. ``dest_blocks`` entries of ``num_blocks`` (aliased
-        prefix blocks on the cached path, reserved-but-unwritten decode
-        blocks) drop their write."""
-        module, fresh_shapes = self._admit_module, self._fresh_shapes
+    def _build_admit_tail(self):
+        """An admit program after its prefill, once for the plain and the
+        cached program: each row's last real logits, the first token's draw,
+        ONE scatter of the fresh rows' newly written blocks into the pool at
+        the slots' reserved block ids (`kv_cache.scatter_rows_to_blocks`),
+        and the per-slot writes. ``dest_blocks`` entries of ``num_blocks``
+        (a hit's aliased prefix blocks, reserved-but-unwritten decode blocks)
+        drop their write. ``last_lens`` is each row's length inside the
+        prefilled bucket; ``cached_lens`` what it resumed after."""
         cache_shardings = self._cache_shardings
         bt = self._block_tokens
         state_leaves = self._contract.state_leaves
 
-        def admit_fn(pool_cache, params, prompt_rows, slots, prompt_lens,
-                     temps, top_ks, rng_batch, budgets, dest_blocks,
-                     group_tables, d_tables, d_tokens, d_pos, d_temps,
-                     d_topks, d_finished, d_remaining, rng_data, eos_id):
-            nb = prompt_rows.shape[0]
-            fresh = jax.tree.map(
-                lambda s: jnp.zeros((nb,) + s.shape[1:], s.dtype), fresh_shapes
-            )
-            logits, mutated = module.apply(
-                {"params": params, "cache": fresh}, prompt_rows, decode=True,
-                position_offset=0, mutable=["cache"],
-                # true lengths for a model with recurrent state (`_build_admit_fn`)
-                **({"cache_write_len": prompt_lens} if state_leaves else {}),
-            )
+        def tail(pool_cache, fresh, logits, last_lens, slots, temps, top_ks,
+                 rng_batch, budgets, dest_blocks, group_tables, d_tables,
+                 d_tokens, d_pos, d_temps, d_topks, d_finished, d_remaining,
+                 rng_data, eos_id, cached_lens=None):
             last = jax.vmap(
                 lambda row, n: jax.lax.dynamic_slice(
                     row, (n - 1, 0), (1, row.shape[-1])
                 )[0]
-            )(logits, prompt_lens)
+            )(logits, last_lens)
             rngs = jax.random.wrap_key_data(rng_batch)
             split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
             new_rngs, keys = split[:, 0], split[:, 1]
             first = _sample_rows(last, keys, temps, top_ks,
                                  jnp.ones_like(temps, bool))
+            # decode resumes from the FULL prompt end: cached prefix + suffix.
+            # The prefill advanced the rows' cursor to the padded bucket
+            # length; the scatter stamps the true one, so decode overwrites
+            # the pad entries and never attends them
+            prompt_lens = (last_lens if cached_lens is None
+                           else cached_lens + last_lens)
             new_pool = scatter_rows_to_blocks(
-                pool_cache, mutated["cache"], slots, dest_blocks, prompt_lens,
-                bt, shardings=cache_shardings,
-                state_leaves=state_leaves,
+                pool_cache, fresh, slots, dest_blocks, prompt_lens, bt,
+                shardings=cache_shardings, state_leaves=state_leaves,
             )
             d_tables = d_tables.at[slots].set(group_tables)
+            # first token rides out of the prefill itself; budget-1 tokens
+            # remain for the decode loop (a 1-token budget or first-token EOS
+            # is finished on arrival)
             rem0 = budgets - 1
             fin0 = (rem0 <= 0) | ((eos_id >= 0) & (first == eos_id))
             d_tokens = d_tokens.at[slots].set(first)
@@ -1565,9 +1326,46 @@ class ServingEngine:
             return (new_pool, first, fin0, d_tables, d_tokens, d_pos, d_temps,
                     d_topks, d_finished, d_remaining, rng_data)
 
+        return tail
+
+    def _build_paged_admit_fn(self):
+        """Plain admission: prefill ALL nb (right-padded) rows of one prompt
+        bucket in one pass into a FRESH contiguous nb-row cache — the
+        numerics of a solo ``generate``'s prefill; the causal mask keeps pad
+        positions from reaching each row's last real token's logits — then
+        `_build_admit_tail` moves the rows into the pool's blocks."""
+        module, fresh_shapes = self._admit_module, self._fresh_shapes
+        state_leaves = self._contract.state_leaves
+        tail = self._build_admit_tail()
+
+        def admit_fn(pool_cache, params, prompt_rows, slots, prompt_lens,
+                     temps, top_ks, rng_batch, budgets, dest_blocks,
+                     group_tables, d_tables, d_tokens, d_pos, d_temps,
+                     d_topks, d_finished, d_remaining, rng_data, eos_id):
+            nb = prompt_rows.shape[0]
+            fresh = jax.tree.map(
+                lambda s: jnp.zeros((nb,) + s.shape[1:], s.dtype), fresh_shapes
+            )
+            logits, mutated = module.apply(
+                {"params": params, "cache": fresh}, prompt_rows, decode=True,
+                position_offset=0, mutable=["cache"],
+                # a model with recurrent state must know each row's true
+                # length inside the padded bucket: pad tokens leave the state
+                # untouched. Keys-and-values models get no argument at all.
+                **({"cache_write_len": prompt_lens} if state_leaves else {}),
+            )
+            return tail(pool_cache, mutated["cache"], logits, prompt_lens,
+                        slots, temps, top_ks, rng_batch, budgets, dest_blocks,
+                        group_tables, d_tables, d_tokens, d_pos, d_temps,
+                        d_topks, d_finished, d_remaining, rng_data, eos_id)
+
         if self.mesh is None:
             return _shared_jit(module, "paged_admit",
                                lambda: jax.jit(admit_fn, donate_argnums=(0,)))
+        # the [nb] admission inputs (padded prompts, lens, sampling params,
+        # seeds) are replicated — nb is small and the prefill's activations
+        # shard over heads via the param/TP rules; the [b] per-slot vectors
+        # and the tables keep the slot layout through the scatter
         row, rep = self._row_sharding, self._rep_sharding
         tab = self._table_sharding
         return jax.jit(
@@ -1580,19 +1378,20 @@ class ServingEngine:
         )
 
     def _build_paged_cached_admit_fn(self):
-        """Cached admission, paged pool: the matched prefix is ALIASED, never
+        """Admission with prefix reuse: the matched prefix is ALIASED, never
         copied — `gather_block_rows` assembles contiguous per-row views
-        straight out of the engine's own pool as a compute transient, the
-        uncached suffix prefills on top exactly like the slot path, and the
-        scatter writes ONLY the suffix's blocks back (aliased entries carry
-        dest id ``num_blocks`` — dropped). The slot's table then points at
-        the trie's pinned blocks for the prefix and its own fresh blocks for
-        the rest: the zero-copy sharing the slot path's `gather` +
-        `scatter_cache_slots` round trip paid a pool-to-slot copy for."""
+        straight out of the engine's own pool as a compute transient, ONLY
+        the uncached suffix prefills on top (each row resuming at its own
+        ``cached_len`` via the [nb] ``position_offset`` vector), and the tail
+        writes ONLY the suffix's blocks back (aliased entries carry dest id
+        ``num_blocks`` — dropped). The slot's table then points at the trie's
+        pinned blocks for the prefix and its own fresh blocks for the rest.
+        One compile per ``(suffix_bucket, batch_bucket)`` pair — the same
+        bounded set as plain admission, because the scheduler re-buckets the
+        SUFFIX (`FIFOScheduler.prefill_bucket_for`)."""
         module, fresh_shapes = self._admit_module, self._fresh_shapes
-        cache_shardings = self._cache_shardings
         fresh_shardings = self._fresh_shardings
-        bt = self._block_tokens
+        tail = self._build_admit_tail()
 
         def admit_fn(pool_cache, params, gather_tables, cached_lens,
                      suffix_rows, suffix_lens, slots, temps, top_ks,
@@ -1609,34 +1408,11 @@ class ServingEngine:
                 {"params": params, "cache": fresh}, suffix_rows, decode=True,
                 position_offset=cached_lens, mutable=["cache"],
             )
-            last = jax.vmap(
-                lambda row, n: jax.lax.dynamic_slice(
-                    row, (n - 1, 0), (1, row.shape[-1])
-                )[0]
-            )(logits, suffix_lens)
-            rngs = jax.random.wrap_key_data(rng_batch)
-            split = jax.vmap(jax.random.split)(rngs)  # [nb, 2] keys
-            new_rngs, keys = split[:, 0], split[:, 1]
-            first = _sample_rows(last, keys, temps, top_ks,
-                                 jnp.ones_like(temps, bool))
-            # decode resumes from the FULL prompt end: cached prefix + suffix
-            prompt_lens = cached_lens + suffix_lens
-            new_pool = scatter_rows_to_blocks(
-                pool_cache, mutated["cache"], slots, dest_blocks, prompt_lens,
-                bt, shardings=cache_shardings,
-            )
-            d_tables = d_tables.at[slots].set(group_tables)
-            rem0 = budgets - 1
-            fin0 = (rem0 <= 0) | ((eos_id >= 0) & (first == eos_id))
-            d_tokens = d_tokens.at[slots].set(first)
-            d_pos = d_pos.at[slots].set(prompt_lens)
-            d_temps = d_temps.at[slots].set(temps)
-            d_topks = d_topks.at[slots].set(top_ks)
-            d_finished = d_finished.at[slots].set(fin0)
-            d_remaining = d_remaining.at[slots].set(rem0)
-            rng_data = rng_data.at[slots].set(jax.random.key_data(new_rngs))
-            return (new_pool, first, fin0, d_tables, d_tokens, d_pos, d_temps,
-                    d_topks, d_finished, d_remaining, rng_data)
+            return tail(pool_cache, mutated["cache"], logits, suffix_lens,
+                        slots, temps, top_ks, rng_batch, budgets, dest_blocks,
+                        group_tables, d_tables, d_tokens, d_pos, d_temps,
+                        d_topks, d_finished, d_remaining, rng_data, eos_id,
+                        cached_lens=cached_lens)
 
         if self.mesh is None:
             return _shared_jit(module, "paged_cached_admit",
@@ -1903,38 +1679,34 @@ class ServingEngine:
             stats["slot_state_bytes_per_slot"] = state_bytes // self.max_concurrency
         for k, v in self.quant_stats().items():
             stats[f"quant/{k}"] = v
-        if self.paged:
-            # paged mode: ``slot_pool_bytes`` above IS the block pool (the
-            # engine's cache tree holds it), so the block_pool/ gauges report
-            # the allocator's view. Invariant: free + resident (trie) +
-            # private (slot-held) == total (tests/test_paged_kv.py).
-            alloc = self._allocator
-            base = (self.prefix_cache.memory_stats()
-                    if self.prefix_cache is not None else {})
-            resident = int(base.get("blocks_resident", 0))
-            for k, v in {
-                "pool_bytes": stats["slot_pool_bytes"] - state_bytes,
-                "block_tokens": self._block_tokens,
-                "blocks_total": alloc.num_blocks,
-                "blocks_free": alloc.free_count,
-                "blocks_resident": resident,
-                "blocks_private": alloc.owned_count - resident,
-                "blocks_pinned": int(base.get("blocks_pinned", 0)),
-                "blocks_evictable": int(base.get("blocks_evictable", 0)),
-                "blocks_stranded": int(base.get("blocks_stranded", 0)),
-                "fragmentation": base.get("fragmentation", 0.0),
-            }.items():
-                stats[f"block_pool/{k}"] = v
-            if self.kv_tier is not None:
-                # host-tier ledger (docs/observability.md "host_tier"): host
-                # bytes/blocks are CURRENT occupancy, the rest are lifetime
-                # counters. The device invariant above is untouched by
-                # tiering — spilled blocks leave the device ledger entirely.
-                for k, v in self.kv_tier.memory_stats().items():
-                    stats[f"host_tier/{k}"] = v
-        elif self.prefix_cache is not None:
-            for k, v in self.prefix_cache.memory_stats().items():
-                stats[f"block_pool/{k}"] = v
+        # ``slot_pool_bytes`` above IS the block pool (the engine's cache tree
+        # holds it), so the block_pool/ gauges report the allocator's view.
+        # Invariant: free + resident (trie) + private (slot-held) == total
+        # (tests/test_paged_kv.py).
+        alloc = self._allocator
+        base = (self.prefix_cache.memory_stats()
+                if self.prefix_cache is not None else {})
+        resident = int(base.get("blocks_resident", 0))
+        for k, v in {
+            "pool_bytes": stats["slot_pool_bytes"] - state_bytes,
+            "block_tokens": self._block_tokens,
+            "blocks_total": alloc.num_blocks,
+            "blocks_free": alloc.free_count,
+            "blocks_resident": resident,
+            "blocks_private": alloc.owned_count - resident,
+            "blocks_pinned": int(base.get("blocks_pinned", 0)),
+            "blocks_evictable": int(base.get("blocks_evictable", 0)),
+            "blocks_stranded": int(base.get("blocks_stranded", 0)),
+            "fragmentation": base.get("fragmentation", 0.0),
+        }.items():
+            stats[f"block_pool/{k}"] = v
+        if self.kv_tier is not None:
+            # host-tier ledger (docs/observability.md "host_tier"): host
+            # bytes/blocks are CURRENT occupancy, the rest are lifetime
+            # counters. The device invariant above is untouched by
+            # tiering — spilled blocks leave the device ledger entirely.
+            for k, v in self.kv_tier.memory_stats().items():
+                stats[f"host_tier/{k}"] = v
         for i, dev in enumerate(jax.local_devices()):
             dm = device_memory_stats(dev)
             if dm is None:  # the CPU keeps no stats
@@ -2007,27 +1779,24 @@ class ServingEngine:
                          self.max_len - plen)
             remaining.append(max(0, budget - len(out.tokens)))
         decode_remaining = sum(remaining)
-        if self.paged:
-            # a free slot is only worth what the block pool can back: the
-            # optimistic free-slot term is capped by blocks_free * bt. Still
-            # monotone non-increasing as slots fill — an admission moves
-            # budget tokens into decode_remaining while shrinking BOTH cap
-            # operands by at least that much (budget <= max_len - 1 and
-            # budget <= reserved_blocks * bt).
-            blocks_free = self._allocator.free_count
-            capacity = decode_remaining + min(
-                free * (self.max_len - 1),
-                blocks_free * self._block_tokens,
-            )
-            if self.kv_tier is not None:
-                # host-backed capacity counts at a discounted rate: those
-                # tokens are servable, but only after a page-in that is
-                # slower than device-resident decode
-                capacity += int(self.kv_tier.cfg.headroom_discount
-                                * self.kv_tier.host_blocks
-                                * self._block_tokens)
-        else:
-            capacity = decode_remaining + free * (self.max_len - 1)
+        # a free slot is only worth what the block pool can back: the
+        # optimistic free-slot term is capped by blocks_free * bt. Still
+        # monotone non-increasing as slots fill — an admission moves
+        # budget tokens into decode_remaining while shrinking BOTH cap
+        # operands by at least that much (budget <= max_len - 1 and
+        # budget <= reserved_blocks * bt).
+        blocks_free = self._allocator.free_count
+        capacity = decode_remaining + min(
+            free * (self.max_len - 1),
+            blocks_free * self._block_tokens,
+        )
+        if self.kv_tier is not None:
+            # host-backed capacity counts at a discounted rate: those
+            # tokens are servable, but only after a page-in that is
+            # slower than device-resident decode
+            capacity += int(self.kv_tier.cfg.headroom_discount
+                            * self.kv_tier.host_blocks
+                            * self._block_tokens)
         rate = self.metrics.tokens_per_sec()
         exhaustion = capacity / rate if rate > 0 else None
         if free > 0:
@@ -2046,18 +1815,17 @@ class ServingEngine:
             "seconds_to_exhaustion": exhaustion,
             "est_slot_free_s": slot_free_s,
         }
-        if self.paged:
-            # paged headroom gauges (serve_top's block-pool occupancy bars):
-            # free blocks, and the observed private-blocks-per-active-request
-            # — the ragged workload's real per-request footprint, vs the
-            # full-context blocks_per_slot a slot-pool engine always pays
-            active = self.active_slots
-            priv = sum(len(p) for p in self._slot_priv)
-            out["blocks_free"] = blocks_free
-            out["blocks_per_request_est"] = (
-                priv / active if active else float(self._blocks_per_slot))
-            if self.kv_tier is not None:
-                out["host_blocks"] = self.kv_tier.host_blocks
+        # block headroom gauges (serve_top's block-pool occupancy bars): free
+        # blocks, and the observed private-blocks-per-active-request — the
+        # ragged workload's real per-request footprint, against the
+        # full-context blocks_per_slot
+        active = self.active_slots
+        priv = sum(len(p) for p in self._slot_priv)
+        out["blocks_free"] = blocks_free
+        out["blocks_per_request_est"] = (
+            priv / active if active else float(self._blocks_per_slot))
+        if self.kv_tier is not None:
+            out["host_blocks"] = self.kv_tier.host_blocks
         return out
 
     @property
@@ -2133,10 +1901,9 @@ class ServingEngine:
                     drafts = jnp.asarray(self._propose_drafts())
                 tm.draft_s = sp.end - sp.start
                 step_args += (drafts,)
-            if self.paged:
-                # tables ride as data (not donated): decode reads through
-                # them but only admission/release rewrites them
-                step_args += (self._d_tables,)
+            # tables ride as data (not donated): decode reads through
+            # them but only admission/release rewrites them
+            step_args += (self._d_tables,)
             if self.draft_tokens:
                 (self._cache, self._d_tokens, self._d_pos, self._d_remaining,
                  self._d_finished, self._rng_data, toks, fins, oks, ns
@@ -3031,13 +2798,11 @@ class ServingEngine:
 
     def _admit_group(self, group: list[Request],
                      finished: list[RequestOutput]) -> bool:
-        reservation = None
-        if self.paged:
-            # reserve BEFORE touching slots: on exhaustion the group goes
-            # back to the queue front untouched (backpressure, not a crash)
-            reservation = self._reserve_blocks(group, None)
-            if reservation is None:
-                return False
+        # reserve BEFORE touching slots: on exhaustion the group goes
+        # back to the queue front untouched (backpressure, not a crash)
+        reservation = self._reserve_blocks(group, None)
+        if reservation is None:
+            return False
         nb = len(group)
         slots = [self._free.popleft() for _ in group]
         bucket = self.scheduler.bucket_for(max(r.prefill_len for r in group))
@@ -3074,35 +2839,21 @@ class ServingEngine:
             rng_rows.append(jax.random.key_data(key))
             if k:
                 self.metrics.replayed_tokens.inc(plen + k)
-        if self.paged:
-            tables_np, dest_np = self._commit_reservation(
-                reservation, group, None, slots)
-            (self._cache, first, fin0, self._d_tables, self._d_tokens,
-             self._d_pos, self._d_temps, self._d_topks, self._d_finished,
-             self._d_remaining, self._rng_data) = self._dispatch(
-                self._compile_key("admit", bucket, nb), self._admit_fn,
-                self._cache, self.params, jnp.asarray(padded),
-                jnp.asarray(np.asarray(slots, np.int32)), jnp.asarray(lens),
-                jnp.asarray(temps), jnp.asarray(topks),
-                jnp.stack(rng_rows), jnp.asarray(budgets),
-                jnp.asarray(dest_np), jnp.asarray(tables_np),
-                self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
-                self._d_topks, self._d_finished, self._d_remaining,
-                self._rng_data, self._d_eos,
-            )
-        else:
-            (self._cache, first, fin0, self._d_tokens, self._d_pos,
-             self._d_temps, self._d_topks, self._d_finished,
-             self._d_remaining, self._rng_data) = self._dispatch(
-                self._compile_key("admit", bucket, nb), self._admit_fn,
-                self._cache, self.params, jnp.asarray(padded),
-                jnp.asarray(np.asarray(slots, np.int32)), jnp.asarray(lens),
-                jnp.asarray(temps), jnp.asarray(topks),
-                jnp.stack(rng_rows), jnp.asarray(budgets),
-                self._d_tokens, self._d_pos, self._d_temps, self._d_topks,
-                self._d_finished, self._d_remaining, self._rng_data,
-                self._d_eos,
-            )
+        tables_np, dest_np = self._commit_reservation(
+            reservation, group, None, slots)
+        (self._cache, first, fin0, self._d_tables, self._d_tokens,
+         self._d_pos, self._d_temps, self._d_topks, self._d_finished,
+         self._d_remaining, self._rng_data) = self._dispatch(
+            self._compile_key("admit", bucket, nb), self._admit_fn,
+            self._cache, self.params, jnp.asarray(padded),
+            jnp.asarray(np.asarray(slots, np.int32)), jnp.asarray(lens),
+            jnp.asarray(temps), jnp.asarray(topks),
+            jnp.stack(rng_rows), jnp.asarray(budgets),
+            jnp.asarray(dest_np), jnp.asarray(tables_np),
+            self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
+            self._d_topks, self._d_finished, self._d_remaining,
+            self._rng_data, self._d_eos,
+        )
         self.metrics.prefill_tokens.inc(int(lens.sum()))
         self.metrics.admit_batch_size.observe(nb)
         self._finish_admit(group, None, slots, (first, fin0), finished, bucket)
@@ -3131,19 +2882,16 @@ class ServingEngine:
             keep = max(0, (self.max_len - bucket) // pc.block_tokens)
             for i in over:
                 matches[i] = pc.trim(matches[i], keep)
-        reservation = None
-        if self.paged:
-            # reservation AFTER the trim fixed point: aliased counts must
-            # reflect the matches admission will actually use. On failure the
-            # pins are released and the group requeued inside _reserve_blocks.
-            reservation = self._reserve_blocks(group, matches)
-            if reservation is None:
-                return False
+        # reservation AFTER the trim fixed point: aliased counts must
+        # reflect the matches admission will actually use. On failure the
+        # pins are released and the group requeued inside _reserve_blocks.
+        reservation = self._reserve_blocks(group, matches)
+        if reservation is None:
+            return False
         slots = [self._free.popleft() for _ in group]
         padded = np.zeros((nb, bucket), np.int32)
         suffix_lens = np.zeros(nb, np.int32)
         cached_lens = np.zeros(nb, np.int32)
-        tables = np.zeros((nb, pc.blocks_per_row), np.int32)
         temps = np.zeros(nb, np.float32)
         topks = np.zeros(nb, np.int32)
         budgets = np.zeros(nb, np.int32)
@@ -3154,8 +2902,6 @@ class ServingEngine:
             padded[i, :len(suffix)] = suffix
             suffix_lens[i] = len(suffix)
             cached_lens[i] = m.tokens
-            if m.block_ids:
-                tables[i, :len(m.block_ids)] = m.block_ids
             sp = request.params
             temps[i] = sp.temperature
             topks[i] = sp.top_k or 0
@@ -3168,45 +2914,29 @@ class ServingEngine:
                 self.metrics.prefix_tokens_reused.inc(m.tokens)
             elif request.cache_prefix:
                 self.metrics.prefix_misses.inc()
-        if self.paged:
-            # the reservation's tables carry the aliased trie blocks up front
-            # and the slot's fresh private blocks after — they serve as BOTH
-            # the gather view (aliased prefix, zero-copy) and the decode
-            # table; dest drops the aliased region so the scatter writes only
-            # the suffix's blocks
-            tables_np, dest_np = self._commit_reservation(
-                reservation, group, matches, slots)
-            (self._cache, first, fin0, self._d_tables, self._d_tokens,
-             self._d_pos, self._d_temps, self._d_topks, self._d_finished,
-             self._d_remaining, self._rng_data) = self._dispatch(
-                self._compile_key("cached_admit", bucket, nb),
-                self._cached_admit_fn,
-                self._cache, self.params, jnp.asarray(tables_np),
-                jnp.asarray(cached_lens), jnp.asarray(padded),
-                jnp.asarray(suffix_lens),
-                jnp.asarray(np.asarray(slots, np.int32)),
-                jnp.asarray(temps), jnp.asarray(topks), jnp.stack(rng_rows),
-                jnp.asarray(budgets), jnp.asarray(dest_np),
-                jnp.asarray(tables_np),
-                self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
-                self._d_topks, self._d_finished, self._d_remaining,
-                self._rng_data, self._d_eos,
-            )
-        else:
-            (self._cache, first, fin0, self._d_tokens, self._d_pos,
-             self._d_temps, self._d_topks, self._d_finished,
-             self._d_remaining, self._rng_data) = self._dispatch(
-                self._compile_key("cached_admit", bucket, nb),
-                self._cached_admit_fn,
-                self._cache, self.params, pc.pool, jnp.asarray(tables),
-                jnp.asarray(cached_lens), jnp.asarray(padded),
-                jnp.asarray(suffix_lens),
-                jnp.asarray(np.asarray(slots, np.int32)),
-                jnp.asarray(temps), jnp.asarray(topks), jnp.stack(rng_rows),
-                jnp.asarray(budgets), self._d_tokens, self._d_pos,
-                self._d_temps, self._d_topks, self._d_finished,
-                self._d_remaining, self._rng_data, self._d_eos,
-            )
+        # the reservation's tables carry the aliased trie blocks up front
+        # and the slot's fresh private blocks after — they serve as BOTH
+        # the gather view (aliased prefix, zero-copy) and the decode
+        # table; dest drops the aliased region so the scatter writes only
+        # the suffix's blocks
+        tables_np, dest_np = self._commit_reservation(
+            reservation, group, matches, slots)
+        (self._cache, first, fin0, self._d_tables, self._d_tokens,
+         self._d_pos, self._d_temps, self._d_topks, self._d_finished,
+         self._d_remaining, self._rng_data) = self._dispatch(
+            self._compile_key("cached_admit", bucket, nb),
+            self._cached_admit_fn,
+            self._cache, self.params, jnp.asarray(tables_np),
+            jnp.asarray(cached_lens), jnp.asarray(padded),
+            jnp.asarray(suffix_lens),
+            jnp.asarray(np.asarray(slots, np.int32)),
+            jnp.asarray(temps), jnp.asarray(topks), jnp.stack(rng_rows),
+            jnp.asarray(budgets), jnp.asarray(dest_np),
+            jnp.asarray(tables_np),
+            self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
+            self._d_topks, self._d_finished, self._d_remaining,
+            self._rng_data, self._d_eos,
+        )
         # only the uncached suffixes hit the model — that delta is the point
         self.metrics.prefill_tokens.inc(int(suffix_lens.sum()))
         self.metrics.admit_batch_size.observe(nb)
@@ -3433,7 +3163,7 @@ class ServingEngine:
         if (self.prefix_cache is not None and reason != FINISH_ERROR
                 and self._slot_req[slot].cache_prefix
                 and not self._slot_req[slot].resume_tokens):
-            # donate the retired slot's prompt-region KV to the prefix pool.
+            # donate the retired slot's prompt-region KV to the prefix trie.
             # Safe under pipelining: decode writes land at >= prompt_len and a
             # finished slot is frozen by its on-device mask, so [0, prompt_len)
             # is exactly the admission-time prefill whenever we get here. A
@@ -3442,28 +3172,23 @@ class ServingEngine:
             # continuation prefill padded to a bigger bucket than a cold
             # prefill of the prompt alone would use, and donated rows must
             # only ever be ones a cold path would have produced.
-            if self.paged:
-                # zero-copy donation: ownership of the prompt's FULL blocks
-                # moves to the trie (duplicates are freed inside adopt, the
-                # already-aliased prefix just stays the trie's). Blocks at or
-                # past the frontier — anything decode wrote or may still
-                # write from a lagged dispatch — are NEVER adopted; they are
-                # freed by _release_slot once the table row is neutralized.
-                prompt = self._slot_req[slot].prompt
-                n_full = len(prompt) // self._block_tokens
-                aliased = int(self._slot_aliased[slot])
-                if n_full:
-                    self.prefix_cache.adopt(
-                        prompt,
-                        [int(x) for x in self._slot_table_host[slot][:n_full]],
-                        owned_from=aliased,
-                    )
-                    donated = max(0, n_full - aliased)
-                    self._slot_priv[slot] = self._slot_priv[slot][donated:]
-            else:
-                self.prefix_cache.insert(
-                    self._slot_req[slot].prompt, self._cache, slot
+            # Zero-copy: ownership of the prompt's FULL blocks moves to the
+            # trie (duplicates are freed inside adopt, the already-aliased
+            # prefix just stays the trie's). Blocks at or past the frontier
+            # — anything decode wrote or may still write from a lagged
+            # dispatch — are NEVER adopted; they are freed by _release_slot
+            # once the table row is neutralized.
+            prompt = self._slot_req[slot].prompt
+            n_full = len(prompt) // self._block_tokens
+            aliased = int(self._slot_aliased[slot])
+            if n_full:
+                self.prefix_cache.adopt(
+                    prompt,
+                    [int(x) for x in self._slot_table_host[slot][:n_full]],
+                    owned_from=aliased,
                 )
+                donated = max(0, n_full - aliased)
+                self._slot_priv[slot] = self._slot_priv[slot][donated:]
         self._release_slot(slot)
         finished.append(out)
 
@@ -3482,23 +3207,22 @@ class ServingEngine:
         admission's scatter rewrites every per-slot array."""
         if self.prefix_cache is not None and self._slot_match[slot] is not None:
             self.prefix_cache.release(self._slot_match[slot])
-        if self.paged:
-            if self._slot_priv[slot]:
-                self._allocator.free(self._slot_priv[slot])
-            self._slot_priv[slot] = []
-            self._slot_table_host[slot] = None
-            self._slot_aliased[slot] = 0
-            # a CANCELLED slot is not device-finished: dispatches already in
-            # flight — and any issued before the next admission reuses this
-            # slot — would keep writing through the stale table row into
-            # blocks just freed (and possibly handed to a new tenant). Point
-            # the row at num_blocks: paged_decode_update's mode="drop"
-            # scatter then discards the write. In-flight work dispatched
-            # BEFORE this update is still safe by device dispatch order —
-            # its stale writes execute before any re-allocating admission's
-            # scatter can land.
-            self._d_tables = self._d_tables.at[slot].set(
-                jnp.int32(self._allocator.num_blocks))
+        if self._slot_priv[slot]:
+            self._allocator.free(self._slot_priv[slot])
+        self._slot_priv[slot] = []
+        self._slot_table_host[slot] = None
+        self._slot_aliased[slot] = 0
+        # a CANCELLED slot is not device-finished: dispatches already in
+        # flight — and any issued before the next admission reuses this
+        # slot — would keep writing through the stale table row into
+        # blocks just freed (and possibly handed to a new tenant). Point
+        # the row at num_blocks: paged_decode_update's mode="drop"
+        # scatter then discards the write. In-flight work dispatched
+        # BEFORE this update is still safe by device dispatch order —
+        # its stale writes execute before any re-allocating admission's
+        # scatter can land.
+        self._d_tables = self._d_tables.at[slot].set(
+            jnp.int32(self._allocator.num_blocks))
         self._count_sample_tail(self._slot_req[slot].params, -1)
         self._slot_match[slot] = None
         self._slot_hit[slot] = False

@@ -284,7 +284,7 @@ class KVTier:
     records, the thrash guard, and every spill/wake policy decision; all
     device work goes through the engine's jitted ``tier_wake`` scatter and
     plain ``jax.device_get`` reads. Constructed by `ServingEngine` when
-    ``kv_tier=`` is set (paged mode only); ``clock`` is injectable for the
+    ``kv_tier=`` is set; ``clock`` is injectable for the
     policy/thrash tests — transfer RATES always use real wall time."""
 
     def __init__(self, engine: Any, config: KVTierConfig | None = None,

@@ -394,7 +394,7 @@ class ServingMetrics:
         self.queue_depth.observe(queue_depth)
 
     def observe_replicas(self, active_per_replica: list[int], capacity: int) -> None:
-        """Per-data-replica occupancy for one step (mesh-sharded slot pool:
+        """Per-data-replica occupancy for one step (mesh-sharded slot state:
         replica ``i`` decodes its own contiguous slot range of ``capacity``)."""
         for active in active_per_replica:
             self.replica_occupancy.observe(active / capacity if capacity else 0.0)

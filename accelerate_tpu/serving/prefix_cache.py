@@ -1,76 +1,46 @@
-"""Prefix KV-cache reuse: a device-resident block pool behind a host radix trie.
+"""Prefix KV-cache reuse: a host radix trie over the engine's block pool.
 
 Production traffic is dominated by shared prefixes — system prompts, few-shot
-templates, multi-turn history — yet the serving engine (pre-PR-4) recomputed
-every admitted prompt from token 0. This module lets admission skip the
-shared part (SGLang-style RadixAttention, adapted to this stack's
-static-shape discipline):
+templates, multi-turn history — and a prompt's keys and values for a prefix
+do not depend on what follows it. This module lets admission skip the shared
+part (SGLang-style RadixAttention, adapted to this stack's static-shape
+discipline):
 
-  - the KV pool is carved into fixed-size **blocks** of ``block_tokens``
-    tokens (power of two, default 16), allocated once on device as a
-    ``[num_blocks, block_tokens, ...]`` pytree mirroring the engine's slot
-    cache (`models/kv_cache.make_block_pool`) — int8 storage rides along
-    bit-exactly because blocks are copied, never recomputed;
+  - the engine's KV store is already carved into fixed-size **blocks** of
+    ``PagedKVConfig.block_tokens`` tokens (`serving/engine.py`); this class
+    owns no device state, only block ids of that pool, shared with the
+    engine's `models.kv_cache.BlockAllocator`;
   - a host-side **radix trie** maps token-id prefixes to blocks at block
     granularity: one trie node per block, keyed by that block's token tuple.
     Nodes are ref-counted while an admitted request uses them and evicted in
     deterministic LRU order (a monotonic touch counter, never wall clock)
-    when the pool is full — only unpinned leaves are evictable, so a pinned
-    long prefix keeps its whole chain resident;
-  - **admission** does a longest-prefix match (`acquire`, which pins), a
-    jitted gather copies the matched blocks into the slot's cache rows
-    (`models/kv_cache.gather_block_rows`, traced inside the engine's cached
-    admission program), and only the uncached suffix is prefetched through
-    the bucketed prefill;
-  - **retire** donates the finished slot's prompt-region KV back to the pool
-    under the trie key (`insert` -> `models/kv_cache.scatter_block_rows`,
-    one jitted scatter however many blocks are new). Poisoned
-    (`FINISH_ERROR`) slots never donate.
+    when admission needs the blocks (`reclaim`) — only unpinned leaves are
+    evictable, so a pinned long prefix keeps its whole chain resident;
+  - **admission** does a longest-prefix match (`acquire`, which pins); the
+    matched blocks are aliased into the slot's block table (zero-copy) and
+    only the uncached suffix is prefilled through the bucketed prefill;
+  - **retire** hands the finished slot's full prompt blocks to the trie
+    (`adopt`, a host-side ownership move of blocks the prefill already
+    wrote). Poisoned (`FINISH_ERROR`) slots never donate.
 
 Because prefix blocks always sit at the same absolute positions (a prefix
 starts at token 0) the cached KV — position embeddings baked in — is valid
-for every request sharing those tokens, and because hits are *copies* into
-the slot's private cache the decode hot path is completely unchanged.
+for every request sharing those tokens, and because decode only ever writes
+at or past a slot's prompt end, an aliased block is never written again.
 Correctness bar: cached-vs-cold output is token-identical
 (tests/test_prefix_cache.py proves the matrix, including under eviction
 pressure and watchdog re-prefill).
 
 Shape discipline (the GSPMD lesson): matching, pinning, and eviction are
-host-side; the only device programs are the per-``(suffix_bucket,
-batch_bucket)`` cached admission (bounded like plain admission) and ONE
-donation scatter — block counts ride as data (out-of-range ids drop), never
-as shape.
+host-side; the only device program is the per-``(suffix_bucket,
+batch_bucket)`` cached admission (bounded like plain admission) — block ids
+ride as data (out-of-range ids drop), never as shape.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-from collections import deque
 from typing import Any
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from ..models.kv_cache import make_block_pool, scatter_block_rows, tree_nbytes
-
-
-@dataclasses.dataclass(frozen=True)
-class PrefixCacheConfig:
-    """Knobs for the engine's ``prefix_cache=`` argument.
-
-    ``block_tokens`` is the reuse granularity: a prefix match is always a
-    whole number of blocks, so smaller blocks reuse more of a shared prefix
-    but spend more trie nodes per prompt. Must be a power of two dividing
-    ``n_positions``. ``num_blocks`` sizes the device pool; None derives
-    ``2 * max_concurrency * (n_positions / block_tokens)`` — twice the KV
-    footprint of a full slot pool, enough that the working set of hot
-    prefixes survives slot churn before LRU pressure starts.
-    """
-
-    block_tokens: int = 16
-    num_blocks: int | None = None
 
 
 class _TrieNode:
@@ -105,16 +75,16 @@ NO_MATCH = PrefixMatch(0)
 class PrefixCache:
     """Block-granular prefix KV cache for `serving.ServingEngine`.
 
-    ``cache`` is the engine's slot-pool cache pytree (used as the layout
-    template — the pool mirrors its leaves block-wise, so fp32/bf16/int8
-    layouts all work unchanged). The trie and all policy live on the host;
-    the pool lives on device and is only touched by the engine's jitted
-    cached-admission gather and this class's jitted donation scatter.
+    ``allocator`` is the engine's `models.kv_cache.BlockAllocator`: the
+    engine's block pool IS the cache, so the trie and all policy live on the
+    host and this class owns no device state at all — donation is `adopt` (a
+    host-side ownership move of blocks the slot already wrote), hits are
+    zero-copy block-table aliases, and eviction returns blocks to the shared
+    free list via `reclaim`.
     """
 
-    def __init__(self, cache: Any, max_len: int, block_tokens: int = 16,
-                 num_blocks: int | None = None, metrics: Any = None,
-                 shardings: Any = None, allocator: Any = None):
+    def __init__(self, allocator: Any, max_len: int, block_tokens: int,
+                 metrics: Any = None):
         block_tokens = int(block_tokens)
         if block_tokens < 1 or block_tokens & (block_tokens - 1):
             raise ValueError(f"block_tokens must be a power of two, got {block_tokens}")
@@ -128,43 +98,12 @@ class PrefixCache:
         self.metrics = metrics
         self._root = _TrieNode((), None, -1)
         self._tick = 0
-        # host-RAM tier hook (`serving/kv_tier.py`, paged mode only): when
-        # set, spilled trie nodes (``block_id is None`` — bytes live in the
-        # tier's host map) stay hit-able: `acquire` pages them back in
-        # instead of recomputing prefill, `adopt` revives them for free
+        # host-RAM tier hook (`serving/kv_tier.py`): when set, spilled trie
+        # nodes (``block_id is None`` — bytes live in the tier's host map)
+        # stay hit-able: `acquire` pages them back in instead of recomputing
+        # prefill, `adopt` revives them for free
         self.tier = None
-        # ``allocator`` (a `models.kv_cache.BlockAllocator`) switches the trie
-        # to PAGED mode (`docs/serving.md` "Paged KV"): the engine's paged KV
-        # cache IS the pool, so this class owns no device state at all —
-        # donation becomes `adopt` (a host-side ownership move of blocks the
-        # slot already wrote), hits are zero-copy block-table aliases, and
-        # eviction returns blocks to the shared free list via `reclaim`.
         self.allocator = allocator
-        if allocator is not None:
-            self.num_blocks = int(allocator.num_blocks)
-            self.pool = None
-            self._free = None
-            self._scatter = None
-            return
-        if num_blocks is None:
-            num_blocks = 2 * self.blocks_per_row * int(cache_batch_size(cache))
-        self.num_blocks = int(num_blocks)
-        if self.num_blocks < 1:
-            raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
-        # ``shardings`` (a congruent NamedSharding pytree,
-        # `parallel.sharding.infer_block_pool_shardings`) allocates the pool
-        # straight into its mesh placement — heads on the model axis, blocks
-        # replicated so any replica reuses any prefix — and pins the donation
-        # scatter's output layout; None is the single-device pool, unchanged.
-        self.pool = make_block_pool(cache, self.num_blocks, block_tokens,
-                                    shardings=shardings)
-        self._free: deque[int] = deque(range(self.num_blocks))
-        # donation scatter: ONE compiled program for any number of new blocks
-        # (skipped blocks ride as dropped out-of-range ids, not shapes)
-        self._scatter = jax.jit(
-            functools.partial(scatter_block_rows, shardings=shardings),
-            donate_argnums=(0,),
-        )
 
     # ------------------------------------------------------------------ matching
     def _walk(self, prompt: list[int]) -> list[_TrieNode]:
@@ -225,47 +164,9 @@ class PrefixCache:
             node.ref -= 1
 
     # ------------------------------------------------------------------ donation
-    def insert(self, prompt: list[int], cache: Any, slot: int) -> int:
-        """Donate a retired slot's prompt-region KV: every full block of
-        ``prompt`` not already in the trie gets a pool block (LRU-evicting
-        unpinned leaves when the free list is empty) and ONE jitted scatter
-        copies the new blocks out of slot row ``slot``. Returns how many
-        blocks were newly stored (0 = full dedup hit, no device work).
-
-        Donation stops at the first block it cannot place (an exhausted,
-        fully-pinned pool): a radix trie cannot reach block ``j+1`` without
-        block ``j``, so a partial prefix is still fully useful and nothing
-        past the gap could ever be matched.
-        """
-        if self.allocator is not None:
-            raise RuntimeError("paged mode donates via adopt(), not insert()")
-        n_blocks = min(len(prompt) // self.block_tokens, self.blocks_per_row)
-        dest = np.full(self.blocks_per_row, self.num_blocks, np.int32)
-        node, new = self._root, 0
-        for j in range(n_blocks):
-            key = tuple(prompt[j * self.block_tokens:(j + 1) * self.block_tokens])
-            child = node.children.get(key)
-            if child is None:
-                block_id = self._alloc()
-                if block_id is None:
-                    break
-                child = _TrieNode(key, node, block_id)
-                node.children[key] = child
-                dest[j] = block_id
-                new += 1
-            self._touch(child)
-            node = child
-        if new:
-            self.pool = self._scatter(
-                self.pool, cache, jnp.asarray(slot, jnp.int32), jnp.asarray(dest)
-            )
-            if self.metrics is not None:
-                self.metrics.prefix_blocks_donated.inc(new)
-        return new
-
     def adopt(self, prompt: list[int], block_ids: list[int],
               owned_from: int) -> int:
-        """Paged-mode donation: transfer ownership of a retired slot's full
+        """Donation: transfer ownership of a retired slot's full
         prompt blocks into the trie with ZERO device work — prefill already
         wrote them in place in the shared pool, so the trie simply starts
         pointing at them. ``block_ids[j]`` is the pool block holding prompt
@@ -306,7 +207,7 @@ class PrefixCache:
 
     # ------------------------------------------------------------------ eviction
     def reclaim(self, n: int) -> int:
-        """Paged-mode eviction: pop up to ``n`` unpinned LRU leaves and hand
+        """Eviction: pop up to ``n`` unpinned LRU leaves and hand
         their blocks back to the shared allocator (admission calls this when
         the free list cannot cover a new request's block reservation).
         Returns how many blocks were actually freed — fewer than ``n`` means
@@ -319,11 +220,6 @@ class PrefixCache:
             self.allocator.free([block_id])
             freed += 1
         return freed
-
-    def _alloc(self) -> int | None:
-        if self._free:
-            return self._free.popleft()
-        return self._evict_one()
 
     def _evict_one(self) -> int | None:
         """Reclaim the least-recently-used evictable block. Only unpinned
@@ -354,41 +250,15 @@ class PrefixCache:
         node.last_used = self._tick
 
     # ----------------------------------------------------------------- inspection
-    @property
-    def cached_blocks(self) -> int:
-        """Blocks currently resident in the trie (slot mode: eviction hands a
-        reclaimed block straight to its new tenant, so allocated == resident;
-        paged mode: counted from the trie, the shared allocator also carries
-        slot-private blocks this class does not see)."""
-        if self._free is None:
-            return self.node_count()
-        return self.num_blocks - len(self._free)
-
     def node_count(self) -> int:
+        """Blocks the trie holds, resident or spilled (the shared allocator
+        also carries slot-private blocks this class does not see)."""
         count, stack = 0, list(self._root.children.values())
         while stack:
             node = stack.pop()
             count += 1
             stack.extend(node.children.values())
         return count
-
-    @property
-    def blocks_free(self) -> int:
-        """Pool blocks on the free list (slot mode: never yet allocated, or
-        returned by an explicit clear — eviction recycles in place and
-        bypasses it; paged mode: the shared allocator's free count)."""
-        if self._free is None:
-            return self.allocator.free_count
-        return len(self._free)
-
-    @property
-    def pool_nbytes(self) -> int:
-        """Exact device bytes of the block pool (constant after allocation —
-        the pool is never resized, only rewritten in place). Zero in paged
-        mode: the pool is the engine's paged KV cache and accounted there."""
-        if self.pool is None:
-            return 0
-        return tree_nbytes(self.pool)
 
     def memory_stats(self) -> dict[str, Any]:
         """Host-side occupancy gauges for the telemetry exporter
@@ -426,9 +296,6 @@ class PrefixCache:
                 evictable += 1
         stranded = resident - pinned - evictable
         out: dict[str, Any] = {
-            "pool_bytes": self.pool_nbytes,
-            "blocks_total": self.num_blocks,
-            "blocks_free": self.blocks_free,
             "blocks_resident": resident,
             "blocks_pinned": pinned,
             "blocks_evictable": evictable,
@@ -441,9 +308,3 @@ class PrefixCache:
                 "bytes": spilled * self.tier.block_bytes,
             }
         return out
-
-
-def cache_batch_size(cache: Any) -> int:
-    """Leading (slot) dimension of a per-slot cache pytree."""
-    leaves = jax.tree_util.tree_leaves(cache)
-    return max(leaf.shape[0] for leaf in leaves)

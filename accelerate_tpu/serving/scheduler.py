@@ -63,7 +63,7 @@ class FIFOScheduler:
         # tracer so every QUEUED edge — fresh acceptance or watchdog requeue —
         # is stamped where the queue actually changes
         self.tracer = NULL_TRACER
-        # paged-KV capacity hook (set by the engine when paged_kv is on):
+        # block-pool capacity hook (set by the engine):
         # maps the front run's requests to how many of them the block pool can
         # actually seat right now. Admission is gated on BLOCKS, not just free
         # slots — a free slot with no blocks behind it would crash mid-decode,
@@ -176,7 +176,7 @@ class FIFOScheduler:
                 break
             n += 1
         if n and self.capacity_fn is not None:
-            # paged mode: shrink the run to what the block pool can seat —
+            # shrink the run to what the block pool can seat —
             # the hook sees the actual front requests so it can price each
             # one's reservation (prompt + budget, minus any aliased prefix)
             n = max(0, min(n, int(self.capacity_fn(

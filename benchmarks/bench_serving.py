@@ -21,16 +21,7 @@ tokens requests actually asked for. Prints ONE machine-readable JSON line:
 with vs_baseline = pipelined_tps / lockstep_tps (>1.0 = continuous batching
 wins); detail carries engine_depth1/engine_pipelined/lockstep breakdowns.
 
-The default (ragged) workload additionally prints a second machine-readable
-row, {"metric": "serving_paged_capacity_ratio", ...}: the same trace through a
-slot-pool engine and a paged engine (`docs/serving.md` "Paged KV") whose block
-pool is sized to EXACTLY the slot pool's KV bytes, with value = peak in-flight
-requests paged / slot (the PR-9 acceptance bar is >= 2.0) and detail carrying
-per-mode ``kv_bytes_per_token`` (peak-resident KV bytes per generated token,
-from `memory_stats()`'s exact byte accounting) and the block-pool low-water
-mark.
-
-A third machine-readable row, {"metric": "serving_decode_dispatches_per_token",
+A second machine-readable row, {"metric": "serving_decode_dispatches_per_token",
 ...}, measures the fused paged-decode amortization (`docs/serving.md` "Fused
 paged decode"): the trace's head runs through paged engines across every
 (batch, tokens_per_sync, gather|fused) combination, each sub-row carrying ITL
@@ -41,7 +32,7 @@ single-step gather engine's dispatches-per-token over value (>1.0 = the scan
 amortizes). On CPU the fused kernel runs in Pallas interpret mode, so the
 sub-rows default to a short head of the trace (``BENCH_SERVE_FUSED_REQUESTS``).
 
-A fourth machine-readable row, {"metric": "serving_spec_forwards_per_accepted",
+A third machine-readable row, {"metric": "serving_spec_forwards_per_accepted",
 ...}, measures speculative decoding (`docs/serving.md` "Speculative
 decoding"): a prompt-lookup-friendly trace (motif-repeated prompts, greedy)
 runs through paged engines across every (batch, draft_k, drafter)
@@ -513,113 +504,6 @@ def _frontend_row(module, params, trace, concurrency, depth, admit) -> None:
         shutil.rmtree(workdir, ignore_errors=True)
 
 
-def _capacity_probe(engine, trace) -> dict:
-    """Drive `trace` through `engine` with every request submitted up front —
-    the probe measures admission capacity, not arrival pacing — sampling
-    `memory_stats()` once per step. Peak in-flight comes from the occupancy
-    histogram (sampled inside `step()` post-admission, so it is the true
-    high-water mark); the block-pool low-water mark is the post-step
-    ``blocks_free`` minimum (paged engines only, None otherwise)."""
-    from accelerate_tpu.serving import ServingMetrics
-
-    engine.metrics = ServingMetrics()
-    for req in trace:
-        engine.submit(Request(req.prompt, req.params, slo=req.slo))
-    t0 = time.perf_counter()
-    done = 0
-    blocks_free_min = None
-    while engine.has_work:
-        done += len(engine.step())
-        mem = engine.memory_stats()
-        if "block_pool/blocks_free" in mem:
-            free = int(mem["block_pool/blocks_free"])
-            blocks_free_min = (free if blocks_free_min is None
-                               else min(blocks_free_min, free))
-    dt = time.perf_counter() - t0
-    assert done == len(trace)
-    peak = int(round(engine.metrics.slot_occupancy.max
-                     * engine.max_concurrency))
-    return {
-        "max_concurrency": engine.max_concurrency,
-        "peak_in_flight": peak,
-        "blocks_free_min": blocks_free_min,
-        "wall_s": round(dt, 3),
-        "steps": engine.metrics.steps.value,
-    }
-
-
-def _paged_capacity_row(module, params, cfg, trace, concurrency, depth,
-                        admit) -> None:
-    """The paged-vs-slot capacity comparison row (PR-9 acceptance bar): both
-    engines get the SAME KV pool bytes — the paged pool is sized to exactly
-    the slot pool's KV footprint (``concurrency * n_positions`` token-slots)
-    while its admission cap is lifted to 4x — so any in-flight gain is pure
-    ragged-occupancy win: requests only hold the blocks their actual extent
-    needs instead of a full ``n_positions`` row. ``kv_bytes_per_token`` is the
-    peak-resident KV bytes per generated token, from `memory_stats()`'s exact
-    ``leaf.nbytes`` accounting: the whole pool for slot mode (every admitted
-    row reserves full context), the block high-water mark for paged mode."""
-    from accelerate_tpu.serving import PagedKVConfig
-
-    block_tokens = 16
-    total_tokens = sum(r.params.max_new_tokens for r in trace)
-
-    slot_engine = ServingEngine(
-        module, params, max_concurrency=concurrency, prompt_buckets=BUCKETS,
-        max_queue=len(trace) + 1, pipeline_depth=depth, admit_batch=admit)
-    slot_row = _capacity_probe(slot_engine, trace)
-    slot_pool_bytes = int(slot_engine.memory_stats()["slot_pool_bytes"])
-
-    paged_engine = ServingEngine(
-        module, params, max_concurrency=4 * concurrency,
-        prompt_buckets=BUCKETS, max_queue=len(trace) + 1,
-        pipeline_depth=depth, admit_batch=admit,
-        paged_kv=PagedKVConfig(
-            block_tokens=block_tokens,
-            num_blocks=concurrency * cfg.n_positions // block_tokens))
-    paged_row = _capacity_probe(paged_engine, trace)
-    mem = paged_engine.memory_stats()
-    blocks_total = int(mem["block_pool/blocks_total"])
-    paged_pool_bytes = int(mem["block_pool/pool_bytes"])
-    blocks_used_peak = blocks_total - paged_row.pop("blocks_free_min")
-    slot_row.pop("blocks_free_min")
-
-    slot_row["pool_bytes"] = slot_pool_bytes
-    slot_row["kv_bytes_per_token"] = round(slot_pool_bytes / total_tokens, 1)
-    paged_row.update({
-        "pool_bytes": paged_pool_bytes,
-        "block_tokens": block_tokens,
-        "blocks_total": blocks_total,
-        "blocks_free_min": blocks_total - blocks_used_peak,
-        "blocks_used_peak": blocks_used_peak,
-        "kv_bytes_per_token": round(
-            paged_pool_bytes / blocks_total * blocks_used_peak / total_tokens,
-            1),
-    })
-    print(json.dumps({
-        "metric": "serving_paged_capacity_ratio",
-        "value": round(paged_row["peak_in_flight"]
-                       / max(slot_row["peak_in_flight"], 1), 3),
-        "unit": "x_concurrent_requests",
-        "detail": {
-            "platform": _host_platform(),
-            "requests": len(trace),
-            "generated_tokens": total_tokens,
-            "admit_batch": admit,
-            "pipeline_depth": depth,
-            # equal-pool check: paged adds only the per-layer int32 write
-            # cursor over the slot pool's KV leaves, so this stays ~1.0
-            "pool_bytes_ratio_paged_over_slot": round(
-                paged_pool_bytes / slot_pool_bytes, 6),
-            "kv_bytes_per_token_ratio": round(
-                paged_row["kv_bytes_per_token"]
-                / slot_row["kv_bytes_per_token"], 4),
-            "slot": slot_row,
-            "paged": paged_row,
-        },
-    }), flush=True)
-
-
 def _fused_decode_row(module, params, cfg, trace, concurrency, depth,
                       admit) -> None:
     """The fused-decode amortization rows: the SAME trace head through paged
@@ -875,7 +759,7 @@ def _prefix_trace(n: int, rate: float, seed: int, vocab: int, prefix_len: int,
 
 
 def main_prefix() -> None:
-    from accelerate_tpu.serving import PrefixCacheConfig, ServingMetrics
+    from accelerate_tpu.serving import ServingMetrics
 
     n_requests = _env_int("BENCH_SERVE_REQUESTS", 32)
     concurrency = _env_int("BENCH_SERVE_CONCURRENCY", 8)
@@ -916,7 +800,7 @@ def main_prefix() -> None:
         return tps, dt, detail, engine.metrics
 
     off_tps, off_dt, off_detail, off_m = timed(False)
-    on_tps, on_dt, on_detail, on_m = timed(PrefixCacheConfig())
+    on_tps, on_dt, on_detail, on_m = timed(True)
     skipped = off_m.prefill_tokens.value - on_m.prefill_tokens.value
     reduction = skipped / max(off_m.prefill_tokens.value, 1)
 
@@ -1020,7 +904,6 @@ def _tenant_trace(n: int, rate: float, seed: int, vocab: int, prefix_len: int,
 def main_cluster() -> None:
     from accelerate_tpu.serving import (
         ClusterConfig,
-        PrefixCacheConfig,
         ServingCluster,
     )
 
@@ -1150,7 +1033,7 @@ def main_cluster() -> None:
                 module, params, max_concurrency=concurrency,
                 prompt_buckets=buckets, max_queue=len(rtrace) + 1,
                 pipeline_depth=depth, admit_batch=admit,
-                prefix_cache=PrefixCacheConfig(), **kw)
+                prefix_cache=True, **kw)
 
         policy_rows: dict[str, dict] = {}
         for policy in ("prefix", "round_robin"):
@@ -1822,7 +1705,6 @@ def main() -> None:
         },
     }), flush=True)
     _frontend_row(module, params, trace, concurrency, depth, admit)
-    _paged_capacity_row(module, params, cfg, trace, concurrency, depth, admit)
     _fused_decode_row(module, params, cfg, trace, concurrency, depth, admit)
     _speculation_row(module, params, cfg, concurrency, depth, admit)
 

@@ -472,7 +472,7 @@ def phase_serve(run: Smoke) -> None:
     fused, _ = _serve(run, "serve", "paged+fused", module, params, eos,
                       paged_kv=True, paged_attention="fused")
     run.kernels_ran_compiled("serve", ("flash_attention._paged_decode_kernel",))
-    default, _ = _serve(run, "serve", "default (slot pool, gather)", module, params, eos)
+    default, _ = _serve(run, "serve", "default (gather)", module, params, eos)
 
     requests = _requests(run, module.config.vocab_size)
     greedy = [i for i, r in enumerate(requests) if r.params.temperature == 0.0]

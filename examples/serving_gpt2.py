@@ -1,6 +1,6 @@
 """GPT-2 continuous-batching serving (`docs/serving.md`): ragged requests with
 per-request sampling params stream through one jitted decode step over a fixed
-slot pool, with metrics logged through the standard tracker interface.
+set of decode slots, with metrics logged through the standard tracker interface.
 
 Runs on the host CPU in seconds:  JAX_PLATFORMS=cpu python examples/serving_gpt2.py
 Swap in `GPT2Config.small()` + real weights and `kv_cache_dtype=jnp.int8`

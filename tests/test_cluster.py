@@ -34,7 +34,6 @@ from accelerate_tpu.serving import (
     FINISH_LENGTH,
     REJECT_UNHEALTHY,
     ClusterConfig,
-    PrefixCacheConfig,
     Request,
     SamplingParams,
     ServingCluster,
@@ -187,7 +186,7 @@ def test_prefix_routing_follows_trie_affinity(model, tmp_path):
     cached prefix of its prompt — match beats the load/index tie-break."""
     module, params = model
     cluster = ServingCluster(
-        _factory(module, params, prefix_cache=PrefixCacheConfig()),
+        _factory(module, params, prefix_cache=True),
         tmp_path, replicas=2)
     r = np.random.default_rng(3)
     tenant_a = r.integers(0, 256, (16,)).astype(np.int32).tolist()

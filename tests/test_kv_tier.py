@@ -28,7 +28,6 @@ from accelerate_tpu.models.generation import generate
 from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead
 from accelerate_tpu.serving import (
     PagedKVConfig,
-    PrefixCacheConfig,
     Request,
     RequestJournal,
     SamplingParams,
@@ -167,9 +166,12 @@ def test_config_validation(model):
         KVTierConfig(min_resident_slots=-1)
     with pytest.raises(ValueError, match="thrash_enter_events"):
         KVTierConfig(thrash_enter_events=0)
-    with pytest.raises(ValueError, match="requires paged_kv"):
+    with pytest.raises(ValueError, match="removed"):
         ServingEngine(module, params, max_concurrency=2, prompt_buckets=(16,),
-                      kv_tier=True)
+                      kv_tier=True, paged_kv=False)
+    # the tier sits behind the pool every engine has
+    assert ServingEngine(module, params, max_concurrency=2, prompt_buckets=(16,),
+                         kv_tier=True).kv_tier is not None
 
 
 # ----------------------------------------------------------- spill ordering
@@ -180,7 +182,7 @@ def test_trie_spill_picks_lru_leaf_and_keeps_invariant(model):
     module, params = model
     engine = ServingEngine(module, params, max_concurrency=4,
                            prompt_buckets=(16, 64), admit_batch=4,
-                           prefix_cache=PrefixCacheConfig(block_tokens=BT),
+                           prefix_cache=True,
                            paged_kv=PagedKVConfig(block_tokens=BT,
                                                   num_blocks=48),
                            kv_tier=True)
@@ -234,7 +236,7 @@ def test_page_in_is_all_or_nothing(model):
     module, params = model
     engine = ServingEngine(module, params, max_concurrency=2,
                            prompt_buckets=(16, 64),
-                           prefix_cache=PrefixCacheConfig(block_tokens=BT),
+                           prefix_cache=True,
                            paged_kv=PagedKVConfig(block_tokens=BT,
                                                   num_blocks=24),
                            kv_tier=True)
@@ -344,7 +346,7 @@ def test_tier_parity_matrix(model, tier_refs, pa, depth):
     prompts, refs = tier_refs
     kw = dict(max_concurrency=4, prompt_buckets=(16, 64), pipeline_depth=depth,
               admit_batch=4, paged_attention=pa,
-              prefix_cache=PrefixCacheConfig(block_tokens=BT),
+              prefix_cache=True,
               paged_kv=PagedKVConfig(block_tokens=BT, num_blocks=48))
     off = ServingEngine(module, params, **kw)
     assert {o.request_id: o.tokens for o in off.run(_requests(prompts))} == refs
@@ -435,7 +437,7 @@ def test_crash_exact_resume_mid_spill(model, tmp_path):
     refs = {i: _solo(module, params, p, 12, seed=i)
             for i, p in enumerate(prompts)}
     kw = dict(max_concurrency=4, prompt_buckets=(16, 64), admit_batch=4,
-              prefix_cache=PrefixCacheConfig(block_tokens=BT),
+              prefix_cache=True,
               paged_kv=PagedKVConfig(block_tokens=BT, num_blocks=48))
     a = ServingEngine(module, params, journal=journal, kv_tier=True, **kw)
     for r in _requests(prompts, n_new=12):

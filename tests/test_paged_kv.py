@@ -1,9 +1,9 @@
 """Paged KV serving (`ServingEngine(paged_kv=...)`): block-table KV as the
-primary store, with copy-free prefix aliasing and block-gated admission.
+engine's one store, with copy-free prefix aliasing and block-gated admission.
 
-The load-bearing contract is threefold. PARITY: paged mode emits exactly the
-tokens slot-pool mode — and a solo ``generate`` — emits, across the pipeline
-depth x admit batch matrix, through prefix-cache-hit admissions, and on the
+The load-bearing contract is threefold. PARITY: the engine emits exactly the
+tokens a solo ``generate`` emits, through the gather path and the fused
+kernel alike, across the pipeline depth x admit batch matrix, through prefix-cache-hit admissions, and on the
 (2, 2) mesh. BACKPRESSURE: block exhaustion delays admission, it never
 crashes a decode (reservation is all-or-nothing, up front). ACCOUNTING: every
 block is either free, trie-resident, or privately held by a live slot, the
@@ -28,7 +28,6 @@ from accelerate_tpu.serving import (
     FINISH_EOS,
     FINISH_LENGTH,
     PagedKVConfig,
-    PrefixCacheConfig,
     Request,
     SamplingParams,
     ServingEngine,
@@ -110,10 +109,23 @@ def test_engine_validates_paged_config(model):
     # construction must succeed and the pool must really be quantized
     eng8 = ServingEngine(m8, p8, paged_kv=True, **kw)
     assert eng8.quant_stats()["kv_bits"] == 8
-    with pytest.raises(ValueError, match="block_tokens"):
-        # paged pool and trie must agree on the block quantum
-        ServingEngine(module, params, paged_kv=PagedKVConfig(block_tokens=32),
-                      prefix_cache=PrefixCacheConfig(block_tokens=16), **kw)
+    # the block quantum is decided once: the trie takes the pool's
+    eng32 = ServingEngine(module, params, prefix_cache=True,
+                          paged_kv=PagedKVConfig(block_tokens=32), **kw)
+    assert eng32.prefix_cache.block_tokens == 32
+    assert eng32.prefix_cache.allocator is eng32._allocator
+
+
+@pytest.mark.parametrize("off", [False, None, 0])
+def test_the_slot_store_is_gone(model, off):
+    """``paged_kv`` only sizes the pool: the per-slot contiguous store it
+    used to switch off is removed, and asking for it says so."""
+    module, params = model
+    with pytest.raises(ValueError, match="removed"):
+        ServingEngine(module, params, max_concurrency=2, prompt_buckets=(16,),
+                      paged_kv=off)
+    default = ServingEngine(module, params, max_concurrency=2, prompt_buckets=(16,))
+    assert default.memory_stats()["block_pool/blocks_total"] == 2 * 128 // BT
 
 
 def test_engine_validates_fused_and_sync_config(model):
@@ -122,10 +134,9 @@ def test_engine_validates_fused_and_sync_config(model):
     with pytest.raises(ValueError, match="gather.*fused|fused.*gather"):
         ServingEngine(module, params, paged_kv=True,
                       paged_attention="pallas", **kw)
-    with pytest.raises(ValueError, match="requires paged_kv"):
-        # the fused kernel reads the block pool through the block tables —
-        # meaningless on the contiguous slot pool
-        ServingEngine(module, params, paged_attention="fused", **kw)
+    # the fused kernel reads the block pool every engine has: no store to ask for
+    assert ServingEngine(module, params, paged_attention="fused",
+                         **kw).module.config.kv_paged_attention == "fused"
     with pytest.raises(ValueError, match="tokens_per_sync"):
         ServingEngine(module, params, tokens_per_sync=0, **kw)
 
@@ -143,8 +154,7 @@ def parity_refs(model):
 @pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("admit", [1, 4])
 def test_paged_parity_matrix(model, parity_refs, depth, admit, sync):
-    """Fused kernel == gather path == slot-pool mode == solo generate,
-    bit-for-bit, across the depth x admit x tokens_per_sync matrix — the
+    """Fused kernel == gather path == solo generate, bit-for-bit, across the depth x admit x tokens_per_sync matrix — the
     tentpole oracle. The fused cell runs the Pallas paged-decode kernel in
     interpret mode on CPU; the multi-token cells run the whole decode loop
     inside one jitted lax.scan per dispatch."""
@@ -157,10 +167,9 @@ def test_paged_parity_matrix(model, parity_refs, depth, admit, sync):
                                admit_batch=admit, tokens_per_sync=sync, **kw)
         return {o.request_id: o.tokens for o in engine.run(_requests(prompts))}
 
-    slot = serve()
-    gather = serve(paged_kv=True)
-    fused = serve(paged_kv=True, paged_attention="fused")
-    assert fused == gather == slot == refs
+    gather = serve()
+    fused = serve(paged_attention="fused")
+    assert fused == gather == refs
 
 
 def test_eos_and_budget_landing_mid_scan(model, parity_refs):
@@ -234,7 +243,7 @@ def test_quarantine_mid_scan_replays_token_identical(model, fault_injection):
 
 def test_paged_parity_with_a_merged_width_that_tiles_nothing():
     """3 heads of 16: the pool's folded last dim is 48 lanes, no multiple of
-    128 nor of 64, with an odd head count. Fused == gather == slot, and the
+    128 nor of 64, with an odd head count. Fused == gather == solo, and the
     pool leaves really are ``[num_blocks, block_tokens, kv_heads * head_dim]``."""
     cfg = GPT2Config.tiny(dtype=jnp.float32, n_embd=48, n_head=3)
     module = GPT2LMHead(cfg)
@@ -247,10 +256,10 @@ def test_paged_parity_with_a_merged_width_that_tiles_nothing():
         tokens = {o.request_id: o.tokens for o in engine.run(_requests(prompts))}
         return tokens, engine
 
-    slot, _ = serve()
-    gather, _ = serve(paged_kv=True)
-    fused, engine = serve(paged_kv=True, paged_attention="fused")
-    assert fused == gather == slot
+    refs = {i: _solo(module, params, p, 12, seed=i) for i, p in enumerate(prompts)}
+    gather, _ = serve()
+    fused, engine = serve(paged_attention="fused")
+    assert fused == gather == refs
     kv_shapes = {leaf.shape for path, leaf in
                  jax.tree_util.tree_leaves_with_path(engine._cache)
                  if path[-1].key in ("cached_key", "cached_value")}
@@ -358,7 +367,7 @@ def test_paged_prefix_hit_parity_zero_copy_aliasing(model):
     engine = ServingEngine(
         module, params, max_concurrency=2, prompt_buckets=(8, 64),
         pipeline_depth=2, admit_batch=2, paged_kv=True,
-        prefix_cache=PrefixCacheConfig(block_tokens=BT),
+        prefix_cache=True,
     )
     # warm: first request donates its 2 full prompt blocks at retirement
     first = engine.run(_requests(prompts[:1], n_new=6))[0]
@@ -438,7 +447,7 @@ def test_refcount_pin_blocks_eviction_of_aliased_prefix_mid_decode(model):
         module, params, max_concurrency=2, prompt_buckets=(8, 64),
         pipeline_depth=1, admit_batch=1,
         paged_kv=PagedKVConfig(block_tokens=BT, num_blocks=8),
-        prefix_cache=PrefixCacheConfig(block_tokens=BT),
+        prefix_cache=True,
     )
     # warm the trie: 2 donated blocks
     warm = engine.run(_requests([prefix], n_new=4))[0]
@@ -494,7 +503,7 @@ def test_retire_reclaims_exactly_the_unpinned_blocks(model):
     # everything else (frontier + decode blocks) returns to the free list
     cached = ServingEngine(module, params, max_concurrency=2,
                            prompt_buckets=(64,), paged_kv=True,
-                           prefix_cache=PrefixCacheConfig(block_tokens=BT))
+                           prefix_cache=True)
     cached.run(_requests(prompts, n_new=6))
     mem = cached.memory_stats()
     assert mem["block_pool/blocks_resident"] == 3
@@ -534,7 +543,7 @@ def test_paged_headroom_reports_blocks_and_stays_monotone(model):
 def test_paged_mesh_parity_with_prefix_hits(model):
     """The (2, 2) acceptance cell: a mesh-sharded paged engine — two waves
     through one engine so wave 2 admits via CACHED aliasing — must match the
-    unsharded paged engine and the slot-pool baseline token-for-token."""
+    unsharded engine and solo ``generate`` token-for-token."""
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 devices")
     module, params = model
@@ -546,11 +555,10 @@ def test_paged_mesh_parity_with_prefix_hits(model):
         for _ in range(2)
     ]
 
-    def serve_waves(mesh, paged):
+    def serve_waves(mesh):
         engine = ServingEngine(
             module, params, max_concurrency=4, prompt_buckets=(8, 32),
-            pipeline_depth=2, admit_batch=4, mesh=mesh, paged_kv=paged,
-            prefix_cache=PrefixCacheConfig(block_tokens=BT),
+            pipeline_depth=2, admit_batch=4, mesh=mesh, prefix_cache=True,
         )
         out = {}
         for wave in waves:
@@ -558,9 +566,10 @@ def test_paged_mesh_parity_with_prefix_hits(model):
                 out[len(out)] = (tuple(o.tokens), o.finish_reason)
         return out, engine
 
-    base, _ = serve_waves(None, False)
-    paged_local, _ = serve_waves(None, True)
-    paged_mesh, engine = serve_waves((2, 2), True)
+    base = {i: (tuple(_solo(module, params, p, 6)), FINISH_LENGTH)
+            for i, p in enumerate(p for wave in waves for p in wave)}
+    paged_local, _ = serve_waves(None)
+    paged_mesh, engine = serve_waves((2, 2))
     assert paged_local == base
     assert paged_mesh == base
     assert engine.metrics.prefix_hits.value >= 3

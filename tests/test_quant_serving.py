@@ -29,7 +29,7 @@ pytestmark = [pytest.mark.serving, pytest.mark.quant]
 from accelerate_tpu.models import kv_cache
 from accelerate_tpu.models.generation import generate
 from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead
-from accelerate_tpu.parallel.sharding import infer_block_pool_shardings
+from accelerate_tpu.parallel.sharding import infer_cache_shardings, kv_cache_sharding
 from accelerate_tpu.serving import (
     PagedKVConfig,
     Request,
@@ -425,9 +425,13 @@ def test_scale_planes_get_pool_shardings():
 
     mesh = Mesh(np.array(jax.devices("cpu")[:1]).reshape(1, 1),
                 ("data", "tensor"))
-    pool = {"k_pool": jnp.zeros((4, 8, 2, 4)),       # payload: 4-dim
-            "k_scale_pool": jnp.zeros((4, 8, 2))}    # scale plane: 3-dim
-    shardings = infer_block_pool_shardings(pool, mesh)
-    assert shardings["k_pool"].spec == PartitionSpec(None, None, None, None)
-    # scale planes ride the same (blocks, tokens, heads) rule minus head_dim
-    assert shardings["k_scale_pool"].spec == PartitionSpec(None, None, None)
+    pool = {"cached_key": jnp.zeros((4, 8, 2 * 4), jnp.int8),  # payload, heads folded
+            "key_scale": jnp.zeros((4, 8, 2)),                # scale plane
+            "cache_index": jnp.zeros((2,), jnp.int32)}
+    rules = kv_cache_sharding(mesh, slots=2, paged=True)
+    shardings = infer_cache_shardings(pool, rules)
+    assert shardings["cached_key"] is rules.kv
+    # scale planes ride the same (blocks, tokens, heads) rule, told by name
+    assert shardings["key_scale"] is rules.scale
+    assert shardings["key_scale"].spec == PartitionSpec(None, None, None)
+    assert shardings["cache_index"] is rules.index

@@ -148,11 +148,10 @@ def _prompts(seed, lengths):
     return [r.integers(0, 256, (n,)).astype(np.int32).tolist() for n in lengths]
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slots", "paged"])
-def test_all_greedy_traffic_counts_every_step_greedy(model, paged):
+def test_all_greedy_traffic_counts_every_step_greedy(model):
     module, params = model
     engine = ServingEngine(module, params, max_concurrency=2,
-                           prompt_buckets=(8, 16), max_queue=8, paged_kv=paged)
+                           prompt_buckets=(8, 16), max_queue=8)
     engine.run([Request(p, SamplingParams(max_new_tokens=6))
                 for p in _prompts(0, [3, 7, 12])])
     greedy, draw, top_k = _tail_counts(engine)

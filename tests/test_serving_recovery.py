@@ -29,7 +29,6 @@ from accelerate_tpu.serving import (
     FINISH_LENGTH,
     REJECT_DEADLINE,
     JournalError,
-    PrefixCacheConfig,
     Request,
     RequestJournal,
     SamplingParams,
@@ -273,7 +272,7 @@ def test_resume_parity_with_prefix_cache_and_pipeline(model, tmp_path):
     def build(jpath):
         return ServingEngine(
             module, params, max_concurrency=2, prompt_buckets=(16, 32),
-            pipeline_depth=2, prefix_cache=PrefixCacheConfig(num_blocks=8),
+            pipeline_depth=2, prefix_cache=True,
             journal=jpath)
 
     base = _prompts(7, (17, 23))
@@ -342,7 +341,7 @@ def test_resume_from_journal_paged_crash_exact(model, tmp_path):
         return ServingEngine(
             module, params, max_concurrency=2, prompt_buckets=(16, 32),
             pipeline_depth=2, paged_kv=True,
-            prefix_cache=PrefixCacheConfig(block_tokens=16), journal=jpath)
+            prefix_cache=True, journal=jpath)
 
     base = _prompts(7, (17, 23))
     prompts = base + [list(base[0]), list(base[1])]  # duplicates: cache hits

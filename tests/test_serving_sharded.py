@@ -23,7 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead, gpt2_sharding_rules
 from accelerate_tpu.parallel.mesh import serving_mesh
 from accelerate_tpu.parallel.sharding import (
-    infer_block_pool_shardings,
     infer_cache_shardings,
     infer_param_shardings,
     kv_cache_sharding,
@@ -248,7 +247,7 @@ def test_infer_param_shardings_degrades_not_raises(model):
 def test_kv_cache_sharding_slot_and_head_rules():
     """Slot dim shards on "data" only when the slot count divides the degree;
     heads shard on "tensor"; the fresh-rows variant (slots=None) never shards
-    the slot dim; block pools replicate blocks and shard only heads."""
+    the slot dim (the block pool's rules: the next test)."""
     mesh = serving_mesh(data=2, model=2)
     s4 = kv_cache_sharding(mesh, slots=4)
     assert s4.kv.spec == P(("data",), None, "tensor", None)
@@ -268,10 +267,6 @@ def test_kv_cache_sharding_slot_and_head_rules():
     assert tree["cached_key"].spec == s4.kv.spec
     assert tree["key_scale"].spec == s4.scale.spec
     assert tree["cache_index"].spec == s4.index.spec
-    pool = infer_block_pool_shardings(
-        {"cached_key": jax.ShapeDtypeStruct((12, 16, 2, 8), jnp.float32)}, mesh
-    )
-    assert pool["cached_key"].spec == P(None, None, "tensor", None)
 
     # TP degree 1: head axis drops out entirely
     s_dp = kv_cache_sharding(serving_mesh(data=4, model=1), slots=4)

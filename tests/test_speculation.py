@@ -133,7 +133,7 @@ def test_engine_rejects_speculation_with_token_scan(model):
 # ------------------------------------------------------------------ parity bar
 def test_spec_parity_matrix(model):
     """THE speculation acceptance contract: spec on == spec off == solo,
-    bit-for-bit, across pipeline depth x admit batch x slot/paged layouts,
+    bit-for-bit, across pipeline depth x admit batch x block size,
     on a mixed greedy/sampled ragged workload (sampled slots must ride the
     verify dispatch untouched, advancing one token per forward)."""
     module, params = model
@@ -148,22 +148,19 @@ def test_spec_parity_matrix(model):
     budgets = [7, 6, 9, 5]
     ref = [_solo(module, params, p, n, **sp)
            for p, n, sp in zip(prompts, budgets, specs)]
-    for paged in (False, True):
+    for pool in (True, PagedKVConfig(block_tokens=8, num_blocks=16)):
         for depth in (1, 2):
             for admit in (1, 4):
-                kw = dict(max_concurrency=2, prompt_buckets=(16,), max_queue=8,
-                          pipeline_depth=depth, admit_batch=admit,
-                          speculation=3)
-                if paged:
-                    kw["paged_kv"] = PagedKVConfig(block_tokens=8,
-                                                   num_blocks=16)
-                engine = ServingEngine(module, params, **kw)
+                engine = ServingEngine(
+                    module, params, max_concurrency=2, prompt_buckets=(16,),
+                    max_queue=8, pipeline_depth=depth, admit_batch=admit,
+                    speculation=3, paged_kv=pool)
                 outs = engine.run([
                     Request(list(p), SamplingParams(max_new_tokens=n, **sp))
                     for p, n, sp in zip(prompts, budgets, specs)
                 ])
                 got = [o.tokens for o in sorted(outs, key=lambda o: o.request_id)]
-                assert got == ref, f"paged={paged} depth={depth} admit={admit}"
+                assert got == ref, f"pool={pool} depth={depth} admit={admit}"
                 assert all(o.finish_reason == FINISH_LENGTH for o in outs)
                 # the verify path actually ran and paid off its accounting
                 m = engine.metrics
@@ -236,18 +233,18 @@ def test_spec_budget_shorter_than_draft_depth(model):
 
 
 # -------------------------------------------------------------------- rollback
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_spec_rollback_keeps_frontier_cursor_exact(model, paged):
+@pytest.mark.parametrize(
+    "pool", [True, PagedKVConfig(block_tokens=8, num_blocks=16)],
+    ids=["default", "bt8"])
+def test_spec_rollback_keeps_frontier_cursor_exact(model, pool):
     """The engine invariant speculation must preserve: after EVERY step, each
     layer's ``cache_index`` equals the host-mirrored ``_d_pos`` for every
     slot — i.e. the rejected draft suffix was rolled back to the accepted
     frontier, not left dangling (where the next dispatch would append AFTER
     garbage)."""
     module, params = model
-    kw = dict(max_concurrency=2, prompt_buckets=(16,), speculation=3)
-    if paged:
-        kw["paged_kv"] = PagedKVConfig(block_tokens=8, num_blocks=16)
-    engine = ServingEngine(module, params, **kw)
+    engine = ServingEngine(module, params, max_concurrency=2,
+                           prompt_buckets=(16,), speculation=3, paged_kv=pool)
     prompts = [p + p for p in _prompts(34, [4, 6])]
     for p in prompts:
         engine.submit(Request(list(p), SamplingParams(max_new_tokens=10)))

@@ -26,7 +26,6 @@ from accelerate_tpu.serving import (
     NULL_TELEMETRY,
     KVTierConfig,
     PagedKVConfig,
-    PrefixCacheConfig,
     Request,
     SamplingParams,
     ServingEngine,
@@ -68,9 +67,10 @@ def _stub_engine(snapshot=None):
 # ----------------------------------------------------------- byte accounting
 @pytest.mark.parametrize("kind", ["fp32", "bf16", "int8"])
 def test_pool_bytes_match_nbytes_across_dtypes(kind):
-    """The contract the gauges are named for: slot-pool and block-pool byte
-    counts equal the sum of the underlying arrays' nbytes, exactly, for
-    fp32/bf16/int8 KV storage."""
+    """The contract the gauges are named for: the ``slot_pool_bytes`` and
+    ``block_pool/pool_bytes`` gauges (one pool, the engine's) equal the sum
+    of the underlying arrays' nbytes, exactly, for fp32/bf16/int8 KV
+    storage."""
     kw = {"fp32": dict(dtype=jnp.float32),
           "bf16": dict(dtype=jnp.bfloat16),
           "int8": dict(dtype=jnp.float32, kv_cache_dtype=jnp.int8)}[kind]
@@ -78,9 +78,8 @@ def test_pool_bytes_match_nbytes_across_dtypes(kind):
     module = GPT2LMHead(cfg)
     params = module.init_params(jax.random.key(0))
     engine = ServingEngine(module, params, max_concurrency=2,
-                           prompt_buckets=(8, 32),
-                           prefix_cache=PrefixCacheConfig(block_tokens=8,
-                                                          num_blocks=4))
+                           prompt_buckets=(8, 32), prefix_cache=True,
+                           paged_kv=PagedKVConfig(block_tokens=8))
     mem = engine.memory_stats()
     assert mem["slot_pool_bytes"] == tree_nbytes(engine._cache) == sum(
         int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(engine._cache))
@@ -93,9 +92,9 @@ def test_pool_bytes_match_nbytes_across_dtypes(kind):
         assert "int8" in by_dtype and "float32" in by_dtype
     if kind == "bf16":
         assert "bfloat16" in by_dtype
-    assert (mem["block_pool/pool_bytes"]
-            == engine.prefix_cache.pool_nbytes
-            == tree_nbytes(engine.prefix_cache.pool))
+    assert mem["block_pool/pool_bytes"] == mem["slot_pool_bytes"]
+    assert mem["block_pool/block_tokens"] == 8
+    assert mem["block_pool/blocks_total"] == 2 * cfg.n_positions // 8
 
 
 # -------------------------------------------------- occupancy gauge parity
@@ -125,17 +124,14 @@ def test_occupancy_gauges_consistent_across_matrix(model, depth, admit, tier):
         params = module.init_params(jax.random.key(0))
     else:
         module, params = model
+    # 16 blocks is one full row — the minimum pool, so pressure is real
     kw = dict(max_concurrency=3, prompt_buckets=(8, 32), max_queue=8,
-              pipeline_depth=depth, admit_batch=admit)
+              pipeline_depth=depth, admit_batch=admit, prefix_cache=True,
+              paged_kv=PagedKVConfig(block_tokens=8, num_blocks=16))
     if tier != "plain":
-        # 16 blocks is one full row — the minimum pool, so pressure is real
-        kw.update(prefix_cache=PrefixCacheConfig(block_tokens=8),
-                  paged_kv=PagedKVConfig(block_tokens=8, num_blocks=16),
-                  kv_tier=KVTierConfig(min_resident_slots=1,
+        kw.update(kv_tier=KVTierConfig(min_resident_slots=1,
                                        low_water_blocks=2,
                                        thrash_enter_events=10_000))
-    else:
-        kw.update(prefix_cache=PrefixCacheConfig(block_tokens=8, num_blocks=3))
     engine = ServingEngine(module, params, **kw)
     if quant:
         # the halved-block-bytes anchor: an int8 block (payload + fp32
@@ -145,7 +141,7 @@ def test_occupancy_gauges_consistent_across_matrix(model, depth, admit, tier):
         assert engine.kv_tier.block_bytes == c.n_layer * 2 * (8 * h * d
                                                               + 8 * h * 4)
         assert engine.kv_tier.block_bytes < c.n_layer * 2 * 8 * h * d * 4 / 2
-    prompts = _prompts(17, [20, 24, 22, 20, 26, 24])
+    prompts = _prompts(17, [20, 24, 22, 20, 26, 24, 22, 26])
     prompts[3] = list(prompts[0])  # duplicate → prefix hit after donation
     for p in prompts:
         assert engine.submit(Request(
@@ -160,7 +156,7 @@ def test_occupancy_gauges_consistent_across_matrix(model, depth, admit, tier):
         assert mem["queue_depth"] == engine.scheduler.queue_depth
         assert (mem["block_pool/blocks_free"]
                 + mem["block_pool/blocks_resident"]
-                + mem.get("block_pool/blocks_private", 0)
+                + mem["block_pool/blocks_private"]
                 == mem["block_pool/blocks_total"])
         assert (mem["block_pool/blocks_pinned"]
                 + mem["block_pool/blocks_evictable"]
@@ -203,21 +199,19 @@ def test_occupancy_gauges_consistent_across_matrix(model, depth, admit, tier):
         assert engine.metrics.prefix_evictions.value > 0
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_capacity_headroom_monotone_as_slots_fill(model, paged):
-    """Headroom is monotone non-increasing as slots fill — in BOTH KV modes
-    (the paged block-gated capacity must never report more room after an
-    admission than before it)."""
+def test_capacity_headroom_monotone_as_slots_fill(model):
+    """Headroom is monotone non-increasing as slots fill (the block-gated
+    capacity must never report more room after an admission than before
+    it)."""
     module, params = model
     engine = ServingEngine(module, params, max_concurrency=4,
-                           prompt_buckets=(8,), max_queue=8, paged_kv=paged)
+                           prompt_buckets=(8,), max_queue=8)
     idle = engine.capacity_headroom()
     assert idle["admissible_requests"] == 4
     assert idle["seconds_to_exhaustion"] is None  # no rate yet, never inf
     assert idle["est_slot_free_s"] == 0.0
     assert idle["token_capacity_remaining"] == 4 * (engine.max_len - 1)
-    if paged:
-        assert idle["blocks_free"] == engine._allocator.num_blocks
+    assert idle["blocks_free"] == engine._allocator.num_blocks
     seen = [idle]
     for i in range(4):
         assert engine.submit(Request(
@@ -231,8 +225,7 @@ def test_capacity_headroom_monotone_as_slots_fill(model, paged):
         assert cur["admissible_requests"] <= prev["admissible_requests"]
         assert (cur["token_capacity_remaining"]
                 <= prev["token_capacity_remaining"])
-        if paged:
-            assert cur["blocks_free"] <= prev["blocks_free"]
+        assert cur["blocks_free"] <= prev["blocks_free"]
     full = seen[-1]
     assert full["seconds_to_exhaustion"] is not None  # decoding → rate > 0
     assert full["est_slot_free_s"] is not None and full["est_slot_free_s"] > 0
@@ -245,9 +238,8 @@ def test_prometheus_round_trip_from_engine_run(model, tmp_path):
     telemetry = TelemetryExporter(TelemetryConfig(
         interval_s=0.0, prometheus_path=str(prom)))
     engine = ServingEngine(module, params, max_concurrency=2,
-                           prompt_buckets=(8,),
-                           prefix_cache=PrefixCacheConfig(block_tokens=8,
-                                                          num_blocks=4),
+                           prompt_buckets=(8,), prefix_cache=True,
+                           paged_kv=PagedKVConfig(block_tokens=8),
                            telemetry=telemetry)
     for p in _prompts(3, [6, 7, 6]):
         engine.submit(Request(prompt=p, params=SamplingParams(
@@ -269,7 +261,7 @@ def test_prometheus_round_trip_from_engine_run(model, tmp_path):
     assert (parsed[prometheus_name("serving/mem/slot_pool_bytes")]
             == tree_nbytes(engine._cache))
     assert (parsed[prometheus_name("serving/mem/block_pool/pool_bytes")]
-            == tree_nbytes(engine.prefix_cache.pool))
+            == tree_nbytes(engine._cache))
     telemetry.close()
 
 
@@ -279,9 +271,8 @@ def test_jsonl_time_series_byte_gauges_exact(model, tmp_path):
     telemetry = TelemetryExporter(TelemetryConfig(
         interval_s=0.0, jsonl_path=str(path)))
     engine = ServingEngine(module, params, max_concurrency=2,
-                           prompt_buckets=(8,),
-                           prefix_cache=PrefixCacheConfig(block_tokens=8,
-                                                          num_blocks=4),
+                           prompt_buckets=(8,), prefix_cache=True,
+                           paged_kv=PagedKVConfig(block_tokens=8),
                            telemetry=telemetry)
     for p in _prompts(5, [6, 7]):
         engine.submit(Request(prompt=p, params=SamplingParams(
@@ -298,7 +289,7 @@ def test_jsonl_time_series_byte_gauges_exact(model, tmp_path):
         assert (point["serving/mem/slot_pool_bytes"]
                 == tree_nbytes(engine._cache))
         assert (point["serving/mem/block_pool/pool_bytes"]
-                == tree_nbytes(engine.prefix_cache.pool))
+                == tree_nbytes(engine._cache))
 
 
 def test_http_metrics_endpoint(tmp_path):

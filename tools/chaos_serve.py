@@ -53,18 +53,18 @@ Env knobs:
                         the zero-lost guarantee survives LAGGED retirement —
                         set 1 to bisect a failure against synchronous dispatch)
   CHAOS_PREFIX          1 (default) serves through the prefix cache; 0 = off
-  CHAOS_PREFIX_BLOCKS   prefix pool size in blocks (default 6: forces eviction)
-  CHAOS_PAGED           1 replays through PAGED KV (``paged_kv=True``,
-                        docs/serving.md "Paged KV"): block-gated admission,
-                        zero-copy prefix aliasing, and block reclaim all run
-                        under the same chaos, with the same zero-lost /
-                        zero-drift bar PLUS full pool reclamation — after the
-                        drain (and, with the trie on, after evicting every
-                        resident block) ``blocks_free`` must return to its
-                        initial value; a single leaked or double-freed block
-                        fails the replay. Works with the crash scenarios too
-                        (the resumed engine re-prefills into fresh blocks).
-                        Default 0: the slot-pool KV path
+  CHAOS_PREFIX_BLOCKS   with the prefix cache on, the engine's block pool
+                        holds one full context plus this many blocks
+                        (default 6: forces eviction). Block-gated admission,
+                        zero-copy prefix aliasing, and block reclaim
+                        (docs/serving.md "Paged KV") all run under the chaos,
+                        with the zero-lost / zero-drift bar PLUS full pool
+                        reclamation — after the drain (and, with the trie on,
+                        after evicting every resident block) ``blocks_free``
+                        must return to its initial value; a single leaked or
+                        double-freed block fails the replay. The crash
+                        scenarios too (the resumed engine re-prefills into
+                        fresh blocks)
   CHAOS_SYNC_TOKENS     engine ``tokens_per_sync`` (default 1): k > 1 runs k
                         decode iterations inside one jitted lax.scan per
                         dispatch (docs/serving.md "Fused paged decode"), so
@@ -108,7 +108,7 @@ Env knobs:
                         `ServingFrontend.resume_stream` — asserting every
                         resumed stream byte-identical to solo generate with
                         no duplicated events (works under CHAOS_SPEC /
-                        CHAOS_SYNC_TOKENS / CHAOS_PAGED too);
+                        CHAOS_SYNC_TOKENS too);
                         "hang" or "storm" runs the SELF-HEALING scenario
                         (`serving/supervisor.py`): a wedged mid-decode
                         dispatch / a NaN quarantine storm that the engine
@@ -185,6 +185,18 @@ def _env_int(name: str, default: int) -> int:
     return int(os.environ.get(name, default))
 
 
+def _pool(module, prefix_cache: bool, prefix_blocks: int):
+    """The replay engine's ``paged_kv=``: with the prefix cache on, a pool of
+    one full context plus ``prefix_blocks`` blocks, small on purpose so trie
+    eviction fires mid-chaos; the engine's default without it."""
+    from accelerate_tpu.serving import PagedKVConfig
+
+    if not prefix_cache:
+        return True
+    per_slot = int(module.config.n_positions) // PagedKVConfig().block_tokens
+    return PagedKVConfig(num_blocks=per_slot + prefix_blocks)
+
+
 def _assert_steady_state(engine) -> dict:
     """The telemetry gauges (`engine.memory_stats` / `capacity_headroom`,
     serving/telemetry.py) must report a fully clean engine once the chaos
@@ -199,28 +211,25 @@ def _assert_steady_state(engine) -> dict:
         f"leaked slots after drain: {mem}"
     assert mem["queue_depth"] == 0 and mem["inflight_dispatches"] == 0, \
         f"work left after drain: {mem}"
-    if "block_pool/blocks_total" in mem:  # prefix trie and/or paged pool
-        assert mem["block_pool/blocks_pinned"] == 0, \
-            f"stuck block pins after drain: {mem}"
-        assert mem.get("block_pool/blocks_private", 0) == 0, \
-            f"retired slots still hold private blocks: {mem}"
-        assert (mem["block_pool/blocks_free"]
-                + mem["block_pool/blocks_resident"]
-                + mem.get("block_pool/blocks_private", 0)
-                == mem["block_pool/blocks_total"]), \
-            f"block accounting inconsistent after drain: {mem}"
-    if getattr(engine, "paged", False):
-        # full reclamation: every resident (trie-donated) block must still be
-        # evictable, and evicting them all returns the pool to its initial
-        # fully-free state — the paged acceptance bar. The replay is over, so
-        # mutating the trie here costs nothing.
-        if engine.prefix_cache is not None:
-            engine.prefix_cache.reclaim(
-                int(mem["block_pool/blocks_resident"]))
-        mem = engine.memory_stats()
-        assert (mem["block_pool/blocks_free"]
-                == mem["block_pool/blocks_total"]), \
-            f"pool not fully reclaimed after drain + evict-all: {mem}"
+    assert mem["block_pool/blocks_pinned"] == 0, \
+        f"stuck block pins after drain: {mem}"
+    assert mem["block_pool/blocks_private"] == 0, \
+        f"retired slots still hold private blocks: {mem}"
+    assert (mem["block_pool/blocks_free"]
+            + mem["block_pool/blocks_resident"]
+            + mem["block_pool/blocks_private"]
+            == mem["block_pool/blocks_total"]), \
+        f"block accounting inconsistent after drain: {mem}"
+    # full reclamation: every resident (trie-donated) block must still be
+    # evictable, and evicting them all returns the pool to its initial
+    # fully-free state. The replay is over, so mutating the trie here costs
+    # nothing.
+    if engine.prefix_cache is not None:
+        engine.prefix_cache.reclaim(int(mem["block_pool/blocks_resident"]))
+    mem = engine.memory_stats()
+    assert (mem["block_pool/blocks_free"]
+            == mem["block_pool/blocks_total"]), \
+        f"pool not fully reclaimed after drain + evict-all: {mem}"
     assert head["slots_free"] == engine.max_concurrency, \
         f"headroom not restored after drain: {head}"
     assert head["admissible_requests"] == engine.max_concurrency, \
@@ -252,7 +261,6 @@ def run(
     verify_parity: bool = True,
     mesh=None,
     trace_path: str | None = None,
-    paged: bool = False,
     sync_tokens: int = 1,
     speculation: int = 0,
 ) -> dict:
@@ -269,7 +277,6 @@ def run(
     from accelerate_tpu.serving import (
         FINISH_EOS,
         FINISH_LENGTH,
-        PrefixCacheConfig,
         Request,
         ServingEngine,
         SLOSpec,
@@ -307,16 +314,14 @@ def run(
         module, params, max_concurrency=concurrency,
         prompt_buckets=BUCKETS, max_queue=n_requests + 1,
         pipeline_depth=pipeline_depth,
-        prefix_cache=(PrefixCacheConfig(num_blocks=prefix_blocks)
-                      if prefix_cache else False),
+        prefix_cache=prefix_cache,
+        paged_kv=_pool(module, prefix_cache, prefix_blocks),
         mesh=mesh,
         tracer=tracer,
-        paged_kv=paged,
         tokens_per_sync=sync_tokens,
         speculation=speculation or None,
     )
-    blocks_free_initial = (engine.memory_stats()["block_pool/blocks_free"]
-                           if paged else None)
+    blocks_free_initial = engine.memory_stats()["block_pool/blocks_free"]
     slo_plain = SLOSpec(name="plain")
     slo_deadline = SLOSpec(name="deadline")
 
@@ -352,10 +357,9 @@ def run(
     lost = sorted(set(submitted) - set(terminal))
     assert not lost, f"lost requests (accepted but no terminal output): {lost}"
     steady = _assert_steady_state(engine)
-    if paged:
-        assert steady["blocks_free"] == blocks_free_initial, \
-            (f"block pool did not return to its initial state: "
-             f"{steady['blocks_free']} != {blocks_free_initial}")
+    assert steady["blocks_free"] == blocks_free_initial, \
+        (f"block pool did not return to its initial state: "
+         f"{steady['blocks_free']} != {blocks_free_initial}")
 
     # parity drift: every cleanly finished request — whether its prefill came
     # cold, from cached blocks, after an eviction, or via a watchdog
@@ -406,7 +410,6 @@ def run(
             "seed": seed,
             "pipeline_depth": pipeline_depth,
             "prefix_cache": bool(prefix_cache),
-            "paged_kv": bool(paged),
             "tokens_per_sync": sync_tokens,
             "speculation": speculation,
             "spec_forwards": m.spec_forwards.value,
@@ -1080,7 +1083,6 @@ def run_stream_kill(
     prefix_blocks: int = 6,
     timeout_s: float = 240.0,
     workdir: str | None = None,
-    paged: bool = False,
     sync_tokens: int = 1,
     speculation: int = 0,
 ) -> dict:
@@ -1110,7 +1112,6 @@ def run_stream_kill(
     from accelerate_tpu.serving import (
         FINISH_EOS,
         FINISH_LENGTH,
-        PrefixCacheConfig,
         RequestJournal,
         ServingEngine,
         ServingFrontend,
@@ -1127,7 +1128,6 @@ def run_stream_kill(
         CHAOS_CONCURRENCY=str(concurrency), CHAOS_SEED=str(seed),
         CHAOS_DEPTH=str(pipeline_depth), CHAOS_PREFIX=str(int(prefix_cache)),
         CHAOS_PREFIX_BLOCKS=str(prefix_blocks),
-        CHAOS_PAGED=str(int(paged)),
         CHAOS_SYNC_TOKENS=str(sync_tokens),
         CHAOS_SPEC=str(speculation),
         JAX_PLATFORMS="cpu",
@@ -1174,10 +1174,9 @@ def run_stream_kill(
         module, params, max_concurrency=concurrency,
         prompt_buckets=BUCKETS, max_queue=n_requests + 1,
         pipeline_depth=pipeline_depth,
-        prefix_cache=(PrefixCacheConfig(num_blocks=prefix_blocks)
-                      if prefix_cache else False),
+        prefix_cache=prefix_cache,
+        paged_kv=_pool(module, prefix_cache, prefix_blocks),
         journal=journal,
-        paged_kv=paged,
         tokens_per_sync=sync_tokens,
         speculation=speculation or None,
     )
@@ -1249,7 +1248,6 @@ def run_stream_kill(
             "seed": seed,
             "pipeline_depth": pipeline_depth,
             "prefix_cache": bool(prefix_cache),
-            "paged_kv": bool(paged),
             "tokens_per_sync": sync_tokens,
             "speculation": speculation,
             "streams": len(streams),
@@ -1278,7 +1276,7 @@ def _crash_child() -> None:
 
     from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead
     from accelerate_tpu.reliability import install_serving_preemption_handler
-    from accelerate_tpu.serving import PrefixCacheConfig, Request, ServingEngine
+    from accelerate_tpu.serving import Request, ServingEngine
 
     n = _env_int("CHAOS_REQUESTS", 12)
     quant = os.environ.get("CHAOS_QUANT", "")
@@ -1295,10 +1293,10 @@ def _crash_child() -> None:
         max_concurrency=_env_int("CHAOS_CONCURRENCY", 2),
         prompt_buckets=BUCKETS, max_queue=n + 1,
         pipeline_depth=_env_int("CHAOS_DEPTH", 2),
-        prefix_cache=(PrefixCacheConfig(num_blocks=_env_int("CHAOS_PREFIX_BLOCKS", 6))
-                      if _env_int("CHAOS_PREFIX", 1) else False),
+        prefix_cache=bool(_env_int("CHAOS_PREFIX", 1)),
+        paged_kv=_pool(module, bool(_env_int("CHAOS_PREFIX", 1)),
+                       _env_int("CHAOS_PREFIX_BLOCKS", 6)),
         journal=os.environ["CHAOS_JOURNAL"],
-        paged_kv=bool(_env_int("CHAOS_PAGED", 0)),
         tokens_per_sync=_env_int("CHAOS_SYNC_TOKENS", 1),
         speculation=_env_int("CHAOS_SPEC", 0) or None,
     )
@@ -1340,7 +1338,6 @@ def _hibernate_kill_child() -> None:
     from accelerate_tpu.serving import (
         KVTierConfig,
         PagedKVConfig,
-        PrefixCacheConfig,
         Request,
         ServingEngine,
     )
@@ -1356,7 +1353,7 @@ def _hibernate_kill_child() -> None:
         max_concurrency=_env_int("CHAOS_CONCURRENCY", 4),
         prompt_buckets=BUCKETS, max_queue=n + 1,
         pipeline_depth=_env_int("CHAOS_DEPTH", 2),
-        prefix_cache=PrefixCacheConfig(block_tokens=16),
+        prefix_cache=True,
         journal=os.environ["CHAOS_JOURNAL"],
         paged_kv=PagedKVConfig(block_tokens=16, num_blocks=32),
         kv_tier=KVTierConfig(),
@@ -1413,7 +1410,6 @@ def run_hibernate_kill(
         FINISH_LENGTH,
         KVTierConfig,
         PagedKVConfig,
-        PrefixCacheConfig,
         RequestJournal,
         ServingEngine,
     )
@@ -1465,7 +1461,7 @@ def run_hibernate_kill(
         module, params, max_concurrency=concurrency,
         prompt_buckets=BUCKETS, max_queue=n_requests + 1,
         pipeline_depth=pipeline_depth,
-        prefix_cache=PrefixCacheConfig(block_tokens=16),
+        prefix_cache=True,
         journal=journal,
         paged_kv=PagedKVConfig(block_tokens=16, num_blocks=32),
         kv_tier=KVTierConfig(),
@@ -1562,7 +1558,6 @@ def run_crash(
     workdir: str | None = None,
     verify_parity: bool = True,
     trace_path: str | None = None,
-    paged: bool = False,
     sync_tokens: int = 1,
     speculation: int = 0,
     quant: str = "",
@@ -1588,7 +1583,6 @@ def run_crash(
     from accelerate_tpu.serving import (
         FINISH_EOS,
         FINISH_LENGTH,
-        PrefixCacheConfig,
         RequestJournal,
         ServingEngine,
         Tracer,
@@ -1607,7 +1601,6 @@ def run_crash(
         CHAOS_CONCURRENCY=str(concurrency), CHAOS_SEED=str(seed),
         CHAOS_DEPTH=str(pipeline_depth), CHAOS_PREFIX=str(int(prefix_cache)),
         CHAOS_PREFIX_BLOCKS=str(prefix_blocks), CHAOS_GRACE=str(grace_s),
-        CHAOS_PAGED=str(int(paged)),
         CHAOS_SYNC_TOKENS=str(sync_tokens),
         CHAOS_SPEC=str(speculation),
         CHAOS_QUANT=quant,
@@ -1669,11 +1662,10 @@ def run_crash(
         module, params, max_concurrency=concurrency,
         prompt_buckets=BUCKETS, max_queue=n_requests + 1,
         pipeline_depth=pipeline_depth,
-        prefix_cache=(PrefixCacheConfig(num_blocks=prefix_blocks)
-                      if prefix_cache else False),
+        prefix_cache=prefix_cache,
+        paged_kv=_pool(module, prefix_cache, prefix_blocks),
         journal=journal,
         tracer=tracer,
-        paged_kv=paged,
         tokens_per_sync=sync_tokens,
         speculation=speculation or None,
     )
@@ -1747,7 +1739,6 @@ def run_crash(
             "seed": seed,
             "pipeline_depth": pipeline_depth,
             "prefix_cache": bool(prefix_cache),
-            "paged_kv": bool(paged),
             "tokens_per_sync": sync_tokens,
             "speculation": speculation,
             "quant": quant or None,
@@ -1836,7 +1827,6 @@ def main() -> None:
             prefix_cache=bool(_env_int("CHAOS_PREFIX", 1)),
             prefix_blocks=_env_int("CHAOS_PREFIX_BLOCKS", 6),
             workdir=os.environ.get("CHAOS_WORKDIR") or None,
-            paged=bool(_env_int("CHAOS_PAGED", 0)),
             sync_tokens=_env_int("CHAOS_SYNC_TOKENS", 1),
             speculation=_env_int("CHAOS_SPEC", 0),
         )
@@ -1854,7 +1844,6 @@ def main() -> None:
             grace_s=float(os.environ.get("CHAOS_GRACE", 0.05)),
             verify_parity=bool(_env_int("CHAOS_VERIFY_PARITY", 1)),
             trace_path=os.environ.get("CHAOS_TRACE") or None,
-            paged=bool(_env_int("CHAOS_PAGED", 0)),
             sync_tokens=_env_int("CHAOS_SYNC_TOKENS", 1),
             speculation=_env_int("CHAOS_SPEC", 0),
             quant=os.environ.get("CHAOS_QUANT", ""),
@@ -1885,7 +1874,6 @@ def main() -> None:
         verify_parity=bool(_env_int("CHAOS_VERIFY_PARITY", 1)),
         mesh=mesh,
         trace_path=os.environ.get("CHAOS_TRACE") or None,
-        paged=bool(_env_int("CHAOS_PAGED", 0)),
         sync_tokens=_env_int("CHAOS_SYNC_TOKENS", 1),
         speculation=_env_int("CHAOS_SPEC", 0),
     )

@@ -5,8 +5,7 @@ Reads the JSONL time-series a `TelemetryExporter` writes (``jsonl_path=``,
 or ``BENCH_SERVE_TELEMETRY=path`` on `benchmarks/bench_serving.py`) and
 renders the latest point as a top(1)-style screen: slot/queue occupancy
 bars, decode rate vs goodput, latency percentiles, speculation accept
-telemetry (when the engine drafts), KV slot-pool and prefix block-pool byte
-accounting, the capacity headroom estimate, and the front-door view
+telemetry (when the engine drafts), KV pool bytes and block occupancy, the capacity headroom estimate, and the front-door view
 (`docs/serving.md` "Front door": open token streams with delivery lag, one
 row per scheduler priority class with queue depth / starvation / predictive
 shed counts, per-SLO-class attainment) — plus a sparkline of the decode rate
@@ -211,15 +210,14 @@ def render(point: dict, history: list[dict] | None = None,
                       f" (saves "
                       f"{_human_bytes(g('serving/quant/weight_saved_bytes', 0))}"
                       f" vs dense)")
-        lines.append(f"kv     slot pool {_human_bytes(pool)}"
+        lines.append(f"kv     pool {_human_bytes(pool)}"
                      + (f" ({by_dtype})" if by_dtype else "") + quant)
     bt = g("serving/mem/block_pool/blocks_total")
     if bt:
         resident = g("serving/mem/block_pool/blocks_resident", 0)
         private = g("serving/mem/block_pool/blocks_private", 0)
-        # paged engines report private (slot-held) blocks too — the bar is
-        # total pool occupancy; a prefix-cache-only pool has private == 0
-        # and renders exactly as before
+        # the bar is total pool occupancy: trie-resident blocks plus the
+        # slots' private ones
         used = resident + private
         priv = f" + {private} private" if private else ""
         lines.append(
