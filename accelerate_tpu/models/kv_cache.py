@@ -464,7 +464,7 @@ def paged_decode_write(
     ``scale_pools`` is ``None`` at full precision; under
     ``kv_cache_dtype=int8`` it is ``(k_scale_pool, v_scale_pool)`` — the fp32
     absmax planes (``[num_blocks, block_tokens, kv_heads]``) the kernel needs
-    to dequantize each block in VMEM scratch, so the pool is never
+    to dequantize each chunk in VMEM, so the pool is never
     materialized at fp32."""
     new_pools, idx, is_init = _paged_pool_step(
         mod, k, v, num_blocks, block_tokens, block_tables, kv_cache_dtype,

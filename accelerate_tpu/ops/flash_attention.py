@@ -739,162 +739,234 @@ def flash_attention(
 
 
 # ------------------------------------------------- paged decode (serving)
-# The fused kernel keeps a row's whole attended K/V span in two fp32 VMEM
-# scratch buffers ``[span, kv_heads * head_dim]``, the pool's own folded row
-# (`models/kv_cache._paged_pool_step`); Mosaic tiles the last two dims (8, 128),
-# so only a merged width that is no multiple of 128 pads. At gpt2-large (1024
-# positions, 20 heads of 64) that is 2 x 5 MiB, at gpt2-medium 2 x 4 MiB; the
-# call asks for what it needs (`vmem_limit_bytes`) and refuses spans that
-# cannot fit the core at all. 128 MiB is the v5e core's VMEM (measured: XLA
-# reports "Used 128.71M of 128.00M" one size past the cap).
+# The fused kernel streams a row's LIVE keys and values through two chunk
+# buffers a pool (`_paged_decode_chunk_blocks` pool blocks each, 128-256
+# tokens) under an online softmax, so its VMEM is a function of the folded row
+# width ``kv_heads * head_dim`` alone: no term grows with ``n_positions``.
+# 128 MiB is the v5e core's VMEM (measured: XLA reports "Used 128.71M of
+# 128.00M" one size past the cap).
 _VMEM_BYTES = 128 << 20
 PAGED_DECODE_VMEM_CAP = _VMEM_BYTES - (16 << 20)  # leave XLA's own share
-_PAGED_DECODE_HEADROOM = 8 << 20  # pipelined input blocks + flush temporaries
+_PAGED_DECODE_HEADROOM = 8 << 20  # scores, probabilities, Mosaic's own temporaries
 
 
-def paged_decode_vmem_bytes(span: int, kv_heads: int, head_dim: int) -> int:
-    """VMEM the fused paged-decode kernel needs for one slot row: the two fp32
-    span buffers, ``ceil8(span) x ceil128(kv_heads * head_dim) x 4`` bytes
-    each, plus fixed headroom."""
-    padded = (-(-span // 8) * 8) * (-(-kv_heads * head_dim // 128) * 128) * 4
-    return 2 * padded + _PAGED_DECODE_HEADROOM
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def check_paged_decode_fits(span: int, kv_heads: int, head_dim: int) -> int:
-    """Raise with the sizes named when the kernel cannot hold ``span``
-    positions of ``kv_heads`` x ``head_dim`` in VMEM; returns the bytes it
-    will ask for. The serving engine calls this at construction, so
+def _paged_decode_chunk_blocks(block_tokens: int, width: int) -> int:
+    """Pool blocks one chunk covers: 256 tokens while a float32 working copy
+    of the chunk (``tokens x width x 4``) stays within 4 MiB (rows to 4,096
+    lanes), else 128 tokens; never under one block."""
+    tokens = 256 if 256 * _ceil_to(width, 128) * 4 <= (4 << 20) else 128
+    return max(1, tokens // block_tokens)
+
+
+def paged_decode_vmem_bytes(
+    kv_heads: int, head_dim: int, *, q_heads: int | None = None,
+    block_tokens: int = 16, itemsize: int = 4,
+) -> int:
+    """VMEM the fused paged-decode kernel asks for. Per pool (keys, values)
+    two chunk buffers ``chunk_tokens x ceil128(kv_heads * head_dim) x
+    itemsize``, one float32 working copy of a chunk each (the upcast of a
+    float32 or int8 pool; a bfloat16 pool feeds the MXU as it is), the folded
+    query and the float32 accumulator ``[q_heads, width]`` twice over (the
+    carry and its update), and fixed headroom. ``itemsize`` is the pool's
+    (4 prices the widest); nothing here depends on the attended span."""
+    lanes = _ceil_to(kv_heads * head_dim, 128)
+    # a pool block is a buffer block: its tokens pad to the dtype's sublane tile
+    tokens = (_paged_decode_chunk_blocks(block_tokens, lanes)
+              * _ceil_to(block_tokens, 32 // itemsize))
+    chunks = 2 * tokens * lanes * (2 * itemsize + 4)
+    heads = _ceil_to(q_heads or kv_heads, 8)
+    return chunks + 4 * heads * lanes * 4 + _PAGED_DECODE_HEADROOM
+
+
+def check_paged_decode_fits(
+    kv_heads: int, head_dim: int, *, q_heads: int | None = None,
+    block_tokens: int = 16, itemsize: int = 4,
+) -> int:
+    """Raise with the sizes named when the kernel cannot hold its chunk
+    buffers for rows of ``kv_heads`` x ``head_dim`` in VMEM; returns the bytes
+    it will ask for. The serving engine calls this at construction, so
     ``paged_attention="fused"`` fails there instead of at first decode."""
-    need = paged_decode_vmem_bytes(span, kv_heads, head_dim)
+    need = paged_decode_vmem_bytes(
+        kv_heads, head_dim, q_heads=q_heads, block_tokens=block_tokens,
+        itemsize=itemsize)
     if need > PAGED_DECODE_VMEM_CAP:
+        tokens = _paged_decode_chunk_blocks(block_tokens, kv_heads * head_dim) * block_tokens
         raise ValueError(
-            f"fused paged decode keeps the whole attended span in VMEM: "
-            f"{span} positions x {kv_heads} kv heads x head_dim {head_dim} "
-            f"needs {need / 2**20:.0f} MiB (two fp32 [span, kv_heads*head_dim] "
-            f"buffers + headroom), over the "
+            f"fused paged decode streams chunks of {tokens} positions of the "
+            f"folded row through VMEM: {kv_heads} kv heads x head_dim "
+            f"{head_dim} = {kv_heads * head_dim} lanes needs "
+            f"{need / 2**20:.0f} MiB (two chunk buffers and a float32 working "
+            f"copy a pool, the accumulators, headroom), over the "
             f"{PAGED_DECODE_VMEM_CAP / 2**20:.0f} MiB this kernel may use of "
-            f"the core's {_VMEM_BYTES / 2**20:.0f} MiB. Shorten n_positions, "
-            "shard heads over the model axis, or use paged_attention='gather'."
+            f"the core's {_VMEM_BYTES / 2**20:.0f} MiB. The attended span is "
+            "no term of it: shard heads over the model axis, or use "
+            "paged_attention='gather'."
         )
     return need
 
 
 def _paged_decode_kernel(
-    tables, lengths, q_ref, k_ref, v_ref, *rest,
-    block_tokens, span, scale, groups, exact,
+    tables, lengths, q_ref, k_hbm, v_hbm, *rest,
+    block_tokens, chunk_blocks, scale, groups, quant,
 ):
-    """One grid cell = (slot row, table block j). The block axis is LAST —
-    sequential on a TensorCore — so the K/V blocks the table names accumulate
-    in VMEM scratch across iterations and the flush at the final block runs
-    the whole single-query attention for ALL heads in one pass: fp32 QK^T,
-    scale after the dot, finfo.min frontier mask, global-max softmax, PV.
-    K/V blocks stream straight from the pool through the scalar-prefetched
-    block table — nothing is materialized in HBM.
+    """One grid cell = one slot row; inside it a loop of ``cdiv(length,
+    chunk)`` turns, each over one CHUNK of ``chunk_blocks`` pool blocks, so a
+    row costs its live blocks and nothing past its frontier is fetched, staged
+    or zero-filled.
 
-    ``exact`` (interpret mode, CPU CI) computes the flush with the head axis
-    BATCHED using the same `dot_general` dimension_numbers the gather
-    oracle's two einsums lower to. XLA's CPU emitter is invariant to the
-    batch extent but NOT to degenerate (size-1) batch dims — a per-head
-    formulation differs by ~1 ulp — so keeping heads batched makes the fused
-    path bit-identical to `dot_product_attention` over the gathered view,
-    which is the parity bar the serving tests hold (docs/serving.md). On TPU
-    the flush unrolls per head into MXU-friendly 2-D dots instead.
+    The pools stay in HBM (``memory_space=pltpu.HBM``). A turn waits for its
+    chunk (one copy a live pool block, block ``tables[row, j]`` into buffer
+    block ``j % chunk_blocks``) after it has started the copies of the next
+    one into the other buffer: the row's next chunk, or the next row's first,
+    so the fetch runs across the row boundary too (``slot_ref`` carries which
+    buffer that was from one grid cell to the next; the grid is sequential).
 
-    K/V blocks and the scratch are ``[block_tokens | span, kv_heads * d]``,
-    head ``h`` in lanes ``h*d:(h+1)*d``: the pool's stored row, so a block is
-    one contiguous DMA and nothing relays the pool in front of the call.
+    All heads go through the MXU at once. The pool's folded row is the
+    operand: ``Q' [heads, kv_heads * d]`` holds head ``h``'s query in the lanes
+    of key/value head ``h // groups`` and zeros elsewhere, so ``Q' x chunk^T``
+    is every head's scores ``[heads, chunk]`` and ``P x chunk`` every head's
+    output, in its own lanes among the others', picked out at the row's end.
+    Scores, the running max and sum, the probabilities and the accumulator are
+    float32; ``scale`` is applied after the dot. Keys and values enter with the
+    values the pool holds: a bfloat16 chunk feeds the MXU as it is (bf16 x bf16
+    products are exact in the float32 accumulation), and the float32
+    probabilities meet it as three bfloat16 pieces whose sum they are, so
+    nothing is rounded that the float32 x float32 product would keep.
 
-    An int8 pool rides two extra refs — the fp32 absmax scale planes
-    (``[1, block_tokens, kv_heads]`` per block) — and each block dequantizes
-    AT STAGING into the fp32 VMEM scratch (value × its head's scale over that
-    head's ``d`` lanes, round-tripped through the compute dtype exactly like
-    the gather oracle's `_dq`), so the quantized pool is never materialized at
-    full precision in HBM and the flush math below is byte-for-byte the same
-    in both modes."""
-    if len(rest) == 5:
-        ks_ref, vs_ref, o_ref, k_scr, v_scr = rest
+    Masking is by select, never by multiply: positions at or past ``length``
+    (the frontier block's tail, and whatever an earlier chunk left in the
+    buffer) have their scores replaced and their value rows selected to zero,
+    so a NaN a retired request wrote cannot reach the output through 0 x NaN.
+
+    An int8 pool rides one more HBM ref: both pools' float32 absmax scales as
+    the caller gathered them by the block table, ``[rows, 2 * kv_heads, span]``
+    with positions on the lanes, a chunk's worth copied beside its payload
+    and stood up ``[chunk, 2 * kv_heads]`` in VMEM. The chunk is dequantised
+    into a staging buffer (value x its head's scale over that head's ``d``
+    lanes, through the compute dtype, exactly the gather oracle's `_dq`) and
+    from there on the arithmetic is the same."""
+    if quant:
+        sc_hbm, o_ref, kbuf, vbuf, scbuf, kstage, vstage, *rest = rest
     else:
-        ks_ref = vs_ref = None
-        o_ref, k_scr, v_scr = rest
-    b_ = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    length = lengths[b_]  # valid kv span for this row (frontier cursor + 1)
-    window = pl.ds(j * block_tokens, block_tokens)
+        sc_hbm = scbuf = kstage = vstage = None
+        o_ref, kbuf, vbuf, *rest = rest
+    sems, slot_ref, qp_ref, acc_ref = rest
+    row = pl.program_id(0)
+    rows = pl.num_programs(0)
     hq, d = q_ref.shape[1], q_ref.shape[2]
-    kvh = k_scr.shape[1] // d
+    width = kbuf.shape[-1]
+    kvh = width // d
+    chunk = chunk_blocks * block_tokens
+    span_blocks = tables.shape[1]
+    f32 = jnp.float32
 
-    def head(h):  # kv head h's lanes of a folded row
-        return slice(h * d, (h + 1) * d)
+    def live_blocks(r):  # pool blocks row r holds
+        return jnp.clip(pl.cdiv(lengths[r], block_tokens), 0, span_blocks)
 
-    @pl.when(j * block_tokens < length)
+    def copies(r, c, slot, wait):
+        """Start (or wait for) the copies of chunk ``c`` of row ``r``."""
+        first = c * chunk_blocks
+        n = jnp.clip(live_blocks(r) - first, 0, chunk_blocks)
+
+        def one(i, carry):
+            blk = tables[r, first + i]
+            for p, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, i], sems.at[p, slot])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+        if quant:  # the chunk's scale columns, gathered by the caller: one copy
+            cp = pltpu.make_async_copy(
+                sc_hbm.at[r, :, pl.ds(pl.multiple_of(c * chunk, chunk), chunk)],
+                scbuf.at[slot], sems.at[2, slot])
+            cp.wait() if wait else cp.start()
+
+    @pl.when(row == 0)
     def _():
-        if ks_ref is None:
-            k_scr[window] = k_ref[0].astype(jnp.float32)  # [bt, kv_heads * d]
-            v_scr[window] = v_ref[0].astype(jnp.float32)
+        slot_ref[0] = 0
+        qp_ref[...] = jnp.zeros_like(qp_ref)  # the off-head lanes stay zero
+        copies(0, 0, 0, wait=False)
+
+    # head h's query into the lanes of its key/value head
+    for g in range(kvh):
+        hs = slice(g * groups, (g + 1) * groups)
+        qp_ref[hs, g * d:(g + 1) * d] = q_ref[0, hs, :].astype(f32)
+
+    length = jnp.minimum(lengths[row], span_blocks * block_tokens)
+    turns = jnp.maximum(pl.cdiv(length, chunk), 1)  # an empty row reads 0, not 0/0
+    slot0 = slot_ref[0]
+    # bf16 x bf16 on the MXU where both sides are bf16, else float32 operands
+    narrow = q_ref.dtype == jnp.bfloat16 and (quant or kbuf.dtype == jnp.bfloat16)
+    qp = qp_ref[...].astype(jnp.bfloat16 if narrow else f32)
+    neg = jnp.finfo(f32).min
+
+    def staged(buf, stage, slot, scales, first):
+        """The chunk ``[chunk, width]`` as the products take it; an int8 chunk
+        through ``stage``, times ``scales[:, first + h]`` over head ``h``."""
+        if quant:
+            for h in range(kvh):
+                lanes = slice(h * d, (h + 1) * d)
+                vals = buf[slot, :, :, lanes].astype(f32).reshape(chunk, d)
+                stage[:, lanes] = (vals * scales[:, first + h:first + h + 1]).astype(q_ref.dtype)
+            x = stage[...]
         else:
-            cdt = q_ref.dtype
-            for src, sc, dst in ((k_ref, ks_ref, k_scr), (v_ref, vs_ref, v_scr)):
-                for h in range(kvh):
-                    dst[window, head(h)] = (
-                        src[0, :, head(h)].astype(jnp.float32) * sc[0, :, h:h + 1]
-                    ).astype(cdt).astype(jnp.float32)
+            x = buf[slot].reshape(chunk, width)
+        return x if narrow else x.astype(f32)
 
-    @pl.when(j * block_tokens >= length)
-    def _():
-        # past-frontier blocks (incl. clamped sentinel table entries): every
-        # position is re-masked at the flush, but the rows must be finite —
-        # a stale NaN would poison the 0-weight products
-        zeros = jnp.zeros((block_tokens,) + k_scr.shape[1:], jnp.float32)
-        k_scr[window] = zeros
-        v_scr[window] = zeros
+    def dot_pv(p, v):
+        if v.dtype != jnp.bfloat16:
+            return jnp.dot(p, v, preferred_element_type=f32)
+        # p = hi + mid + lo exactly (3 x 8 bits); each bf16 product is exact
+        hi = p.astype(jnp.bfloat16)
+        r = p - hi.astype(f32)
+        mid = r.astype(jnp.bfloat16)
+        lo = (r - mid.astype(f32)).astype(jnp.bfloat16)
+        return (jnp.dot(hi, v, preferred_element_type=f32)
+                + jnp.dot(mid, v, preferred_element_type=f32)
+                + jnp.dot(lo, v, preferred_element_type=f32))
 
-    @pl.when(j == nj - 1)
-    def _():
-        neg = jnp.finfo(jnp.float32).min
-        if exact:
-            q4 = q_ref[...].astype(jnp.float32).reshape(1, 1, hq, d)  # [b,q,h,d]
-            k4 = k_scr[...].reshape(1, span, kvh, d)  # [b,k,h,d]
-            v4 = v_scr[...].reshape(1, span, kvh, d)
-            if groups > 1:
-                # attention() repeats kv heads before the xla path; mirror it
-                k4 = jnp.repeat(k4, groups, axis=2)
-                v4 = jnp.repeat(v4, groups, axis=2)
-            s = jax.lax.dot_general(
-                q4, k4, (((3,), (3,)), ((0, 2), (0, 2))),
-                preferred_element_type=jnp.float32,
-            )  # [1, h, 1, span] — einsum "bqhd,bkhd->bhqk"
-            s = s * scale
-            pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, span), 3)
-            s = jnp.where(pos < length, s, neg)
-            m = jnp.max(s, axis=-1, keepdims=True)
-            p = jnp.exp(s - m)
-            w = p / jnp.sum(p, axis=-1, keepdims=True)
-            # einsum "bhqk,bkhd->bqhd" lowers with v as the LHS:
-            # dot_general(v, w, (([1],[3]), ([0,2],[0,1]))) -> [b,h,d,q]
-            o = jax.lax.dot_general(
-                v4, w, (((1,), (3,)), ((0, 2), (0, 1))),
-                preferred_element_type=jnp.float32,
-            )  # [1, h, d, 1]
-            o_ref[0] = jnp.transpose(o, (0, 3, 1, 2)).reshape(hq, d).astype(o_ref.dtype)
-        else:
-            for hh in range(hq):
-                q2 = q_ref[0, hh].astype(jnp.float32).reshape(1, d)
-                k2 = k_scr[:, head(hh // groups)]  # [span, d]
-                s = jax.lax.dot_general(
-                    q2, k2, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # [1, span]
-                pos = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-                s = jnp.where(pos < length, s, neg)
-                m = jnp.max(s, axis=-1, keepdims=True)
-                p = jnp.exp(s - m)
-                w = p / jnp.sum(p, axis=-1, keepdims=True)
-                o = jax.lax.dot_general(
-                    w, v_scr[:, head(hh // groups)], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )  # [1, d]
-                o_ref[0, hh] = o.reshape(d).astype(o_ref.dtype)
+    def turn(c, carry):
+        m, l, acc = carry
+        slot = (slot0 + c) % 2
+        more = c + 1 < turns
+        nr = jnp.where(more, row, row + 1)
+
+        @pl.when(nr < rows)
+        def _():
+            copies(nr, jnp.where(more, c + 1, 0), 1 - slot, wait=False)
+
+        copies(row, c, slot, wait=True)
+        # an int8 pool's scales come as rows [2 * kv_heads, chunk]: stand them up
+        scales = scbuf[slot].T if quant else None
+        k = staged(kbuf, kstage, slot, scales, 0)
+        s = jax.lax.dot_general(
+            qp, k, (((1,), (1,)), ((), ())), preferred_element_type=f32,
+        ) * scale  # [heads, chunk]
+        pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        s = jnp.where(pos < length, s, neg)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        v = staged(vbuf, vstage, slot, scales, kvh)
+        vpos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+        v = jnp.where(vpos < length, v, jnp.zeros_like(v))
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        return m_new, l, alpha * acc + dot_pv(p, v)
+
+    m, l, acc = jax.lax.fori_loop(
+        0, turns, turn,
+        (jnp.full((hq, 1), neg, f32), jnp.zeros((hq, 1), f32), jnp.zeros((hq, width), f32)),
+    )
+    slot_ref[0] = (slot0 + turns) % 2  # where the next row's first chunk went
+    acc_ref[...] = acc / l
+    for g in range(kvh):
+        hs = slice(g * groups, (g + 1) * groups)
+        o_ref[0, hs, :] = acc_ref[hs, g * d:(g + 1) * d].astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -925,25 +997,30 @@ def paged_decode_attention(
     Row ``i`` attends positions ``0..lengths[i]-1`` of its logical sequence;
     position ``p`` lives in pool block ``block_tables[i, p // block_tokens]``
     at offset ``p % block_tokens`` (the paged admission/decode layout).
-    Table entries at or past the pool size (the engine's released-slot
-    sentinel) are clamped to a real block id — every position they could
-    contribute is past the frontier and masked. GQA pools read kv head
-    ``h // (n_heads // kv_heads)`` directly; K/V are never repeated in HBM.
+    **The cost follows the tokens a row holds**: the kernel fetches the
+    ``cdiv(lengths[i], block_tokens)`` live blocks of row ``i`` and no other,
+    a chunk of 128-256 tokens a turn (`_paged_decode_chunk_blocks`, from
+    ``block_tokens`` and the row width), under an online softmax with all
+    heads in one MXU product a chunk (`_paged_decode_kernel`). Table entries
+    at or past the pool size (the engine's released-slot sentinel) are
+    clamped to a real block id; what such a block holds past the frontier is
+    masked by select. GQA pools read kv head ``h // (n_heads // kv_heads)``
+    directly; K/V are never repeated in HBM.
 
-    VMEM cost per slot-row cell is `paged_decode_vmem_bytes` (two fp32
-    ``[span, kv_heads * head_dim]`` buffers: 10.5 MB at gpt2-large's 1024
-    positions) — the attended K/V span lives in fp32 scratch so the flush
-    runs a single global-max softmax, bit-identical to the XLA gather oracle under the interpreter
-    (`docs/serving.md` "Fused paged decode"). A span that cannot fit raises
-    (`check_paged_decode_fits`); an online-softmax variant is what lifts the
-    limit. Returns ``[b, n_heads, head_dim]`` in ``q.dtype``. On CPU
-    (tests/CI) runs under the Pallas interpreter.
+    VMEM is `paged_decode_vmem_bytes`: chunk buffers and accumulators, a
+    function of the row width and no function of the span (5.5 MiB and headroom at
+    gpt2-large's 1,280 bfloat16 lanes, at 1,024 positions or 32,768). A row too wide
+    raises (`check_paged_decode_fits`). Returns ``[b, n_heads, head_dim]`` in
+    ``q.dtype``. One body runs compiled on the chip and under the Pallas
+    interpreter on CPU (tests/CI); it equals the gather oracle to float32
+    rounding (the softmax is accumulated chunk by chunk), not bit for bit
+    (`docs/serving.md` "Fused paged decode").
 
     An int8 pool (`kv_cache_dtype=int8` paged serving) passes its fp32 absmax
     planes as ``k_scale_pool``/``v_scale_pool`` (``[num_blocks, block_tokens,
-    kv_heads]``, addressed through the same block table); each block is
-    dequantized in VMEM scratch at staging time, so the quantized pool is
-    never materialized at full precision."""
+    kv_heads]``, addressed through the same block table); each chunk is
+    dequantized in VMEM, so the quantized pool is never materialized at full
+    precision."""
     b, hq, d = q.shape
     if k_pool.ndim != 3 or v_pool.shape != k_pool.shape:
         raise ValueError(
@@ -965,65 +1042,61 @@ def paged_decode_attention(
             f"scale pool shape {k_scale_pool.shape} != "
             f"{(num_blocks, block_tokens, kvh)} (per-block absmax planes)"
         )
-    groups = hq // kvh
-    bps = block_tables.shape[1]
-    span = bps * block_tokens
     if interpret is None:
         interpret = not _on_tpu()
-    vmem_bytes = check_paged_decode_fits(span, kvh, d)
+    vmem_bytes = check_paged_decode_fits(
+        kvh, d, q_heads=hq, block_tokens=block_tokens, itemsize=k_pool.dtype.itemsize)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    chunk_blocks = min(_paged_decode_chunk_blocks(block_tokens, width), block_tables.shape[1])
+    chunk = chunk_blocks * block_tokens
     # released slots park their whole table at the sentinel id num_blocks;
-    # clamp to a real block (fully frontier-masked) so the index map never
-    # reads out of range
+    # clamp to a real block (past the frontier, masked) so no copy reads out
+    # of range
     tables = jnp.minimum(block_tables.astype(jnp.int32), num_blocks - 1)
     lengths = lengths.astype(jnp.int32)
 
-    in_specs = [
-        pl.BlockSpec((1, hq, d), lambda b_, j, t, l: (b_, 0, 0)),
-        pl.BlockSpec(
-            (1, block_tokens, width),
-            lambda b_, j, t, l: (t[b_, j], 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, block_tokens, width),
-            lambda b_, j, t, l: (t[b_, j], 0, 0),
-        ),
-    ]
+    row_spec = pl.BlockSpec((1, hq, d), lambda r, t, l: (r, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     inputs = [tables, lengths, q, k_pool, v_pool]
+    buffers = [pltpu.VMEM((2, chunk_blocks, block_tokens, width), k_pool.dtype)] * 2
     if quant:
-        # the scale planes page in through the same block-table index map as
-        # their payload blocks, one [block_tokens, kv_heads] plane per cell
-        in_specs += [
-            pl.BlockSpec(
-                (1, block_tokens, kvh),
-                lambda b_, j, t, l: (t[b_, j], 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_tokens, kvh),
-                lambda b_, j, t, l: (t[b_, j], 0, 0),
-            ),
-        ]
-        inputs += [k_scale_pool.astype(jnp.float32),
-                   v_scale_pool.astype(jnp.float32)]
+        # Mosaic copies no HBM slice whose minor dim is short of a lane tile,
+        # so a block's [block_tokens, kv_heads] plane cannot ride beside its
+        # payload. The planes (a 16th of the payload's bytes at head_dim 64)
+        # are gathered here instead, both pools' in one array with positions
+        # on the lanes, [b, 2 * kv_heads, span]: a chunk's scales are then one
+        # aligned copy, stood up in the kernel
+        turns = -(-block_tables.shape[1] // chunk_blocks)
+        planes = jnp.concatenate(
+            [k_scale_pool[tables], v_scale_pool[tables]], axis=-1).astype(jnp.float32)
+        planes = planes.reshape(b, -1, 2 * kvh).transpose(0, 2, 1)
+        planes = jnp.pad(planes, (
+            (0, 0), (0, -2 * kvh % 8), (0, turns * chunk - planes.shape[2])))
+        inputs.append(planes)
+        buffers.append(pltpu.VMEM((2, planes.shape[1], chunk), jnp.float32))
+        buffers += [pltpu.VMEM((chunk, width), q.dtype)] * 2  # dequantised chunk
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, bps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hq, d), lambda b_, j, t, l: (b_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((span, width), jnp.float32),
-            pltpu.VMEM((span, width), jnp.float32),
+        grid=(b,),
+        in_specs=[row_spec] + [in_hbm] * (len(inputs) - 3),
+        out_specs=row_spec,
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((len(inputs) - 3, 2)),
+            pltpu.SMEM((1,), jnp.int32),  # buffer the row's first chunk is in
+            pltpu.VMEM((hq, width), jnp.float32),  # Q'
+            pltpu.VMEM((hq, width), jnp.float32),  # the row's output, all lanes
         ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, block_tokens=block_tokens, span=span, scale=scale,
-        groups=groups, exact=bool(interpret),
+        _paged_decode_kernel, block_tokens=block_tokens, chunk_blocks=chunk_blocks,
+        scale=scale, groups=hq // kvh, quant=quant,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
     )(*inputs)

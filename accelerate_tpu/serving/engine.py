@@ -622,14 +622,14 @@ class ServingEngine:
                 self.mesh, slots=self.max_concurrency
             )
         if self.paged_attention == "fused":
-            # the kernel holds a row's whole attended span in VMEM; a model it
-            # cannot fit fails HERE with the sizes named — never at the first
-            # decode step, and never by quietly serving through "gather"
+            # the kernel streams chunks of the folded row through VMEM; a row
+            # too wide for it fails HERE with the sizes named — never at the
+            # first decode step, and never by quietly serving through "gather"
             from ..ops.flash_attention import check_paged_decode_fits
 
             check_paged_decode_fits(
-                int(cfg.n_positions), contract.kv_heads // self._mesh_model,
-                contract.head_dim,
+                contract.kv_heads // self._mesh_model, contract.head_dim,
+                block_tokens=self._block_tokens,
             )
         # contiguous slot ranges per data replica (the slot dim shards like any
         # leading batch dim: replica i owns rows [i*b/d, (i+1)*b/d)) — 1 when
@@ -877,6 +877,12 @@ class ServingEngine:
         # dispatch for the `serving/sample_tail/*` counters
         self._draw_slots = 0
         self._top_k_slots = 0
+        # keys and values each held slot has in the pool, by the host's view
+        # (prompt + delivered tokens), and their sum: kept at admit, delivery
+        # and release, read at each decode dispatch for
+        # `serving/paged_decode/*`
+        self._slot_kv_tokens = [0] * b
+        self._held_kv_tokens = 0
         self._free: deque[int] = deque(range(b))
         self._inflight: deque[_Inflight] = deque()
         self._next_id = 0
@@ -1555,6 +1561,7 @@ class ServingEngine:
         self._slot_gen[slot] += 1
         self._slot_req[slot] = request
         self._count_sample_tail(sp, 1)
+        self._hold_kv_tokens(slot, plen + m)
         out = RequestOutput(
             request_id=request.request_id, prompt_len=plen,
             tokens=list(rec.tokens), finish_reason="",
@@ -1885,6 +1892,8 @@ class ServingEngine:
         self._step_count += 1
         if n_active:
             self.metrics.observe_sample_tail(self._draw_slots, self._top_k_slots)
+            self.metrics.observe_paged_decode(
+                self._held_kv_tokens, n_active * self.max_len)
             poison = self._poison_mask()
             step_args = (
                 self._cache, self.params, self._d_tokens, self._d_pos,
@@ -2528,6 +2537,7 @@ class ServingEngine:
                      else self.metrics.ttft_miss_s).observe(ttft)
             token = int(tokens[i])
             out.tokens.append(token)
+            self._hold_kv_tokens(slot, 1)
             self.metrics.tokens_generated.inc()
             self._slot_last_token_t[slot] = now
             if self.journal is not None:
@@ -2592,6 +2602,7 @@ class ServingEngine:
                 out = self._slot_out[slot]
                 out.tokens.append(token)
                 out.token_times.append(now)
+                self._hold_kv_tokens(slot, 1)
                 appended += 1
                 self.metrics.tokens_generated.inc()
                 gap = gaps.get(slot, now - self._slot_last_token_t[slot])
@@ -2690,6 +2701,7 @@ class ServingEngine:
                 out = self._slot_out[slot]
                 out.tokens.append(token)
                 out.token_times.append(now)
+                self._hold_kv_tokens(slot, 1)
                 appended += 1
                 self.metrics.tokens_generated.inc()
                 gap = gaps.get(slot, now - self._slot_last_token_t[slot])
@@ -3057,6 +3069,8 @@ class ServingEngine:
             gens.append(int(self._slot_gen[slot]))
             self._slot_req[slot] = request
             self._count_sample_tail(request.params, 1)
+            self._hold_kv_tokens(
+                slot, len(request.prompt) + len(request.resume_tokens))
             self._slot_out[slot] = RequestOutput(
                 request_id=request.request_id, prompt_len=len(request.prompt),
                 # a resumed stream's recovered prefix is part of the output;
@@ -3199,6 +3213,12 @@ class ServingEngine:
             if (params.top_k or 0) > 0:
                 self._top_k_slots += sign
 
+    def _hold_kv_tokens(self, slot: int, n: int) -> None:
+        """``slot`` holds ``n`` more keys and values (its prompt at admit, one
+        a delivered token)."""
+        self._slot_kv_tokens[slot] += n
+        self._held_kv_tokens += n
+
     def _release_slot(self, slot: int) -> None:
         """Return a slot to the free pool. Device state needs no touch-up:
         the slot is frozen by its on-device finished mask (or, for a cancel,
@@ -3224,6 +3244,7 @@ class ServingEngine:
         self._d_tables = self._d_tables.at[slot].set(
             jnp.int32(self._allocator.num_blocks))
         self._count_sample_tail(self._slot_req[slot].params, -1)
+        self._hold_kv_tokens(slot, -self._slot_kv_tokens[slot])
         self._slot_match[slot] = None
         self._slot_hit[slot] = False
         self._slot_itl[slot] = None

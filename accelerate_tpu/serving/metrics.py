@@ -229,6 +229,13 @@ class ServingMetrics:
         self.sample_tail_greedy_steps = Counter()
         self.sample_tail_draw_steps = Counter()
         self.sample_tail_top_k_steps = Counter()
+        # what the fused paged-decode kernel reads against what its table
+        # spans (`ops/flash_attention.paged_decode_attention` fetches a row's
+        # live blocks): at each dispatched decode step the keys and values the
+        # held slots have, by the host's view (prompt + delivered tokens, a
+        # turn or two behind the device), and held slots x n_positions
+        self.paged_decode_live_tokens = Counter()
+        self.paged_decode_span_tokens = Counter()
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -427,6 +434,12 @@ class ServingMetrics:
         else:
             self.sample_tail_greedy_steps.inc()
 
+    def observe_paged_decode(self, live_tokens: int, span_tokens: int) -> None:
+        """One dispatched decode step over rows that hold ``live_tokens`` of
+        the ``span_tokens`` positions their block tables address."""
+        self.paged_decode_live_tokens.inc(live_tokens)
+        self.paged_decode_span_tokens.inc(span_tokens)
+
     def record_compile(self, key: str, seconds: float) -> None:
         """First dispatch of a jitted serving program: one compile, keyed by
         ``kind[pb{prompt_bucket}b{batch_bucket}]@mesh{data}x{model}``."""
@@ -488,6 +501,10 @@ class ServingMetrics:
             "serving/sample_tail/draw_steps": self.sample_tail_draw_steps.value,
             "serving/sample_tail/top_k_steps": (
                 self.sample_tail_top_k_steps.value),
+            "serving/paged_decode/live_tokens": (
+                self.paged_decode_live_tokens.value),
+            "serving/paged_decode/span_tokens": (
+                self.paged_decode_span_tokens.value),
             "serving/streams_opened": self.streams_opened.value,
             "serving/streams_finished": self.streams_finished.value,
             "serving/stream_events": self.stream_events.value,

@@ -49,6 +49,7 @@ class Sizes:
     window: int
     band_block: int
     positions: int
+    gqa: tuple  # (query heads, key/value heads, head_dim, positions) of a grouped-query layer
     nf4_shapes: tuple  # (K, N) of the quantized weights
     train_batch_per_chip: int
     train_seq: int
@@ -66,6 +67,7 @@ class Sizes:
 CHIP = Sizes(
     preset="medium", batch=8, seq=1024, heads=16, head_dim=64, embed=1024,
     vocab=50257, ce_rows=8192, window=256, band_block=512, positions=1024,
+    gqa=(16, 2, 256, 2560),  # Qwen3-Next's attention layer
     nf4_shapes=((1024, 3072), (1024, 4096), (4096, 1024)),
     train_batch_per_chip=8, train_seq=1024, train_steps=6,
     prompt_buckets=(32, 128), prompt_lengths=(5, 31, 12, 24, 120, 77, 50, 97),
@@ -75,6 +77,7 @@ CHIP = Sizes(
 REHEARSAL = Sizes(
     preset="tiny", batch=2, seq=128, heads=2, head_dim=32, embed=64,
     vocab=256, ce_rows=128, window=48, band_block=32, positions=128,
+    gqa=(8, 2, 32, 320),
     nf4_shapes=((256, 256),),
     train_batch_per_chip=2, train_seq=64, train_steps=6,
     prompt_buckets=(16, 64), prompt_lengths=(5, 15, 9, 12, 60, 33, 20, 47),
@@ -233,52 +236,59 @@ def phase_kernels(run: Smoke) -> None:
     compare("fused_ce dwte", dw, rdw, z.grad_tol)
     del hidden, wte, dh, dw, rdh, rdw
 
-    # ---- fused paged decode, full-precision and int8 pools
+    # ---- fused paged decode, full-precision and int8 pools: the preset's own
+    # heads, and the Qwen3-Next layer's (8 query heads a key/value head); rows
+    # hold from one position to the whole span
     bt = 16
-    bps = z.positions // bt
-    blocks = z.batch * bps
-    lengths = np.linspace(1, z.positions, z.batch).astype(np.int32)
-    lengths[1] = bt + 1  # one row just past a block boundary
-    tables = rng.permutation(blocks).astype(np.int32).reshape(z.batch, bps)
-    # entries past a row's frontier may hold the released-slot sentinel
-    for row, n in enumerate(lengths):
-        tables[row, -(-int(n) // bt):] = blocks
-    tables_j, lengths_j = jnp.asarray(tables), jnp.asarray(lengths)
-    qd = rand((z.batch, z.heads, z.head_dim))
-    pool_shape = (blocks, bt, z.heads, z.head_dim)
-    k_pool, v_pool = rand(pool_shape), rand(pool_shape)
-
-    def fold(pool):
-        # the engine's stored layout: heads folded into the last dim
-        return pool.reshape(blocks, bt, z.heads * z.head_dim)
-
-    def paged_ref(q, k_view, v_view):
-        # the gather oracle: pool[table] laid out contiguously, frontier mask
-        gathered = [p[jnp.minimum(tables_j, blocks - 1)].reshape(
-            z.batch, z.positions, z.heads, z.head_dim) for p in (k_view, v_view)]
-        mask = (jnp.arange(z.positions)[None] < lengths_j[:, None])[:, None, None, :]
-        return dot_product_attention(q[:, None], *gathered, mask=mask)[:, 0]
-
-    got = jax.jit(lambda *a: paged_decode_attention(*a, interpret=interpret))(
-        qd, fold(k_pool), fold(v_pool), tables_j, lengths_j)
-    ref = exact(paged_ref, *_f32(qd, k_pool, v_pool))
-    compare(f"paged_decode {z.heads}x{z.head_dim} pos={z.positions} bt={bt}", got, ref, z.tol)
 
     def quantize_pool(pool):
         scale = jnp.abs(pool.astype(jnp.float32)).max(axis=-1) / 127.0
         q8 = jnp.round(pool.astype(jnp.float32) / scale[..., None]).astype(jnp.int8)
         return q8, scale
 
-    (k8, ks), (v8, vs) = quantize_pool(k_pool), quantize_pool(v_pool)
-    got = jax.jit(lambda q, k, v, t, l, ks_, vs_: paged_decode_attention(
-        q, k, v, t, l, k_scale_pool=ks_, v_scale_pool=vs_, interpret=interpret
-    ))(qd, fold(k8), fold(v8), tables_j, lengths_j, ks, vs)
-    # dequantized through the compute dtype, as both serving paths do
-    deq = [(p.astype(jnp.float32) * s[..., None]).astype(dtype).astype(jnp.float32)
-           for p, s in ((k8, ks), (v8, vs))]
-    ref = exact(paged_ref, qd.astype(jnp.float32), *deq)
-    compare("paged_decode int8 pool", got, ref, z.tol)
-    del k_pool, v_pool, k8, v8, deq
+    for heads, kv_heads, head_dim, positions in (
+            (z.heads, z.heads, z.head_dim, z.positions), z.gqa):
+        bps = positions // bt
+        blocks = z.batch * bps
+        lengths = np.linspace(1, positions, z.batch).astype(np.int32)
+        lengths[1] = bt + 1  # one row just past a block boundary
+        tables = rng.permutation(blocks).astype(np.int32).reshape(z.batch, bps)
+        # entries past a row's frontier may hold the released-slot sentinel
+        for row, n in enumerate(lengths):
+            tables[row, -(-int(n) // bt):] = blocks
+        tables_j, lengths_j = jnp.asarray(tables), jnp.asarray(lengths)
+        qd = rand((z.batch, heads, head_dim))
+        pool_shape = (blocks, bt, kv_heads, head_dim)
+        k_pool, v_pool = rand(pool_shape), rand(pool_shape)
+
+        def fold(pool):
+            # the engine's stored layout: heads folded into the last dim
+            return pool.reshape(blocks, bt, kv_heads * head_dim)
+
+        def paged_ref(q, k_view, v_view):
+            # the gather oracle: pool[table] laid out contiguously, frontier mask
+            gathered = [jnp.repeat(p[jnp.minimum(tables_j, blocks - 1)].reshape(
+                z.batch, positions, kv_heads, head_dim), heads // kv_heads, axis=2)
+                for p in (k_view, v_view)]
+            mask = (jnp.arange(positions)[None] < lengths_j[:, None])[:, None, None, :]
+            return dot_product_attention(q[:, None], *gathered, mask=mask)[:, 0]
+
+        shape = f"{heads}:{kv_heads}x{head_dim} pos={positions} bt={bt}"
+        got = jax.jit(lambda *a: paged_decode_attention(*a, interpret=interpret))(
+            qd, fold(k_pool), fold(v_pool), tables_j, lengths_j)
+        ref = exact(paged_ref, *_f32(qd, k_pool, v_pool))
+        compare(f"paged_decode {shape}", got, ref, z.tol)
+
+        (k8, ks), (v8, vs) = quantize_pool(k_pool), quantize_pool(v_pool)
+        got = jax.jit(lambda q, k, v, t, l, ks_, vs_: paged_decode_attention(
+            q, k, v, t, l, k_scale_pool=ks_, v_scale_pool=vs_, interpret=interpret
+        ))(qd, fold(k8), fold(v8), tables_j, lengths_j, ks, vs)
+        # dequantized through the compute dtype, as both serving paths do
+        deq = [(p.astype(jnp.float32) * s[..., None]).astype(dtype).astype(jnp.float32)
+               for p, s in ((k8, ks), (v8, vs))]
+        ref = exact(paged_ref, qd.astype(jnp.float32), *deq)
+        compare(f"paged_decode int8 pool {shape}", got, ref, z.tol)
+        del k_pool, v_pool, k8, v8, deq
 
     # ---- nf4 dequant-matmul (concrete payload: the only way it runs)
     for kdim, ndim in z.nf4_shapes:

@@ -11,6 +11,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from accelerate_tpu.utils import environment
@@ -150,40 +151,77 @@ def test_notebook_launcher_refuses_to_share_tpu_chips(monkeypatch):
 
 
 # ------------------------------------------- fused paged decode: fit, or refuse
-@pytest.mark.parametrize("span, heads, head_dim, buffers", [
-    (1024, 16, 64, 8 << 20),  # gpt2-medium: two 4 MiB [1024, 1024] fp32 buffers
-    (1024, 20, 64, 10 << 20),  # gpt2-large: 1280 lanes, no padding (was 25 MB)
-    (1024, 12, 64, 6 << 20),  # gpt2-small
-    (128, 2, 32, 2 * 128 * 128 * 4),  # tiny: 64 merged lanes pad to one 128 tile
-    (8192, 16, 64, 64 << 20),  # refused before the pool folded its heads
+@pytest.mark.parametrize("kv_heads, head_dim, q_heads, itemsize, chunk_tokens, buffers", [
+    (16, 64, 16, 2, 256, 4456448),  # gpt2-medium, bf16 pool: 4 x 512 KiB of chunk buffers
+    (20, 64, 20, 2, 256, 5734400),  # gpt2-large: 1,280 lanes (two fp32 span buffers were 10 MiB)
+    (12, 64, 12, 2, 256, 3342336),  # gpt2-small
+    (2, 32, 2, 4, 256, 802816),  # tiny, float32: 64 merged lanes pad to one 128 tile
+    (2, 256, 16, 2, 256, 2228224),  # the Qwen3-Next layer: 16 query heads on 2 of 256
+    (20, 64, 20, 1, 256, 8355840),  # gpt2-large, int8 pool: 16-token blocks pad to 32 sublanes
+    (64, 128, 64, 2, 128, 25165824),  # 8,192 lanes: past 4,096 a chunk is 128 tokens
 ])
-def test_fused_kernel_vmem_model(span, heads, head_dim, buffers):
-    """Two fp32 ``[span, ceil128(kv_heads * head_dim)]`` buffers plus headroom."""
+def test_fused_kernel_vmem_model(kv_heads, head_dim, q_heads, itemsize, chunk_tokens, buffers):
+    """Two chunk buffers and one float32 working copy a pool, the folded query
+    and the accumulators, plus headroom: a function of the row's width."""
     from accelerate_tpu.ops.flash_attention import (
+        _PAGED_DECODE_HEADROOM,
         PAGED_DECODE_VMEM_CAP,
+        _paged_decode_chunk_blocks,
         check_paged_decode_fits,
         paged_decode_vmem_bytes,
     )
 
-    headroom = paged_decode_vmem_bytes(0, heads, head_dim)
-    assert paged_decode_vmem_bytes(span, heads, head_dim) - headroom == buffers
-    assert check_paged_decode_fits(span, heads, head_dim) == buffers + headroom
-    assert buffers + headroom <= PAGED_DECODE_VMEM_CAP
+    kw = dict(q_heads=q_heads, itemsize=itemsize)
+    assert _paged_decode_chunk_blocks(16, kv_heads * head_dim) * 16 == chunk_tokens
+    assert paged_decode_vmem_bytes(kv_heads, head_dim, **kw) - _PAGED_DECODE_HEADROOM == buffers
+    assert check_paged_decode_fits(kv_heads, head_dim, **kw) == buffers + _PAGED_DECODE_HEADROOM
+    assert buffers + _PAGED_DECODE_HEADROOM <= PAGED_DECODE_VMEM_CAP
 
 
-def test_fused_kernel_refuses_the_first_span_that_cannot_fit():
-    """gpt2-medium heads: 13,312 positions fill the cap to the byte, the next
-    block of 16 is refused, and so is the next power of two."""
+@pytest.mark.parametrize("positions", [1024, 8192, 32768])
+def test_fused_kernel_asks_the_same_vmem_at_every_span(positions, monkeypatch):
+    """16 heads of 64 over 1,024, 8,192 and 32,768 positions (the span-wide
+    buffers of the kernel before refused 13,328): the call's scratch shapes
+    and its ``vmem_limit_bytes`` do not see the block table's width."""
+    from accelerate_tpu.ops import flash_attention as fa
+
+    asked = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, *, grid_spec, compiler_params, **kw):
+        asked.append(([(s.shape, s.dtype) for s in grid_spec.scratch_shapes
+                       if hasattr(s, "shape")], compiler_params.vmem_limit_bytes))
+        return real(kernel, grid_spec=grid_spec, compiler_params=compiler_params, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    rows, heads, d, bt = 2, 16, 64, 16
+
+    def lower(positions):
+        sds = jax.ShapeDtypeStruct
+        pool = sds((2 * positions // bt, bt, heads * d), jnp.bfloat16)
+        jax.jit(lambda *a: fa.paged_decode_attention(*a)).lower(
+            sds((rows, heads, d), jnp.bfloat16), pool, pool,
+            sds((rows, positions // bt), jnp.int32), sds((rows,), jnp.int32))
+
+    lower(1024)
+    lower(positions)
+    assert asked[0] == asked[1]
+    assert asked[1][1] == fa.paged_decode_vmem_bytes(heads, d, itemsize=2)
+
+
+def test_fused_kernel_refuses_the_first_width_that_cannot_fit():
+    """Heads of 128, float32 pool: 152 of them (19,456 lanes) fit under the
+    cap, the 153rd is refused with the sizes named, whatever the span."""
     from accelerate_tpu.ops.flash_attention import (
         PAGED_DECODE_VMEM_CAP,
         check_paged_decode_fits,
     )
 
-    assert check_paged_decode_fits(13312, 16, 64) == PAGED_DECODE_VMEM_CAP
-    with pytest.raises(ValueError, match=r"13328 positions x 16 kv heads x head_dim 64"):
-        check_paged_decode_fits(13328, 16, 64)
-    with pytest.raises(ValueError, match=r"needs 136 MiB \(two fp32 \[span, kv_heads\*head_dim\]"):
-        check_paged_decode_fits(16384, 16, 64)
+    assert check_paged_decode_fits(152, 128) <= PAGED_DECODE_VMEM_CAP
+    with pytest.raises(ValueError, match=r"153 kv heads x head_dim 128 = 19584 lanes needs 113 MiB"):
+        check_paged_decode_fits(153, 128)
+    with pytest.raises(ValueError, match=r"chunks of 128 positions.*The attended span is no term of it"):
+        check_paged_decode_fits(256, 128)
 
 
 def test_engine_refuses_a_fused_kernel_that_cannot_fit():
@@ -193,7 +231,25 @@ def test_engine_refuses_a_fused_kernel_that_cannot_fit():
     from accelerate_tpu.serving import ServingEngine
 
     module = GPT2LMHead(GPT2Config(
-        vocab_size=64, n_positions=16384, n_embd=1024, n_layer=1, n_head=16))
+        vocab_size=64, n_positions=64, n_embd=160 * 128, n_layer=1, n_head=160))
     params = jax.eval_shape(lambda: module.init_params(jax.random.key(0)))
-    with pytest.raises(ValueError, match="needs 136 MiB.*paged_attention='gather'"):
+    with pytest.raises(ValueError, match="20480 lanes needs 1.. MiB.*paged_attention='gather'"):
         ServingEngine(module, params, max_concurrency=2, paged_kv=True, paged_attention="fused")
+
+
+def test_engine_takes_a_span_the_span_wide_kernel_refused():
+    """16 heads of 64 over 16,384 positions asked the kernel before for 136
+    MiB of VMEM and failed at construction; the span is no term now."""
+    from accelerate_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+    from accelerate_tpu.serving import PagedKVConfig, ServingEngine
+
+    module = GPT2LMHead(GPT2Config(
+        vocab_size=64, n_positions=16384, n_embd=1024, n_layer=1, n_head=16,
+        dtype=jnp.bfloat16))
+    params = module.init_params(jax.random.key(0))
+    engine = ServingEngine(module, params, max_concurrency=2, prompt_buckets=(16,),
+                           paged_kv=PagedKVConfig(num_blocks=1024),
+                           paged_attention="fused")
+    assert engine.paged_attention == "fused" and engine._blocks_per_slot == 1024
+
+

@@ -266,51 +266,209 @@ def test_paged_parity_with_a_merged_width_that_tiles_nothing():
     assert kv_shapes == {(4 * 128 // BT, BT, 48)}
 
 
+CHUNK = 256  # tokens a turn of the kernel covers at these widths (`_paged_decode_chunk_blocks`)
+SPAN_BLOCKS = 2 * CHUNK // BT + 8  # two chunks and half of a third
+SPAN = SPAN_BLOCKS * BT
+
+
+def _kernel_case(heads, kv_heads, head_dim, quant, lengths, dtype=jnp.float32,
+                 released=(), poison=None, seed=None, interpret=None):
+    """`paged_decode_attention` on a ``[num_blocks, block_tokens, kv_heads *
+    head_dim]`` pool, and XLA attention in float32 over the gathered,
+    unfolded view (what `paged_decode_update` hands the gather path).
+
+    Row ``i`` holds ``lengths[i]`` positions in distinct blocks; its table
+    entries past them, and every entry of the rows in ``released``, are the
+    sentinel id the engine parks a released slot at. With ``poison`` every
+    block no row holds, and the frontier block's tail past each length, reads
+    that value (in the scale planes of an int8 pool): the oracle is still
+    handed the clean pool."""
+    from accelerate_tpu.models.kv_cache import _dq, _q
+    from accelerate_tpu.ops.attention import attention
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    rows = len(lengths)
+    live = [0 if i in released else -(-n // BT) for i, n in enumerate(lengths)]
+    num_blocks = sum(live) + 3  # three blocks no row holds, the last among them
+    rng = np.random.default_rng(
+        heads * 100 + head_dim if seed is None else seed)
+    q = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+    k4, v4 = (jnp.asarray(rng.normal(size=(num_blocks, BT, kv_heads, head_dim)), dtype)
+              for _ in range(2))
+    ids = iter(rng.permutation(num_blocks - 1))  # never the clamped sentinel's block
+    tables = np.full((rows, SPAN_BLOCKS), num_blocks, np.int32)
+    for i, n in enumerate(live):
+        tables[i, :n] = [next(ids) for _ in range(n)]
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+    def fold(x):
+        return x.reshape(num_blocks, BT, -1)
+
+    def view(pool, *tail):
+        return pool[jnp.minimum(tables, num_blocks - 1)].reshape((rows, SPAN) + tail)
+
+    def poisoned(x):
+        """``x [num_blocks, BT, ...]`` with ``poison`` wherever no row's live
+        positions are."""
+        held = np.zeros((num_blocks, BT), bool)
+        for i, n in enumerate(np.asarray(lengths)):
+            if i not in released:
+                held[np.asarray(tables)[i, : -(-n // BT)]] = True
+                held[np.asarray(tables)[i, (n - 1) // BT], (n - 1) % BT + 1:] = False
+        keep = jnp.asarray(held).reshape((num_blocks, BT) + (1,) * (x.ndim - 2))
+        return jnp.where(keep, x, jnp.asarray(poison, x.dtype))
+
+    if quant:
+        (kq, ks), (vq, vs) = _q(k4), _q(v4)
+        ks_in, vs_in = (ks, vs) if poison is None else (poisoned(ks), poisoned(vs))
+        got = paged_decode_attention(q, fold(kq), fold(vq), tables, lengths,
+                                     k_scale_pool=ks_in, v_scale_pool=vs_in,
+                                     interpret=interpret)
+        k_all = _dq(view(kq, kv_heads, head_dim), view(ks, kv_heads), dtype)
+        v_all = _dq(view(vq, kv_heads, head_dim), view(vs, kv_heads), dtype)
+    else:
+        k_in, v_in = (k4, v4) if poison is None else (poisoned(k4), poisoned(v4))
+        got = paged_decode_attention(q, fold(k_in), fold(v_in), tables, lengths,
+                                     interpret=interpret)
+        k_all, v_all = view(k4, kv_heads, head_dim), view(v4, kv_heads, head_dim)
+    mask = (jnp.arange(SPAN)[None, :] < lengths[:, None])[:, None, None, :]
+    f32 = jnp.float32
+    want = attention(q[:, None].astype(f32), k_all.astype(f32), v_all.astype(f32),
+                     causal=False, mask=mask, implementation="xla")[:, 0]
+    return np.asarray(got, np.float32), np.asarray(want)
+
+
+# float32 rounding, not the same bits: the kernel accumulates the softmax chunk
+# by chunk (a running max) where the oracle takes one global max; over 640
+# positions of unit-normal keys the two part by a few last places of 1.0
+KERNEL_ATOL = 3e-6
+
+# lengths of one call: the full span, mid-block, block-exact, a single
+# position, one short of a block, one past a chunk boundary, chunk-exact,
+# and a released row (all-sentinel table, a stale length)
+KERNEL_LENGTHS = (SPAN, 21, 2 * BT, 1, BT - 1, CHUNK + 1, CHUNK, 37)
+
+
 @pytest.mark.parametrize("heads, kv_heads, head_dim, quant", [
     (2, 2, 32, False),  # the tiny config: 64 merged lanes, half a tile
     (4, 2, 32, False),  # GQA, groups of 2
     (6, 2, 16, True),  # GQA, groups of 3, int8 pool: per-head scale lanes
     (8, 8, 16, False),  # 128 merged lanes: exactly one tile
     (3, 3, 16, True),  # odd head count, 48 lanes, int8
+    (16, 2, 256, False),  # the Qwen3-Next attention layer: 8 queries a key/value head
+    (20, 20, 64, False),  # gpt2-large: 1,280 lanes
 ])
-def test_fused_kernel_reads_the_folded_pool_bit_for_bit(heads, kv_heads, head_dim, quant):
-    """`paged_decode_attention` on a ``[num_blocks, block_tokens, kv_heads *
-    head_dim]`` pool against XLA attention over the gathered, unfolded view
-    (what `paged_decode_update` hands the gather path): the same bits."""
-    from accelerate_tpu.models.kv_cache import _dq, _q
-    from accelerate_tpu.ops.attention import attention
-    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+def test_fused_kernel_reads_the_folded_pool_to_float32_rounding(heads, kv_heads, head_dim, quant):
+    """One body on the chip and here (under the Pallas interpreter): it equals
+    the gather oracle to float32 rounding at every kind of length a row can
+    have, for a released row too, at MHA and GQA head shapes."""
+    got, want = _kernel_case(heads, kv_heads, head_dim, quant, KERNEL_LENGTHS, released=(7,))
+    np.testing.assert_allclose(got, want, rtol=0, atol=KERNEL_ATOL)
 
-    rows, bps, num_blocks = 3, 4, 16
-    span = bps * BT
-    rng = np.random.default_rng(heads * 100 + head_dim)
-    dtype = jnp.float32  # the parity bar is the engine tests': float32 compute
-    q = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
-    k4, v4 = (jnp.asarray(rng.normal(size=(num_blocks, BT, kv_heads, head_dim)), dtype)
-              for _ in range(2))
-    tables = jnp.asarray(rng.permutation(num_blocks)[: rows * bps].reshape(rows, bps), jnp.int32)
-    tables = tables.at[2, 2:].set(num_blocks)  # a released tail: the clamped sentinel
-    lengths = jnp.asarray([span, 21, 32], jnp.int32)  # full, mid-block, block-exact
 
-    def fold(x):
-        return x.reshape(num_blocks, BT, kv_heads * head_dim)
+@pytest.mark.parametrize("dtype, quant, atol", [
+    (jnp.float32, False, KERNEL_ATOL),
+    (jnp.float32, True, KERNEL_ATOL),
+    (jnp.bfloat16, False, 2e-2),  # the output is rounded to bfloat16 (8 bits)
+    (jnp.bfloat16, True, 2e-2),
+])
+def test_fused_kernel_hands_chunk_buffers_from_row_to_row(dtype, quant, atol):
+    """More rows than the two chunk buffers: a row's first chunk is fetched
+    while the row before it is reduced, into whichever buffer that row's last
+    turn left free, so rows of one, two and three turns in every order must
+    each read their own keys and values. bfloat16 pools take the MXU path the
+    cells run (bf16 x bf16 scores, probabilities as three bf16 pieces)."""
+    lengths = (5, SPAN, 300, 17, CHUNK, CHUNK + 40, SPAN - 1, 2, 2 * CHUNK, 100, 2 * CHUNK + 1)
+    got, want = _kernel_case(4, 2, 32, quant, lengths, dtype=dtype, seed=5)
+    np.testing.assert_allclose(got, want, rtol=0, atol=atol)
 
-    def view(pool, *tail):
-        return pool[jnp.minimum(tables, num_blocks - 1)].reshape((rows, span) + tail)
 
-    if quant:
-        (kq, ks), (vq, vs) = _q(k4), _q(v4)
-        got = paged_decode_attention(q, fold(kq), fold(vq), tables, lengths,
-                                     k_scale_pool=ks, v_scale_pool=vs)
-        k_all = _dq(view(kq, kv_heads, head_dim), view(ks, kv_heads), dtype)
-        v_all = _dq(view(vq, kv_heads, head_dim), view(vs, kv_heads), dtype)
-    else:
-        got = paged_decode_attention(q, fold(k4), fold(v4), tables, lengths)
-        k_all, v_all = view(k4, kv_heads, head_dim), view(v4, kv_heads, head_dim)
-    mask = (jnp.arange(span)[None, :] < lengths[:, None])[:, None, None, :]
-    want = attention(q[:, None], k_all, v_all, causal=False, mask=mask,
-                     implementation="xla")[:, 0]
-    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_fused_kernel_waits_for_every_copy_it_reads(quant):
+    """The same body under JAX's TPU interpreter, which models the manual
+    copies and their semaphores: a copy lands only when the kernel waits for it
+    (``on_wait``), buffers start as NaN, and a happens-before detector watches
+    every buffer. The double-buffered fetch across turns and rows reads nothing
+    early, and gives the bits the plain interpreter gives."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+    from jax.experimental.pallas import tpu as pltpu
+
+    lengths = (5, SPAN, 300, CHUNK, CHUNK + 1, 2)
+    plain, want = _kernel_case(4, 2, 32, quant, lengths, seed=9)
+    try:
+        got, _ = _kernel_case(4, 2, 32, quant, lengths, seed=9, interpret=pltpu.InterpretParams(
+            detect_races=True, dma_execution_mode="on_wait"))
+        assert not tpu_interpreter.races.races_found
+    finally:
+        pltpu.reset_tpu_interpret_mode_state()
+    np.testing.assert_array_equal(got, plain)
+    np.testing.assert_allclose(got, want, rtol=0, atol=KERNEL_ATOL)
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_fused_kernel_masks_by_select(poison, quant):
+    """S11. A retired request leaves what it wrote: blocks no row holds (a
+    released row's clamped sentinel points at one) and the frontier block's
+    tail past ``length`` read NaN or inf here. The scores there are replaced
+    and the value rows selected to zero, never multiplied by a zero
+    probability, so the output is finite and the same bits as from a clean
+    pool. (An int8 pool's payload cannot hold either; its scale planes do.)"""
+    clean, want = _kernel_case(4, 2, 32, quant, KERNEL_LENGTHS, released=(7,))
+    got, _ = _kernel_case(4, 2, 32, quant, KERNEL_LENGTHS, released=(7,), poison=poison)
+    # the released row reads the clamped sentinel's block as its stale length's
+    # live positions, poison and all: nobody reads its output
+    got, clean, want = got[:7], clean[:7], want[:7]
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    np.testing.assert_allclose(got, want, rtol=0, atol=KERNEL_ATOL)
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+def test_engine_serves_on_over_what_retired_requests_left(model, poison):
+    """S11 at the engine. A first wave retires and every block is free; all of
+    them then read NaN or inf, as after a request whose state went non-finite.
+    The second wave is admitted into those blocks: its prompts overwrite what
+    they cover, and the frontier blocks' tails and the blocks reserved ahead
+    of the cursor keep the poison under the fused kernel's mask. Every request
+    still ends ``length`` with the tokens of a solo run."""
+    module, params = model
+    second = _prompts(22, (7, 30, 12, 18))
+    refs = [_solo(module, params, p, 12, seed=i) for i, p in enumerate(second)]
+    engine = ServingEngine(module, params, max_concurrency=4, prompt_buckets=(16, 64),
+                           admit_batch=2, paged_attention="fused")
+    engine.run(_requests(_prompts(21, (5, 23, 40, 9))))
+    assert engine._allocator.owned_count == 0
+
+    def left_behind(path, leaf):
+        if path[-1].key in ("cached_key", "cached_value"):
+            return jnp.full_like(leaf, poison)
+        return leaf
+
+    engine._cache = jax.tree_util.tree_map_with_path(left_behind, engine._cache)
+    outs = engine.run(_requests(second))
+    assert [o.finish_reason for o in outs] == [FINISH_LENGTH] * 4
+    assert [o.tokens for o in outs] == refs
+
+
+@pytest.mark.parametrize("lengths", [(9,), (9, 20, 5)])
+def test_paged_decode_counters_follow_the_tokens_held(model, lengths):
+    """`serving/paged_decode/live_tokens` adds, at each decode dispatch, the
+    keys and values the held rows have (a row of prompt ``p`` that has been
+    delivered ``i`` tokens attends ``p + i`` positions in its next step);
+    ``span_tokens`` adds ``n_positions`` a held row. Unpipelined, the host's
+    view is the device's: the sums are exact, and release returns every
+    token."""
+    module, params = model
+    n_new, n_positions = 12, module.config.n_positions
+    engine = ServingEngine(module, params, max_concurrency=4, prompt_buckets=(16, 64),
+                           pipeline_depth=1, admit_batch=4)
+    engine.run(_requests(_prompts(3, lengths), n_new=n_new))
+    snap = engine.metrics.snapshot()
+    assert snap["serving/paged_decode/live_tokens"] == sum(
+        p + i for p in lengths for i in range(1, n_new))
+    assert snap["serving/paged_decode/span_tokens"] == len(lengths) * (n_new - 1) * n_positions
+    assert engine._held_kv_tokens == 0 and not any(engine._slot_kv_tokens)
 
 
 def test_fused_kernel_refuses_an_unfolded_pool():

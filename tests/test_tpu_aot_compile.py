@@ -57,19 +57,19 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _paged_args(heads, sharding_for, quant):
-    bps = S // BLOCK_TOKENS
-    blocks = B * bps
+def _paged_args(heads, sharding_for, quant, *, kv_heads=None, head_dim=D, rows=B, positions=S):
+    kv_heads = kv_heads or heads
+    bps = positions // BLOCK_TOKENS
+    blocks = rows * bps
     pool_dtype = jnp.int8 if quant else jnp.bfloat16
+    pool = _sds((blocks, BLOCK_TOKENS, kv_heads * head_dim), pool_dtype, sharding_for("pool"))
     args = [
-        _sds((B, heads, D), jnp.bfloat16, sharding_for("q")),
-        _sds((blocks, BLOCK_TOKENS, heads * D), pool_dtype, sharding_for("pool")),
-        _sds((blocks, BLOCK_TOKENS, heads * D), pool_dtype, sharding_for("pool")),
-        _sds((B, bps), jnp.int32, sharding_for("tables")),
-        _sds((B,), jnp.int32, sharding_for("lengths")),
+        _sds((rows, heads, head_dim), jnp.bfloat16, sharding_for("q")), pool, pool,
+        _sds((rows, bps), jnp.int32, sharding_for("tables")),
+        _sds((rows,), jnp.int32, sharding_for("lengths")),
     ]
     if quant:
-        args += [_sds((blocks, BLOCK_TOKENS, heads), jnp.float32, sharding_for("scale"))] * 2
+        args += [_sds((blocks, BLOCK_TOKENS, kv_heads), jnp.float32, sharding_for("scale"))] * 2
     return args
 
 
@@ -102,12 +102,21 @@ def test_fused_ce_fwd_bwd_one_device(topology):
     )
 
 
-@slow
-@pytest.mark.parametrize("heads", [12, 16, 20])  # small, medium, large
+@pytest.mark.parametrize("shape", [
+    *(pytest.param(dict(heads=heads), id=name, marks=slow)
+      for name, heads in (("small", 12), ("medium", 16), ("large", 20))),
+    # not slow (the body compiles in a second or two): the Qwen3-Next cell's
+    # layer, 16 query heads on 2 key/value heads of 256 over 128 rows of 160
+    # table blocks, and a span the span-wide kernel refused at construction
+    pytest.param(dict(heads=16, kv_heads=2, head_dim=256, rows=128, positions=2560),
+                 id="qwen3-next"),
+    pytest.param(dict(heads=16, rows=8, positions=16384), id="16384-positions"),
+])
 @pytest.mark.parametrize("quant", [False, True])
-def test_paged_decode_one_device(topology, heads, quant):
-    """The two fp32 span buffers (8-10 MiB at these widths) plus headroom pass
-    the default 16 MiB scoped-VMEM limit; the call must ask for what it needs."""
+def test_paged_decode_one_device(topology, shape, quant):
+    """The call asks for the VMEM its chunk buffers need and Mosaic takes the
+    body: copies from the HBM pools by block, the folded query's lane-offset
+    stores, bf16 products (an int8 pool's chunk dequantised in VMEM first)."""
     from accelerate_tpu.ops.flash_attention import paged_decode_attention
 
     s = _one_device(topology)
@@ -118,7 +127,9 @@ def test_paged_decode_one_device(topology, heads, quant):
             q, k, v, tables, lengths, k_scale_pool=k_sp, v_scale_pool=v_sp, interpret=False
         )
 
-    _compile(decode, *_paged_args(heads, lambda _: s, quant))
+    shape = dict(shape)
+    compiled = _compile(decode, *_paged_args(shape.pop("heads"), lambda _: s, quant, **shape))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 @slow
