@@ -42,13 +42,48 @@ class CacheContract:
     ``step_counters`` names int32 scalars the module sows into a ``counters``
     collection (summed over layers); the decode step returns them beside its
     tokens and `ServingMetrics` accumulates them. ``param_rules`` gives the
-    parameter sharding rules for a serving mesh."""
+    parameter sharding rules for a serving mesh.
+
+    ``value_dim`` set says that a layer's cache is ONE latent leaf
+    (``cached_latent``, rows of ``head_dim`` lanes shared by all query heads:
+    ``kv_heads`` is 1) whose leading ``value_dim`` lanes are the value: there
+    is no value pool, and the fused kernel reads the value out of the key
+    chunk it already holds. ``None`` is a value pool of its own."""
 
     kv_heads: int
     head_dim: int
     state_leaves: tuple[str, ...] = ()
     step_counters: tuple[str, ...] = ()
     param_rules: Callable[[], Any] | None = None
+    value_dim: int | None = None
+
+
+LATENT_LEAF = "cached_latent"
+
+
+def _kv_leaf_names(v) -> tuple[str, ...]:
+    """A layer's payload leaves: keys and values, or (``v is None``) the one
+    latent leaf."""
+    return (LATENT_LEAF,) if v is None else ("cached_key", "cached_value")
+
+
+def _check_cache_dtype(kv_cache_dtype, latent: bool) -> bool:
+    """True for an int8 store; any other dtype, and int8 for a latent leaf,
+    fails fast with the cause named (an arbitrary dtype would surface as an
+    obscure lax dtype-mismatch deep in the cache update)."""
+    if kv_cache_dtype is None:
+        return False
+    if np.dtype(kv_cache_dtype) != np.dtype("int8"):
+        raise ValueError(
+            f"kv_cache_dtype supports None (compute dtype) or int8, got {kv_cache_dtype}"
+        )
+    if latent:
+        raise ValueError(
+            "kv_cache_dtype=int8 is not supported for a latent cache leaf: its row "
+            "is shared by all heads and one absmax scale a row would quantise the "
+            "compressed keys and the rotary lanes together"
+        )
+    return True
 
 
 def _q(x):
@@ -70,7 +105,7 @@ def _dq(q, scale, dtype):
 def decode_cache_update(
     mod: Any,  # the flax module (self) owning the "cache" collection
     k: jax.Array,  # [b, s, kv_heads, head_dim] new keys
-    v: jax.Array,
+    v: jax.Array | None,  # None: k is a latent row, the one leaf of the layer
     max_len: int,
     kv_cache_dtype: Any = None,  # None = store at k.dtype; int8 = quantized
     per_slot: bool = False,  # [b]-vector write index (continuous batching)
@@ -105,13 +140,11 @@ def decode_cache_update(
     constraints — heads on the ``model`` axis, slots optionally on ``data`` —
     so XLA's propagation cannot drift the donated pool cache's layout between
     steps. ``None`` (the default, and all of training) changes nothing.
+
+    ``v=None`` keeps ONE leaf, ``cached_latent`` (`CacheContract.value_dim`):
+    ``k`` is then the latent row and ``v_all`` comes back ``None``.
     """
-    if kv_cache_dtype is not None and np.dtype(kv_cache_dtype) != np.dtype("int8"):
-        # fail fast with the cause named — an arbitrary dtype would surface as
-        # an obscure lax dtype-mismatch deep in the cache update
-        raise ValueError(
-            f"kv_cache_dtype supports None (compute dtype) or int8, got {kv_cache_dtype}"
-        )
+    quant = _check_cache_dtype(kv_cache_dtype, v is None)
     if write_mask is not None and not per_slot:
         raise ValueError(
             "write_mask requires per_slot=True (the scalar-index cache has no "
@@ -122,14 +155,14 @@ def decode_cache_update(
             "write_len requires per_slot=True (per-row segment clamping is a "
             "per-slot decode concept)"
         )
-    quant = kv_cache_dtype is not None
     b, s, kv_heads, head_dim = k.shape
     store_dtype = jnp.int8 if quant else k.dtype
-    is_init = mod.has_variable("cache", "cached_key")
-    cached_k = mod.variable("cache", "cached_key", jnp.zeros,
-                            (b, max_len, kv_heads, head_dim), store_dtype)
-    cached_v = mod.variable("cache", "cached_value", jnp.zeros,
-                            (b, max_len, kv_heads, head_dim), store_dtype)
+    names = _kv_leaf_names(v)
+    news = (k,) if v is None else (k, v)
+    is_init = mod.has_variable("cache", names[0])
+    cached = [mod.variable("cache", name, jnp.zeros,
+                           (b, max_len, kv_heads, head_dim), store_dtype) for name in names]
+    cached_k, cached_v = cached[0], cached[-1]
     if quant:
         k_scale = mod.variable("cache", "key_scale", jnp.zeros,
                                (b, max_len, kv_heads), jnp.float32)
@@ -202,11 +235,11 @@ def decode_cache_update(
             k_all = _dq(cached_k.value, k_scale.value, k.dtype)
             v_all = _dq(cached_v.value, v_scale.value, v.dtype)
         else:
-            cached_k.value = row4(cached_k.value, k, idx)
-            cached_v.value = row4(cached_v.value, v, idx)
+            for var, new in zip(cached, news):
+                var.value = row4(var.value, new, idx)
             if sharding is not None:
-                cached_k.value = jax.lax.with_sharding_constraint(cached_k.value, sharding.kv)
-                cached_v.value = jax.lax.with_sharding_constraint(cached_v.value, sharding.kv)
+                for var in cached:
+                    var.value = jax.lax.with_sharding_constraint(var.value, sharding.kv)
             k_all, v_all = cached_k.value, cached_v.value
         if sharding is not None:
             next_idx = jax.lax.with_sharding_constraint(next_idx, sharding.index)
@@ -220,11 +253,11 @@ def decode_cache_update(
         k_all = _dq(cached_k.value, k_scale.value, k.dtype)
         v_all = _dq(cached_v.value, v_scale.value, v.dtype)
     else:
-        k_all = jax.lax.dynamic_update_slice(cached_k.value, k, (0, idx, 0, 0))
-        v_all = jax.lax.dynamic_update_slice(cached_v.value, v, (0, idx, 0, 0))
-        cached_k.value, cached_v.value = k_all, v_all
+        for var, new in zip(cached, news):
+            var.value = jax.lax.dynamic_update_slice(var.value, new, (0, idx, 0, 0))
+        k_all, v_all = cached_k.value, cached_v.value
     cache_idx.value = next_idx
-    return k_all, v_all, idx, True
+    return k_all, (None if v is None else v_all), idx, True
 
 
 def _paged_frontier_write(
@@ -281,7 +314,7 @@ def _paged_frontier_write(
 def _paged_pool_step(
     mod: Any,
     k: jax.Array,
-    v: jax.Array,
+    v: jax.Array | None,
     num_blocks: int,
     block_tokens: int,
     block_tables: jax.Array | None,
@@ -303,20 +336,18 @@ def _paged_pool_step(
     at each program's boundary and rewrites all of it on the way in and out.
     Returns
     ``(pool_leaves, write_index, is_init)`` where ``pool_leaves`` is
-    ``(k_pool, v_pool)`` at full precision or
-    ``(k_pool, v_pool, k_scale_pool, v_scale_pool)`` under int8."""
-    if kv_cache_dtype is not None and np.dtype(kv_cache_dtype) != np.dtype("int8"):
-        raise ValueError(
-            f"kv_cache_dtype supports None (compute dtype) or int8, got {kv_cache_dtype}"
-        )
-    quant = kv_cache_dtype is not None
+    ``(k_pool, v_pool)`` at full precision,
+    ``(k_pool, v_pool, k_scale_pool, v_scale_pool)`` under int8, or, with
+    ``v=None`` (a latent row, `CacheContract.value_dim`), the one
+    ``(latent_pool,)`` of ``[num_blocks, block_tokens, head_dim]``."""
+    quant = _check_cache_dtype(kv_cache_dtype, v is None)
     b, s, kv_heads, head_dim = k.shape
     store_dtype = jnp.int8 if quant else k.dtype
-    is_init = mod.has_variable("cache", "cached_key")
-    cached_k = mod.variable("cache", "cached_key", jnp.zeros,
-                            (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
-    cached_v = mod.variable("cache", "cached_value", jnp.zeros,
-                            (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
+    names = _kv_leaf_names(v)
+    is_init = mod.has_variable("cache", names[0])
+    cached = [mod.variable("cache", name, jnp.zeros,
+                           (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
+              for name in names]
     if quant:
         k_scale = mod.variable("cache", "key_scale", jnp.zeros,
                                (num_blocks, block_tokens, kv_heads), jnp.float32)
@@ -338,27 +369,28 @@ def _paged_pool_step(
     idx = cache_idx.value  # [b]
     mask = (jnp.ones((b,), bool) if write_mask is None
             else write_mask.astype(bool))
+    pools = tuple(var.value for var in cached)
     if quant:
         kq, ks = _q(k)
         vq, vs = _q(v)
-        pools = (cached_k.value, cached_v.value, k_scale.value, v_scale.value)
+        pools += (k_scale.value, v_scale.value)
         news = (kq, vq, ks, vs)
     else:
-        pools = (cached_k.value, cached_v.value)
-        news = (k, v)
+        news = (k,) if v is None else (k, v)
     new_pools, next_idx = _paged_frontier_write(
         pools, news, idx, mask, write_len,
         num_blocks, block_tokens, block_tables,
     )
     if sharding is not None:
-        kv_specs = (sharding.kv, sharding.kv) + (
+        kv_specs = (sharding.kv,) * len(cached) + (
             (sharding.scale, sharding.scale) if quant else ())
         new_pools = tuple(
             jax.lax.with_sharding_constraint(leaf, spec)
             for leaf, spec in zip(new_pools, kv_specs)
         )
         next_idx = jax.lax.with_sharding_constraint(next_idx, sharding.index)
-    cached_k.value, cached_v.value = new_pools[0], new_pools[1]
+    for var, leaf in zip(cached, new_pools):
+        var.value = leaf
     if quant:
         k_scale.value, v_scale.value = new_pools[2], new_pools[3]
     cache_idx.value = next_idx
@@ -368,7 +400,7 @@ def _paged_pool_step(
 def paged_decode_update(
     mod: Any,  # the flax module (self) owning the "cache" collection
     k: jax.Array,  # [b, s, kv_heads, head_dim] new keys (s == 1 unless write_len)
-    v: jax.Array,
+    v: jax.Array | None,  # None: k is a latent row, one pool a layer (v comes back None)
     num_blocks: int,  # pool size; block id == num_blocks is the dropped write
     block_tokens: int,
     block_tables: jax.Array | None,  # [b, blocks_per_slot] int32 pool block ids
@@ -428,6 +460,8 @@ def paged_decode_update(
         new_k, new_v, new_ks, new_vs = new_pools
         k_all = _dq(_view(new_k, kv_heads, head_dim), _view(new_ks, kv_heads), k.dtype)
         v_all = _dq(_view(new_v, kv_heads, head_dim), _view(new_vs, kv_heads), v.dtype)
+    elif v is None:
+        return _view(new_pools[0], kv_heads, head_dim), None, idx, True
     else:
         new_k, new_v = new_pools
         k_all = _view(new_k, kv_heads, head_dim)
@@ -441,7 +475,7 @@ def paged_decode_update(
 def paged_decode_write(
     mod: Any,  # the flax module (self) owning the "cache" collection
     k: jax.Array,  # [b, s, kv_heads, head_dim] new keys (s == 1 unless write_len)
-    v: jax.Array,
+    v: jax.Array | None,  # None: k is a latent row, one pool a layer (v comes back None)
     num_blocks: int,  # pool size; block id == num_blocks is the dropped write
     block_tokens: int,
     block_tables: jax.Array | None,  # [b, blocks_per_slot] int32 pool block ids
@@ -475,6 +509,8 @@ def paged_decode_write(
     if kv_cache_dtype is not None:
         new_k, new_v, new_ks, new_vs = new_pools
         return new_k, new_v, idx, True, (new_ks, new_vs)
+    if v is None:
+        return new_pools[0], None, idx, True, None
     new_k, new_v = new_pools
     return new_k, new_v, idx, True, None
 

@@ -754,20 +754,27 @@ def _ceil_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _paged_decode_chunk_blocks(block_tokens: int, width: int) -> int:
+def _paged_decode_chunk_blocks(block_tokens: int, width: int, latent: bool = False) -> int:
     """Pool blocks one chunk covers: 256 tokens while a float32 working copy
     of the chunk (``tokens x width x 4``) stays within 4 MiB (rows to 4,096
-    lanes), else 128 tokens; never under one block."""
-    tokens = 256 if 256 * _ceil_to(width, 128) * 4 <= (4 << 20) else 128
+    lanes), else 128 tokens; never under one block. A latent pool's chunk is
+    512 tokens: its one narrow row a token makes a turn's fixed cost, not
+    its bytes, what a row pays (v5e, PR 32: 1.20 against 1.38 ms a call at
+    the Kimi cell's shape with blocks of 64; `PERF.md` section 6)."""
+    if latent:
+        tokens = 512
+    else:
+        tokens = 256 if 256 * _ceil_to(width, 128) * 4 <= (4 << 20) else 128
     return max(1, tokens // block_tokens)
 
 
 def paged_decode_vmem_bytes(
     kv_heads: int, head_dim: int, *, q_heads: int | None = None,
-    block_tokens: int = 16, itemsize: int = 4,
+    block_tokens: int = 16, itemsize: int = 4, value_dim: int | None = None,
 ) -> int:
-    """VMEM the fused paged-decode kernel asks for. Per pool (keys, values)
-    two chunk buffers ``chunk_tokens x ceil128(kv_heads * head_dim) x
+    """VMEM the fused paged-decode kernel asks for. Per pool (keys, values;
+    one alone for a latent leaf, ``value_dim`` set: the value is lanes of the
+    key chunk) two chunk buffers ``chunk_tokens x ceil128(kv_heads * head_dim) x
     itemsize``, one float32 working copy of a chunk each (the upcast of a
     float32 or int8 pool; a bfloat16 pool feeds the MXU as it is), the folded
     query and the float32 accumulator ``[q_heads, width]`` twice over (the
@@ -775,16 +782,16 @@ def paged_decode_vmem_bytes(
     (4 prices the widest); nothing here depends on the attended span."""
     lanes = _ceil_to(kv_heads * head_dim, 128)
     # a pool block is a buffer block: its tokens pad to the dtype's sublane tile
-    tokens = (_paged_decode_chunk_blocks(block_tokens, lanes)
+    tokens = (_paged_decode_chunk_blocks(block_tokens, lanes, value_dim is not None)
               * _ceil_to(block_tokens, 32 // itemsize))
-    chunks = 2 * tokens * lanes * (2 * itemsize + 4)
+    chunks = (2 if value_dim is None else 1) * tokens * lanes * (2 * itemsize + 4)
     heads = _ceil_to(q_heads or kv_heads, 8)
     return chunks + 4 * heads * lanes * 4 + _PAGED_DECODE_HEADROOM
 
 
 def check_paged_decode_fits(
     kv_heads: int, head_dim: int, *, q_heads: int | None = None,
-    block_tokens: int = 16, itemsize: int = 4,
+    block_tokens: int = 16, itemsize: int = 4, value_dim: int | None = None,
 ) -> int:
     """Raise with the sizes named when the kernel cannot hold its chunk
     buffers for rows of ``kv_heads`` x ``head_dim`` in VMEM; returns the bytes
@@ -792,9 +799,10 @@ def check_paged_decode_fits(
     ``paged_attention="fused"`` fails there instead of at first decode."""
     need = paged_decode_vmem_bytes(
         kv_heads, head_dim, q_heads=q_heads, block_tokens=block_tokens,
-        itemsize=itemsize)
+        itemsize=itemsize, value_dim=value_dim)
     if need > PAGED_DECODE_VMEM_CAP:
-        tokens = _paged_decode_chunk_blocks(block_tokens, kv_heads * head_dim) * block_tokens
+        tokens = _paged_decode_chunk_blocks(
+            block_tokens, kv_heads * head_dim, value_dim is not None) * block_tokens
         raise ValueError(
             f"fused paged decode streams chunks of {tokens} positions of the "
             f"folded row through VMEM: {kv_heads} kv heads x head_dim "
@@ -810,8 +818,8 @@ def check_paged_decode_fits(
 
 
 def _paged_decode_kernel(
-    tables, lengths, q_ref, k_hbm, v_hbm, *rest,
-    block_tokens, chunk_blocks, scale, groups, quant,
+    tables, lengths, q_ref, k_hbm, *rest,
+    block_tokens, chunk_blocks, scale, groups, quant, value_dim=None,
 ):
     """One grid cell = one slot row; inside it a loop of ``cdiv(length,
     chunk)`` turns, each over one CHUNK of ``chunk_blocks`` pool blocks, so a
@@ -848,13 +856,25 @@ def _paged_decode_kernel(
     and stood up ``[chunk, 2 * kv_heads]`` in VMEM. The chunk is dequantised
     into a staging buffer (value x its head's scale over that head's ``d``
     lanes, through the compute dtype, exactly the gather oracle's `_dq`) and
-    from there on the arithmetic is the same."""
-    if quant:
-        sc_hbm, o_ref, kbuf, vbuf, scbuf, kstage, vstage, *rest = rest
+    from there on the arithmetic is the same.
+
+    A latent pool (``value_dim`` set; no value pool, ``v_hbm`` absent) is the
+    absorbed form of latent attention: one row of ``width`` lanes a token that
+    every head shares (``kv_heads`` 1, ``Q'`` is the query itself), and the
+    value is lanes ``[0, value_dim)`` of the key chunk already in VMEM, so a
+    chunk costs one copy a block and not two; the output is ``[heads,
+    value_dim]``."""
+    latent = value_dim is not None
+    if latent:
+        v_hbm = vbuf = sc_hbm = scbuf = kstage = vstage = None
+        o_ref, kbuf, *rest = rest
+    elif quant:
+        v_hbm, sc_hbm, o_ref, kbuf, vbuf, scbuf, kstage, vstage, *rest = rest
     else:
         sc_hbm = scbuf = kstage = vstage = None
-        o_ref, kbuf, vbuf, *rest = rest
+        v_hbm, o_ref, kbuf, vbuf, *rest = rest
     sems, slot_ref, qp_ref, acc_ref = rest
+    pools = ((k_hbm, kbuf),) if latent else ((k_hbm, kbuf), (v_hbm, vbuf))
     row = pl.program_id(0)
     rows = pl.num_programs(0)
     hq, d = q_ref.shape[1], q_ref.shape[2]
@@ -874,7 +894,7 @@ def _paged_decode_kernel(
 
         def one(i, carry):
             blk = tables[r, first + i]
-            for p, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            for p, (hbm, buf) in enumerate(pools):
                 cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, i], sems.at[p, slot])
                 cp.wait() if wait else cp.start()
             return carry
@@ -952,7 +972,7 @@ def _paged_decode_kernel(
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        v = staged(vbuf, vstage, slot, scales, kvh)
+        v = k[:, :value_dim] if latent else staged(vbuf, vstage, slot, scales, kvh)
         vpos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
         v = jnp.where(vpos < length, v, jnp.zeros_like(v))
         l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
@@ -960,9 +980,13 @@ def _paged_decode_kernel(
 
     m, l, acc = jax.lax.fori_loop(
         0, turns, turn,
-        (jnp.full((hq, 1), neg, f32), jnp.zeros((hq, 1), f32), jnp.zeros((hq, width), f32)),
+        (jnp.full((hq, 1), neg, f32), jnp.zeros((hq, 1), f32),
+         jnp.zeros((hq, value_dim if latent else width), f32)),
     )
     slot_ref[0] = (slot0 + turns) % 2  # where the next row's first chunk went
+    if latent:
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        return
     acc_ref[...] = acc / l
     for g in range(kvh):
         hs = slice(g * groups, (g + 1) * groups)
@@ -972,10 +996,11 @@ def _paged_decode_kernel(
 def paged_decode_attention(
     q: jax.Array,  # [b, n_heads, head_dim] — ONE decode query per slot row
     k_pool: jax.Array,  # [num_blocks, block_tokens, kv_heads * head_dim]
-    v_pool: jax.Array,
+    v_pool: jax.Array | None,  # None: k_pool is a latent pool, see value_dim
     block_tables: jax.Array,  # [b, blocks_per_slot] int32 pool block ids
     lengths: jax.Array,  # [b] int32 valid kv positions (frontier cursor + 1)
     *,
+    value_dim: int | None = None,  # a latent pool's leading lanes that are the value
     k_scale_pool: jax.Array | None = None,  # [num_blocks, block_tokens, kv_heads]
     v_scale_pool: jax.Array | None = None,  # fp32 absmax planes (int8 pool)
     scale: float | None = None,
@@ -1020,9 +1045,28 @@ def paged_decode_attention(
     planes as ``k_scale_pool``/``v_scale_pool`` (``[num_blocks, block_tokens,
     kv_heads]``, addressed through the same block table); each chunk is
     dequantized in VMEM, so the quantized pool is never materialized at full
-    precision."""
+    precision.
+
+    **A latent pool** (``v_pool=None`` with ``value_dim``; latent attention in
+    absorbed form, `models/kimi_k2.py`): ``k_pool`` is ``[num_blocks,
+    block_tokens, width]`` with one row a token shared by all heads, ``q`` is
+    ``[b, n_heads, width]`` (each head's query already carried into the latent
+    space, the rotary lanes beside it), the value is lanes ``[0, value_dim)``
+    of the same row, and the result is ``[b, n_heads, value_dim]``: all heads
+    against one row in the one MXU product a chunk, one copy a block. No int8
+    form."""
     b, hq, d = q.shape
-    if k_pool.ndim != 3 or v_pool.shape != k_pool.shape:
+    latent = v_pool is None
+    if latent:
+        if k_pool.ndim != 3 or k_pool.shape[-1] != d or value_dim is None \
+                or not 0 < value_dim <= d or k_scale_pool is not None or v_scale_pool is not None:
+            raise ValueError(
+                f"a latent pool is [num_blocks, block_tokens, {d}] (the query's width) with "
+                f"0 < value_dim <= {d} and no scale planes, got {k_pool.shape}, "
+                f"value_dim={value_dim}")
+    elif value_dim is not None:
+        raise ValueError("value_dim is a latent pool's (v_pool=None); got both pools")
+    elif k_pool.ndim != 3 or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pools must be [num_blocks, block_tokens, kv_heads * head_dim], "
             f"got {k_pool.shape} and {v_pool.shape}"
@@ -1045,10 +1089,12 @@ def paged_decode_attention(
     if interpret is None:
         interpret = not _on_tpu()
     vmem_bytes = check_paged_decode_fits(
-        kvh, d, q_heads=hq, block_tokens=block_tokens, itemsize=k_pool.dtype.itemsize)
+        kvh, d, q_heads=hq, block_tokens=block_tokens, itemsize=k_pool.dtype.itemsize,
+        value_dim=value_dim)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    chunk_blocks = min(_paged_decode_chunk_blocks(block_tokens, width), block_tables.shape[1])
+    chunk_blocks = min(_paged_decode_chunk_blocks(block_tokens, width, latent),
+                       block_tables.shape[1])
     chunk = chunk_blocks * block_tokens
     # released slots park their whole table at the sentinel id num_blocks;
     # clamp to a real block (past the frontier, masked) so no copy reads out
@@ -1056,10 +1102,12 @@ def paged_decode_attention(
     tables = jnp.minimum(block_tables.astype(jnp.int32), num_blocks - 1)
     lengths = lengths.astype(jnp.int32)
 
-    row_spec = pl.BlockSpec((1, hq, d), lambda r, t, l: (r, 0, 0))
+    d_out = value_dim if latent else d
+    row_spec, out_spec = (pl.BlockSpec((1, hq, lanes), lambda r, t, l: (r, 0, 0))
+                          for lanes in (d, d_out))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    inputs = [tables, lengths, q, k_pool, v_pool]
-    buffers = [pltpu.VMEM((2, chunk_blocks, block_tokens, width), k_pool.dtype)] * 2
+    inputs = [tables, lengths, q, k_pool] + ([] if latent else [v_pool])
+    buffers = [pltpu.VMEM((2, chunk_blocks, block_tokens, width), k_pool.dtype)] * (len(inputs) - 3)
     if quant:
         # Mosaic copies no HBM slice whose minor dim is short of a lane tile,
         # so a block's [block_tokens, kv_heads] plane cannot ride beside its
@@ -1080,7 +1128,7 @@ def paged_decode_attention(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[row_spec] + [in_hbm] * (len(inputs) - 3),
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=buffers + [
             pltpu.SemaphoreType.DMA((len(inputs) - 3, 2)),
             pltpu.SMEM((1,), jnp.int32),  # buffer the row's first chunk is in
@@ -1090,12 +1138,12 @@ def paged_decode_attention(
     )
     kernel = functools.partial(
         _paged_decode_kernel, block_tokens=block_tokens, chunk_blocks=chunk_blocks,
-        scale=scale, groups=hq // kvh, quant=quant,
+        scale=scale, groups=hq // kvh, quant=quant, value_dim=value_dim,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d_out), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes),
         interpret=interpret,

@@ -24,8 +24,9 @@ holds, routes over all of them, drops no token, and computes the held experts'
 part of the result with a grouped matrix product over the picks sorted by
 expert (`grouped_product`: `jax.lax.ragged_dot`, or the Pallas grouped matmul
 that ships with JAX for a prefill's rows on the TPU). `shared_expert_mlp` is
-the always-on expert behind a sigmoid gate that such models put beside the
-routed ones.
+the always-on expert (behind a sigmoid gate, or ungated) that such models put
+beside the routed ones. Two routers: `route_top_k` (softmax over all experts)
+and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias).
 """
 
 from __future__ import annotations
@@ -164,6 +165,21 @@ def route_top_k(x: jax.Array, router: jax.Array, top_k: int) -> tuple[jax.Array,
         return top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
+def route_sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
+                        scaling: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid scores ``s = sigmoid(x W_g)`` in float32 over ALL of the
+    router's experts; the ``top_k`` with the largest ``s + bias`` are chosen
+    (the bias chooses, it never weighs); their own scores, renormalised to sum
+    1 and times ``scaling``, are the weights: ``(weights [T, k], ids [T, k])``."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+        return scaling * top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20), idx
+
+
 GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small groups share one
 GMM_MIN_ROWS = 4096  # from a 512-token prefill's picks up
 GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
@@ -229,14 +245,17 @@ def held_experts_mlp(
     return out, jnp.sum(held).astype(jnp.int32), jnp.sum(sizes > 0).astype(jnp.int32)
 
 
-def shared_expert_mlp(x: jax.Array, gate: jax.Array, w_gate_up: jax.Array,
+def shared_expert_mlp(x: jax.Array, gate: jax.Array | None, w_gate_up: jax.Array,
                       w_down: jax.Array) -> jax.Array:
     """``sigmoid(x . gate) * down(silu(gate_proj x) * up_proj x)``: the expert
-    every token passes through. ``w_gate_up`` is ``[hidden, 2 * F]``. float32."""
+    every token passes through; ``gate=None`` is the ungated form (no sigmoid
+    in front). ``w_gate_up`` is ``[hidden, 2 * F]``. float32."""
     f = w_gate_up.shape[-1] // 2
     gu = jnp.matmul(x, w_gate_up.astype(x.dtype), preferred_element_type=jnp.float32)
     act = (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(x.dtype)
     y = jnp.matmul(act, w_down.astype(x.dtype), preferred_element_type=jnp.float32)
+    if gate is None:
+        return y
     on = jax.nn.sigmoid(jnp.sum(x.astype(jnp.float32) * gate.astype(jnp.float32), -1, keepdims=True))
     return on * y
 

@@ -426,7 +426,8 @@ class ServingEngine:
 
     ``module`` is any causal LM whose config declares a cache contract
     (``config.cache_contract()``, `models/kv_cache.CacheContract`: GPT-2's keys
-    and values, or keys and values beside per-slot recurrent state); the
+    and values, keys and values beside per-slot recurrent state, or one
+    latent leaf a layer with the value inside the key row); the
     engine re-instantiates it with its cache switches on, so callers pass the
     same module they would hand to ``generate``. ``params`` is the matching
     param tree. The context length is the config's ``n_positions``. A model
@@ -504,7 +505,8 @@ class ServingEngine:
                 f"{type(module).__name__}'s config declares no cache contract; "
                 "the serving engine needs `config.cache_contract()` and the "
                 "per-slot cache switches it describes (models/kv_cache.py "
-                "CacheContract) — GPT2LMHead and Qwen3NextForCausalLM have them."
+                "CacheContract) — GPT2LMHead, Qwen3NextForCausalLM and "
+                "KimiK2ForCausalLM have them."
             )
         contract = self._contract = cfg.cache_contract()
         if contract.state_leaves:
@@ -520,6 +522,14 @@ class ServingEngine:
                     raise ValueError(
                         f"{type(module).__name__} keeps per-slot recurrent state "
                         f"{contract.state_leaves}; {name} is not supported for it")
+        if contract.value_dim is not None and mesh is not None:
+            # one latent row a token is shared by all heads: the pool has no
+            # head dim to split over the model axis, and such a model's
+            # experts' exchange is not written. Refuse, never answer wrongly.
+            raise ValueError(
+                f"{type(module).__name__} keeps one latent cache leaf a layer "
+                "(CacheContract.value_dim) shared by all heads; mesh is not "
+                "supported for it")
         self.max_concurrency = int(max_concurrency)
         if self.max_concurrency < 1:
             raise ValueError(f"max_concurrency must be >= 1, got {max_concurrency}")
@@ -629,7 +639,7 @@ class ServingEngine:
 
             check_paged_decode_fits(
                 contract.kv_heads // self._mesh_model, contract.head_dim,
-                block_tokens=self._block_tokens,
+                block_tokens=self._block_tokens, value_dim=contract.value_dim,
             )
         # contiguous slot ranges per data replica (the slot dim shards like any
         # leading batch dim: replica i owns rows [i*b/d, (i+1)*b/d)) — 1 when

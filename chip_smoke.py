@@ -50,6 +50,7 @@ class Sizes:
     band_block: int
     positions: int
     gqa: tuple  # (query heads, key/value heads, head_dim, positions) of a grouped-query layer
+    latent: tuple  # (query heads, row lanes, value lanes, positions) of a latent-attention layer
     nf4_shapes: tuple  # (K, N) of the quantized weights
     train_batch_per_chip: int
     train_seq: int
@@ -68,6 +69,7 @@ CHIP = Sizes(
     preset="medium", batch=8, seq=1024, heads=16, head_dim=64, embed=1024,
     vocab=50257, ce_rows=8192, window=256, band_block=512, positions=1024,
     gqa=(16, 2, 256, 2560),  # Qwen3-Next's attention layer
+    latent=(64, 640, 512, 4608),  # Kimi K2's: 64 heads on one row of 576 lanes stored as 640
     nf4_shapes=((1024, 3072), (1024, 4096), (4096, 1024)),
     train_batch_per_chip=8, train_seq=1024, train_steps=6,
     prompt_buckets=(32, 128), prompt_lengths=(5, 31, 12, 24, 120, 77, 50, 97),
@@ -78,6 +80,7 @@ REHEARSAL = Sizes(
     preset="tiny", batch=2, seq=128, heads=2, head_dim=32, embed=64,
     vocab=256, ce_rows=128, window=48, band_block=32, positions=128,
     gqa=(8, 2, 32, 320),
+    latent=(4, 128, 96, 320),
     nf4_shapes=((256, 256),),
     train_batch_per_chip=2, train_seq=64, train_steps=6,
     prompt_buckets=(16, 64), prompt_lengths=(5, 15, 9, 12, 60, 33, 20, 47),
@@ -289,6 +292,33 @@ def phase_kernels(run: Smoke) -> None:
         ref = exact(paged_ref, qd.astype(jnp.float32), *deq)
         compare(f"paged_decode int8 pool {shape}", got, ref, z.tol)
         del k_pool, v_pool, k8, v8, deq
+
+    # ---- fused paged decode over a latent pool (absorbed latent attention):
+    # every query head against one shared row a token, the value its leading
+    # lanes; no value pool
+    heads, lanes, value_dim, positions = z.latent
+    bps = positions // bt
+    blocks = z.batch * bps
+    lengths = np.linspace(1, positions, z.batch).astype(np.int32)
+    lengths[1] = bt + 1
+    tables = rng.permutation(blocks).astype(np.int32).reshape(z.batch, bps)
+    for row, n in enumerate(lengths):
+        tables[row, -(-int(n) // bt):] = blocks  # the released-slot sentinel
+    tables_j, lengths_j = jnp.asarray(tables), jnp.asarray(lengths)
+    qd, pool = rand((z.batch, heads, lanes)), rand((blocks, bt, lanes))
+
+    def latent_ref(q, pool):
+        rows = pool[jnp.minimum(tables_j, blocks - 1)].reshape(z.batch, positions, 1, lanes)
+        mask = (jnp.arange(positions)[None] < lengths_j[:, None])[:, None, None, :]
+        keys = jnp.repeat(rows, heads, axis=2)
+        return dot_product_attention(q[:, None], keys, keys[..., :value_dim], mask=mask)[:, 0]
+
+    got = jax.jit(lambda q, p, t, l: paged_decode_attention(
+        q, p, None, t, l, value_dim=value_dim, interpret=interpret))(qd, pool, tables_j, lengths_j)
+    ref = exact(latent_ref, *_f32(qd, pool))
+    compare(f"paged_decode latent {heads}:1x{lanes}/{value_dim} pos={positions} bt={bt}",
+            got, ref, z.tol)
+    del pool
 
     # ---- nf4 dequant-matmul (concrete payload: the only way it runs)
     for kdim, ndim in z.nf4_shapes:

@@ -287,6 +287,107 @@ def test_admit_scatter_leaves_the_pool_in_place(topology, bucket):
     )
 
 
+# ----------------------------------------------- the latent pool (not slow)
+# The Kimi K2 cell's layer: 64 query heads against one shared row a token of
+# 576 lanes stored as 640, the value its first 512; 256 slot rows of 288 table
+# blocks over a pool of 43,008 blocks.
+LATENT_ROWS, LATENT_HEADS, LATENT_LANES, LATENT_VALUE = 256, 64, 640, 512
+LATENT_BLOCKS, LATENT_ROW_BLOCKS = 43008, 288
+
+
+def test_paged_decode_latent_pool_one_device(topology):
+    """Mosaic takes the body with no value pool: one copy a block, the value
+    sliced from the key chunk in VMEM at a lane-tile edge, the output 512 wide;
+    the frontier write beside it leaves the donated pool in place."""
+    from accelerate_tpu.models.kv_cache import _paged_frontier_write
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    s = _one_device(topology)
+    shape = (LATENT_BLOCKS, BLOCK_TOKENS, LATENT_LANES)
+
+    def decode(pool, q, row, tables, idx):
+        (pool,), _ = _paged_frontier_write(
+            (pool,), (row,), idx, jnp.ones((LATENT_ROWS,), bool), None,
+            LATENT_BLOCKS, BLOCK_TOKENS, tables)
+        out = paged_decode_attention(q, pool, None, tables, idx + 1, value_dim=LATENT_VALUE,
+                                     interpret=False)
+        return pool, out
+
+    compiled = jax.jit(decode, donate_argnums=(0,)).lower(
+        _sds(shape, jnp.bfloat16, s), _sds((LATENT_ROWS, LATENT_HEADS, LATENT_LANES), jnp.bfloat16, s),
+        _sds((LATENT_ROWS, 1, 1, LATENT_LANES), jnp.bfloat16, s),
+        _sds((LATENT_ROWS, LATENT_ROW_BLOCKS), jnp.int32, s), _sds((LATENT_ROWS,), jnp.int32, s),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"bf16[{LATENT_ROWS},{LATENT_HEADS},{LATENT_VALUE}]" in text
+    dims = ",".join(map(str, shape))
+    assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(shape)) * 2 // 8
+
+
+def test_latent_row_of_576_lanes_is_refused_by_the_compiler(topology):
+    """Why the row is stored padded to 640: a bfloat16 array whose minor
+    dimension is 576 is tiled to 640 lanes in HBM in any case, and Mosaic
+    copies no slice of it that is not a whole number of lane tiles."""
+    from accelerate_tpu.ops.flash_attention import paged_decode_attention
+
+    s = _one_device(topology)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(lambda q, pool, tables, lengths: paged_decode_attention(
+            q, pool, None, tables, lengths, value_dim=LATENT_VALUE, interpret=False),
+            _sds((8, LATENT_HEADS, 576), jnp.bfloat16, s), _sds((256, BLOCK_TOKENS, 576), jnp.bfloat16, s),
+            _sds((8, 32), jnp.int32, s), _sds((8,), jnp.int32, s))
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_kimi_k2_scopes_and_kernel_name(topology, compiled_kernels, program):
+    """The model's `jax.named_scope`s reach the compiled HLO's `op_name`, and
+    the decode step's fused kernel keeps the flax scope's name (`%attn.N`, what
+    the benchmark's readers look for) while the absorbing products around it
+    carry `mla_absorb`. Published attention widths, everything else small."""
+    import dataclasses
+
+    from accelerate_tpu.models.kimi_k2 import KimiK2Config, KimiK2ForCausalLM
+
+    s = _one_device(topology)
+    cfg = KimiK2Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=8, q_lora_rank=128, n_routed_experts=16,
+        experts_held=4, num_experts_per_tok=4, n_positions=2048, kv_cache_per_slot=True)
+    rows, bucket = 8, 1024
+    if program == "step":
+        cfg = dataclasses.replace(cfg, kv_cache_paged=True, kv_num_blocks=256,
+                                  kv_paged_attention="fused")
+    module = KimiK2ForCausalLM(cfg)
+    tables = jnp.zeros((rows, cfg.n_positions // 16), jnp.int32)
+    extra = dict(block_tables=tables) if program == "step" else {}
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((rows, 1), jnp.int32), decode=True, **extra))
+    place = lambda tree: jax.tree.map(lambda x: _sds(x.shape, x.dtype, s), tree)  # noqa: E731
+
+    def run(params, cache, ids, offsets, tables):
+        kw = dict(position_offset=offsets, block_tables=tables) if program == "step" else \
+            dict(position_offset=0)
+        return module.apply({"params": params, "cache": cache}, ids, decode=True,
+                            mutable=["cache", "counters"], **kw)
+
+    ids = _sds((rows, 1 if program == "step" else bucket), jnp.int32, s)
+    hlo = jax.jit(run, donate_argnums=(1,)).lower(
+        place(shapes["params"]), place(shapes["cache"]), ids, _sds((rows,), jnp.int32, s),
+        _sds(tables.shape, jnp.int32, s)).compile().as_text()
+    scopes = {"step": ("mla_absorb", "moe_router", "moe_experts", "dense_mlp"),
+              "admit": ("mla_prefill", "moe_router", "moe_experts", "dense_mlp")}[program]
+    for scope in scopes:
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    kernels = re.findall(r'%([\w\-]+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    if program == "step":
+        assert "attn" in kernels and "mla_absorb" not in kernels, kernels
+        assert "mla_prefill" not in hlo
+    else:
+        assert "mla_prefill" in kernels and "mla_absorb" not in hlo, kernels  # the flash kernel, plain form
+
+
 # ------------------------------------- the expert layer's grouped products
 @pytest.mark.parametrize("tokens, pallas", [(512, True), (1536, True), (128, False)])
 def test_held_experts_grouped_product(topology, compiled_kernels, tokens, pallas):
