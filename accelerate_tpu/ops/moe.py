@@ -22,8 +22,9 @@ GShard/Switch.
 many small experts under expert parallelism. It is told which experts it
 holds, routes over all of them, drops no token, and computes the held experts'
 part of the result with a grouped matrix product over the picks sorted by
-expert (`grouped_product`: `jax.lax.ragged_dot`, or the Pallas grouped matmul
-that ships with JAX for a prefill's rows on the TPU). `shared_expert_mlp` is
+expert (`grouped_product`: the Pallas grouped matmul that ships with JAX on
+the TPU, for a prefill's rows and a decode step's alike, `jax.lax.ragged_dot`
+on any other backend). `shared_expert_mlp` is
 the always-on expert (behind a sigmoid gate, or ungated) that such models put
 beside the routed ones. Two routers: `route_top_k` (softmax over all experts)
 and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias).
@@ -31,6 +32,7 @@ and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias).
 
 from __future__ import annotations
 
+import collections
 from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Any
@@ -181,30 +183,59 @@ def route_sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, top_k:
 
 
 GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small groups share one
-GMM_MIN_ROWS = 4096  # from a 512-token prefill's picks up
 GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
+
+# Grouped products by the path they took and their row count, counted where
+# the path is decided: when a program is TRACED. `ServingEngine._dispatch`
+# reads the difference around a program's first call
+# (`serving/grouped_product/*`).
+GROUPED_PRODUCT_TRACES: collections.Counter = collections.Counter()
+
+
+def grouped_tiling(k: int, n: int) -> tuple[int, int, int]:
+    """``(row tile, tile_k, tile_n)`` of the Pallas grouped matmul for rows
+    ``[R, k]`` times ``[G, k, n]``, read off the weights' shape alone. The
+    kernel's time is the fetch of its weight tiles, so a tile is 4 MB (two
+    of them and the rows' fit the 16 MiB of scoped VMEM): the whole of
+    either matrix of a Qwen3-Next expert, a seventh of a Kimi K2 expert's
+    down projection. One rule for a prefill's thousands of rows and for a
+    decode step's 3-5 a group: on the v5e it is within 2% of the best of a
+    sweep over row tiles of 16 to 128 and eight weight tiles at each of both
+    serving cells' decode shapes (PERF.md section 6, PR 33). A smaller row
+    tile only makes more groups straddle two tiles and fetch their weights
+    twice."""
+    tile_k = min(k, 2048)
+    return GMM_ROW_TILE, tile_k, min(n, GMM_WEIGHT_TILE // tile_k)
 
 
 def grouped_product(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
     """``rows [R, K]`` sorted by group times each group's ``w [G, K, N]``,
     ``sizes [G]`` rows a group, float32 ``[R, N]``; rows past the last group
-    hold anything. A prefill's rows on the TPU go through the Pallas grouped
-    matmul that ships with JAX (megablox): with a dozen rows a group XLA's
-    `ragged_dot` takes 2.5 to 3 times the weights' read there, and a turn that
-    admits a request is what a decoding slot waits on. Any other backend and
-    a row count the tile does not divide keep `jax.lax.ragged_dot`; so, for
-    now, do a decode step's few rows, though the kernel measured faster there
-    too (ROADMAP S10): that step's roofline reader looks for `ragged_dot`."""
+    hold anything. On the TPU every call, a prefill's and a decode step's,
+    goes through the Pallas grouped matmul that ships with JAX (megablox
+    `gmm`; trace events `%gmm.N`), tiled by `grouped_tiling`: it visits only
+    the row tiles that hold a group and fetches a group's weight tiles once a
+    visit, so each touched expert's weights are read once (twice where a
+    group straddles two row tiles). XLA's `ragged_dot` takes 1.7 to 2.7
+    times the touched weights' read at a decode step's 3-5 rows a group and
+    2.5 to 3 times at a prefill's dozen. A row count the row tile does not
+    divide is padded up to it: the pad lies past the last group. Any other
+    backend keeps `jax.lax.ragged_dot`."""
     from ..utils.environment import on_tpu_platform
 
     n_rows, (_, k, n) = rows.shape[0], w.shape
-    if n_rows >= GMM_MIN_ROWS and n_rows % GMM_ROW_TILE == 0 and on_tpu_platform():
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
+    if not on_tpu_platform():
+        GROUPED_PRODUCT_TRACES["ragged_dot", n_rows] += 1
+        return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        tile_k = min(k, 2048)
-        tile_n = min(n, GMM_WEIGHT_TILE // tile_k)  # the whole width of both of an expert's matrices
-        return gmm(rows, w, sizes, jnp.float32, tiling=(GMM_ROW_TILE, tile_k, tile_n))
-    return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=jnp.float32)
+    GROUPED_PRODUCT_TRACES["pallas", n_rows] += 1
+    tiling = grouped_tiling(k, n)
+    pad = -n_rows % tiling[0]
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, w, sizes, jnp.float32, tiling=tiling)
+    return out[:n_rows] if pad else out
 
 
 def held_experts_mlp(

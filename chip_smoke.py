@@ -6,7 +6,9 @@ positions, bf16 compute, random weights from a seed), in ONE process:
 
   kernels  every `pl.pallas_call` in `accelerate_tpu/ops/`, compiled
            (``interpret=False`` passed, not inferred), against a float32
-           `jax.numpy` reference at the model's shapes
+           `jax.numpy` reference at the model's shapes; and the Pallas grouped
+           matmul `ops/moe.grouped_product` takes on the TPU, at the two MoE
+           cells' decode shapes, against `jax.lax.ragged_dot`
   train    `Accelerator.prepare` + `make_train_step(lm_loss_fn)`, flash
            attention, per-chip batch 8 x 1024, a few steps on one batch
   serve    `ServingEngine` (paged KV, fused decode kernel) answering eight
@@ -51,6 +53,7 @@ class Sizes:
     positions: int
     gqa: tuple  # (query heads, key/value heads, head_dim, positions) of a grouped-query layer
     latent: tuple  # (query heads, row lanes, value lanes, positions) of a latent-attention layer
+    experts: tuple  # (tokens, picks a token, router width, experts held, hidden, expert width) of decode steps
     nf4_shapes: tuple  # (K, N) of the quantized weights
     train_batch_per_chip: int
     train_seq: int
@@ -70,6 +73,8 @@ CHIP = Sizes(
     vocab=50257, ce_rows=8192, window=256, band_block=512, positions=1024,
     gqa=(16, 2, 256, 2560),  # Qwen3-Next's attention layer
     latent=(64, 640, 512, 4608),  # Kimi K2's: 64 heads on one row of 576 lanes stored as 640
+    # a decode step of the Kimi K2 and Qwen3-Next cells, and a row count the row tile does not divide
+    experts=((256, 8, 384, 12, 7168, 2048), (128, 10, 512, 256, 2048, 512), (24, 10, 512, 256, 2048, 512)),
     nf4_shapes=((1024, 3072), (1024, 4096), (4096, 1024)),
     train_batch_per_chip=8, train_seq=1024, train_steps=6,
     prompt_buckets=(32, 128), prompt_lengths=(5, 31, 12, 24, 120, 77, 50, 97),
@@ -81,6 +86,7 @@ REHEARSAL = Sizes(
     vocab=256, ce_rows=128, window=48, band_block=32, positions=128,
     gqa=(8, 2, 32, 320),
     latent=(4, 128, 96, 320),
+    experts=((8, 2, 8, 4, 64, 32),),
     nf4_shapes=((256, 256),),
     train_batch_per_chip=2, train_seq=64, train_steps=6,
     prompt_buckets=(16, 64), prompt_lengths=(5, 15, 9, 12, 60, 33, 20, 47),
@@ -167,6 +173,7 @@ def phase_kernels(run: Smoke) -> None:
     from accelerate_tpu.ops.attention import dot_product_attention
     from accelerate_tpu.ops.flash_attention import flash_attention, paged_decode_attention
     from accelerate_tpu.ops.fused_ce import fused_cross_entropy
+    from accelerate_tpu.ops.moe import grouped_product
     from accelerate_tpu.ops.nf4_matmul import nf4_matmul
     from accelerate_tpu.utils.quantization import QuantizationConfig, dequantize, quantize
 
@@ -320,6 +327,23 @@ def phase_kernels(run: Smoke) -> None:
             got, ref, z.tol)
     del pool
 
+    # ---- the held experts' grouped products at a decode step's rows: the
+    # Pallas grouped matmul on the TPU (`ops/moe.grouped_product`; off the TPU
+    # it is `ragged_dot` itself, and the rehearsal compares that with itself)
+    for tokens, picks, router_width, held, hidden, width in z.experts:
+        ids = np.stack([rng.permutation(router_width)[:picks] for _ in range(tokens)]).reshape(-1)
+        sizes = np.bincount(ids[ids < held], minlength=held).astype(np.int32)
+        n_held = int(sizes.sum())
+        for name, k_dim, n_dim in (("gate_up", hidden, 2 * width), ("down", width, hidden)):
+            rows, wts = rand((tokens * picks, k_dim)), rand((held, k_dim, n_dim), 0.02)
+            got = jax.jit(grouped_product)(rows, wts, jnp.asarray(sizes))
+            ref = jax.jit(lambda r, w_, s: jax.lax.ragged_dot(
+                r, w_, s, preferred_element_type=jnp.float32))(rows, wts, jnp.asarray(sizes))
+            compare(f"grouped_product {name} rows={tokens * picks} held={n_held} in "
+                    f"{int((sizes > 0).sum())} of {held} groups [{k_dim},{n_dim}]",
+                    got[:n_held], ref[:n_held], z.tol)
+            del rows, wts
+
     # ---- nf4 dequant-matmul (concrete payload: the only way it runs)
     for kdim, ndim in z.nf4_shapes:
         weight = rng.normal(size=(kdim, ndim)).astype(np.float32) * 0.02
@@ -336,7 +360,7 @@ def phase_kernels(run: Smoke) -> None:
         "flash_attention._dq_band_kernel", "flash_attention._dkv_band_kernel",
         "fused_ce._fwd_kernel", "fused_ce._dh_kernel", "fused_ce._dw_kernel",
         "flash_attention._paged_decode_kernel", "nf4_matmul._kernel",
-    ))
+    ) + (() if run.rehearsal else ("gmm.kernel",)))  # megablox's, through `grouped_product`
 
 
 # ======================================================================= train

@@ -1,11 +1,15 @@
 """MoE layer: routing correctness vs naive per-token loop, EP sharding, training."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
-from accelerate_tpu.ops.moe import MoEConfig, MoEMLP, moe_sharding_rules
+from accelerate_tpu.ops import moe
+from accelerate_tpu.ops.moe import MoEConfig, MoEMLP, held_experts_mlp, moe_sharding_rules
 from accelerate_tpu.parallel.mesh import ParallelismConfig
 from accelerate_tpu.state import AcceleratorState, GradientState
 
@@ -117,3 +121,53 @@ def test_moe_trains():
         params, opt_state, loss = step(params, opt_state)
         losses.append(float(loss))
     assert losses[-1] < losses[0] * 0.7
+
+
+# ------------------------------------------- the held experts' grouped products
+def _small_tiles(k, n):
+    return 16, min(k, 128), min(n, 128)
+
+
+HELD = 6  # of a router 8 wide: ids 6 and 7 are another chip's
+
+
+@pytest.mark.parametrize("picks_of, tokens, hidden, tiling", [
+    # expert ids a token's picks cycle through
+    pytest.param([(0, 2), (2, 5), (5, 0)], 24, 128, _small_tiles, id="empty-groups-between-full-ones"),
+    pytest.param([(6, 7)], 16, 128, _small_tiles, id="every-pick-absent"),
+    pytest.param([(6, 7)], 13, 64, None, id="every-pick-absent-padded-rows"),
+    pytest.param([(1, 3)] * 9 + [(0, 7)], 30, 128, _small_tiles, id="a-group-straddles-row-tiles"),
+    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 64, None, id="rows-not-a-multiple-of-128"),
+    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 128, _small_tiles, id="rows-not-a-multiple-of-16"),
+    pytest.param([(3, 4), (4, 6), (0, 3)], 24, 192, _small_tiles, id="k-not-a-multiple-of-its-tile"),
+])
+def test_pallas_grouped_product_matches_ragged_dot(monkeypatch, picks_of, tokens, hidden, tiling):
+    """`held_experts_mlp` through the Pallas grouped matmul (interpreted; the
+    path a TPU takes) against the same call through `jax.lax.ragged_dot`."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    from accelerate_tpu.utils import environment
+
+    width = 64
+    ks = jax.random.split(jax.random.key(len(picks_of) + tokens), 4)
+    x = jax.random.normal(ks[0], (tokens, hidden), jnp.float32).astype(jnp.bfloat16)
+    idx = jnp.asarray([picks_of[t % len(picks_of)] for t in range(tokens)], jnp.int32)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], idx.shape), -1)
+    w_gate_up = (jax.random.normal(ks[2], (HELD, hidden, 2 * width)) * 0.1).astype(jnp.bfloat16)
+    w_down = (jax.random.normal(ks[3], (HELD, width, hidden)) * 0.1).astype(jnp.bfloat16)
+    want, picks, touched = held_experts_mlp(x, weights, idx, w_gate_up, w_down)
+
+    monkeypatch.setattr(environment, "on_tpu_platform", lambda: True)
+    monkeypatch.setattr(megablox, "gmm", functools.partial(megablox.gmm, interpret=True))
+    if tiling is not None:
+        monkeypatch.setattr(moe, "grouped_tiling", tiling)
+    before = moe.GROUPED_PRODUCT_TRACES.copy()
+    got, got_picks, got_touched = held_experts_mlp(x, weights, idx, w_gate_up, w_down)
+    assert moe.GROUPED_PRODUCT_TRACES - before == {("pallas", idx.size): 2}
+    assert (int(got_picks), int(got_touched)) == (int(picks), int(touched))
+    assert np.isfinite(np.asarray(got)).all()
+    if int(picks) == 0:
+        np.testing.assert_array_equal(got, np.zeros_like(got))  # no tile visited: exactly nothing
+    else:
+        assert float(jnp.abs(want).max()) > 1e-2
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)

@@ -327,6 +327,23 @@ def test_step_counters_and_state_gauges(cfg, model_cfg, params):
     assert stats["block_pool/pool_bytes"] == stats["slot_pool_bytes"] - stats["slot_state_bytes"]
 
 
+def test_grouped_product_counters_name_each_programs_path(cfg, model_cfg, params):
+    """Which path the grouped expert products of each compiled program took is
+    fixed when it is traced, and counted there: off the TPU, `ragged_dot`."""
+    layers, k = model_cfg.num_hidden_layers, model_cfg.num_experts_per_tok
+    for _ in range(2):  # an engine counts its own programs, whatever was traced before it
+        engine = engine_for(Qwen3NextForCausalLM(model_cfg), params)
+        serve(engine, prompts_of(cfg, (10, 12)), 3)
+        snap = engine.metrics.snapshot()
+        products = {key.removeprefix("serving/grouped_product/"): calls for key, calls in snap.items()
+                    if key.startswith("serving/grouped_product/")}
+        assert products == {
+            "pallas_calls": 0, "ragged_dot_calls": 4 * layers,
+            f"step@mesh1x1/ragged_dot/{2 * k}": 2 * layers,  # two slots' picks
+            f"admit[pb32b2]@mesh1x1/ragged_dot/{2 * 32 * k}": 2 * layers,  # two rows of the 32 bucket
+        }
+
+
 @pytest.mark.parametrize("argument", [{"prefix_cache": True}, {"kv_tier": True},
                                       {"speculation": "ngram"}, {"mesh": (1, 2)}])
 def test_engine_refuses_what_recurrent_state_cannot_do(model_cfg, params, argument):
@@ -353,7 +370,10 @@ def test_gpt2_declares_keys_and_values_only_and_counts_nothing():
                            prompt_buckets=(32,), paged_kv=True)
     serve(engine, [[1, 2, 3]], 4)
     assert engine.metrics.step_counters == {} and "slot_state_bytes" not in engine.memory_stats()
-    assert not any(k.startswith("serving/step_counters") for k in engine.metrics.snapshot())
+    snap = engine.metrics.snapshot()
+    assert not any(k.startswith("serving/step_counters") for k in snap)
+    assert {k: v for k, v in snap.items() if k.startswith("serving/grouped_product")} == {
+        "serving/grouped_product/pallas_calls": 0, "serving/grouped_product/ragged_dot_calls": 0}
 
 
 def test_a_model_without_a_contract_is_refused():

@@ -345,7 +345,8 @@ def test_kimi_k2_scopes_and_kernel_name(topology, compiled_kernels, program):
     """The model's `jax.named_scope`s reach the compiled HLO's `op_name`, and
     the decode step's fused kernel keeps the flax scope's name (`%attn.N`, what
     the benchmark's readers look for) while the absorbing products around it
-    carry `mla_absorb`. Published attention widths, everything else small."""
+    carry `mla_absorb`; no program holds a `ragged_dot`. Published attention
+    widths, everything else small."""
     import dataclasses
 
     from accelerate_tpu.models.kimi_k2 import KimiK2Config, KimiK2ForCausalLM
@@ -381,6 +382,8 @@ def test_kimi_k2_scopes_and_kernel_name(topology, compiled_kernels, program):
     for scope in scopes:
         assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
     kernels = re.findall(r'%([\w\-]+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    # the one expert layer's two grouped products, decode step and admit alike (`%gmm.N`)
+    assert kernels.count("gmm") == 2 and "ragged-dot" not in hlo, kernels
     if program == "step":
         assert "attn" in kernels and "mla_absorb" not in kernels, kernels
         assert "mla_prefill" not in hlo
@@ -389,22 +392,37 @@ def test_kimi_k2_scopes_and_kernel_name(topology, compiled_kernels, program):
 
 
 # ------------------------------------- the expert layer's grouped products
-@pytest.mark.parametrize("tokens, pallas", [(512, True), (1536, True), (128, False)])
-def test_held_experts_grouped_product(topology, compiled_kernels, tokens, pallas):
-    """One expert-parallel chip's share at the Qwen3-Next cell's widths (256
-    held of 512 experts of width 512, hidden 2048, 10 picks a token): a prompt
-    bucket's picks go through the Pallas grouped matmul inside its 16 MiB of
-    VMEM, a decode step's 1,280 keep XLA's `ragged_dot`."""
+# (hidden, expert width, experts held, picks a token)
+QWEN3_NEXT_EXPERTS = (2048, 512, 256, 10)  # 256 held of 512 experts
+KIMI_K2_EXPERTS = (7168, 2048, 12, 8)  # 12 held of 384 experts
+
+
+@pytest.mark.parametrize("tokens, experts", [
+    pytest.param(512, QWEN3_NEXT_EXPERTS, id="qwen3next-admit-512"),
+    pytest.param(1536, QWEN3_NEXT_EXPERTS, id="qwen3next-admit-1536"),
+    pytest.param(128, QWEN3_NEXT_EXPERTS, id="qwen3next-decode-128"),
+    pytest.param(256, KIMI_K2_EXPERTS, id="kimik2-decode-256"),
+    pytest.param(24, QWEN3_NEXT_EXPERTS, id="rows-240-padded-to-the-tile"),
+])
+def test_held_experts_grouped_product(topology, compiled_kernels, tokens, experts):
+    """One expert-parallel chip's share at the two MoE cells' widths: a prompt
+    bucket's picks, a decode step's (1,280 rows at 3 a group; Kimi K2's 2,048
+    of which 64 are held, in tiles of 7,168 x 4,096 and 2,048 x 7,168) and a
+    row count the row tile does not divide all compile to the Pallas grouped
+    matmul inside its 16 MiB of VMEM, twice, and to no `ragged_dot`."""
     from accelerate_tpu.ops.moe import held_experts_mlp
 
     s = _one_device(topology)
-    hidden, width, held, k = 2048, 512, 256, 10
-    compiled = _compile(
+    hidden, width, held, k = experts
+    hlo = _compile(
         lambda x, p, idx, wgu, wd: held_experts_mlp(x, p, idx, wgu, wd)[0],
         _sds((tokens, hidden), jnp.bfloat16, s), _sds((tokens, k), jnp.float32, s),
         _sds((tokens, k), jnp.int32, s), _sds((held, hidden, 2 * width), jnp.bfloat16, s),
-        _sds((held, width, hidden), jnp.bfloat16, s))
-    assert (compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2) is pallas
+        _sds((held, width, hidden), jnp.bfloat16, s)).as_text()
+    kernels = re.findall(r"%(\S+) = f32\[(\d+),\d+\]\S* custom-call\(.*tpu_custom_call", hlo)
+    rows = -(-tokens * k // 128) * 128
+    assert [(name.partition(".")[0], int(r)) for name, r in kernels] == [("gmm", rows)] * 2, kernels
+    assert "ragged-dot" not in hlo  # the instruction's name; a caller's may ride in the stack frames
 
 
 # ------------------------------------------- the sampling tail's conditional
