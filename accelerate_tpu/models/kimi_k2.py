@@ -222,6 +222,92 @@ def absorbed_attention(q_abs: jax.Array, rows: jax.Array, mask: jax.Array, scale
     return jnp.einsum("bhst,btv->bshv", weights, rows[..., :value_dim])
 
 
+def latent_attend(mod: nn.Module, cfg, q_nope, q_pe, c, k_pe, kv_b, scale: float, decode=False,
+                  fresh_prefill=False, cache_write_mask=None, block_tables=None,
+                  cache_write_len=None) -> jax.Array:
+    """Latent attention from its projected parts to the heads' outputs ``[b, s,
+    h, dv]``, through ``mod``'s cache in decode mode (module docstring: the
+    plain form for a segment that starts a sequence, the absorbed form on top
+    of cached rows). ``q_nope [b, s, h, nope]``, ``q_pe [b, s, h, rope]`` and
+    ``k_pe [b, s, 1, rope]`` rotated, ``c [b, s, rank]`` normed, ``kv_b [rank,
+    h, nope + dv]``. ``cfg`` gives ``latent_row_lanes``, ``attention_impl``,
+    ``n_positions`` and the engine's cache switches: any model's config whose
+    contract declares the latent leaf."""
+    b, s, h, nope = q_nope.shape
+    rope, rank, lanes = q_pe.shape[-1], c.shape[-1], cfg.latent_row_lanes
+    dv = kv_b.shape[-1] - nope
+
+    def plain():
+        """Multi-head attention of the segment over itself (query/key 192,
+        value 128). `attention` takes one head size and scales by its
+        inverse root: the value is padded with zeros to the keys' width,
+        and the scale's other factors ride on the query."""
+        with jax.named_scope("mla_prefill"):
+            kv = jnp.einsum("bsc,chd->bshd", c, kv_b)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, rope))], -1)
+            fold = scale * (nope + rope) ** 0.5
+            qs = jnp.concatenate([q_nope, q_pe], -1) * jnp.asarray(fold, q_nope.dtype)
+            v = jnp.pad(kv[..., nope:], ((0, 0), (0, 0), (0, 0), (0, nope + rope - dv)))
+            # blocks of 512 divide both serving buckets the benchmark uses;
+            # `auto` then takes the flash kernel on the chip from 1,024 up
+            blocks = dict(block_q=512, block_kv=512) if s % 512 == 0 else {}
+            return attention(qs, k, v, causal=True, implementation=cfg.attention_impl,
+                             **blocks)[..., :dv]
+
+    def absorbed(attend):
+        """``attend(q_abs [b, s, h, lanes]) -> o_lat [b, s, h, rank]``, between
+        the two absorbing products. ``attend`` runs outside their scope: the
+        fused kernel keeps the name of the flax scope, ``%attn.N``, as the
+        other models' does."""
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bshn,chn->bshc", q_nope, kv_b[..., :nope])
+            q_abs = jnp.concatenate(
+                [q_lat, q_pe, jnp.zeros((b, s, h, lanes - rank - rope), q_nope.dtype)], -1)
+        o_lat = attend(q_abs)
+        with jax.named_scope("mla_absorb"):
+            return jnp.einsum("bshc,chv->bshv", o_lat, kv_b[..., nope:])
+
+    # the row the cache keeps: after the norm and after the rotation
+    row = jnp.concatenate([c[:, :, None, :], k_pe,
+                           jnp.zeros((b, s, 1, lanes - rank - rope), c.dtype)], -1)
+    if not decode:
+        return plain()
+    if cfg.kv_cache_paged and cfg.kv_paged_attention == "fused" and s == 1 \
+            and cache_write_len is None:
+        from ..ops.flash_attention import paged_decode_attention
+        from .kv_cache import paged_decode_write
+
+        pool, _, idx, is_init, _ = paged_decode_write(
+            mod, row, None, cfg.kv_num_blocks, cfg.kv_block_tokens, block_tables,
+            kv_cache_dtype=cfg.kv_cache_dtype, write_mask=cache_write_mask,
+            sharding=cfg.kv_cache_sharding)
+        if not is_init:
+            return plain()
+        return absorbed(lambda q_abs: paged_decode_attention(
+            q_abs[:, 0], pool, None, block_tables, idx + 1, value_dim=rank,
+            scale=scale)[:, None])
+    if cfg.kv_cache_paged:
+        from .kv_cache import paged_decode_update
+
+        rows, _, idx, is_init = paged_decode_update(
+            mod, row, None, cfg.kv_num_blocks, cfg.kv_block_tokens, block_tables,
+            kv_cache_dtype=cfg.kv_cache_dtype, write_mask=cache_write_mask,
+            write_len=cache_write_len, sharding=cfg.kv_cache_sharding)
+    else:
+        from .kv_cache import decode_cache_update
+
+        rows, _, idx, is_init = decode_cache_update(
+            mod, row, None, cfg.n_positions, kv_cache_dtype=cfg.kv_cache_dtype,
+            per_slot=cfg.kv_cache_per_slot, write_mask=cache_write_mask,
+            write_len=cache_write_len, sharding=cfg.kv_cache_sharding)
+    if not is_init or fresh_prefill:
+        # nothing earlier to read: the segment attends itself
+        return plain()
+    q_pos = jnp.reshape(idx, (-1, 1, 1)) + jnp.arange(s)[None, :, None]
+    mask = (jnp.arange(rows.shape[1])[None, None, :] <= q_pos)[:, None]
+    return absorbed(lambda q_abs: absorbed_attention(q_abs, rows[:, :, 0], mask, scale, rank))
+
+
 class LatentAttention(nn.Module):
     config: KimiK2Config
 
@@ -232,7 +318,7 @@ class LatentAttention(nn.Module):
         b, s, _ = x.shape
         h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
-        rank, lanes = cfg.kv_lora_rank, cfg.latent_row_lanes
+        rank = cfg.kv_lora_rank
         q = _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x)
         q = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="q_a_norm")(q)
         q = _dense(cfg, h * (nope + rope), "q_b_proj")(q).reshape(b, s, h, nope + rope)
@@ -242,80 +328,8 @@ class LatentAttention(nn.Module):
         k_pe = yarn_rope(ckv[..., None, rank:], positions, cfg)  # [b, s, 1, rope]
         kv_b = self.param("kv_b_proj", nn.initializers.normal(0.02),
                           (rank, h, nope + dv), cfg.param_dtype).astype(cfg.dtype)
-
-        def plain():
-            """Multi-head attention of the segment over itself (query/key 192,
-            value 128). `attention` takes one head size and scales by its
-            inverse root: the value is padded with zeros to the keys' width,
-            and the YaRN factor of the scale rides on the query."""
-            with jax.named_scope("mla_prefill"):
-                kv = jnp.einsum("bsc,chd->bshd", c, kv_b)
-                k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, rope))], -1)
-                fold = cfg.softmax_scale * (nope + rope) ** 0.5
-                qs = jnp.concatenate([q_nope, q_pe], -1) * jnp.asarray(fold, q.dtype)
-                v = jnp.pad(kv[..., nope:], ((0, 0), (0, 0), (0, 0), (0, nope + rope - dv)))
-                # blocks of 512 divide both serving buckets the benchmark uses;
-                # `auto` then takes the flash kernel on the chip from 1,024 up
-                blocks = dict(block_q=512, block_kv=512) if s % 512 == 0 else {}
-                return attention(qs, k, v, causal=True, implementation=cfg.attention_impl,
-                                 **blocks)[..., :dv]
-
-        def absorbed(attend):
-            """``attend(q_abs [b, s, h, lanes]) -> o_lat [b, s, h, rank]``, between
-            the two absorbing products. ``attend`` runs outside their scope: the
-            fused kernel keeps the name of the flax scope, ``%attn.N``, as the
-            other models' does."""
-            with jax.named_scope("mla_absorb"):
-                q_lat = jnp.einsum("bshn,chn->bshc", q_nope, kv_b[..., :nope])
-                q_abs = jnp.concatenate(
-                    [q_lat, q_pe, jnp.zeros((b, s, h, lanes - rank - rope), q.dtype)], -1)
-            o_lat = attend(q_abs)
-            with jax.named_scope("mla_absorb"):
-                return jnp.einsum("bshc,chv->bshv", o_lat, kv_b[..., nope:])
-
-        # the row the cache keeps: after the norm and after the rotation
-        row = jnp.concatenate([c[:, :, None, :], k_pe,
-                               jnp.zeros((b, s, 1, lanes - rank - rope), c.dtype)], -1)
-        if not decode:
-            out = plain()
-        elif cfg.kv_cache_paged and cfg.kv_paged_attention == "fused" and s == 1 \
-                and cache_write_len is None:
-            from ..ops.flash_attention import paged_decode_attention
-            from .kv_cache import paged_decode_write
-
-            pool, _, idx, is_init, _ = paged_decode_write(
-                self, row, None, cfg.kv_num_blocks, cfg.kv_block_tokens, block_tables,
-                kv_cache_dtype=cfg.kv_cache_dtype, write_mask=cache_write_mask,
-                sharding=cfg.kv_cache_sharding)
-            if is_init:
-                out = absorbed(lambda q_abs: paged_decode_attention(
-                    q_abs[:, 0], pool, None, block_tables, idx + 1, value_dim=rank,
-                    scale=cfg.softmax_scale)[:, None])
-            else:
-                out = plain()
-        else:
-            if cfg.kv_cache_paged:
-                from .kv_cache import paged_decode_update
-
-                rows, _, idx, is_init = paged_decode_update(
-                    self, row, None, cfg.kv_num_blocks, cfg.kv_block_tokens, block_tables,
-                    kv_cache_dtype=cfg.kv_cache_dtype, write_mask=cache_write_mask,
-                    write_len=cache_write_len, sharding=cfg.kv_cache_sharding)
-            else:
-                from .kv_cache import decode_cache_update
-
-                rows, _, idx, is_init = decode_cache_update(
-                    self, row, None, cfg.n_positions, kv_cache_dtype=cfg.kv_cache_dtype,
-                    per_slot=cfg.kv_cache_per_slot, write_mask=cache_write_mask,
-                    write_len=cache_write_len, sharding=cfg.kv_cache_sharding)
-            if not is_init or fresh_prefill:
-                # nothing earlier to read: the segment attends itself
-                out = plain()
-            else:
-                q_pos = jnp.reshape(idx, (-1, 1, 1)) + jnp.arange(s)[None, :, None]
-                mask = (jnp.arange(rows.shape[1])[None, None, :] <= q_pos)[:, None]
-                out = absorbed(lambda q_abs: absorbed_attention(
-                    q_abs, rows[:, :, 0], mask, cfg.softmax_scale, rank))
+        out = latent_attend(self, cfg, q_nope, q_pe, c, k_pe, kv_b, cfg.softmax_scale, decode,
+                            fresh_prefill, cache_write_mask, block_tables, cache_write_len)
         return _dense(cfg, cfg.hidden_size, "o_proj")(out.reshape(b, s, h * dv))
 
 

@@ -12,6 +12,15 @@ the short depthwise convolution in front of it. All plain XLA under
 `jax.named_scope`s (``delta_step`` / ``delta_prefill``) so the device trace can
 find them.
 
+The log decay ``g`` is one scalar a head (``[..., h]``: Gated DeltaNet) or one
+a key channel (``[..., h, dk]``: Kimi Delta Attention, arXiv:2510.26692, ``S <-
+Diag(exp(g_t)) S``; scopes ``kda_step`` / ``kda_prefill``). The step differs by
+how ``exp(g)`` broadcasts over ``S``. The chunked form does not carry over: a
+scalar decay multiplies ``k_i . k_j`` by one ``exp(cum_i - cum_j)``, a
+per-channel one sits inside the contraction, ``sum_c k_i[c] exp(cum_i[c] -
+cum_j[c]) k_j[c]``, and `kda_prefill` factors it around a reference that keeps
+every exponent inside float32 (its docstring).
+
 Precision: ``S`` and the arithmetic on it stay float32 at `HIGHEST` — the TPU
 otherwise multiplies float32 operands in one bf16 pass, which is a different
 state after a few hundred tokens. The matmuls here are small beside the
@@ -31,11 +40,12 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def mask_pad(g: jax.Array, beta: jax.Array, lengths: jax.Array | None):
-    """Zero ``g`` and ``beta`` ([b, t, h]) at positions >= ``lengths`` ([b])."""
+    """Zero ``g`` ([b, t, h] or [b, t, h, dk]) and ``beta`` ([b, t, h]) at
+    positions >= ``lengths`` ([b])."""
     if lengths is None:
         return g, beta
     real = (jnp.arange(g.shape[1])[None, :] < lengths[:, None])[..., None]
-    return jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    return jnp.where(real.reshape(real.shape + (1,) * (g.ndim - 3)), g, 0.0), jnp.where(real, beta, 0.0)
 
 
 def gated_delta_step(
@@ -43,15 +53,17 @@ def gated_delta_step(
     q: jax.Array,  # [b, h, dk]
     k: jax.Array,  # [b, h, dk]
     v: jax.Array,  # [b, h, dv]
-    g: jax.Array,  # [b, h] log decay (<= 0)
+    g: jax.Array,  # [b, h] log decay (<= 0), or [b, h, dk]: one a key channel
     beta: jax.Array,  # [b, h]
 ) -> tuple[jax.Array, jax.Array]:
     """One token: returns ``(new_state, o [b, h, dv])`` in float32. Products
     with the state are elementwise-and-sum on purpose: a batched matvec gives
     the MXU nothing, and the VPU keeps float32."""
-    with jax.named_scope("delta_step"):
+    per_channel = g.ndim == k.ndim
+    with jax.named_scope("kda_step" if per_channel else "delta_step"):
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        state = state * jnp.exp(g.astype(jnp.float32))[..., None, None]
+        decay = jnp.exp(g.astype(jnp.float32))
+        state = state * (decay[..., :, None] if per_channel else decay[..., None, None])
         read = jnp.sum(state * k[..., :, None], axis=-2)  # S^T k
         d = beta.astype(jnp.float32)[..., None] * (v - read)
         state = state + k[..., :, None] * d[..., None, :]
@@ -88,6 +100,24 @@ def unit_lower_inverse(system: jax.Array) -> jax.Array:
     return jnp.concatenate([top, jnp.concatenate([below, b], axis=-1)], axis=-2)
 
 
+def _chunked(x: jax.Array, chunk: int) -> jax.Array:
+    """``[b, t, h, ...] -> [n, b, h, chunk, ...]`` in float32, ``t`` padded
+    with zeros to whole chunks."""
+    b, t = x.shape[:2]
+    pad = (-t) % chunk
+    x = x.astype(jnp.float32)
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((b, (t + pad) // chunk, chunk) + x.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+
+def _unchunked(o: jax.Array, t: int) -> jax.Array:
+    """``[n, b, h, chunk, dv] -> [b, t, h, dv]``, the pad dropped."""
+    n, b, h, chunk, dv = o.shape
+    return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2).reshape(b, n * chunk, h, dv)[:, :t]
+
+
 def gated_delta_prefill(
     q: jax.Array,  # [b, t, h, dk]
     k: jax.Array,  # [b, t, h, dk]
@@ -98,19 +128,14 @@ def gated_delta_prefill(
     chunk: int = 64,
 ) -> tuple[jax.Array, jax.Array]:
     """A whole segment: returns ``(o [b, t, h, dv], final_state)`` in float32,
-    equal to ``t`` calls of `gated_delta_step`."""
+    equal to ``t`` calls of `gated_delta_step`. A per-channel ``g [b, t, h,
+    dk]`` goes to `kda_prefill`."""
+    if g.ndim == q.ndim:
+        return kda_prefill(q, k, v, g, beta, state, chunk)
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    pad = (-t) % chunk
     with jax.named_scope("delta_prefill"):
-        def chunks(x):  # [b, t, h, ...] -> [n, b, h, chunk, ...]
-            x = x.astype(jnp.float32)
-            if pad:
-                x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            x = x.reshape((b, (t + pad) // chunk, chunk) + x.shape[2:])
-            return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-
-        q, k, v, g, beta = chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta)
+        q, k, v, g, beta = (_chunked(x, chunk) for x in (q, k, v, g, beta))
         cum = jnp.cumsum(g, axis=-1)  # [n, b, h, c] log decay since the chunk began
         lower = jnp.tril(jnp.ones((chunk, chunk), bool))
         # decay from token j to token i >= j; the exponent is masked first so
@@ -140,8 +165,85 @@ def gated_delta_prefill(
             return S, o
 
         state, o = jax.lax.scan(one, state.astype(jnp.float32), (q, k, u, w, qk, cum))
-        o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2).reshape(b, t + pad, h, dv)
-        return o[:, :t], state
+        return _unchunked(o, t), state
+
+
+KDA_SUB_CHUNK = 16  # tokens: with g >= -5 a token, |cum| inside one stays <= 80 < ln(float32 max) = 88.7
+
+
+def kda_prefill(
+    q: jax.Array,  # [b, t, h, dk]
+    k: jax.Array,  # [b, t, h, dk]
+    v: jax.Array,  # [b, t, h, dv]
+    g: jax.Array,  # [b, t, h, dk] log decay a key channel, in [-5, 0]
+    beta: jax.Array,  # [b, t, h]
+    state: jax.Array | None = None,  # [b, h, dk, dv] float32, zeros if None
+    chunk: int = 64,
+) -> tuple[jax.Array, jax.Array]:
+    """`gated_delta_prefill` for a decay that is a vector over the key
+    channels: ``(o [b, t, h, dv], final_state)`` in float32, equal to ``t``
+    calls of `gated_delta_step` with ``g [b, h, dk]``.
+
+    The same WY form, with ``cum`` ``[.., c, dk]``. Between chunks nothing
+    changes but the broadcast: every factor there is ``exp`` of something
+    ``<= 0``. Inside a chunk the pair products ``P[i, j] = sum_c x_i[c]
+    exp(cum_i[c] - cum_j[c]) k_j[c]`` (``x`` = ``k beta`` for the triangular
+    system, ``q`` for the outputs; only ``j <= i`` is used) have to be one
+    matmul, so the exponent is split around a reference ``r``: ``(x_i
+    exp(cum_i - r)) . (k_j exp(r - cum_j))``. The plain choice ``r = 0``
+    overflows float32 once ``-cum_j`` passes 88. Here the rows are cut into
+    sub-chunks of `KDA_SUB_CHUNK` tokens and sub-chunk ``a`` takes ``r_a`` =
+    ``cum`` at its start: the left factor is then in ``[exp(-80), 1]``; the
+    right factor is ``<= 1`` for every ``j`` of an earlier sub-chunk and ``<=
+    exp(80)`` inside the same one, given ``g >= -5`` a token (the bound KDA's
+    gate keeps, ``kda_lower_bound``); later ``j`` are never used and are
+    zeroed before the ``exp``. The materialised ``[c, c, dk]`` form would be
+    1.6 GB for an admit of 4 x 1,536 tokens even at ``c = 16``."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    sub = min(KDA_SUB_CHUNK, chunk)
+    if chunk % sub:
+        raise ValueError(f"chunk {chunk} is not a multiple of the sub-chunk {sub}")
+    n_sub = chunk // sub
+    with jax.named_scope("kda_prefill"):
+        q, k, v, g, beta = (_chunked(x, chunk) for x in (q, k, v, g, beta))
+        cum = jnp.cumsum(g, axis=-2)  # [n, b, h, c, dk] log decay since the chunk began
+        lead = cum.shape[:-2]
+        # r_a: cum at the last token before sub-chunk a (0 for the first)
+        ref = jnp.concatenate([jnp.zeros(lead + (1, dk), jnp.float32),
+                               cum[..., sub - 1:: sub, :][..., : n_sub - 1, :]], axis=-2)
+        left = jnp.exp(cum - jnp.repeat(ref, sub, axis=-2))  # [.., c, dk], in [exp(-80), 1]
+        seen = jnp.arange(chunk)[None, :] < (jnp.arange(n_sub)[:, None] + 1) * sub  # [n_sub, c]
+        right = jnp.exp(jnp.where(seen[..., None], ref[..., :, None, :] - cum[..., None, :, :], -jnp.inf))
+        k_right = k[..., None, :, :] * right  # [.., n_sub, c, dk]
+
+        def pairs(x):  # [.., c, dk] -> P [.., c, c], right where j <= i
+            x = (x * left).reshape(lead + (n_sub, sub, dk))
+            return jnp.einsum("...aik,...ajk->...aij", x, k_right, precision=HIGHEST).reshape(
+                lead + (chunk, chunk))
+
+        k_beta, v_beta = k * beta[..., None], v * beta[..., None]
+        system = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1), pairs(k_beta), 0.0) \
+            + jnp.eye(chunk, dtype=jnp.float32)
+        rhs = jnp.concatenate([v_beta, k_beta * jnp.exp(cum)], axis=-1)
+        solved = jnp.einsum("...ij,...jv->...iv", unit_lower_inverse(system), rhs, precision=HIGHEST)
+        u, w = solved[..., :dv], solved[..., dv:]
+        qk = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool)), pairs(q), 0.0)
+        if state is None:
+            state = jnp.zeros((b, h, dk, dv), jnp.float32)
+
+        def one(S, xs):
+            q_c, k_c, u_c, w_c, qk_c, cum_c = xs
+            v_new = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, S, precision=HIGHEST)
+            o = jnp.einsum("bhck,bhkv->bhcv", q_c * jnp.exp(cum_c), S, precision=HIGHEST) \
+                + jnp.einsum("bhij,bhjv->bhiv", qk_c, v_new, precision=HIGHEST)
+            last = cum_c[..., -1:, :]
+            S = S * jnp.exp(last[..., 0, :, None]) + jnp.einsum(
+                "bhck,bhcv->bhkv", k_c * jnp.exp(last - cum_c), v_new, precision=HIGHEST)
+            return S, o
+
+        state, o = jax.lax.scan(one, state.astype(jnp.float32), (q, k, u, w, qk, cum))
+        return _unchunked(o, t), state
 
 
 def causal_conv_prefill(

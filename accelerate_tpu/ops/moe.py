@@ -27,7 +27,8 @@ the TPU, for a prefill's rows and a decode step's alike, `jax.lax.ragged_dot`
 on any other backend). `shared_expert_mlp` is
 the always-on expert (behind a sigmoid gate, or ungated) that such models put
 beside the routed ones. Two routers: `route_top_k` (softmax over all experts)
-and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias).
+and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias,
+within the best few groups of experts where the model limits the choice).
 """
 
 from __future__ import annotations
@@ -168,16 +169,30 @@ def route_top_k(x: jax.Array, router: jax.Array, top_k: int) -> tuple[jax.Array,
 
 
 def route_sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
-                        scaling: float = 1.0) -> tuple[jax.Array, jax.Array]:
+                        scaling: float = 1.0, n_group: int = 1,
+                        topk_group: int = 1) -> tuple[jax.Array, jax.Array]:
     """Sigmoid scores ``s = sigmoid(x W_g)`` in float32 over ALL of the
     router's experts; the ``top_k`` with the largest ``s + bias`` are chosen
     (the bias chooses, it never weighs); their own scores, renormalised to sum
-    1 and times ``scaling``, are the weights: ``(weights [T, k], ids [T, k])``."""
+    1 and times ``scaling``, are the weights: ``(weights [T, k], ids [T, k])``.
+
+    ``n_group > 1`` is DeepSeek-V3's group-limited choice: the experts lie in
+    ``n_group`` groups of equal size, a group's score is the sum of its two
+    largest ``s + bias``, only the ``topk_group`` best groups are kept, and
+    the ``top_k`` are chosen inside them. Under expert parallelism a chip
+    holds whole groups, so a token sends it several picks or none."""
     with jax.named_scope("moe_router"):
         logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        choose = scores + bias.astype(jnp.float32)
+        if n_group > 1:
+            grouped = choose.reshape(choose.shape[:-1] + (n_group, -1))
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+            _, kept = jax.lax.top_k(group_score, topk_group)
+            keep = jax.nn.one_hot(kept, n_group, dtype=bool).any(-2)
+            choose = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choose.shape)
+        _, idx = jax.lax.top_k(choose, top_k)
         top = jnp.take_along_axis(scores, idx, axis=-1)
         return scaling * top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20), idx
 

@@ -428,7 +428,8 @@ class ServingEngine:
     ``module`` is any causal LM whose config declares a cache contract
     (``config.cache_contract()``, `models/kv_cache.CacheContract`: GPT-2's keys
     and values, keys and values beside per-slot recurrent state, or one
-    latent leaf a layer with the value inside the key row); the
+    latent leaf a layer with the value inside the key row, Ling 3.0's
+    per-slot KDA state beside one latent leaf in one layer of six); the
     engine re-instantiates it with its cache switches on, so callers pass the
     same module they would hand to ``generate``. ``params`` is the matching
     param tree. The context length is the config's ``n_positions``. A model
@@ -506,8 +507,8 @@ class ServingEngine:
                 f"{type(module).__name__}'s config declares no cache contract; "
                 "the serving engine needs `config.cache_contract()` and the "
                 "per-slot cache switches it describes (models/kv_cache.py "
-                "CacheContract) — GPT2LMHead, Qwen3NextForCausalLM and "
-                "KimiK2ForCausalLM have them."
+                "CacheContract) — GPT2LMHead, Qwen3NextForCausalLM, "
+                "KimiK2ForCausalLM and Ling3ForCausalLM have them."
             )
         contract = self._contract = cfg.cache_contract()
         if contract.state_leaves:

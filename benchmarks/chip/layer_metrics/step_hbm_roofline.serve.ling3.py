@@ -1,0 +1,31 @@
+"""The whole decode step's share of its memory roofline for Ling 3.0 flash:
+the least bytes one step has to move (`flops_ling3.decode_step_bytes`: the
+routed experts the step touched, from the program's `moe_experts_touched`
+counter over the traced slice; every other held weight once; the KDA state
+and convolution windows read and written; the live latent rows, from the
+program's `serving/paged_decode/live_tokens` count) at the chip's HBM
+bandwidth, over the step's device time (`steps_ling3.step_device_ns`: the
+median busy time from one step's decode kernel to the next one's with no admit
+program between them). In percent."""
+
+import flops_ling3 as flops
+import peaks
+import steps_ling3 as steps
+
+
+def read(run):
+    cell = run["cell"]
+    step_ns, counted, live = steps.step_device_ns(run), steps.per_step(run), steps.live_tokens(run)
+    if step_ns is None or counted is None or live is None:
+        return None
+    rows = int(cell.spec["engine"]["max_concurrency"])
+    layers = flops.expert_layers(cell.config)
+    least = flops.decode_step_bytes(cell.config, rows, counted["experts_touched"] / layers, live)
+    here = steps.rows_routed_here(run)
+    print("step bytes " + " ".join(f"{k} {v / 1e9:.3f} GB" for k, v in least.items())
+          + f" at {live:.0f} live rows, {counted['picks_held'] / layers:.1f} picks on "
+          f"{counted['experts_touched'] / layers:.1f} experts a layer"
+          + ("" if here is None else f" from {here:.1f} of {rows} rows")
+          + f" over {counted['steps']} counted steps", flush=True)
+    bandwidth = peaks.peaks_for(run["peaks_kind"])["hbm_bytes_per_s"]
+    return 100.0 * (least["total"] / bandwidth) / (step_ns / 1e9)

@@ -8,7 +8,8 @@ file compiles each kernel at gpt2-medium shapes with ``interpret=False`` — on
 one device and inside a four-device jit. It checks that the program builds;
 only a chip run (`chip_smoke.py`) checks what it computes. Marked slow, all
 but the guards at the end: the paged pool's layout (two compiles, about 3 s),
-the expert layer's grouped products, and the sampling tail's conditional.
+the latent pool and the two latent-attention models' scopes, the expert
+layer's grouped products, and the sampling tail's conditional.
 """
 
 import functools
@@ -389,6 +390,59 @@ def test_kimi_k2_scopes_and_kernel_name(topology, compiled_kernels, program):
         assert "mla_prefill" not in hlo
     else:
         assert "mla_prefill" in kernels and "mla_absorb" not in hlo, kernels  # the flash kernel, plain form
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_ling3_scopes_and_kernel_name(topology, compiled_kernels, program):
+    """Ling 3.0 flash's scopes reach the compiled HLO's `op_name`: the
+    per-channel delta rule's (`kda_step` in a decode step, `kda_prefill` in an
+    admit) beside the latent layer's, the router's, the experts' and the dense
+    MLP's; the fused kernel of its one latent layer keeps the flax scope's
+    name (`%attn.N`); the decode step's delta rule reads and writes the whole
+    per-slot state under that shape, which is how the benchmark's reader finds
+    it. Published head sizes, everything else small; one period of six layers."""
+    import dataclasses
+
+    from accelerate_tpu.models.ling3 import Ling3Config, Ling3ForCausalLM
+
+    s = _one_device(topology)
+    cfg = Ling3Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        moe_shared_expert_intermediate_size=128, num_hidden_layers=6, num_attention_heads=4,
+        num_experts=16, experts_held=4, num_experts_per_tok=4, n_group=4, topk_group=2,
+        n_positions=2048, kv_cache_per_slot=True)
+    rows, bucket = 8, 1024
+    if program == "step":
+        cfg = dataclasses.replace(cfg, kv_cache_paged=True, kv_num_blocks=256,
+                                  kv_paged_attention="fused")
+    module = Ling3ForCausalLM(cfg)
+    tables = jnp.zeros((rows, cfg.n_positions // 16), jnp.int32)
+    extra = dict(block_tables=tables) if program == "step" else {}
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((rows, 1), jnp.int32), decode=True, **extra))
+    place = lambda tree: jax.tree.map(lambda x: _sds(x.shape, x.dtype, s), tree)  # noqa: E731
+
+    def run(params, cache, ids, lens, tables):
+        kw = dict(position_offset=lens, block_tables=tables) if program == "step" else \
+            dict(position_offset=0, cache_write_len=lens)
+        return module.apply({"params": params, "cache": cache}, ids, decode=True,
+                            mutable=["cache", "counters"], **kw)
+
+    ids = _sds((rows, 1 if program == "step" else bucket), jnp.int32, s)
+    hlo = jax.jit(run, donate_argnums=(1,)).lower(
+        place(shapes["params"]), place(shapes["cache"]), ids, _sds((rows,), jnp.int32, s),
+        _sds(tables.shape, jnp.int32, s)).compile().as_text()
+    scopes = {"step": ("kda_step", "mla_absorb", "moe_router", "moe_experts", "dense_mlp"),
+              "admit": ("kda_prefill", "mla_prefill", "moe_router", "moe_experts", "dense_mlp")}[program]
+    for scope in scopes:
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    kernels = re.findall(r'%([\w\-]+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert "gmm" in kernels and "ragged-dot" not in hlo, kernels
+    if program == "step":
+        assert kernels.count("attn") == 1 and "kda_prefill" not in hlo and "mla_prefill" not in hlo
+        assert f"f32[{rows},4,128,128]" in hlo  # the whole per-slot state, by shape
+    else:
+        assert "mla_prefill" in kernels and "kda_step" not in hlo and "mla_absorb" not in hlo
 
 
 # ------------------------------------- the expert layer's grouped products
