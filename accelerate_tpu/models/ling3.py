@@ -232,11 +232,10 @@ class KimiDeltaAttention(nn.Module):
         if is_init and s == 1 and not fresh_prefill:
             mixed, window = causal_conv_step(conv_state.value, qkv[:, 0], conv_w)
             q, k, v = heads(mixed)
+            if cache_write_mask is not None:  # a finished slot's state does not move: no decay, no write
+                g, beta = mask_pad(g, beta, cache_write_mask.astype(jnp.int32))
+                window = jnp.where(cache_write_mask.astype(bool)[:, None, None], window, conv_state.value)
             new_state, o = gated_delta_step(kda_state.value, q, k, v, g[:, 0], beta[:, 0])
-            if cache_write_mask is not None:  # a finished slot's state does not move
-                live = cache_write_mask.astype(bool)
-                window = jnp.where(live[:, None, None], window, conv_state.value)
-                new_state = jnp.where(live[:, None, None, None], new_state, kda_state.value)
             conv_state.value, kda_state.value = window, new_state
             o = o[:, None]
         else:

@@ -8,9 +8,12 @@ layer whose per-head state is a ``[key_dim, value_dim]`` matrix ``S``,
 (the WY form of the FLA kernels: inside a chunk the rule is one triangular
 system, inverted by `unit_lower_inverse`, and a few matmuls; between chunks a
 `lax.scan` carries ``S``), and `causal_conv_prefill` / `causal_conv_step` are
-the short depthwise convolution in front of it. All plain XLA under
-`jax.named_scope`s (``delta_step`` / ``delta_prefill``) so the device trace can
-find them.
+the short depthwise convolution in front of it. On the TPU the decode step is
+one Pallas kernel, `delta_step_kernel`, which reads ``S`` once and writes it
+once in place (the XLA form reads it twice: the read against ``k`` has to
+finish over the whole key axis before a row of the new ``S`` exists); the rest
+is plain XLA. All of it runs under `jax.named_scope`s (``delta_step`` /
+``delta_prefill``), which name the kernel's event (``%delta_step.N``).
 
 The log decay ``g`` is one scalar a head (``[..., h]``: Gated DeltaNet) or one
 a key channel (``[..., h, dk]``: Kimi Delta Attention, arXiv:2510.26692, ``S <-
@@ -24,7 +27,7 @@ every exponent inside float32 (its docstring).
 Precision: ``S`` and the arithmetic on it stay float32 at `HIGHEST` — the TPU
 otherwise multiplies float32 operands in one bf16 pass, which is a different
 state after a few hundred tokens. The matmuls here are small beside the
-projections around them.
+projections around them; the step's products are on the VPU, float32.
 
 Ragged rows: a row whose true length is shorter than the segment passes
 ``g = 0, beta = 0`` for its pad tokens (`mask_pad`): no decay, no write, so the
@@ -32,6 +35,9 @@ state after the segment is the state after the row's last real token.
 """
 
 from __future__ import annotations
+
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -56,18 +62,126 @@ def gated_delta_step(
     g: jax.Array,  # [b, h] log decay (<= 0), or [b, h, dk]: one a key channel
     beta: jax.Array,  # [b, h]
 ) -> tuple[jax.Array, jax.Array]:
-    """One token: returns ``(new_state, o [b, h, dv])`` in float32. Products
-    with the state are elementwise-and-sum on purpose: a batched matvec gives
-    the MXU nothing, and the VPU keeps float32."""
+    """One token: returns ``(new_state, o [b, h, dv])`` in float32. A head
+    whose ``g`` is all zero and whose ``beta`` is zero keeps its state
+    bit-equal, whatever it holds (the models pass that for a finished slot).
+    On the TPU it is `delta_step_kernel`, one pass over ``S``; any other
+    backend keeps this XLA body, products with the state elementwise-and-sum
+    so that the VPU keeps float32."""
+    from ..utils.environment import on_tpu_platform
+
     per_channel = g.ndim == k.ndim
     with jax.named_scope("kda_step" if per_channel else "delta_step"):
+        if on_tpu_platform():
+            DELTA_STEP_TRACES["pallas", state.shape[0]] += 1
+            return delta_step_kernel(state, q, k, v, g, beta)
+        DELTA_STEP_TRACES["xla", state.shape[0]] += 1
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        decay = jnp.exp(g.astype(jnp.float32))
-        state = state * (decay[..., :, None] if per_channel else decay[..., None, None])
-        read = jnp.sum(state * k[..., :, None], axis=-2)  # S^T k
-        d = beta.astype(jnp.float32)[..., None] * (v - read)
-        state = state + k[..., :, None] * d[..., None, :]
-        return state, jnp.sum(state * q[..., :, None], axis=-2)
+        g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+        decay = jnp.exp(g)
+        new = state * (decay[..., :, None] if per_channel else decay[..., None, None])
+        read = jnp.sum(new * k[..., :, None], axis=-2)  # S^T k
+        d = beta[..., None] * (v - read)
+        new = new + k[..., :, None] * d[..., None, :]
+        o = jnp.sum(new * q[..., :, None], axis=-2)
+        still = (beta == 0) & (jnp.all(g == 0, axis=-1) if per_channel else g == 0)
+        return jnp.where(still[..., None, None], state, new), o
+
+
+# Decode steps of the delta rule by the path they took and their slot count,
+# counted where the path is decided: when a program is TRACED.
+# `ServingEngine._dispatch` reads the difference around a program's first call
+# (`serving/delta_step/*`).
+DELTA_STEP_TRACES: collections.Counter = collections.Counter()
+DELTA_STEP_VMEM = 12 * 2**20  # bytes of state blocks in VMEM: one in and one out, two buffers each
+
+
+def delta_head_block(h: int, dk: int, dv: int) -> int:
+    """Heads a grid cell of `delta_step_kernel`, read off the state's shape:
+    the most that divide ``h`` (and are ``h`` or a multiple of 8, the
+    sublane tile of the ``[heads, dk]`` blocks) whose state blocks, in and
+    out and two buffers each, fit `DELTA_STEP_VMEM`. Both delta-rule cells'
+    ``[*, 32, 128, 128]`` take all 32 heads: a slot a cell, 2 MiB."""
+    blocks = [hb for hb in range(h, 0, -1) if h % hb == 0 and (hb == h or hb % 8 == 0)]
+    return next((hb for hb in blocks if 4 * hb * dk * dv * 4 <= DELTA_STEP_VMEM), blocks[-1])
+
+
+def _delta_step_kernel(s_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s_out, o_out, *, per_channel):
+    """One grid cell: ``hb`` heads of one slot. The block of ``S`` is read
+    once and written once; a head at a time (``[dk, dv]``, 16 vregs) it is
+    decayed, read against ``k``, written with ``k d^T`` and read against
+    ``q``, all float32 on the VPU. ``q``, ``k`` (and a per-channel ``g``)
+    arrive as rows ``[hb, dk]`` and are stood up once, ``[dk, hb]``, so that a
+    head's vector lies along the key axis of its block of ``S``."""
+    hb, _, dv = s_ref.shape
+    rows = [q_ref[...], k_ref[...]] + ([g_ref[...]] if per_channel else [])
+    cols = jnp.concatenate(rows, axis=0).T  # [dk, 2 hb] or [dk, 3 hb]
+    beta = beta_ref[...]  # [1, hb]
+    for j in range(hb):
+        s = s_ref[j]
+        q, k = cols[:, j: j + 1], cols[:, hb + j: hb + j + 1]
+        b = beta[:, j: j + 1]
+        if per_channel:
+            g = cols[:, 2 * hb + j: 2 * hb + j + 1]  # [dk, 1]
+            still = jnp.max(jnp.abs(g), axis=0, keepdims=True) == 0
+        else:
+            g = jnp.broadcast_to(g_ref[:, j: j + 1], (1, dv))  # Mosaic broadcasts one axis at a time
+            still = g == 0
+        new = s * jnp.exp(g)
+        d = b * (v_ref[j: j + 1, :] - jnp.sum(new * k, axis=0, keepdims=True))  # [1, dv]
+        new = new + k * d
+        o_out[j: j + 1, :] = jnp.sum(new * q, axis=0, keepdims=True)
+        # a finished head's block goes back as it came; Mosaic broadcasts a
+        # [1, 1] mask over both axes only by way of a float row
+        keep = jnp.broadcast_to(jnp.where(still & (b == 0), 1.0, 0.0), (1, dv))
+        s_out[j] = jnp.where(keep != 0, s, new)
+
+
+def delta_step_kernel(
+    state: jax.Array,  # [b, h, dk, dv] float32, updated in place
+    q: jax.Array,  # [b, h, dk]
+    k: jax.Array,  # [b, h, dk]
+    v: jax.Array,  # [b, h, dv]
+    g: jax.Array,  # [b, h] or [b, h, dk]
+    beta: jax.Array,  # [b, h]
+    *,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """`gated_delta_step` as one Pallas TPU kernel: grid (slots, head blocks
+    of `delta_head_block`), ``S`` read once and written once into its own
+    buffer (``input_output_aliases``), in its stored shape, so that XLA adds
+    no copy of the donated cache leaf. Where a head's ``g`` is zero and its
+    ``beta`` zero the block is written back as it was read. On the v5e the
+    pass takes what a Pallas copy of ``S`` with the same blocks takes (77% of
+    one read and one write at 819 GB/s; PERF.md section 6, PR 36): the
+    arithmetic hides under the copies."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ..utils.environment import on_tpu_platform
+
+    b, h, dk, dv = state.shape
+    per_channel = g.ndim == k.ndim
+    hb = delta_head_block(h, dk, dv)
+    n = h // hb
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    if not per_channel:
+        g = g.reshape(b, n, 1, hb)
+    beta = beta.reshape(b, n, 1, hb)
+    state_spec = pl.BlockSpec((None, hb, dk, dv), lambda i, j: (i, j, 0, 0))
+    heads = lambda d: pl.BlockSpec((None, hb, d), lambda i, j: (i, j, 0))  # noqa: E731
+    row = pl.BlockSpec((None, None, 1, hb), lambda i, j: (i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_delta_step_kernel, per_channel=per_channel),
+        grid=(b, n),
+        in_specs=[state_spec, heads(dk), heads(dk), heads(dv), heads(dk) if per_channel else row, row],
+        out_specs=[state_spec, heads(dv)],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((b, h, dv), jnp.float32)],
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=not on_tpu_platform() if interpret is None else interpret,
+    )(state, q, k, v, g, beta)
 
 
 def unit_lower_inverse(system: jax.Array) -> jax.Array:

@@ -81,6 +81,7 @@ from ..models.kv_cache import (
     tree_bytes_by_dtype,
     tree_nbytes,
 )
+from ..ops.gated_delta import DELTA_STEP_TRACES
 from ..ops.moe import GROUPED_PRODUCT_TRACES
 from ..parallel.mesh import ParallelismConfig, serving_mesh
 from ..parallel.sharding import (
@@ -1011,7 +1012,7 @@ class ServingEngine:
         compiled = kind is None
         if compiled:
             kind = key.partition("@")[0].partition("[")[0]
-        traced = GROUPED_PRODUCT_TRACES.copy() if compiled else None
+        traced = (GROUPED_PRODUCT_TRACES.copy(), DELTA_STEP_TRACES.copy()) if compiled else None
         with spans.span("serve.dispatch", seq=spans.next_seq(), kind=kind,
                         key=key, compiled=compiled) as sp:
             out = fn(*args)
@@ -1020,7 +1021,8 @@ class ServingEngine:
         if compiled:
             self._compile_seen[key] = kind
             self.metrics.record_compile(key, dt)
-            self.metrics.record_grouped_products(key, GROUPED_PRODUCT_TRACES - traced)
+            self.metrics.record_grouped_products(key, GROUPED_PRODUCT_TRACES - traced[0])
+            self.metrics.record_delta_steps(DELTA_STEP_TRACES - traced[1])
         self._last_dispatch = sp
         return out
 

@@ -243,6 +243,10 @@ class ServingMetrics:
         # ``{"<compile key>/<path>/<rows>": call sites}``
         self.grouped_product_calls = {"pallas": Counter(), "ragged_dot": Counter()}
         self.grouped_products: dict[str, int] = {}
+        # the delta rule's decode updates (`ops/gated_delta.gated_delta_step`)
+        # in the programs this engine compiled, by the path each took when its
+        # program was traced: the Pallas kernel on the TPU, XLA elsewhere
+        self.delta_step_calls = {"pallas": Counter(), "xla": Counter()}
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -462,6 +466,13 @@ class ServingMetrics:
             self.grouped_product_calls[path].inc(calls)
             self.grouped_products[f"{key}/{path}/{rows}"] = calls
 
+    def record_delta_steps(self, traced) -> None:
+        """The delta-rule decode updates traced into a program just compiled:
+        ``{(path, slots): call sites}``, path ``pallas`` or ``xla``
+        (`ops/gated_delta.DELTA_STEP_TRACES` around its trace)."""
+        for (path, _), calls in traced.items():
+            self.delta_step_calls[path].inc(calls)
+
     def tokens_per_sec(self) -> float:
         """Aggregate decode rate over the current window (see
         `reset_rate_window` — without resets this is the lifetime rate since
@@ -522,6 +533,8 @@ class ServingMetrics:
                 self.paged_decode_span_tokens.value),
             **{f"serving/grouped_product/{path}_calls": calls.value
                for path, calls in self.grouped_product_calls.items()},
+            **{f"serving/delta_step/{path}_calls": calls.value
+               for path, calls in self.delta_step_calls.items()},
             "serving/streams_opened": self.streams_opened.value,
             "serving/streams_finished": self.streams_finished.value,
             "serving/stream_events": self.stream_events.value,

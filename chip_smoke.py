@@ -8,7 +8,8 @@ positions, bf16 compute, random weights from a seed), in ONE process:
            (``interpret=False`` passed, not inferred), against a float32
            `jax.numpy` reference at the model's shapes; and the Pallas grouped
            matmul `ops/moe.grouped_product` takes on the TPU, at the two MoE
-           cells' decode shapes, against `jax.lax.ragged_dot`
+           cells' decode shapes, against `jax.lax.ragged_dot`; the delta
+           rule's one-pass decode update at the two delta-rule cells' heads
   train    `Accelerator.prepare` + `make_train_step(lm_loss_fn)`, flash
            attention, per-chip batch 8 x 1024, a few steps on one batch
   serve    `ServingEngine` (paged KV, fused decode kernel) answering eight
@@ -55,6 +56,7 @@ class Sizes:
     latent: tuple  # (query heads, row lanes, value lanes, positions) of a latent-attention layer
     experts: tuple  # (tokens, picks a token, router width, experts held, hidden, expert width) of decode steps
     nf4_shapes: tuple  # (K, N) of the quantized weights
+    delta: tuple  # (slots, heads, key = value width) of a delta rule's per-slot state
     train_batch_per_chip: int
     train_seq: int
     train_steps: int
@@ -76,6 +78,7 @@ CHIP = Sizes(
     # a decode step of the Kimi K2 and Qwen3-Next cells, and a row count the row tile does not divide
     experts=((256, 8, 384, 12, 7168, 2048), (128, 10, 512, 256, 2048, 512), (24, 10, 512, 256, 2048, 512)),
     nf4_shapes=((1024, 3072), (1024, 4096), (4096, 1024)),
+    delta=(16, 32, 128),  # the two delta-rule cells' heads, a few slots
     train_batch_per_chip=8, train_seq=1024, train_steps=6,
     prompt_buckets=(32, 128), prompt_lengths=(5, 31, 12, 24, 120, 77, 50, 97),
     new_tokens=(16, 64, 24, 32, 48, 16, 64, 40),
@@ -88,6 +91,7 @@ REHEARSAL = Sizes(
     latent=(4, 128, 96, 320),
     experts=((8, 2, 8, 4, 64, 32),),
     nf4_shapes=((256, 256),),
+    delta=(3, 4, 16),
     train_batch_per_chip=2, train_seq=64, train_steps=6,
     prompt_buckets=(16, 64), prompt_lengths=(5, 15, 9, 12, 60, 33, 20, 47),
     new_tokens=(8, 16, 12, 8, 16, 8, 16, 12),
@@ -173,6 +177,7 @@ def phase_kernels(run: Smoke) -> None:
     from accelerate_tpu.ops.attention import dot_product_attention
     from accelerate_tpu.ops.flash_attention import flash_attention, paged_decode_attention
     from accelerate_tpu.ops.fused_ce import fused_cross_entropy
+    from accelerate_tpu.ops.gated_delta import delta_step_kernel
     from accelerate_tpu.ops.moe import grouped_product
     from accelerate_tpu.ops.nf4_matmul import nf4_matmul
     from accelerate_tpu.utils.quantization import QuantizationConfig, dequantize, quantize
@@ -344,6 +349,30 @@ def phase_kernels(run: Smoke) -> None:
                     got[:n_held], ref[:n_held], z.tol)
             del rows, wts
 
+    # ---- the delta rule's decode update, one Pallas pass over the state
+    # (`ops/gated_delta.delta_step_kernel`), both decays, slot 0 finished
+    # (g = 0, beta = 0: its state comes back bit-equal), against the rule's
+    # float32 arithmetic in plain jax.numpy
+    def delta_ref(state, q, k, v, g, beta):
+        state = state * (jnp.exp(g)[..., :, None] if g.ndim == 3 else jnp.exp(g)[..., None, None])
+        d = beta[..., None] * (v - jnp.sum(state * k[..., :, None], axis=-2))
+        state = state + k[..., :, None] * d[..., None, :]
+        return state, jnp.sum(state * q[..., :, None], axis=-2)
+
+    slots, heads, width = z.delta
+    for name, g_shape in (("delta_step", (slots, heads)), ("kda_step", (slots, heads, width))):
+        f32 = lambda shape, scale=1.0: jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)  # noqa: E731
+        state = f32((slots, heads, width, width), 0.3)
+        q, k, v = (f32((slots, heads, width), width ** -0.5) for _ in range(3))
+        g = -jnp.asarray(rng.uniform(size=g_shape), jnp.float32).at[0].set(0.0)
+        beta = jnp.asarray(rng.uniform(size=(slots, heads)), jnp.float32).at[0].set(0.0)
+        got_state, got_o = jax.jit(lambda *a: delta_step_kernel(*a, interpret=interpret))(state, q, k, v, g, beta)
+        want_state, want_o = exact(delta_ref, state, q, k, v, g, beta)
+        compare(f"{name} state {list(state.shape)}", got_state, want_state, 1e-5)
+        compare(f"{name} output", got_o, want_o, 1e-5)
+        run.check("kernels", f"{name} finished slot bit-equal", bool(jnp.all(got_state[0] == state[0])))
+    del state, want_state, got_state
+
     # ---- nf4 dequant-matmul (concrete payload: the only way it runs)
     for kdim, ndim in z.nf4_shapes:
         weight = rng.normal(size=(kdim, ndim)).astype(np.float32) * 0.02
@@ -359,7 +388,7 @@ def phase_kernels(run: Smoke) -> None:
         "flash_attention._dkv_kernel", "flash_attention._fwd_band_kernel",
         "flash_attention._dq_band_kernel", "flash_attention._dkv_band_kernel",
         "fused_ce._fwd_kernel", "fused_ce._dh_kernel", "fused_ce._dw_kernel",
-        "flash_attention._paged_decode_kernel", "nf4_matmul._kernel",
+        "flash_attention._paged_decode_kernel", "nf4_matmul._kernel", "gated_delta._delta_step_kernel",
     ) + (() if run.rehearsal else ("gmm.kernel",)))  # megablox's, through `grouped_product`
 
 

@@ -8,8 +8,9 @@ file compiles each kernel at gpt2-medium shapes with ``interpret=False`` — on
 one device and inside a four-device jit. It checks that the program builds;
 only a chip run (`chip_smoke.py`) checks what it computes. Marked slow, all
 but the guards at the end: the paged pool's layout (two compiles, about 3 s),
-the latent pool and the two latent-attention models' scopes, the expert
-layer's grouped products, and the sampling tail's conditional.
+the latent pool and the two latent-attention models' scopes, the delta
+rule's in-place update, the expert layer's grouped products, and the sampling
+tail's conditional.
 """
 
 import functools
@@ -400,7 +401,8 @@ def test_ling3_scopes_and_kernel_name(topology, compiled_kernels, program):
     MLP's; the fused kernel of its one latent layer keeps the flax scope's
     name (`%attn.N`); the decode step's delta rule reads and writes the whole
     per-slot state under that shape, which is how the benchmark's reader finds
-    it. Published head sizes, everything else small; one period of six layers."""
+    it (since PR 36 a Pallas call a KDA layer, `%kda_step.N`). Published head
+    sizes, everything else small; one period of six layers."""
     import dataclasses
 
     from accelerate_tpu.models.ling3 import Ling3Config, Ling3ForCausalLM
@@ -440,10 +442,35 @@ def test_ling3_scopes_and_kernel_name(topology, compiled_kernels, program):
     assert "gmm" in kernels and "ragged-dot" not in hlo, kernels
     if program == "step":
         assert kernels.count("attn") == 1 and "kda_prefill" not in hlo and "mla_prefill" not in hlo
+        assert kernels.count("kda_step") == 5  # the rule's Pallas pass, one a KDA layer
         assert f"f32[{rows},4,128,128]" in hlo  # the whole per-slot state, by shape
     else:
         assert "mla_prefill" in kernels and "kda_step" not in hlo and "mla_absorb" not in hlo
 
+
+
+@pytest.mark.parametrize("slots, per_channel", [pytest.param(256, True, id="ling3-kda"),
+                                                pytest.param(128, False, id="qwen3-next-delta")])
+def test_delta_step_kernel_updates_the_state_in_place(topology, compiled_kernels, slots, per_channel):
+    """The delta rule's decode update at both delta-rule cells' state (32
+    heads of 128 x 128), donated as the engine donates its cache: one Pallas
+    call, named after the rule's scope and not `attn` (the paged kernel's
+    readers count those), whose line names the whole state (the benchmark's
+    reader finds it so), with the state's buffer aliased from argument to
+    result and no copy of it. About a second."""
+    from accelerate_tpu.ops.gated_delta import gated_delta_step
+
+    s = _one_device(topology)
+    h, d, f32 = 32, 128, jnp.float32
+    args = [_sds((slots, h, d, d), f32, s)] + [_sds((slots, h, d), f32, s)] * 3 + [
+        _sds((slots, h, d) if per_channel else (slots, h), f32, s), _sds((slots, h), f32, s)]
+    hlo = jax.jit(gated_delta_step, donate_argnums=(0,)).lower(*args).compile().as_text()
+    kernels = re.findall(r'%([\w\-]+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert kernels == ["kda_step" if per_channel else "delta_step"], kernels
+    state = re.escape(f"f32[{slots},{h},{d},{d}]")
+    assert re.search(rf"%{kernels[0]}\.\d+ = \({state}", hlo)
+    assert not re.search(rf"= {state}\S* copy(-start)?\(", hlo)
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, ", hlo)
 
 # ------------------------------------- the expert layer's grouped products
 # (hidden, expert width, experts held, picks a token)
