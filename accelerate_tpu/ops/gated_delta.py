@@ -89,9 +89,9 @@ def gated_delta_step(
 
 
 # Decode steps of the delta rule by the path they took and their slot count,
-# counted where the path is decided: when a program is TRACED.
-# `ServingEngine._dispatch` reads the difference around a program's first call
-# (`serving/delta_step/*`).
+# counted where the path is decided: when a program is TRACED. The kernel's
+# tests read it; on the chip the device trace names the kernel that ran
+# (`%delta_step.N` / `%kda_step.N` events in the benchmark's `device_ops`).
 DELTA_STEP_TRACES: collections.Counter = collections.Counter()
 DELTA_STEP_VMEM = 12 * 2**20  # bytes of state blocks in VMEM: one in and one out, two buffers each
 

@@ -201,9 +201,9 @@ GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small gro
 GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
 
 # Grouped products by the path they took and their row count, counted where
-# the path is decided: when a program is TRACED. `ServingEngine._dispatch`
-# reads the difference around a program's first call
-# (`serving/grouped_product/*`).
+# the path is decided: when a program is TRACED. The kernel's tests read it; on
+# the chip the device trace names the kernel that ran (`%gmm.N` events in the
+# benchmark's `device_ops`).
 GROUPED_PRODUCT_TRACES: collections.Counter = collections.Counter()
 
 

@@ -81,8 +81,6 @@ from ..models.kv_cache import (
     tree_bytes_by_dtype,
     tree_nbytes,
 )
-from ..ops.gated_delta import DELTA_STEP_TRACES
-from ..ops.moe import GROUPED_PRODUCT_TRACES
 from ..parallel.mesh import ParallelismConfig, serving_mesh
 from ..parallel.sharding import (
     block_table_sharding,
@@ -266,7 +264,10 @@ class StepTimings:
     the telemetry poll. The phases partition ``total_s`` up to clock jitter.
     ``total_s``, ``draft_s``, ``dispatch_s``, ``fetch_blocked_s`` and
     ``telemetry_s`` are the lengths of the step's ``serve.*`` spans in
-    `utils.spans.RING`: one set of stamps feeds both.
+    `utils.spans.RING`: one set of stamps feeds both. ``deliver_s`` is the
+    ``serve.deliver`` spans net of the journal appends inside them, and
+    ``schedule_s`` the step's stretch to the end of its ``serve.admit`` span
+    net of the dispatch, fetch, deliver and journal spans inside it.
     """
 
     schedule_s: float = 0.0
@@ -1012,7 +1013,6 @@ class ServingEngine:
         compiled = kind is None
         if compiled:
             kind = key.partition("@")[0].partition("[")[0]
-        traced = (GROUPED_PRODUCT_TRACES.copy(), DELTA_STEP_TRACES.copy()) if compiled else None
         with spans.span("serve.dispatch", seq=spans.next_seq(), kind=kind,
                         key=key, compiled=compiled) as sp:
             out = fn(*args)
@@ -1021,8 +1021,6 @@ class ServingEngine:
         if compiled:
             self._compile_seen[key] = kind
             self.metrics.record_compile(key, dt)
-            self.metrics.record_grouped_products(key, GROUPED_PRODUCT_TRACES - traced[0])
-            self.metrics.record_delta_steps(DELTA_STEP_TRACES - traced[1])
         self._last_dispatch = sp
         return out
 
@@ -1868,8 +1866,10 @@ class ServingEngine:
 
         The call is one ``serve.step`` span in `utils.spans.RING`, numbered
         with the count `ServingMetrics.step_total_s` reports once the step is
-        observed; its dispatches, fetches, draft, journal appends and
-        telemetry poll are spans that name it as their parent."""
+        observed; its admission, dispatches, fetches, deliveries, draft,
+        journal appends, telemetry poll, the queue waits of the requests it
+        admits and any full collection inside it are spans that name it as
+        their parent."""
         tm = self._timings
         tm.reset()
         with spans.span("serve.step", is_step=True,
@@ -1888,12 +1888,12 @@ class ServingEngine:
         j_start = journal.append_s if journal is not None else 0.0
         finished: list[RequestOutput] = []
         self._reap_ready(finished)
-        self._admit_pending(finished)
-        # schedule = reap/admit bookkeeping wall net of the dispatches,
-        # fetches, delivery, and journal writes the admission path performed
+        admit = self._admit_pending(finished)
+        # schedule = the step's stretch up to the end of its `serve.admit`
+        # span net of the dispatch, fetch, deliver and journal spans inside it
         # (each already accumulated into its own phase)
         j_sched = (journal.append_s - j_start) if journal is not None else 0.0
-        tm.schedule_s = max(0.0, (time.perf_counter() - t_start)
+        tm.schedule_s = max(0.0, (admit.end - t_start)
                             - tm.dispatch_s - tm.fetch_blocked_s
                             - tm.deliver_s - j_sched)
         n_active = self.active_slots
@@ -2510,17 +2510,18 @@ class ServingEngine:
         blocked = sp.end - sp.start
         tm.fetch_blocked_s += blocked
         self.metrics.host_blocked_s.observe(blocked)
-        j0 = journal.append_s if journal is not None else 0.0
         now = sp.end
-        if entry.kind == "admit":
-            self._process_admit(entry, fetched, now, finished)
-        elif entry.kind == "spec":
-            self._process_spec(entry, fetched, now, finished)
-        else:
-            self._process_step(entry, fetched, now, finished)
-        t_done = time.perf_counter()
-        j1 = journal.append_s if journal is not None else 0.0
-        deliver = max(0.0, (t_done - now) - (j1 - j0))
+        # retirement, tokens and SLO accounting, the journal's appends nested
+        with spans.span("serve.deliver", seq=entry.seq, kind=entry.kind) as work:
+            j0 = journal.append_s if journal is not None else 0.0
+            if entry.kind == "admit":
+                self._process_admit(entry, fetched, now, finished)
+            elif entry.kind == "spec":
+                self._process_spec(entry, fetched, now, finished)
+            else:
+                self._process_step(entry, fetched, now, finished)
+            j1 = journal.append_s if journal is not None else 0.0
+        deliver = max(0.0, (work.end - work.start) - (j1 - j0))
         tm.deliver_s += deliver
         if self.tracer.enabled:
             # emitted after delivery so the fetch event can attribute its own
@@ -2768,7 +2769,16 @@ class ServingEngine:
         else:
             self._retire(slot, FINISH_ERROR, now, finished)
 
-    def _admit_pending(self, finished: list[RequestOutput]) -> None:
+    def _admit_pending(self, finished: list[RequestOutput]) -> spans.span:
+        """Expire overdue requests, tick the KV tier and seat queued runs in
+        free slots: one ``serve.admit`` span, returned, whose ``admitted``
+        counts the requests seated (their dispatches, fetches and deliveries
+        nest inside it)."""
+        with spans.span("serve.admit", admitted=0) as sp:
+            self._admit_queued(finished, sp.attrs)
+        return sp
+
+    def _admit_queued(self, finished: list[RequestOutput], attrs: dict) -> None:
         now = time.perf_counter()
         for request in self.scheduler.pop_expired(now):
             # expired while queued: reject rather than serve a reply the
@@ -2815,6 +2825,7 @@ class ServingEngine:
                 if any(m.tokens for m in matches):
                     if not self._admit_group_cached(group, matches, finished):
                         return  # block-pool backpressure: group requeued
+                    attrs["admitted"] += len(group)
                     continue
                 for r in group:
                     if r.cache_prefix and not r.resume_tokens:
@@ -2823,6 +2834,7 @@ class ServingEngine:
             # prefix cache disabled this path is bit-for-bit the pre-cache one
             if not self._admit_group(group, finished):
                 return  # block-pool backpressure: group requeued
+            attrs["admitted"] += len(group)
 
     def _admit_group(self, group: list[Request],
                      finished: list[RequestOutput]) -> bool:
@@ -3114,6 +3126,14 @@ class ServingEngine:
         self._trace_dispatch(
             entry, "cached_admit" if matches is not None else "admit"
         )
+        # each request's wait, from the scheduler's enqueue stamp to the
+        # start of the dispatch that took it
+        taken = self._last_dispatch.start
+        for request in group:
+            if request.queued_time is not None:
+                spans.record("serve.queued", request.queued_time, taken,
+                             rid=request.request_id, bucket=bucket, seq=entry.seq)
+                request.queued_time = None
         if self.tracer.enabled:
             for i, (slot, request) in enumerate(zip(slots, group)):
                 m = matches[i] if matches is not None else None

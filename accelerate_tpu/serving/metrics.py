@@ -236,17 +236,6 @@ class ServingMetrics:
         # turn or two behind the device), and held slots x n_positions
         self.paged_decode_live_tokens = Counter()
         self.paged_decode_span_tokens = Counter()
-        # the grouped expert products (`ops/moe.grouped_product`) in the
-        # programs this engine compiled, by the path each took when its
-        # program was traced (the Pallas grouped matmul on the TPU,
-        # `jax.lax.ragged_dot` elsewhere) and, per program, by row count:
-        # ``{"<compile key>/<path>/<rows>": call sites}``
-        self.grouped_product_calls = {"pallas": Counter(), "ragged_dot": Counter()}
-        self.grouped_products: dict[str, int] = {}
-        # the delta rule's decode updates (`ops/gated_delta.gated_delta_step`)
-        # in the programs this engine compiled, by the path each took when its
-        # program was traced: the Pallas kernel on the TPU, XLA elsewhere
-        self.delta_step_calls = {"pallas": Counter(), "xla": Counter()}
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -458,21 +447,6 @@ class ServingMetrics:
         self.compile_s.observe(seconds)
         self.compiles[key] = round(float(seconds), 4)
 
-    def record_grouped_products(self, key: str, traced) -> None:
-        """The grouped products traced into the program compiled under
-        ``key``: ``{(path, rows): call sites}``, path ``pallas`` or
-        ``ragged_dot`` (`ops/moe.GROUPED_PRODUCT_TRACES` around its trace)."""
-        for (path, rows), calls in traced.items():
-            self.grouped_product_calls[path].inc(calls)
-            self.grouped_products[f"{key}/{path}/{rows}"] = calls
-
-    def record_delta_steps(self, traced) -> None:
-        """The delta-rule decode updates traced into a program just compiled:
-        ``{(path, slots): call sites}``, path ``pallas`` or ``xla``
-        (`ops/gated_delta.DELTA_STEP_TRACES` around its trace)."""
-        for (path, _), calls in traced.items():
-            self.delta_step_calls[path].inc(calls)
-
     def tokens_per_sec(self) -> float:
         """Aggregate decode rate over the current window (see
         `reset_rate_window` — without resets this is the lifetime rate since
@@ -531,10 +505,6 @@ class ServingMetrics:
                 self.paged_decode_live_tokens.value),
             "serving/paged_decode/span_tokens": (
                 self.paged_decode_span_tokens.value),
-            **{f"serving/grouped_product/{path}_calls": calls.value
-               for path, calls in self.grouped_product_calls.items()},
-            **{f"serving/delta_step/{path}_calls": calls.value
-               for path, calls in self.delta_step_calls.items()},
             "serving/streams_opened": self.streams_opened.value,
             "serving/streams_finished": self.streams_finished.value,
             "serving/stream_events": self.stream_events.value,
@@ -559,8 +529,6 @@ class ServingMetrics:
                 out[f"serving/slo/{name}/{stat}"] = stats[stat]
         for key, seconds in self.compiles.items():
             out[f"serving/compile/{key}"] = seconds
-        for name, calls in self.grouped_products.items():
-            out[f"serving/grouped_product/{name}"] = calls
         for name, total in self.step_counters.items():
             out[f"serving/step_counters/{name}"] = total
         if self.step_counters:

@@ -125,6 +125,9 @@ class Request:
     # monopolize its priority class. Journaled and restored across crash
     # resume and replica migration. "" = the anonymous shared tenant.
     tenant: str = ""
+    # when the scheduler last queued it (`time.perf_counter()`; None while
+    # not waiting): the start of its `serve.queued` span (`utils/spans.py`)
+    queued_time: float | None = None
 
     @property
     def prefill_len(self) -> int:

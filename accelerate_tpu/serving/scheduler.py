@@ -22,6 +22,7 @@ otherwise be host memory).
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -33,6 +34,15 @@ from .request import (
     SubmitResult,
 )
 from .trace import EV_QUEUED, NULL_TRACER
+
+
+def _stamp_queued(request: Request) -> None:
+    """Stamp the request's enqueue time unless it already waits: a group put
+    back by block-pool backpressure keeps its first stamp, and the engine
+    clears the stamp once a dispatch takes the request (its ``serve.queued``
+    span), so a requeue after admission starts a new wait."""
+    if request.queued_time is None:
+        request.queued_time = time.perf_counter()
 
 
 class FIFOScheduler:
@@ -119,6 +129,7 @@ class FIFOScheduler:
         if rejected is not None:
             return rejected
         self._queue.append(request)
+        _stamp_queued(request)
         if self.tracer.enabled:
             self.tracer.emit(EV_QUEUED, request.request_id,
                              queue_depth=len(self._queue),
@@ -191,6 +202,7 @@ class FIFOScheduler:
         """Put a request at the FRONT of the queue (the watchdog's re-prefill
         path: a quarantined request must not wait behind new arrivals)."""
         self._queue.appendleft(request)
+        _stamp_queued(request)
         if self.tracer.enabled:
             self.tracer.emit(EV_QUEUED, request.request_id,
                              queue_depth=len(self._queue),
@@ -454,6 +466,7 @@ class FairScheduler(FIFOScheduler):
         if rejected is not None:
             return rejected
         self._enqueue(request)
+        _stamp_queued(request)
         if self.tracer.enabled:
             self.tracer.emit(EV_QUEUED, request.request_id,
                              queue_depth=self.queue_depth,
@@ -487,6 +500,7 @@ class FairScheduler(FIFOScheduler):
     def requeue(self, request: Request) -> None:
         self._seq += 1
         self._front.appendleft(_Entry(request, self._seq))
+        _stamp_queued(request)
         if self.tracer.enabled:
             self.tracer.emit(EV_QUEUED, request.request_id,
                              queue_depth=self.queue_depth,

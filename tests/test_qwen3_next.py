@@ -327,35 +327,6 @@ def test_step_counters_and_state_gauges(cfg, model_cfg, params):
     assert stats["block_pool/pool_bytes"] == stats["slot_pool_bytes"] - stats["slot_state_bytes"]
 
 
-def test_grouped_product_counters_name_each_programs_path(cfg, model_cfg, params):
-    """Which path the grouped expert products of each compiled program took is
-    fixed when it is traced, and counted there: off the TPU, `ragged_dot`."""
-    layers, k = model_cfg.num_hidden_layers, model_cfg.num_experts_per_tok
-    for _ in range(2):  # an engine counts its own programs, whatever was traced before it
-        engine = engine_for(Qwen3NextForCausalLM(model_cfg), params)
-        serve(engine, prompts_of(cfg, (10, 12)), 3)
-        snap = engine.metrics.snapshot()
-        products = {key.removeprefix("serving/grouped_product/"): calls for key, calls in snap.items()
-                    if key.startswith("serving/grouped_product/")}
-        assert products == {
-            "pallas_calls": 0, "ragged_dot_calls": 4 * layers,
-            f"step@mesh1x1/ragged_dot/{2 * k}": 2 * layers,  # two slots' picks
-            f"admit[pb32b2]@mesh1x1/ragged_dot/{2 * 32 * k}": 2 * layers,  # two rows of the 32 bucket
-        }
-
-
-def test_delta_step_counters_count_each_linear_layer_of_the_decode_program(cfg, model_cfg, params):
-    """The delta rule's decode update is counted where its path is decided,
-    when a program is traced: off the TPU the XLA body, once for each linear
-    layer of the decode program; an admit runs the chunked form, uncounted."""
-    engine = engine_for(Qwen3NextForCausalLM(model_cfg), params)
-    serve(engine, prompts_of(cfg, (10, 12)), 3)
-    snap = engine.metrics.snapshot()
-    linear = sum(not model_cfg.is_full_attention(i) for i in range(model_cfg.num_hidden_layers))
-    assert linear == 3 and {k: v for k, v in snap.items() if k.startswith("serving/delta_step/")} == {
-        "serving/delta_step/pallas_calls": 0, "serving/delta_step/xla_calls": linear}
-
-
 @pytest.mark.parametrize("argument", [{"prefix_cache": True}, {"kv_tier": True},
                                       {"speculation": "ngram"}, {"mesh": (1, 2)}])
 def test_engine_refuses_what_recurrent_state_cannot_do(model_cfg, params, argument):
@@ -384,9 +355,6 @@ def test_gpt2_declares_keys_and_values_only_and_counts_nothing():
     assert engine.metrics.step_counters == {} and "slot_state_bytes" not in engine.memory_stats()
     snap = engine.metrics.snapshot()
     assert not any(k.startswith("serving/step_counters") for k in snap)
-    assert {k: v for k, v in snap.items() if k.startswith("serving/grouped_product")} == {
-        "serving/grouped_product/pallas_calls": 0, "serving/grouped_product/ragged_dot_calls": 0}
-    assert snap["serving/delta_step/pallas_calls"] == snap["serving/delta_step/xla_calls"] == 0
 
 
 def test_a_model_without_a_contract_is_refused():

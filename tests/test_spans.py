@@ -1,6 +1,7 @@
 """The host-span ring (`utils/spans.py`) and what feeds it: the serving step,
 the train step, the loader; and `RequestOutput.token_times`."""
 
+import gc
 import math
 import subprocess
 import sys
@@ -104,6 +105,81 @@ def test_a_step_on_another_thread_is_no_parent_here():
     assert mine[0] == "mine" and mine[3] == 0 and step[4]["id"] != 0
 
 
+def test_record_adds_a_span_timed_elsewhere_under_the_open_step():
+    ring = spans.SpanRing()
+    spans.record("outside", 1.0, 2.0, ring=ring, rid=3)
+    with spans.span("step", is_step=True, ring=ring) as step:
+        spans.record("inside", 0.5, 4.0, ring=ring, rid=4, seq=9)
+    outside, inside, held = ring.snapshot()
+    assert outside == ("outside", 1.0, 2.0, 0, {"rid": 3})
+    assert inside == ("inside", 0.5, 4.0, step.attrs["id"], {"rid": 4, "seq": 9})
+    assert held[0] == "step"
+
+
+def test_annotations_carry_the_step_and_sequence_numbers(monkeypatch):
+    """What pairs an annotation on a profile with its span in the ring."""
+    made = []
+    monkeypatch.setattr(spans, "TraceAnnotation", lambda name, **meta: made.append(
+        (name, meta)) or __import__("contextlib").nullcontext())
+    ring = spans.SpanRing()
+    with spans.span("serve.step", is_step=True, ring=ring, step=5):
+        with spans.span("serve.dispatch", ring=ring, seq=7, kind="step", key="k"):
+            pass
+        with spans.span("serve.admit", ring=ring, admitted=0):
+            pass
+    assert made == [("serve.step", {"step": 5}), ("serve.dispatch", {"seq": 7}),
+                    ("serve.admit", {})]
+
+
+def test_a_capture_holds_the_spans_with_their_numbers(tmp_path):
+    """On a real capture the span's annotation lands on the host plane under
+    its own name, its number as the event's metadata."""
+    import glob
+
+    ring = spans.SpanRing()
+    with jax.profiler.trace(str(tmp_path)):
+        with spans.span("serve.step", is_step=True, ring=ring, step=41):
+            with spans.span("serve.fetch", ring=ring, seq=12, kind="step"):
+                jnp.ones(8).block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = {e.name: dict(e.stats) for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:") for line in plane.lines for e in line.events
+              if e.name.startswith("serve.")}
+    assert events == {"serve.step": {"step": 41}, "serve.fetch": {"seq": 12}}
+
+
+# ---------------------------------------------------------------- collections
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_a_full_collection_is_a_span_of_its_step_and_every_collection_counts(generation):
+    """`gc.collect(2)` inside a step is one `host.gc` span naming the step;
+    the younger generations are counted in `spans.GC` only."""
+    before = (list(spans.GC.collections), list(spans.GC.seconds))
+    spans.RING.clear()
+    garbage = [[] for _ in range(100)]
+    for a, b in zip(garbage, garbage[1:]):
+        a.append(b), b.append(a)  # cycles: only a collection frees them
+    del garbage, a, b
+    with spans.span("serve.step", is_step=True, step=1) as step:
+        gc.collect(generation)
+    collections = [n - m for n, m in zip(spans.GC.collections, before[0])]
+    assert collections[generation] >= 1  # automatic collections may add to it
+    assert spans.GC.seconds[generation] > before[1][generation]
+    held = [s for s in spans.RING.snapshot() if s[0] == "host.gc"]
+    if generation < 2:
+        assert held == []
+        return
+    (name, start, end, parent, attrs), = held
+    assert parent == step.attrs["id"] and step.start <= start <= end <= step.end
+    assert attrs["generation"] == 2 and attrs["collected"] >= 100
+
+
+def test_the_ring_holds_the_busiest_cells_window():
+    """GPT-2's closed loop: 108 steps a second of five spans, 24 admits of
+    three more and a queue wait for each of 25 requests, over 65 s of ramp and
+    window; a fifth to spare."""
+    assert spans.RING.maxlen == spans.RING_SPANS >= 1.2 * 65 * (108 * 5 + 24 * 3 + 25)
+
+
 def test_sequence_numbers_are_one_counter_with_the_tracer():
     tracer = Tracer()
     a, b, c = spans.next_seq(), tracer.next_seq(), spans.next_seq()
@@ -132,16 +208,26 @@ def test_serving_spans_nest_pair_and_sum_to_step_timings(model, depth, admit):
     engine, outputs, ring = _serve(model, pipeline_depth=depth, admit_batch=admit,
                                    tracer=tracer)
     assert all(o.finish_reason == FINISH_LENGTH for o in outputs)
+    ring = [s for s in ring if s[0] != "host.gc"]  # a full collection may land anywhere
     steps = {s[4]["id"]: s for s in ring if s[0] == "serve.step"}
     dispatches = [s for s in ring if s[0] == "serve.dispatch"]
     fetches = [s for s in ring if s[0] == "serve.fetch"]
+    delivers = [s for s in ring if s[0] == "serve.deliver"]
+    admits = {s[3]: s for s in ring if s[0] == "serve.admit"}
     assert steps and dispatches and fetches
-    assert {s[0] for s in ring} == {"serve.step", "serve.dispatch", "serve.fetch"}
+    assert {s[0] for s in ring} == {"serve.step", "serve.admit", "serve.dispatch", "serve.fetch",
+                                    "serve.deliver", "serve.queued"}
+    # one admit span a step, counting every request it seated
+    assert sorted(admits) == sorted(steps)
+    assert sum(s[4]["admitted"] for s in admits.values()) == len(outputs)
+    # a delivery follows its fetch and carries its sequence number
+    assert sorted((s[4]["seq"], s[4]["kind"]) for s in delivers) == sorted(
+        (s[4]["seq"], s[4]["kind"]) for s in fetches)
     # step numbers are the counts ServingMetrics.step_total_s reports
     numbers = [s[4]["step"] for s in steps.values()]
     assert numbers == list(range(1, engine.metrics.step_total_s.count + 1))
-    # every dispatch and fetch lies inside the step it names
-    for name, start, end, parent, attrs in dispatches + fetches:
+    # every dispatch, fetch, delivery and admission lies inside the step it names
+    for name, start, end, parent, attrs in dispatches + fetches + delivers + list(admits.values()):
         step = steps[parent]
         assert step[1] <= start <= end <= step[2], (name, attrs)
     # they pair by sequence number: each dispatch is fetched once, later
@@ -164,6 +250,17 @@ def test_serving_spans_nest_pair_and_sum_to_step_timings(model, depth, admit):
     assert m.step_total_s.sum == pytest.approx(length(steps.values()), abs=1e-9)
     assert m.step_phase_dispatch_s.sum == pytest.approx(length(dispatches), abs=1e-9)
     assert m.step_phase_fetch_blocked_s.sum == pytest.approx(length(fetches), abs=1e-9)
+    assert m.step_phase_deliver_s.sum == pytest.approx(length(delivers), abs=1e-9)
+    # schedule: a step's stretch to the end of its admit span, net of the
+    # spans inside that stretch; the phases partition the step's time
+    inside = lambda sid, t: [s for s in dispatches + fetches + delivers  # noqa: E731
+                             if s[3] == sid and s[2] <= t]
+    schedule = sum(admits[sid][2] - step[1] - length(inside(sid, admits[sid][2]))
+                   for sid, step in steps.items())
+    assert m.step_phase_schedule_s.sum == pytest.approx(schedule, abs=1e-9)
+    phases = sum(getattr(m, f"step_phase_{p}_s").sum for p in ("schedule", "dispatch",
+                                                              "fetch_blocked", "deliver"))
+    assert phases <= m.step_total_s.sum
     # EV_DISPATCH is built from the dispatch's span: key, flag and wall time
     for e in events:
         if e.kind == EV_DISPATCH:
@@ -174,6 +271,32 @@ def test_serving_spans_nest_pair_and_sum_to_step_timings(model, depth, admit):
     keys = [d[4]["key"] for d in sorted(dispatches, key=lambda d: d[4]["seq"]) if d[4]["compiled"]]
     assert len(keys) == len(set(keys)) == len(engine.metrics.compiles)
     assert all(set(s[4]) == {"id", "step"} for s in steps.values())
+
+
+@pytest.mark.parametrize("admit", [1, 4])
+def test_one_queue_wait_per_admitted_request_ending_at_its_dispatch(model, admit):
+    tracer = Tracer()
+    _, outputs, ring = _serve(model, admit_batch=admit, tracer=tracer)
+    dispatches = {s[4]["seq"]: s for s in ring if s[0] == "serve.dispatch"}
+    queued = [s for s in ring if s[0] == "serve.queued"]
+    assert sorted(s[4]["rid"] for s in queued) == sorted(o.request_id for o in outputs)
+    admitted = {e.rid: e.data for e in tracer.events() if e.kind == "admit"}
+    steps = {s[4]["id"] for s in ring if s[0] == "serve.step"}
+    for name, start, end, parent, attrs in queued:
+        taken = dispatches[attrs["seq"]]
+        assert taken[4]["kind"] == "admit" and end == taken[1] and start <= end
+        assert (attrs["seq"], attrs["bucket"]) == (admitted[attrs["rid"]]["seq"],
+                                                   admitted[attrs["rid"]]["bucket"])
+        assert parent in steps
+    arrived = {o.request_id: o.arrival_time for o in outputs}
+    assert all(arrived[s[4]["rid"]] <= s[1] for s in queued)
+
+
+def test_span_attrs_are_untracked_by_the_collector(model):
+    """A full collection walks no span the ring holds: their attrs hold
+    atomic values alone."""
+    _, _, ring = _serve(model)
+    assert ring and not any(gc.is_tracked(s[4]) for s in ring)
 
 
 def test_optional_phases_open_spans_only_when_they_run(model, tmp_path):
