@@ -199,6 +199,7 @@ def route_sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, top_k:
 
 GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small groups share one
 GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
+GMM_FLOPS_PER_BYTE = 240  # the v5e's bfloat16 peak over its HBM bandwidth: 197e12 / 819e9
 
 # Grouped products by the path they took and their row count, counted where
 # the path is decided: when a program is TRACED. The kernel's tests read it; on
@@ -207,20 +208,40 @@ GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bf
 GROUPED_PRODUCT_TRACES: collections.Counter = collections.Counter()
 
 
+def _dividing_tiles(d: int) -> list[int]:
+    """``d`` itself and the multiples of 128 that divide it."""
+    return [d] + [t for t in range(128, d, 128) if d % t == 0]
+
+
 def grouped_tiling(k: int, n: int) -> tuple[int, int, int]:
     """``(row tile, tile_k, tile_n)`` of the Pallas grouped matmul for rows
     ``[R, k]`` times ``[G, k, n]``, read off the weights' shape alone. The
     kernel's time is the fetch of its weight tiles, so a tile is 4 MB (two
-    of them and the rows' fit the 16 MiB of scoped VMEM): the whole of
-    either matrix of a Qwen3-Next expert, a seventh of a Kimi K2 expert's
-    down projection. One rule for a prefill's thousands of rows and for a
-    decode step's 3-5 a group: on the v5e it is within 2% of the best of a
-    sweep over row tiles of 16 to 128 and eight weight tiles at each of both
-    serving cells' decode shapes (PERF.md section 6, PR 33). A smaller row
-    tile only makes more groups straddle two tiles and fetch their weights
-    twice."""
+    of them and the rows' fit the 16 MiB of scoped VMEM): at most 2,048 of
+    k by the rest of the budget. A tile that overhangs the matrix computes
+    its masked k remainder and its n overhang on the MXU all the same, so
+    where a row tile's product over the tiles' area would outlast the read
+    of the weights, the tile is instead the largest whose sides DIVIDE the
+    matrix (each the whole dimension or a multiple of 128; the larger
+    ``tile_k`` on a tie): Ling 3.0 flash's ``[2560, 1536]`` in tiles of
+    2,048 x 1,024 computed over 2.13 times its area. One rule for a
+    prefill's thousands of rows and for a decode step's 3-5 a group: on the
+    v5e it is within 2% of the best tile of a sweep at the three MoE
+    serving cells' decode shapes and Ling 3.0 flash's admits (PERF.md
+    section 6; the earlier one also over row tiles: a smaller one only
+    makes more groups straddle two tiles and fetch their weights twice),
+    but at Kimi K2's gate and up, which overhang 1.14 times and keep their
+    tile: the sweep's best there, 7,168 x 256, ran 12% faster alone and
+    moved the cell's decode step under 1%."""
     tile_k = min(k, 2048)
-    return GMM_ROW_TILE, tile_k, min(n, GMM_WEIGHT_TILE // tile_k)
+    tile_n = min(n, GMM_WEIGHT_TILE // tile_k)
+    computed = -(-k // tile_k) * tile_k * -(-n // tile_n) * tile_n
+    if GMM_ROW_TILE * computed > GMM_FLOPS_PER_BYTE * k * n:
+        fits = [(tk * tn, tk, tn) for tk in _dividing_tiles(k) for tn in _dividing_tiles(n)
+                if tk * tn <= GMM_WEIGHT_TILE]
+        if fits:
+            _, tile_k, tile_n = max(fits)
+    return GMM_ROW_TILE, tile_k, tile_n
 
 
 def grouped_product(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:

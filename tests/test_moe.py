@@ -131,24 +131,29 @@ def _small_tiles(k, n):
 HELD = 6  # of a router 8 wide: ids 6 and 7 are another chip's
 
 
-@pytest.mark.parametrize("picks_of, tokens, hidden, tiling", [
+@pytest.mark.parametrize("picks_of, tokens, hidden, width, tiling", [
     # expert ids a token's picks cycle through
-    pytest.param([(0, 2), (2, 5), (5, 0)], 24, 128, _small_tiles, id="empty-groups-between-full-ones"),
-    pytest.param([(6, 7)], 16, 128, _small_tiles, id="every-pick-absent"),
-    pytest.param([(6, 7)], 13, 64, None, id="every-pick-absent-padded-rows"),
-    pytest.param([(1, 3)] * 9 + [(0, 7)], 30, 128, _small_tiles, id="a-group-straddles-row-tiles"),
-    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 64, None, id="rows-not-a-multiple-of-128"),
-    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 128, _small_tiles, id="rows-not-a-multiple-of-16"),
-    pytest.param([(3, 4), (4, 6), (0, 3)], 24, 192, _small_tiles, id="k-not-a-multiple-of-its-tile"),
+    pytest.param([(0, 2), (2, 5), (5, 0)], 24, 128, 64, _small_tiles, id="empty-groups-between-full-ones"),
+    pytest.param([(6, 7)], 16, 128, 64, _small_tiles, id="every-pick-absent"),
+    pytest.param([(6, 7)], 13, 64, 64, None, id="every-pick-absent-padded-rows"),
+    pytest.param([(1, 3)] * 9 + [(0, 7)], 30, 128, 64, _small_tiles, id="a-group-straddles-row-tiles"),
+    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 64, 64, None, id="rows-not-a-multiple-of-128"),
+    pytest.param([(0, 1, 4), (2, 7, 5)], 13, 128, 64, _small_tiles, id="rows-not-a-multiple-of-16"),
+    pytest.param([(3, 4), (4, 6), (0, 3)], 24, 192, 64, _small_tiles, id="k-not-a-multiple-of-its-tile"),
+    # the rule itself under a weight tile of 128 x 128: gate and up [128, 256]
+    # in tiles of the whole k by half of n (the split Ling 3.0 flash's take),
+    # down whole
+    pytest.param([(0, 3), (3, 5), (1, 0)], 40, 128, 128, 128 * 128, id="the-rule-whole-k-split-n"),
 ])
-def test_pallas_grouped_product_matches_ragged_dot(monkeypatch, picks_of, tokens, hidden, tiling):
+def test_pallas_grouped_product_matches_ragged_dot(monkeypatch, picks_of, tokens, hidden, width, tiling):
     """`held_experts_mlp` through the Pallas grouped matmul (interpreted; the
-    path a TPU takes) against the same call through `jax.lax.ragged_dot`."""
+    path a TPU takes) against the same call through `jax.lax.ragged_dot`.
+    ``tiling`` is a tiling function in place of `grouped_tiling`, or an int:
+    the weight tile `grouped_tiling` itself is held to."""
     from jax.experimental.pallas.ops.tpu import megablox
 
     from accelerate_tpu.utils import environment
 
-    width = 64
     ks = jax.random.split(jax.random.key(len(picks_of) + tokens), 4)
     x = jax.random.normal(ks[0], (tokens, hidden), jnp.float32).astype(jnp.bfloat16)
     idx = jnp.asarray([picks_of[t % len(picks_of)] for t in range(tokens)], jnp.int32)
@@ -159,7 +164,11 @@ def test_pallas_grouped_product_matches_ragged_dot(monkeypatch, picks_of, tokens
 
     monkeypatch.setattr(environment, "on_tpu_platform", lambda: True)
     monkeypatch.setattr(megablox, "gmm", functools.partial(megablox.gmm, interpret=True))
-    if tiling is not None:
+    if isinstance(tiling, int):
+        monkeypatch.setattr(moe, "GMM_WEIGHT_TILE", tiling)
+        assert moe.grouped_tiling(hidden, 2 * width) == (128, hidden, width)
+        assert moe.grouped_tiling(width, hidden) == (128, width, hidden)
+    elif tiling is not None:
         monkeypatch.setattr(moe, "grouped_tiling", tiling)
     before = moe.GROUPED_PRODUCT_TRACES.copy()
     got, got_picks, got_touched = held_experts_mlp(x, weights, idx, w_gate_up, w_down)
@@ -171,3 +180,52 @@ def test_pallas_grouped_product_matches_ragged_dot(monkeypatch, picks_of, tokens
     else:
         assert float(jnp.abs(want).max()) > 1e-2
         np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+
+
+# (k, n) of each expert matrix of the three MoE serving cells: gate and up, down
+QWEN3_NEXT = [(2048, 1024), (512, 2048)]
+KIMI_K2 = [(7168, 4096), (2048, 7168)]
+LING3 = [(2560, 1536), (768, 2560)]
+
+
+@pytest.mark.parametrize("k, n, tiles", [
+    pytest.param(*QWEN3_NEXT[0], (2048, 1024), id="qwen3next-gate-up"),
+    pytest.param(*QWEN3_NEXT[1], (512, 2048), id="qwen3next-down"),
+    pytest.param(*KIMI_K2[0], (2048, 1024), id="kimik2-gate-up"),
+    pytest.param(*KIMI_K2[1], (2048, 1024), id="kimik2-down"),
+    pytest.param(*LING3[0], (2560, 768), id="ling3-gate-up"),
+    pytest.param(*LING3[1], (768, 2560), id="ling3-down"),
+])
+def test_grouped_tiling_at_the_moe_cells(k, n, tiles):
+    """The weight tile at each MoE cell's matrices: at most `GMM_WEIGHT_TILE`
+    elements, each side the whole dimension or a multiple of 128, and never
+    a tile whose product over its overhang outlasts the read of the weights
+    (Ling 3.0 flash's gate and up took 2,048 x 1,024 over 2.13 times their
+    area). Every tile divides its matrix but Kimi K2's gate and up, which
+    overhang k 1.14 times and keep their tile; Qwen3-Next's keep theirs,
+    whole."""
+    row_tile, tile_k, tile_n = moe.grouped_tiling(k, n)
+    assert (row_tile, tile_k, tile_n) == (moe.GMM_ROW_TILE, *tiles)
+    assert tile_k * tile_n <= moe.GMM_WEIGHT_TILE
+    for side, dim in ((tile_k, k), (tile_n, n)):
+        assert side == dim or side % 128 == 0
+    computed = -(-k // tile_k) * tile_k * -(-n // tile_n) * tile_n
+    assert row_tile * computed <= moe.GMM_FLOPS_PER_BYTE * k * n
+    assert (k % tile_k, n % tile_n) == ((1024, 0) if (k, n) == KIMI_K2[0] else (0, 0))
+    if (k, n) in QWEN3_NEXT or (k, n) in KIMI_K2:  # 2,048 of k at most, by the rest of the budget
+        assert (tile_k, tile_n) == (min(k, 2048), min(n, moe.GMM_WEIGHT_TILE // min(k, 2048)))
+
+
+@pytest.mark.parametrize("k, n, tiles", [
+    # no multiple of 128 divides 20,000; the 2,048 x 1,024 tile overhangs k by
+    # 2% and keeps its masked remainder
+    pytest.param(20000, 4096, (2048, 1024), id="k-without-a-128-divisor"),
+    # the 2,048 x 1,024 tile would compute 2.98 times the area; no multiple of
+    # 128 divides 1,100, so n is whole and k the largest divisor beside it
+    pytest.param(2560, 1100, (1280, 1100), id="n-without-a-128-divisor"),
+    # 3.63 times the area, and no pair of dividing sides fits 4 MB: the
+    # overhanging tile stays
+    pytest.param(2100, 1100, (2048, 1024), id="no-dividing-tile-fits"),
+])
+def test_grouped_tiling_falls_back(k, n, tiles):
+    assert moe.grouped_tiling(k, n) == (moe.GMM_ROW_TILE, *tiles)

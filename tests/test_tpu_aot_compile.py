@@ -476,6 +476,7 @@ def test_delta_step_kernel_updates_the_state_in_place(topology, compiled_kernels
 # (hidden, expert width, experts held, picks a token)
 QWEN3_NEXT_EXPERTS = (2048, 512, 256, 10)  # 256 held of 512 experts
 KIMI_K2_EXPERTS = (7168, 2048, 12, 8)  # 12 held of 384 experts
+LING3_EXPERTS = (2560, 768, 128, 8)  # 128 held of 512 experts
 
 
 @pytest.mark.parametrize("tokens, experts", [
@@ -484,13 +485,16 @@ KIMI_K2_EXPERTS = (7168, 2048, 12, 8)  # 12 held of 384 experts
     pytest.param(128, QWEN3_NEXT_EXPERTS, id="qwen3next-decode-128"),
     pytest.param(256, KIMI_K2_EXPERTS, id="kimik2-decode-256"),
     pytest.param(24, QWEN3_NEXT_EXPERTS, id="rows-240-padded-to-the-tile"),
+    pytest.param(256, LING3_EXPERTS, id="ling3-decode-256"),
+    pytest.param(1536, LING3_EXPERTS, id="ling3-admit-1536"),
 ])
 def test_held_experts_grouped_product(topology, compiled_kernels, tokens, experts):
-    """One expert-parallel chip's share at the two MoE cells' widths: a prompt
-    bucket's picks, a decode step's (1,280 rows at 3 a group; Kimi K2's 2,048
-    of which 64 are held, in tiles of 7,168 x 4,096 and 2,048 x 7,168) and a
-    row count the row tile does not divide all compile to the Pallas grouped
-    matmul inside its 16 MiB of VMEM, twice, and to no `ragged_dot`."""
+    """One expert-parallel chip's share at the three MoE cells' widths: a
+    prompt bucket's picks, a decode step's (1,280 rows at 3 a group; Kimi
+    K2's 2,048 of which 64 are held; Ling 3.0 flash's 2,048 at 4 a group) and
+    a row count the row tile does not divide all compile to the Pallas
+    grouped matmul inside its 16 MiB of VMEM, twice, and to no `ragged_dot`
+    (Ling 3.0 flash's gate and up in tiles of 2,560 x 768)."""
     from accelerate_tpu.ops.moe import held_experts_mlp
 
     s = _one_device(topology)
