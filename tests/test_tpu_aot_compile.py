@@ -448,6 +448,69 @@ def test_ling3_scopes_and_kernel_name(topology, compiled_kernels, program):
         assert "mla_prefill" in kernels and "kda_step" not in hlo and "mla_absorb" not in hlo
 
 
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_k_exaone_programs_at_the_cells_shapes(topology, compiled_kernels, program):
+    """K-EXAONE's decode step (128 slots, the full layer's pool of 15,360
+    blocks of 64) and an 8,192-token admit, at the benchmark cell's published
+    widths and cut (five layers, 16 held experts, 19,200 vocabulary rows): the
+    scopes `window_attn` and `global_attn` reach the compiled HLO's `op_name`;
+    the decode step runs the fused kernel on the full layer's pool under the
+    flax scope's name (`%attn.N`) and on each sliding layer's rings, read as
+    a pool of one block a slot, under `window_attn` (its line names the rings'
+    shape `[128, 128, 1024]`, which the benchmark's readers look for); the admit runs the flash kernel with the window
+    on the four sliding layers and causal on the full one; both run the eight
+    grouped products. The programs with the weights, the pool and the rings
+    fit the chip. About 20 s."""
+    import json
+    import os
+
+    from accelerate_tpu.models.k_exaone import KExaoneConfig, KExaoneForCausalLM
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks", "chip")
+    with open(os.path.join(here, "configs", "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(here, "workloads", "k-exaone-236b-a23b.serve.long128.json")) as f:
+        engine = json.load(f)["engine"]
+    s = _one_device(topology)
+    rows, bt, blocks = engine["max_concurrency"], engine["paged_kv"]["block_tokens"], engine["paged_kv"]["num_blocks"]
+    n = cfg["num_hidden_layers"]
+    model_cfg = KExaoneConfig(
+        vocab_size=cfg["vocab_size"], num_hidden_layers=n, layer_types=tuple(cfg["layer_types"][:n]),
+        mlp_layer_types=tuple(cfg["mlp_layer_types"][:n]), experts_held=cfg["num_experts"],
+        n_positions=cfg["n_positions"], kv_cache_per_slot=True, kv_cache_paged=True, kv_num_blocks=blocks,
+        kv_block_tokens=bt, kv_paged_attention="fused")
+    module = KExaoneForCausalLM(model_cfg)
+    b, t = (rows, 1) if program == "step" else (engine["admit_batch"], max(engine["prompt_buckets"]))
+    tables = jnp.zeros((b, model_cfg.n_positions // bt), jnp.int32)
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((b, 1), jnp.int32), decode=True, block_tables=tables))
+    place = lambda tree: jax.tree.map(lambda x: _sds(x.shape, x.dtype, s), tree)  # noqa: E731
+
+    def run(params, cache, ids, lens, tables):
+        kw = dict(position_offset=lens) if program == "step" else dict(position_offset=0, cache_write_len=lens)
+        return module.apply({"params": params, "cache": cache}, ids, decode=True, block_tables=tables,
+                            mutable=["cache", "counters"], **kw)
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        place(shapes["params"]), place(shapes["cache"]), _sds((b, t), jnp.int32, s),
+        _sds((b,), jnp.int32, s), _sds(tables.shape, jnp.int32, s)).compile()
+    hlo = compiled.as_text()
+    for scope in ("window_attn", "global_attn", "moe_router", "moe_experts", "dense_mlp"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    kernels = re.findall(r'%([A-Za-z_\-]+)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert kernels.count("gmm") == 8 and "ragged-dot" not in hlo, kernels
+    if program == "step":
+        # the full layer's pool and, on each sliding layer, its rings read as a pool of one block a slot
+        assert kernels.count("attn") == 1 and kernels.count("window_attn") == 4 and len(kernels) == 13, kernels
+        assert re.search(rf"%window_attn(\.\d+)? = [^\n]*bf16\[{rows},128,1024\]", hlo)  # by the rings' shape
+    else:
+        assert kernels.count("window_attn") == 4 and kernels.count("global_attn") == 1, kernels
+    memory = compiled.memory_analysis()
+    rings = 4 * 2 * rows * 128 * 1024 * 2  # the full engine's rings beside the admit rows' own
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes \
+        - memory.alias_size_in_bytes + (rings if program == "admit" else 0)
+    assert held < 14.5e9, held / 1e9
+
 
 @pytest.mark.parametrize("slots, per_channel", [pytest.param(256, True, id="ling3-kda"),
                                                 pytest.param(128, False, id="qwen3-next-delta")])
