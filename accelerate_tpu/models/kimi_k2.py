@@ -336,19 +336,23 @@ class LatentAttention(nn.Module):
 PREFILL_TOKEN_CHUNKS = (1536, 1024)  # tokens a turn of an FFN over a long segment
 
 
-def by_token_chunks(ffn, xt: jax.Array):
-    """``ffn(xt [T, hidden]) -> (out [T, hidden], *int32 counts)`` over an admit
-    program's tokens a chunk at a time (`lax.map`): the float32 intermediates
-    of 6,144 tokens (an expert layer's ``[T * 8, 7168]`` rows twice over, the
-    dense layer's ``[T, 36864]``) are 3 GB at once and 0.8 GB a chunk, for one
-    more read of the layer's weights a chunk in a program bound by compute. A
+def by_token_chunks(ffn, *xs: jax.Array):
+    """``ffn(*xs) -> (out [T, hidden], *int32 counts)`` over an admit program's
+    tokens a chunk at a time (`lax.map` over the leading axis of every
+    ``xs``): what it wraps is compute bound, so a chunk's one more read of
+    the weights costs little, and the float32 intermediates of 6,144 tokens
+    (the dense layer's ``[T, 36864]``) are GBs at once. It wraps the dense
+    MLP and the shared expert; the routed experts take the whole segment and
+    bound their own intermediates by windows (`held_experts_mlp`), because at
+    tens of rows an expert a chunk the products wait on the weights' read. A
     decode step's few rows, and any count the chunks do not divide, go whole.
     Counts add up over the chunks."""
-    n_tokens = xt.shape[0]
+    n_tokens = xs[0].shape[0]
     chunk = next((c for c in PREFILL_TOKEN_CHUNKS if n_tokens > c and n_tokens % c == 0), None)
     if chunk is None:
-        return ffn(xt)
-    out, *counts = jax.lax.map(ffn, xt.reshape(n_tokens // chunk, chunk, -1))
+        return ffn(*xs)
+    out, *counts = jax.lax.map(lambda part: ffn(*part),
+                               tuple(a.reshape(n_tokens // chunk, chunk, -1) for a in xs))
     return (out.reshape(n_tokens, -1), *(c.sum() for c in counts))
 
 
@@ -391,14 +395,13 @@ class SigmoidMoE(nn.Module):
         s_gate_up = self.param("shared_gate_up", init, (e, 2 * fs), cfg.param_dtype)
         s_down = self.param("shared_down", init, (fs, e), cfg.param_dtype)
 
-        def ffn(xt):
-            weights, idx = route_sigmoid_top_k(xt, router, bias, cfg.num_experts_per_tok,
-                                               cfg.routed_scaling_factor)
-            out, picks, touched = held_experts_mlp(xt, weights, idx, w_gate_up, w_down,
-                                                   cfg.first_expert)
-            return (out + shared_expert_mlp(xt, None, s_gate_up, s_down)).astype(x.dtype), picks, touched
-
-        out, *counts = by_token_chunks(ffn, x.reshape(b * s, e))
+        xt = x.reshape(b * s, e)
+        weights, idx = route_sigmoid_top_k(xt, router, bias, cfg.num_experts_per_tok,
+                                           cfg.routed_scaling_factor)
+        routed, *counts = held_experts_mlp(xt, weights, idx, w_gate_up, w_down, cfg.first_expert,
+                                           cfg.n_routed_experts)
+        out, = by_token_chunks(lambda xc, rc: (
+            (rc + shared_expert_mlp(xc, None, s_gate_up, s_down)).astype(x.dtype),), xt, routed)
         for name, value in zip(STEP_COUNTERS, counts):
             self.sow("counters", name, value, reduce_fn=lambda a, c: a + c,
                      init_fn=lambda: jnp.zeros((), jnp.int32))

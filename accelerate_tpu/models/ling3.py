@@ -243,7 +243,7 @@ class KimiDeltaAttention(nn.Module):
                 raise NotImplementedError(
                     "a multi-token segment on top of recurrent state (prefix reuse, speculative "
                     "verify) is not supported: only prefill from an empty cache and one-token decode")
-            mixed, window = causal_conv_prefill(qkv, conv_w, cache_write_len)
+            mixed, window = causal_conv_prefill(qkv, conv_w, cache_write_len, window_first=True)
             q, k, v = heads(mixed)
             g, beta = mask_pad(g, beta, cache_write_len)
             o, new_state = gated_delta_prefill(q, k, v, g, beta, chunk=cfg.kda_chunk)
@@ -302,17 +302,16 @@ class GroupLimitedMoE(nn.Module):
         s_gate_up = self.param("shared_gate_up", init, (e, 2 * fs), cfg.param_dtype)
         s_down = self.param("shared_down", init, (fs, e), cfg.param_dtype)
 
-        def ffn(xt):
-            weights, idx = route_sigmoid_top_k(
-                xt, router, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
-                cfg.n_group, cfg.topk_group)
-            out, picks, touched = held_experts_mlp(xt, weights, idx, w_gate_up, w_down,
-                                                   cfg.first_expert)
-            here = (idx >= cfg.first_expert) & (idx < cfg.first_expert + held)
-            out = out + shared_expert_mlp(xt, None, s_gate_up, s_down)
-            return out.astype(x.dtype), picks, touched, jnp.sum(here.any(-1)).astype(jnp.int32)
-
-        out, *counts = by_token_chunks(ffn, x.reshape(b * s, e))
+        xt = x.reshape(b * s, e)
+        weights, idx = route_sigmoid_top_k(
+            xt, router, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+            cfg.n_group, cfg.topk_group)
+        routed, picks, touched = held_experts_mlp(xt, weights, idx, w_gate_up, w_down,
+                                                  cfg.first_expert, cfg.num_experts)
+        here = (idx >= cfg.first_expert) & (idx < cfg.first_expert + held)
+        out, = by_token_chunks(lambda xc, rc: (
+            (rc + shared_expert_mlp(xc, None, s_gate_up, s_down)).astype(x.dtype),), xt, routed)
+        counts = (picks, touched, jnp.sum(here.any(-1)).astype(jnp.int32))
         for name, value in zip(STEP_COUNTERS, counts):
             self.sow("counters", name, value, reduce_fn=lambda a, c: a + c,
                      init_fn=lambda: jnp.zeros((), jnp.int32))

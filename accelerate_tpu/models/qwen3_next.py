@@ -289,7 +289,8 @@ class SparseMoE(nn.Module):
         s_down = self.param("shared_down", init, (fs, e), cfg.param_dtype)
         xt = x.reshape(b * s, e)
         weights, idx = route_top_k(xt, router, cfg.num_experts_per_tok)
-        out, picks, touched = held_experts_mlp(xt, weights, idx, w_gate_up, w_down, cfg.first_expert)
+        out, picks, touched = held_experts_mlp(xt, weights, idx, w_gate_up, w_down, cfg.first_expert,
+                                               cfg.num_experts)
         out = out + shared_expert_mlp(xt, s_gate, s_gate_up, s_down)
         for name, value in zip(STEP_COUNTERS, (picks, touched)):
             self.sow("counters", name, value, reduce_fn=lambda a, c: a + c,

@@ -24,7 +24,8 @@ holds, routes over all of them, drops no token, and computes the held experts'
 part of the result with a grouped matrix product over the picks sorted by
 expert (`grouped_product`: the Pallas grouped matmul that ships with JAX on
 the TPU, for a prefill's rows and a decode step's alike, `jax.lax.ragged_dot`
-on any other backend). `shared_expert_mlp` is
+on any other backend), a long segment's in windows of the sorted held picks
+(`expert_pass_rows`). `shared_expert_mlp` is
 the always-on expert (behind a sigmoid gate, or ungated) that such models put
 beside the routed ones. Two routers: `route_top_k` (softmax over all experts)
 and `route_sigmoid_top_k` (sigmoid scores chosen under a selection bias,
@@ -200,11 +201,16 @@ def route_sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, top_k:
 GMM_ROW_TILE = 128  # rows a tile of the Pallas grouped product: a few small groups share one
 GMM_WEIGHT_TILE = 2048 * 1024  # elements of a group's matrix a tile: 4 MB in bfloat16, twice in VMEM
 GMM_FLOPS_PER_BYTE = 240  # the v5e's bfloat16 peak over its HBM bandwidth: 197e12 / 819e9
+# the intermediates one pass of `held_experts_mlp` may hold: just over the
+# largest segment a serving cell ran in one pass before windows, Qwen3-Next's
+# 4 x 1,536-token admit (61,440 picks of 17 KB, 1.07 GB)
+EXPERT_PASS_BYTES = 1 << 30
 
-# Grouped products by the path they took and their row count, counted where
-# the path is decided: when a program is TRACED. The kernel's tests read it; on
-# the chip the device trace names the kernel that ran (`%gmm.N` events in the
-# benchmark's `device_ops`).
+# Grouped products by the path they took and their row count, and
+# `held_experts_mlp`'s windowed calls as ("window", rows a window), counted
+# where the path is decided: when a program is TRACED. The kernel's tests read
+# it; on the chip the device trace names the kernel that ran (`%gmm.N` events
+# in the benchmark's `device_ops`, a window's with `f32[rows,` in their line).
 GROUPED_PRODUCT_TRACES: collections.Counter = collections.Counter()
 
 
@@ -274,6 +280,25 @@ def grouped_product(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Arra
     return out[:n_rows] if pad else out
 
 
+def expert_pass_rows(hidden: int, width: int, dtype: Any) -> int:
+    """Picks one pass of `held_experts_mlp` takes at most: the largest
+    multiple of the row tile whose gathered rows, float32 gate-up, activation
+    and float32 output fit `EXPERT_PASS_BYTES`. 18,688 at K-EXAONE's widths,
+    16,896 at Kimi K2's, 46,592 at Ling 3.0 flash's, 61,568 at Qwen3-Next's."""
+    item = jnp.dtype(dtype).itemsize
+    per_pick = hidden * item + 2 * width * 4 + width * item + hidden * 4
+    return max(GMM_ROW_TILE, EXPERT_PASS_BYTES // per_pick // GMM_ROW_TILE * GMM_ROW_TILE)
+
+
+def _expert_products(rows: jax.Array, sizes: jax.Array, w_gate_up: jax.Array,
+                     w_down: jax.Array) -> jax.Array:
+    """``down_e(silu(gate_e r) * up_e r)`` of rows sorted by expert, float32."""
+    two_f = w_gate_up.shape[-1]
+    gate_up = grouped_product(rows, w_gate_up.astype(rows.dtype), sizes)
+    act = (jax.nn.silu(gate_up[:, : two_f // 2]) * gate_up[:, two_f // 2:]).astype(rows.dtype)
+    return grouped_product(act, w_down.astype(rows.dtype), sizes)
+
+
 def held_experts_mlp(
     x: jax.Array,  # [T, hidden] tokens, compute dtype
     weights: jax.Array,  # [T, k] float32 combine weights (`route_top_k`)
@@ -281,6 +306,7 @@ def held_experts_mlp(
     w_gate_up: jax.Array,  # [E_held, hidden, 2 * F]: gate columns, then up
     w_down: jax.Array,  # [E_held, F, hidden]
     first_expert: int = 0,  # this share holds experts [first, first + E_held)
+    n_experts: int | None = None,  # the router's width; None: the experts held
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """``sum_e p_e * down_e(silu(gate_e x) * up_e x)`` over each token's chosen
     experts that are held here; a pick that falls on an absent expert adds
@@ -288,9 +314,24 @@ def held_experts_mlp(
     the ``T * k`` picks are sorted by expert, absent ones last, and the held
     ones run through two grouped products whose group sizes are the experts'
     pick counts. Returns ``(out [T, hidden] float32, picks_held, experts_touched)``
-    with the two int32 counts of this call."""
+    with the two int32 counts of this call.
+
+    Where the ``T * k`` picks fit `expert_pass_rows` (what `EXPERT_PASS_BYTES`
+    holds at these widths: every decode step's and every admit the engine
+    ran whole before windows), they go through in this one pass, with no
+    loop. Where they do not, the sorted held picks go through in windows in a
+    `lax.while_loop` of ``ceil(held / C)`` turns, ``C`` being the held picks
+    the segment is expected to bring (``T * k * E_held / n_experts``, up to
+    the row tile) and at most that budget: a window gathers its ``C`` rows,
+    runs the grouped products with the group sizes clipped to it (the Pallas
+    grouped matmul fetches only the experts whose group is not empty, so a
+    segment reads each held expert about once, twice where its group
+    straddles two windows, where token chunks read them once a chunk) and
+    adds each row times its weight into a float32 ``[T, hidden]`` sum by
+    scatter. Each product is the one the single pass computes; only the
+    order in which a token's picks are summed differs."""
     n_tokens, k = expert_idx.shape
-    n_held, _, two_f = w_gate_up.shape
+    n_held, hidden, two_f = w_gate_up.shape
     # picks place by place, pick j of token t at j * T + t: the weighted sum
     # over a token's picks is then over the leading axis of [k, T, hidden],
     # which no tiling pads (k = 10 as a second-minor axis is relaid to 16)
@@ -299,17 +340,43 @@ def held_experts_mlp(
     group = jnp.where(held, local, n_held).astype(jnp.int32)  # absent picks sort last
     order = jnp.argsort(group, stable=True)
     sizes = jnp.bincount(group, length=n_held + 1)[:n_held].astype(jnp.int32)
+    cap = expert_pass_rows(hidden, two_f // 2, x.dtype)
     with jax.named_scope("moe_experts"):
-        rows = x[order % n_tokens]
-        gate_up = grouped_product(rows, w_gate_up.astype(x.dtype), sizes)
-        act = (jax.nn.silu(gate_up[:, : two_f // 2]) * gate_up[:, two_f // 2:]).astype(x.dtype)
-        y = grouped_product(act, w_down.astype(x.dtype), sizes)
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
-        # rows past the last group belong to absent experts: whatever the
-        # grouped product left there is dropped, not weighted
-        scale = jnp.where(held, weights.T.reshape(-1), 0.0)[:, None]
-        out = jnp.where(held[:, None], y[inverse] * scale, 0.0).reshape(k, n_tokens, -1).sum(axis=0)
+        if n_tokens * k <= cap:
+            y = _expert_products(x[order % n_tokens], sizes, w_gate_up, w_down)
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+            # rows past the last group belong to absent experts: whatever the
+            # grouped product left there is dropped, not weighted
+            scale = jnp.where(held, weights.T.reshape(-1), 0.0)[:, None]
+            out = jnp.where(held[:, None], y[inverse] * scale, 0.0).reshape(k, n_tokens, -1).sum(axis=0)
+        else:
+            expected = -(-n_tokens * k * n_held // ((n_experts or n_held) * GMM_ROW_TILE)) * GMM_ROW_TILE
+            window = min(cap, expected)
+            GROUPED_PRODUCT_TRACES["window", window] += 1
+            out = _held_by_windows(x, weights.T.reshape(-1), order, sizes, jnp.sum(held),
+                                   window, w_gate_up, w_down)
     return out, jnp.sum(held).astype(jnp.int32), jnp.sum(sizes > 0).astype(jnp.int32)
+
+
+def _held_by_windows(x, place_weights, order, sizes, n_live, window, w_gate_up, w_down):
+    """`held_experts_mlp`'s sum over the first ``n_live`` picks of ``order``
+    (held, sorted by expert) in windows of ``window`` rows."""
+    n_tokens = x.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    order = jnp.pad(order, (0, -order.shape[0] % window))  # whole windows; the pad lies past the live picks
+
+    def one_window(carry):
+        lo, acc = carry
+        part = jax.lax.dynamic_slice(order, (lo,), (window,))
+        clipped = jnp.clip(ends, lo, lo + window) - jnp.clip(starts, lo, lo + window)
+        y = _expert_products(x[part % n_tokens], clipped, w_gate_up, w_down)
+        # rows past the live picks hold anything: sent past the end, dropped
+        token = jnp.where(lo + jnp.arange(window) < n_live, part % n_tokens, n_tokens)
+        return lo + window, acc.at[token].add(y * place_weights[part][:, None], mode="drop")
+
+    acc = jnp.zeros((n_tokens, x.shape[1]), jnp.float32)
+    return jax.lax.while_loop(lambda c: c[0] < n_live, one_window, (jnp.int32(0), acc))[1]
 
 
 def shared_expert_mlp(x: jax.Array, gate: jax.Array | None, w_gate_up: jax.Array,

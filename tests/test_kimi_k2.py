@@ -39,6 +39,7 @@ from accelerate_tpu.models.kimi_k2 import (  # noqa: E402
     yarn_rope,
 )
 from accelerate_tpu.models.kv_cache import LATENT_LEAF, leaf_name, tree_nbytes  # noqa: E402
+from accelerate_tpu.ops import moe as moe_ops  # noqa: E402
 from accelerate_tpu.ops.flash_attention import (  # noqa: E402
     paged_decode_attention,
     paged_decode_vmem_bytes,
@@ -127,20 +128,29 @@ def test_whole_model_matches_reference(cfg, model_cfg, params, ref_params):
 
 
 @pytest.mark.parametrize("tokens", [2048, 3072])
-def test_ffn_by_token_chunks_equals_the_whole(cfg, model_cfg, params, tokens):
-    """An admit program's long segment runs its FFNs a chunk of tokens at a
-    time; the result and the picks held are those of the whole."""
+def test_ffn_by_token_chunks_equals_the_whole(monkeypatch, cfg, model_cfg, params, tokens):
+    """An admit program's long segment runs its shared expert a chunk of
+    tokens at a time and its routed experts over the whole segment, in
+    windows of held picks where they outnumber one (forced here: 128 picks a
+    window); the result and the picks held are those of 512-token pieces."""
     x = hidden(cfg, (1, tokens), key=tokens)
     moe = SigmoidMoE(model_cfg)
-    got, counted = moe.apply({"params": params["layer_2"]["moe"]}, x, mutable=["counters"])
     pieces = [moe.apply({"params": params["layer_2"]["moe"]}, x[:, at: at + 512], mutable=["counters"])
               for at in range(0, tokens, 512)]
+    e, f, item = model_cfg.hidden_size, model_cfg.moe_intermediate_size, jnp.dtype(model_cfg.dtype).itemsize
+    monkeypatch.setattr(moe_ops, "EXPERT_PASS_BYTES", 128 * (e * item + 2 * f * 4 + f * item + e * 4))
+    assert moe_ops.expert_pass_rows(e, f, model_cfg.dtype) == 128
+    before = moe_ops.GROUPED_PRODUCT_TRACES.copy()
+    got, counted = moe.apply({"params": params["layer_2"]["moe"]}, x, mutable=["counters"])
+    assert (moe_ops.GROUPED_PRODUCT_TRACES - before)["window", 128] == 1
     np.testing.assert_allclose(got, jnp.concatenate([p[0] for p in pieces], 1), atol=TOL, rtol=TOL)
     assert int(counted["counters"]["moe_picks_held"]) == sum(
         int(p[1]["counters"]["moe_picks_held"]) for p in pieces)
     out, n = by_token_chunks(lambda xt: (xt * 2, jnp.int32(1)), x[0])
     assert int(n) == tokens // (1536 if tokens % 1536 == 0 else 1024)
     np.testing.assert_array_equal(out, x[0] * 2)
+    out, = by_token_chunks(lambda xt, yt: (xt + yt,), x[0], x[0] * 2)
+    np.testing.assert_array_equal(out, x[0] * 3)
 
 
 # ----------------------------------------------------- absorbed equals plain

@@ -229,3 +229,118 @@ def test_grouped_tiling_at_the_moe_cells(k, n, tiles):
 ])
 def test_grouped_tiling_falls_back(k, n, tiles):
     assert moe.grouped_tiling(k, n) == (moe.GMM_ROW_TILE, *tiles)
+
+
+# ------------------------------------------ the held picks in windows of rows
+ROUTER = 16  # a router this wide, of which `HELD` experts from `first` are held here
+WINDOW_HIDDEN, WINDOW_WIDTH = 64, 32  # 704 bytes a pick in bfloat16: `expert_pass_rows` reads n at n * 704
+
+
+def _window_picks(tokens, k, held_a_token, first, held_ids=None, seed=0):
+    """``[tokens, k]`` distinct ids a token, ``held_a_token`` of them among
+    ``held_ids`` (default: every held expert), the rest absent ones."""
+    rng = np.random.default_rng(seed)
+    held_ids = np.arange(first, first + HELD) if held_ids is None else np.asarray(held_ids) + first
+    absent = np.asarray([e for e in range(ROUTER) if not first <= e < first + HELD])
+    rows = [np.concatenate([rng.choice(held_ids, held_a_token, replace=False),
+                            rng.choice(absent, k - held_a_token, replace=False)]) for _ in range(tokens)]
+    return jnp.asarray(np.stack([rng.permutation(r) for r in rows]), jnp.int32)
+
+
+@pytest.mark.parametrize("tokens, k, held_a_token, first, held_ids, router, cap, window, windows, path", [
+    pytest.param(64, 4, 1, 0, None, None, 128, 128, 1, "ragged_dot", id="one-window"),
+    pytest.param(64, 4, 3, 0, None, None, 128, 128, 2, "ragged_dot", id="two-windows"),
+    pytest.param(96, 4, 3, 0, None, None, 128, 128, 3, "ragged_dot", id="three-windows"),
+    # experts 0 and 1 take 75 picks each: rows 75-149, expert 1's, straddle 128
+    pytest.param(75, 4, 2, 0, (0, 1), None, 128, 128, 2, "ragged_dot", id="a-group-straddles-two-windows"),
+    pytest.param(80, 4, 4, 2, None, None, 128, 128, 3, "ragged_dot", id="every-pick-held"),
+    pytest.param(64, 4, 0, 0, None, None, 128, 128, 0, "ragged_dot", id="no-pick-held"),
+    pytest.param(64, 4, 2, 5, None, None, 128, 128, 1, "ragged_dot", id="first-expert-5"),
+    # 128 picks: one window holds them all, so the single pass, with no loop
+    pytest.param(32, 4, 2, 0, None, None, 128, None, None, "ragged_dot", id="picks-fit-one-window"),
+    # a router 16 wide over 6 held experts: 640 picks bring 240 held ones
+    # expected, a window of 256 under a budget of 512
+    pytest.param(160, 4, 1, 0, None, ROUTER, 512, 256, 1, "ragged_dot", id="expected-held-picks-one-window"),
+    pytest.param(160, 4, 2, 0, None, ROUTER, 512, 256, 2, "ragged_dot", id="expected-held-picks-two-windows"),
+    # 384 picks bring 144 expected, 256 rows to the tile, over the budget of 128
+    pytest.param(96, 4, 3, 0, None, ROUTER, 128, 128, 3, "ragged_dot", id="the-budget-under-the-expected"),
+    # the windows through the Pallas grouped matmul (interpreted), row tiles of 16
+    pytest.param(75, 4, 2, 1, (0, 1), None, 128, 128, 2, "pallas", id="pallas-a-group-straddles-two-windows"),
+])
+def test_held_experts_by_windows_match_one_pass(monkeypatch, tokens, k, held_a_token, first, held_ids, router,
+                                                cap, window, windows, path):
+    """`held_experts_mlp` with `EXPERT_PASS_BYTES` cut to ``cap`` picks,
+    against the same call in one pass: each product is the same, so the sums
+    agree to float32 rounding, and the counts are the same. A window takes
+    the held picks expected of a ``router``-wide router (``None``: every pick
+    may be held), at most ``cap``. ``windows`` is how many the held picks
+    fill; ``None`` where the ``T * k`` picks fit the budget, which traces the
+    single pass and no loop."""
+    if path == "pallas":
+        from jax.experimental.pallas.ops.tpu import megablox
+
+        from accelerate_tpu.utils import environment
+
+        monkeypatch.setattr(environment, "on_tpu_platform", lambda: True)
+        monkeypatch.setattr(megablox, "gmm", functools.partial(megablox.gmm, interpret=True))
+        monkeypatch.setattr(moe, "grouped_tiling", _small_tiles)
+    ks = jax.random.split(jax.random.key(tokens * k + held_a_token), 4)
+    x = jax.random.normal(ks[0], (tokens, WINDOW_HIDDEN), jnp.float32).astype(jnp.bfloat16)
+    idx = _window_picks(tokens, k, held_a_token, first, held_ids)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], idx.shape), -1)
+    w_gate_up = (jax.random.normal(ks[2], (HELD, WINDOW_HIDDEN, 2 * WINDOW_WIDTH)) * 0.1).astype(jnp.bfloat16)
+    w_down = (jax.random.normal(ks[3], (HELD, WINDOW_WIDTH, WINDOW_HIDDEN)) * 0.1).astype(jnp.bfloat16)
+    args = (x, weights, idx, w_gate_up, w_down)
+    call = lambda: lambda *a: held_experts_mlp(  # noqa: E731  (a new trace each)
+        *a, first_expert=first, n_experts=router)
+    want, picks, touched = jax.jit(call())(*args)
+
+    monkeypatch.setattr(moe, "EXPERT_PASS_BYTES", cap * 704)
+    assert moe.expert_pass_rows(WINDOW_HIDDEN, WINDOW_WIDTH, jnp.bfloat16) == cap
+    looped = "while" in str(jax.make_jaxpr(call())(*args))
+    before = moe.GROUPED_PRODUCT_TRACES.copy()
+    got, got_picks, got_touched = jax.jit(call())(*args)
+    assert looped == (windows is not None) == (tokens * k > cap)
+    assert moe.GROUPED_PRODUCT_TRACES - before == (
+        {("window", window): 1, (path, window): 2} if looped else {(path, tokens * k): 2})
+    assert (int(got_picks), int(got_touched)) == (int(picks), int(touched))
+    assert int(picks) == tokens * held_a_token
+    if windows is not None:
+        assert -(-int(picks) // window) == windows
+    if held_ids is not None:  # the straddle the case is named for
+        sizes = np.bincount(np.asarray(idx).ravel(), minlength=ROUTER)[first: first + HELD]
+        assert any(end - size < window < end for end, size in zip(np.cumsum(sizes), sizes))
+    if int(picks) == 0:
+        np.testing.assert_array_equal(got, np.zeros_like(got))
+    else:
+        assert float(jnp.abs(want).max()) > 1e-2
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("hidden, width, held, router, k, tokens, window", [
+    # (widths, held experts of the router's, picks a token) of each MoE serving cell
+    pytest.param(2048, 512, 256, 512, 10, 6144, None, id="qwen3-next-4x1536"),
+    pytest.param(7168, 2048, 12, 384, 8, 256, None, id="kimi-k2-decode-step"),
+    pytest.param(7168, 2048, 12, 384, 8, 1536, None, id="kimi-k2-1x1536"),
+    pytest.param(7168, 2048, 12, 384, 8, 2048, None, id="kimi-k2-4x512"),
+    pytest.param(7168, 2048, 12, 384, 8, 3072, 768, id="kimi-k2-2x1536"),
+    pytest.param(7168, 2048, 12, 384, 8, 6144, 1536, id="kimi-k2-4x1536"),
+    pytest.param(2560, 768, 128, 512, 8, 3072, None, id="ling3-2x1536"),
+    pytest.param(2560, 768, 128, 512, 8, 6144, 12288, id="ling3-4x1536"),
+    pytest.param(6144, 2048, 16, 128, 8, 128, None, id="k-exaone-decode-step"),
+    pytest.param(6144, 2048, 16, 128, 8, 4096, 4096, id="k-exaone-4096"),
+    pytest.param(6144, 2048, 16, 128, 8, 8192, 8192, id="k-exaone-8192"),
+])
+def test_held_experts_pass_or_windows_at_the_cells_shapes(hidden, width, held, router, k, tokens, window):
+    """At the serving cells' widths (traced, not run): every decode step and
+    every segment up to Qwen3-Next's 4 x 1,536-token admit goes through the
+    single pass; a longer one through windows of the held picks it is
+    expected to bring (``window``)."""
+    sds = jax.ShapeDtypeStruct
+    args = (sds((tokens, hidden), jnp.bfloat16), sds((tokens, k), jnp.float32), sds((tokens, k), jnp.int32),
+            sds((held, hidden, 2 * width), jnp.bfloat16), sds((held, width, hidden), jnp.bfloat16))
+    before = moe.GROUPED_PRODUCT_TRACES.copy()
+    jaxpr = str(jax.make_jaxpr(lambda *a: held_experts_mlp(*a, n_experts=router))(*args))
+    assert ("while" in jaxpr) == (window is not None)
+    assert moe.GROUPED_PRODUCT_TRACES - before == (
+        {("window", window): 1, ("ragged_dot", window): 2} if window else {("ragged_dot", tokens * k): 2})

@@ -217,6 +217,22 @@ def test_conv_window_is_the_last_true_inputs():
     np.testing.assert_array_equal(window_next[0], x[1, 2:5])
 
 
+def test_conv_window_first_is_the_same_convolution():
+    """Taking the window first, behind a barrier beside the input (what Ling
+    3.0 flash's KDA layers ask for), changes the program's order, not what it
+    computes."""
+    x = jax.random.normal(jax.random.key(0), (3, 10, 6))
+    w = jax.random.normal(jax.random.key(1), (4, 6))
+    lengths = jnp.asarray([10, 4, 2])
+    want = gated_delta.causal_conv_prefill(x, w, lengths)
+    got = gated_delta.causal_conv_prefill(x, w, lengths, window_first=True)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    for first in (False, True):
+        jaxpr = str(jax.make_jaxpr(lambda a: gated_delta.causal_conv_prefill(a, w, lengths, first))(x))
+        assert ("optimization_barrier" in jaxpr) == first
+
+
 # ------------------------------------------------------------------ the engine
 class Probe(nn.Module):
     """The model with its logits handed to the test as they are computed."""

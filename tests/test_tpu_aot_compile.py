@@ -459,8 +459,10 @@ def test_k_exaone_programs_at_the_cells_shapes(topology, compiled_kernels, progr
     a pool of one block a slot, under `window_attn` (its line names the rings'
     shape `[128, 128, 1024]`, which the benchmark's readers look for); the admit runs the flash kernel with the window
     on the four sliding layers and causal on the full one; both run the eight
-    grouped products. The programs with the weights, the pool and the rings
-    fit the chip. About 20 s."""
+    grouped products, the decode step's in no loop and the admit's inside the
+    loop over windows of held picks. The programs with the weights, the pool
+    and the rings fit the chip, and the admit's workspace is no larger than
+    when its FFNs ran in token chunks. About 20 s."""
     import json
     import os
 
@@ -499,6 +501,14 @@ def test_k_exaone_programs_at_the_cells_shapes(topology, compiled_kernels, progr
         assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
     kernels = re.findall(r'%([A-Za-z_\-]+)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"', hlo)
     assert kernels.count("gmm") == 8 and "ragged-dot" not in hlo, kernels
+    # a decode step's 1,024 picks fit one pass, an admit's 65,536 do not:
+    # its grouped products run in the loop over windows of held picks
+    comps, _ = _hlo_computations(hlo)
+    looped = _reached(comps, [body for lines in comps.values() for line in lines
+                              for body in re.findall(r" while\(.*body=%?([\w.\-]+)", line)])
+    gmm = re.compile(r'%gmm(\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"')
+    in_loops = sum(bool(gmm.search(line)) for comp in looped for line in comps[comp])
+    assert in_loops == (0 if program == "step" else 8), in_loops
     if program == "step":
         # the full layer's pool and, on each sliding layer, its rings read as a pool of one block a slot
         assert kernels.count("attn") == 1 and kernels.count("window_attn") == 4 and len(kernels) == 13, kernels
@@ -510,6 +520,10 @@ def test_k_exaone_programs_at_the_cells_shapes(topology, compiled_kernels, progr
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes \
         - memory.alias_size_in_bytes + (rings if program == "admit" else 0)
     assert held < 14.5e9, held / 1e9
+    if program == "admit":
+        # the admit's workspace when every FFN ran a 1,024-token chunk at a
+        # time, compiled here the same way: the windows hold no more
+        assert memory.temp_size_in_bytes <= 1_500_432_384, memory.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("slots, per_channel", [pytest.param(256, True, id="ling3-kda"),
@@ -581,11 +595,8 @@ def test_held_experts_grouped_product(topology, compiled_kernels, tokens, expert
 TAIL_VOCAB = 1000  # a sort this wide compiles in a second; 8,192 wide takes 20
 
 
-def _always_run_wide_sorts(hlo, width):
-    """``(sorts, branch_sorts)``: the ``sort`` instructions over ``[..., width]``
-    in computations the program runs on every call (reached from ``ENTRY``
-    through fusions, calls and loop bodies), and those reached only through a
-    ``conditional``'s branch computations."""
+def _hlo_computations(hlo):
+    """``({computation: its instruction lines}, the ENTRY computation's name)``."""
     comps, entry, name = {}, None, None
     for line in hlo.splitlines():
         head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
@@ -597,19 +608,33 @@ def _always_run_wide_sorts(hlo, width):
             name = None
         elif name is not None:
             comps[name].append(line)
-    wide = re.compile(rf"\[(\d+,)*{width}\]\S* sort\(")  # the result, or a tuple result's last part
-    todo, seen, always = [entry], set(), []
+    return comps, entry
+
+
+def _reached(comps, roots):
+    """The computations reached from ``roots`` through fusions, calls and loop
+    bodies, never into a ``conditional``'s branches."""
+    todo, seen = list(roots), set()
     while todo:
         comp = todo.pop()
         if comp in seen:
             continue
         seen.add(comp)
         for line in comps[comp]:
-            if wide.search(line):
-                always.append(line.strip()[:160])
             if " conditional(" not in line:
-                todo += re.findall(
-                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+                todo += re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+    return seen
+
+
+def _always_run_wide_sorts(hlo, width):
+    """``(sorts, branch_sorts)``: the ``sort`` instructions over ``[..., width]``
+    in computations the program runs on every call (reached from ``ENTRY``
+    through fusions, calls and loop bodies), and those reached only through a
+    ``conditional``'s branch computations."""
+    comps, entry = _hlo_computations(hlo)
+    wide = re.compile(rf"\[(\d+,)*{width}\]\S* sort\(")  # the result, or a tuple result's last part
+    seen = _reached(comps, [entry])
+    always = [line.strip()[:160] for comp in seen for line in comps[comp] if wide.search(line)]
     elsewhere = [line.strip()[:160] for comp in comps.keys() - seen
                  for line in comps[comp] if wide.search(line)]
     return always, elsewhere
