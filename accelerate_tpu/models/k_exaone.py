@@ -138,22 +138,27 @@ class KExaoneConfig:
             state_leaves=WINDOW_LEAVES, step_counters=STEP_COUNTERS)
 
 
-def _window_attend(mod, cfg: KExaoneConfig, q, k, v, positions, decode, fresh_prefill,
-                   cache_write_mask, cache_write_len):
-    """A sliding layer's attention through its ring (module docstring)."""
-    b, s, hkv, d = k.shape
+def _window_attend(mod, cfg, q, k, v, positions, decode, fresh_prefill,
+                   cache_write_mask, cache_write_len, sinks=None):
+    """A sliding layer's attention through its ring (module docstring). The
+    key and the value ring each keep their own head size (``v`` may be
+    narrower than ``k``); ``sinks`` (``[q heads]``, `models/mimo_v2.py`) go to
+    the band kernel and the fused decode kernel alike."""
+    b, s, hkv, _ = k.shape
     w = cfg.sliding_window
     is_init = decode and mod.has_variable("cache", WINDOW_LEAVES[0])
-    ring = [mod.variable("cache", name, jnp.zeros, (b, w, hkv * d), cfg.dtype)
-            for name in WINDOW_LEAVES] if decode else []
+    ring = [mod.variable("cache", name, jnp.zeros, (b, w, hkv * new.shape[-1]), cfg.dtype)
+            for name, new in zip(WINDOW_LEAVES, (k, v))] if decode else []
     if not is_init or fresh_prefill:
-        out = attention(q, k, v, causal=True, window=w, implementation=cfg.attention_impl)
+        out = attention(q, k, v, causal=True, window=w, implementation=cfg.attention_impl,
+                        sinks=sinks)
         if is_init:
             # ring row t keeps the last position p < len with p % w == t
             lens = jnp.full((b,), s, jnp.int32) if cache_write_len is None else cache_write_len
             last = lens[:, None] - 1 - (lens[:, None] - 1 - jnp.arange(w)[None, :]) % w  # [b, w]
             for var, new in zip(ring, (k, v)):
-                rows = jnp.take_along_axis(new.reshape(b, s, hkv * d), jnp.maximum(last, 0)[..., None], 1)
+                rows = jnp.take_along_axis(new.reshape(b, s, var.value.shape[-1]),
+                                           jnp.maximum(last, 0)[..., None], 1)
                 var.value = jnp.where((last >= 0)[..., None], rows, 0).astype(cfg.dtype)
         return out
     if s != 1:
@@ -164,19 +169,22 @@ def _window_attend(mod, cfg: KExaoneConfig, q, k, v, positions, decode, fresh_pr
     live = jnp.ones((b,), bool) if cache_write_mask is None else cache_write_mask.astype(bool)
     row = jnp.where(live, pos % w, w)  # a frozen slot's write lands past the ring: dropped
     for var, new in zip(ring, (k, v)):
-        var.value = var.value.at[jnp.arange(b), row].set(new[:, 0].reshape(b, hkv * d), mode="drop")
+        var.value = var.value.at[jnp.arange(b), row].set(new[:, 0].reshape(b, var.value.shape[-1]),
+                                                         mode="drop")
     length = jnp.minimum(pos + 1, w)
     mod.sow("counters", "window_rows", jnp.sum(jnp.where(live, length, 0)).astype(jnp.int32),
             reduce_fn=lambda a, c: a + c, init_fn=lambda: jnp.zeros((), jnp.int32))
     # the rings as a pool of one block a slot: slot i's table is [i]
     slots = jnp.arange(b, dtype=jnp.int32)[:, None]
-    return paged_decode_attention(q[:, 0], ring[0].value, ring[1].value, slots, length)[:, None]
+    return paged_decode_attention(q[:, 0], ring[0].value, ring[1].value, slots, length,
+                                  sinks=sinks)[:, None]
 
 
-def _global_attend(mod, cfg: KExaoneConfig, q, k, v, decode, fresh_prefill, cache_write_mask,
+def _global_attend(mod, cfg, q, k, v, decode, fresh_prefill, cache_write_mask,
                    block_tables, cache_write_len):
     """A full layer's attention: the segment over itself, or through the
-    paged pool (the fused kernel for a one-token step of the fused engine)."""
+    paged pool (the fused kernel for a one-token step of the fused engine).
+    ``v`` may be narrower than ``k``: the pool keeps it at its own width."""
     s = q.shape[1]
 
     def prefill():

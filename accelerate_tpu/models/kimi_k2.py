@@ -376,7 +376,9 @@ class DenseMLP(nn.Module):
 
 
 class SigmoidMoE(nn.Module):
-    """The routed experts this chip holds, plus the ungated shared expert."""
+    """The routed experts this chip holds, plus the ungated shared expert
+    where ``n_shared_experts`` is above 0 (0 or None: none, and no weights
+    for it)."""
 
     config: KimiK2Config
 
@@ -385,23 +387,27 @@ class SigmoidMoE(nn.Module):
         cfg = self.config
         b, s, e = x.shape
         held = cfg.n_routed_experts if cfg.experts_held is None else cfg.experts_held
-        f, fs = cfg.moe_intermediate_size, cfg.moe_intermediate_size * cfg.n_shared_experts
+        f, fs = cfg.moe_intermediate_size, cfg.moe_intermediate_size * (cfg.n_shared_experts or 0)
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (e, cfg.n_routed_experts), jnp.float32)
         bias = self.param("e_score_correction_bias", nn.initializers.zeros,
                           (cfg.n_routed_experts,), jnp.float32)
         w_gate_up = self.param("w_gate_up", init, (held, e, 2 * f), cfg.param_dtype)
         w_down = self.param("w_down", init, (held, f, e), cfg.param_dtype)
-        s_gate_up = self.param("shared_gate_up", init, (e, 2 * fs), cfg.param_dtype)
-        s_down = self.param("shared_down", init, (fs, e), cfg.param_dtype)
+        if fs:
+            s_gate_up = self.param("shared_gate_up", init, (e, 2 * fs), cfg.param_dtype)
+            s_down = self.param("shared_down", init, (fs, e), cfg.param_dtype)
 
         xt = x.reshape(b * s, e)
         weights, idx = route_sigmoid_top_k(xt, router, bias, cfg.num_experts_per_tok,
                                            cfg.routed_scaling_factor)
         routed, *counts = held_experts_mlp(xt, weights, idx, w_gate_up, w_down, cfg.first_expert,
                                            cfg.n_routed_experts)
-        out, = by_token_chunks(lambda xc, rc: (
-            (rc + shared_expert_mlp(xc, None, s_gate_up, s_down)).astype(x.dtype),), xt, routed)
+        if fs:
+            out, = by_token_chunks(lambda xc, rc: (
+                (rc + shared_expert_mlp(xc, None, s_gate_up, s_down)).astype(x.dtype),), xt, routed)
+        else:
+            out = routed.astype(x.dtype)
         for name, value in zip(STEP_COUNTERS, counts):
             self.sow("counters", name, value, reduce_fn=lambda a, c: a + c,
                      init_fn=lambda: jnp.zeros((), jnp.int32))

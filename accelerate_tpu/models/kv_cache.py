@@ -48,7 +48,19 @@ class CacheContract:
     (``cached_latent``, rows of ``head_dim`` lanes shared by all query heads:
     ``kv_heads`` is 1) whose leading ``value_dim`` lanes are the value: there
     is no value pool, and the fused kernel reads the value out of the key
-    chunk it already holds. ``None`` is a value pool of its own."""
+    chunk it already holds. ``None`` is a value pool of its own.
+
+    ``value_head_dim`` is the head size of that value pool where it differs
+    from the keys' (MiMo-V2: keys 192, values 128): the pool's value leaf is
+    then ``[num_blocks, block_tokens, kv_heads * value_head_dim]``, stored at
+    its own width and read so by the fused kernel. ``None`` is ``head_dim``.
+
+    ``kv_heads``, ``head_dim`` and ``value_head_dim`` describe the PAGED
+    pool's layers. A model whose layers differ in kind (MiMo-V2's and
+    K-EXAONE's sliding layers) may keep other layers' caches as state leaves
+    at head counts and sizes of their own (per-slot window rings): the
+    engine sizes and checks the pool from this contract and counts state
+    leaves by their bytes."""
 
     kv_heads: int
     head_dim: int
@@ -56,6 +68,7 @@ class CacheContract:
     step_counters: tuple[str, ...] = ()
     param_rules: Callable[[], Any] | None = None
     value_dim: int | None = None
+    value_head_dim: int | None = None
 
 
 LATENT_LEAF = "cached_latent"
@@ -160,8 +173,10 @@ def decode_cache_update(
     names = _kv_leaf_names(v)
     news = (k,) if v is None else (k, v)
     is_init = mod.has_variable("cache", names[0])
+    # a value head may be narrower than the key's: each leaf at its own width
     cached = [mod.variable("cache", name, jnp.zeros,
-                           (b, max_len, kv_heads, head_dim), store_dtype) for name in names]
+                           (b, max_len, kv_heads, new.shape[-1]), store_dtype)
+              for name, new in zip(names, news)]
     cached_k, cached_v = cached[0], cached[-1]
     if quant:
         k_scale = mod.variable("cache", "key_scale", jnp.zeros,
@@ -345,9 +360,10 @@ def _paged_pool_step(
     store_dtype = jnp.int8 if quant else k.dtype
     names = _kv_leaf_names(v)
     is_init = mod.has_variable("cache", names[0])
+    # the value leaf at its own head size (`CacheContract.value_head_dim`)
     cached = [mod.variable("cache", name, jnp.zeros,
-                           (num_blocks, block_tokens, kv_heads * head_dim), store_dtype)
-              for name in names]
+                           (num_blocks, block_tokens, kv_heads * new.shape[-1]), store_dtype)
+              for name, new in zip(names, (k,) if v is None else (k, v))]
     if quant:
         k_scale = mod.variable("cache", "key_scale", jnp.zeros,
                                (num_blocks, block_tokens, kv_heads), jnp.float32)
@@ -465,7 +481,7 @@ def paged_decode_update(
     else:
         new_k, new_v = new_pools
         k_all = _view(new_k, kv_heads, head_dim)
-        v_all = _view(new_v, kv_heads, head_dim)
+        v_all = _view(new_v, kv_heads, v.shape[-1])
     if sharding is not None and getattr(sharding, "gathered", None) is not None:
         k_all = jax.lax.with_sharding_constraint(k_all, sharding.gathered)
         v_all = jax.lax.with_sharding_constraint(v_all, sharding.gathered)

@@ -45,9 +45,13 @@ def dot_product_attention(
     dropout_rate: float = 0.0,
     dropout_rng: jax.Array | None = None,
     dtype=None,
+    sinks: jax.Array | None = None,  # [H] a sink logit a head
 ) -> jax.Array:
     """Plain XLA attention. Softmax accumulates in fp32 regardless of input dtype
-    (bf16 logits lose too much range), output returns to the input dtype."""
+    (bf16 logits lose too much range), output returns to the input dtype.
+    ``v`` may have a head size of its own. ``sinks`` adds to each head's
+    softmax a column of logit ``sinks[h]`` whose value is zero (the column is
+    dropped after the softmax)."""
     orig_dtype = q.dtype
     d = q.shape[-1]
     scale = 1.0 / math.sqrt(d) if scale is None else scale
@@ -71,7 +75,11 @@ def dot_product_attention(
         if mask.ndim == 2:
             mask = mask[None, None, :, :]
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    weights = jax.nn.softmax(logits, axis=-1)
+    if sinks is None:
+        weights = jax.nn.softmax(logits, axis=-1)
+    else:
+        column = jnp.broadcast_to(sinks.astype(jnp.float32)[None, :, None, None], logits.shape[:3] + (1,))
+        weights = jax.nn.softmax(jnp.concatenate([logits, column], -1), axis=-1)[..., :-1]
     if dropout_rate > 0.0 and dropout_rng is not None:
         keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, weights.shape)
         weights = jnp.where(keep, weights / (1.0 - dropout_rate), 0.0)
@@ -112,6 +120,7 @@ def attention(
     implementation: str = "auto",
     block_q: int | None = None,
     block_kv: int | None = None,
+    sinks: jax.Array | None = None,
 ) -> jax.Array:
     """Dispatching entry point: 'xla' | 'flash' | 'auto'.
 
@@ -119,6 +128,9 @@ def attention(
     O(S^2) logits buffer dominates HBM traffic, else the fused XLA path.
     ``window`` is Mistral-class sliding-window attention: on the flash path it
     runs on the band grid (compute scales with the window, not seq^2).
+    ``v`` may have a head size of its own; ``sinks`` (``[q heads]``) are
+    attention sinks (`dot_product_attention`), on the flash path only with a
+    window (the band grid) and never under a mesh: 'auto' takes xla otherwise.
     """
     if k.shape[2] != q.shape[2] and (k.shape[2] == 0 or q.shape[2] % k.shape[2]):
         raise ValueError(
@@ -134,6 +146,8 @@ def attention(
 
         on_tpu = on_tpu_platform()
         implementation = "flash" if (on_tpu and q.shape[1] >= 1024 and q.shape[1] == k.shape[1]) else "xla"
+        if sinks is not None and window is None:
+            implementation = "xla"  # sinks run on the band grid alone
         if window is not None and implementation == "flash":
             # the band grid needs a block divisor of seq; un-tileable lengths
             # (e.g. prime) would raise in the kernel — auto routes them to xla
@@ -158,6 +172,10 @@ def attention(
             flash_attention, causal=causal, window=window, block_q=block_q, block_kv=block_kv
         )
         wrapped = _mesh_shard_map(flash, q, k)
+        if sinks is not None:
+            if wrapped is not None:
+                raise NotImplementedError("flash attention with sinks under a mesh is not written")
+            return flash(q, k, v, sinks=sinks)
         if wrapped is not None:
             return wrapped(q, k, v)
         return flash(q, k, v)
@@ -165,4 +183,4 @@ def attention(
         groups = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, groups, axis=2)
         v = jnp.repeat(v, groups, axis=2)
-    return dot_product_attention(q, k, v, causal=causal, mask=mask, window=window)
+    return dot_product_attention(q, k, v, causal=causal, mask=mask, window=window, sinks=sinks)

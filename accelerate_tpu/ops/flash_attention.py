@@ -337,15 +337,23 @@ def _band_logits(q, k, iq, ik, block_q, block_kv, window):
     return jnp.where(bad, NEG_INF, s)
 
 
-def _fwd_band_kernel(iqm, ikm, first, last, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, block_q, block_kv, window):
+def _fwd_band_kernel(iqm, ikm, first, last, q_ref, k_ref, v_ref, *rest, block_q, block_kv, window, sinks=False):
+    """A band row's online softmax. With ``sinks`` one more input, the query
+    head's sink logit ``[1, 1, 128]``: the running max and sum start from it
+    and 1 (a column whose value is zero), not from -inf and 0."""
+    sink_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest if sinks else (None, *rest)
     t = pl.program_id(2)
     iq, ik = iqm[t], ikm[t]
 
     @pl.when(first[t] == 1)
     def _():
         acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sinks:
+            m_scr[:] = jnp.broadcast_to(sink_ref[0][:, :1], m_scr.shape)
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
 
     q = q_ref[0, 0]
     k = k_ref[0, 0]
@@ -428,24 +436,31 @@ def _dkv_band_kernel(iqm, ikm, gm, first, last, q_ref, k_ref, v_ref, do_ref, lse
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _band_grid_spec(n_cells, b, h, block_q, block_kv, d, n_in, out_specs, scratch_shapes, groups=1):
+def _band_grid_spec(n_cells, b, h, block_q, block_kv, d, n_in, out_specs, scratch_shapes, groups=1,
+                    dv=None):
     """PrefetchScalarGridSpec over the linearized band; q-indexed inputs use
     iqm, kv-indexed use ikm (the four scalar-prefetch operands lead the kernel
     args). Under GQA (``groups`` > 1) the grid's head axis is the QUERY head
     and kv blocks come from head ``h // groups`` — K/V are never repeated in
-    HBM. Scratch lives in the spec — pallas_call rejects it separately when a
-    grid_spec is given."""
+    HBM. ``"v"`` is a value block of ``dv`` lanes (``d`` unless given),
+    ``"sink"`` the query head's ``[1, 1, 128]`` sink row. Scratch lives in the
+    spec — pallas_call rejects it separately when a grid_spec is given."""
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d), lambda b_, h_, t, iqm, ikm, first, last: (b_, h_, iqm[t], 0)
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_kv, d),
-        lambda b_, h_, t, iqm, ikm, first, last: (b_, h_ // groups, ikm[t], 0),
-    )
+
+    def kv_spec(lanes):
+        return pl.BlockSpec(
+            (1, 1, block_kv, lanes),
+            lambda b_, h_, t, iqm, ikm, first, last: (b_, h_ // groups, ikm[t], 0),
+        )
+
     row8 = pl.BlockSpec(
         (1, 1, block_q, 8), lambda b_, h_, t, iqm, ikm, first, last: (b_, h_, iqm[t], 0)
     )
-    per_input = {"q": q_spec, "kv": kv_spec, "row8": row8}
+    sink = pl.BlockSpec((1, 1, 128), lambda b_, h_, t, iqm, ikm, first, last: (h_, 0, 0))
+    per_input = {"q": q_spec, "kv": kv_spec(d), "v": kv_spec(d if dv is None else dv),
+                 "row8": row8, "sink": sink}
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, h, n_cells),
@@ -491,35 +506,42 @@ def _kv_out_spec_dkv(block, d):
     )
 
 
-def _fwd_band(q, k, v, block, window, interpret):
+def _fwd_band(q, k, v, block, window, interpret, sinks=None):
+    """The band forward. ``v`` may be narrower or wider than ``q`` and ``k``
+    (its own head size ``dv``; the output has it); ``sinks`` (float32
+    ``[h, 1, 128]``, a query head's sink logit on every lane) adds each head's
+    sink column to the softmax."""
     b, h, sq, d = q.shape
+    dv = v.shape[-1]
     groups = h // k.shape[1]
     nq = sq // block
     maps = _band_maps_row(nq, block, window)
     grid_spec = _band_grid_spec(
-        len(maps[0]), b, h, block, block, d, ["q", "kv", "kv"],
+        len(maps[0]), b, h, block, block, d, ["q", "kv", "v"] + (["sink"] if sinks is not None else []),
         [
-            _q_out_spec(block, d),
+            _q_out_spec(block, dv),
             pl.BlockSpec(
                 (1, 1, block, 8), lambda b_, h_, t, iqm, ikm, first, last: (b_, h_, iqm[t], 0)
             ),
         ],
         [
-            pltpu.VMEM((block, d), jnp.float32),
+            pltpu.VMEM((block, dv), jnp.float32),
             pltpu.VMEM((block, 128), jnp.float32),
             pltpu.VMEM((block, 128), jnp.float32),
         ],
         groups=groups,
+        dv=dv,
     )
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_band_kernel, block_q=block, block_kv=block, window=window),
+        functools.partial(_fwd_band_kernel, block_q=block, block_kv=block, window=window,
+                          **({"sinks": True} if sinks is not None else {})),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 8), jnp.float32),
         ],
         interpret=interpret,
-    )(*maps, q, k, v)
+    )(*maps, q, k, v, *(() if sinks is None else (sinks,)))
     return out, lse
 
 
@@ -581,6 +603,26 @@ def _flash_band_fwd(q, k, v, block, window, interpret):
 _flash_band.defvjp(_flash_band_fwd, _bwd_band)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_band_sinks(q, k, v, sinks, block, window, interpret):
+    """The band forward with attention sinks, for serving: no backward."""
+    out, _ = _fwd_band(q, k, v, block, window, interpret, sinks)
+    return out
+
+
+def _flash_band_sinks_fwd(q, k, v, sinks, block, window, interpret):
+    return _flash_band_sinks(q, k, v, sinks, block, window, interpret), None
+
+
+def _flash_band_sinks_bwd(block, window, interpret, residuals, dout):
+    raise NotImplementedError(
+        "flash attention with sinks has no backward pass (it serves a forward); "
+        "differentiate through implementation='xla'")
+
+
+_flash_band_sinks.defvjp(_flash_band_sinks_fwd, _flash_band_sinks_bwd)
+
+
 # ------------------------------------------------------------------ public API
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, block_q, block_kv, interpret):
@@ -637,6 +679,7 @@ def flash_attention(
     block_kv: int | None = None,
     triangle_block: int | None = None,
     window: int | None = None,
+    sinks: jax.Array | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] inputs.
@@ -654,9 +697,17 @@ def flash_attention(
     scales with W rather than seq — Mistral-class attention; it requires the
     band grid (``triangle_block``/env, defaulting to 512 when only ``window``
     is given).
+
+    ``v`` may have a head size of its own (``dv``; the output has it): the
+    band grid reads it as it is, the rectangular grid pads it to ``head_dim``
+    and drops the pad from the output. ``sinks`` (``[heads]``, a logit per
+    query head) adds a column to each query's softmax whose value is zero,
+    ``p_ij = exp(s_ij) / (exp(sink_h) + sum_j exp(s_ij))``; it needs the band
+    grid and has no backward pass.
     """
     b, sq, hn, d = q.shape
     skv = k.shape[1]
+    dv = v.shape[-1]
     if interpret is None:
         interpret = not _on_tpu()
     scale = 1.0 / math.sqrt(d) if scale is None else scale
@@ -704,19 +755,36 @@ def flash_attention(
     if hn != hk and (hk == 0 or hn % hk):
         raise ValueError(f"q heads ({hn}) must be a multiple of kv heads ({hk})")
 
+    band = bool(causal and triangle_block and sq == skv and sq % min(triangle_block, sq) == 0)
+    if sinks is not None and (not band or sinks.shape != (hn,)):
+        raise ValueError(
+            f"sinks ([{hn}], one logit a query head) run on the band grid only (causal "
+            f"self-attention with a window or triangle_block); got {sinks.shape}, band={band}")
+    if dv > d and not band:
+        raise ValueError(f"a value head ({dv}) wider than the key's ({d}) needs the band grid")
+
     qt = jnp.transpose(q, (0, 2, 1, 3)) * jnp.asarray(scale, q.dtype)
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     d_pad = 0 if d % 64 == 0 else (128 - d % 128) % 128
     if d_pad:
         pad = [(0, 0), (0, 0), (0, 0), (0, d_pad)]
-        qt, kt, vt = jnp.pad(qt, pad), jnp.pad(kt, pad), jnp.pad(vt, pad)
+        qt, kt = jnp.pad(qt, pad), jnp.pad(kt, pad)
+    # the rectangular grid takes values as wide as the keys; the band grid
+    # takes a value head of its own, padded as a key head would be
+    v_lanes = d + d_pad if dv == d or not band else dv + (0 if dv % 64 == 0 else (128 - dv % 128) % 128)
+    if v_lanes != dv:
+        vt = jnp.pad(vt, [(0, 0), (0, 0), (0, 0), (0, v_lanes - dv)])
 
-    if causal and triangle_block and sq == skv and sq % min(triangle_block, sq) == 0:
+    if band:
         # GQA runs natively on the band grid: kv blocks are fetched from head
         # h // groups, so K/V are never repeated in HBM and dk/dv come back in
         # kv-head shape
-        out = _flash_band(qt, kt, vt, min(triangle_block, sq), window, interpret)
+        if sinks is None:
+            out = _flash_band(qt, kt, vt, min(triangle_block, sq), window, interpret)
+        else:
+            rows = jnp.broadcast_to(sinks.astype(jnp.float32)[:, None, None], (hn, 1, 128))
+            out = _flash_band_sinks(qt, kt, vt, rows, min(triangle_block, sq), window, interpret)
     else:
         if hn != hk:
             groups = hn // hk
@@ -733,8 +801,8 @@ def flash_attention(
                 f"seq lengths ({sq}, {skv}) must divide block sizes ({block_q}, {block_kv})"
             )
         out = _flash(qt, kt, vt, causal, block_q, block_kv, interpret)
-    if d_pad:
-        out = out[..., :d]
+    if out.shape[-1] != dv:
+        out = out[..., :dv]
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
@@ -771,35 +839,41 @@ def _paged_decode_chunk_blocks(block_tokens: int, width: int, latent: bool = Fal
 def paged_decode_vmem_bytes(
     kv_heads: int, head_dim: int, *, q_heads: int | None = None,
     block_tokens: int = 16, itemsize: int = 4, value_dim: int | None = None,
+    value_head_dim: int | None = None,
 ) -> int:
     """VMEM the fused paged-decode kernel asks for. Per pool (keys, values;
     one alone for a latent leaf, ``value_dim`` set: the value is lanes of the
-    key chunk) two chunk buffers ``chunk_tokens x ceil128(kv_heads * head_dim) x
-    itemsize``, one float32 working copy of a chunk each (the upcast of a
+    key chunk) two chunk buffers ``chunk_tokens x ceil128(kv_heads * head)
+    x itemsize``, one float32 working copy of a chunk each (the upcast of a
     float32 or int8 pool; a bfloat16 pool feeds the MXU as it is), the folded
-    query and the float32 accumulator ``[q_heads, width]`` twice over (the
-    carry and its update), and fixed headroom. ``itemsize`` is the pool's
-    (4 prices the widest); nothing here depends on the attended span."""
+    query ``[q_heads, key width]`` and the float32 accumulator ``[q_heads,
+    value width]`` twice over each (the carry and its update), and fixed
+    headroom. The value pool's head is ``value_head_dim`` (``head_dim``
+    unless given). ``itemsize`` is the pool's (4 prices the widest); nothing
+    here depends on the attended span."""
     lanes = _ceil_to(kv_heads * head_dim, 128)
+    v_lanes = 0 if value_dim is not None else _ceil_to(kv_heads * (value_head_dim or head_dim), 128)
     # a pool block is a buffer block: its tokens pad to the dtype's sublane tile
     tokens = (_paged_decode_chunk_blocks(block_tokens, lanes, value_dim is not None)
               * _ceil_to(block_tokens, 32 // itemsize))
-    chunks = (2 if value_dim is None else 1) * tokens * lanes * (2 * itemsize + 4)
+    chunks = tokens * (lanes + v_lanes) * (2 * itemsize + 4)
     heads = _ceil_to(q_heads or kv_heads, 8)
-    return chunks + 4 * heads * lanes * 4 + _PAGED_DECODE_HEADROOM
+    return chunks + 2 * heads * (lanes + (v_lanes or lanes)) * 4 + _PAGED_DECODE_HEADROOM
 
 
 def check_paged_decode_fits(
     kv_heads: int, head_dim: int, *, q_heads: int | None = None,
     block_tokens: int = 16, itemsize: int = 4, value_dim: int | None = None,
+    value_head_dim: int | None = None,
 ) -> int:
     """Raise with the sizes named when the kernel cannot hold its chunk
-    buffers for rows of ``kv_heads`` x ``head_dim`` in VMEM; returns the bytes
-    it will ask for. The serving engine calls this at construction, so
-    ``paged_attention="fused"`` fails there instead of at first decode."""
+    buffers for rows of ``kv_heads`` x ``head_dim`` (values ``kv_heads`` x
+    ``value_head_dim``) in VMEM; returns the bytes it will ask for. The
+    serving engine calls this at construction, so ``paged_attention="fused"``
+    fails there instead of at first decode."""
     need = paged_decode_vmem_bytes(
         kv_heads, head_dim, q_heads=q_heads, block_tokens=block_tokens,
-        itemsize=itemsize, value_dim=value_dim)
+        itemsize=itemsize, value_dim=value_dim, value_head_dim=value_head_dim)
     if need > PAGED_DECODE_VMEM_CAP:
         tokens = _paged_decode_chunk_blocks(
             block_tokens, kv_heads * head_dim, value_dim is not None) * block_tokens
@@ -819,7 +893,7 @@ def check_paged_decode_fits(
 
 def _paged_decode_kernel(
     tables, lengths, q_ref, k_hbm, *rest,
-    block_tokens, chunk_blocks, scale, groups, quant, value_dim=None,
+    block_tokens, chunk_blocks, scale, groups, quant, value_dim=None, sinks=False,
 ):
     """One grid cell = one slot row; inside it a loop of ``cdiv(length,
     chunk)`` turns, each over one CHUNK of ``chunk_blocks`` pool blocks, so a
@@ -863,16 +937,27 @@ def _paged_decode_kernel(
     every head shares (``kv_heads`` 1, ``Q'`` is the query itself), and the
     value is lanes ``[0, value_dim)`` of the key chunk already in VMEM, so a
     chunk costs one copy a block and not two; the output is ``[heads,
-    value_dim]``."""
+    value_dim]``.
+
+    A value pool may be narrower than the key pool (``kv_heads x dv`` lanes, a
+    value head of its own): the accumulator is ``[heads, kv_heads * dv]`` and
+    head ``h``'s output lanes ``[g * dv, (g + 1) * dv)`` of it. With ``sinks``
+    one more input, ``[heads, 128]`` float32 with head ``h``'s sink logit on
+    its row: the running max and sum start from it and 1 (a column of the
+    softmax whose value is zero), not from the least float and 0."""
     latent = value_dim is not None
-    if latent:
-        v_hbm = vbuf = sc_hbm = scbuf = kstage = vstage = None
-        o_ref, kbuf, *rest = rest
-    elif quant:
-        v_hbm, sc_hbm, o_ref, kbuf, vbuf, scbuf, kstage, vstage, *rest = rest
-    else:
-        sc_hbm = scbuf = kstage = vstage = None
-        v_hbm, o_ref, kbuf, vbuf, *rest = rest
+    v_hbm = vbuf = sc_hbm = scbuf = kstage = vstage = sink_ref = None
+    if not latent:
+        v_hbm, *rest = rest
+    if quant:
+        sc_hbm, *rest = rest
+    if sinks:
+        sink_ref, *rest = rest
+    o_ref, kbuf, *rest = rest
+    if not latent:
+        vbuf, *rest = rest
+    if quant:
+        scbuf, kstage, vstage, *rest = rest
     sems, slot_ref, qp_ref, acc_ref = rest
     pools = ((k_hbm, kbuf),) if latent else ((k_hbm, kbuf), (v_hbm, vbuf))
     row = pl.program_id(0)
@@ -880,6 +965,7 @@ def _paged_decode_kernel(
     hq, d = q_ref.shape[1], q_ref.shape[2]
     width = kbuf.shape[-1]
     kvh = width // d
+    dv = d if latent else vbuf.shape[-1] // kvh
     chunk = chunk_blocks * block_tokens
     span_blocks = tables.shape[1]
     f32 = jnp.float32
@@ -935,7 +1021,7 @@ def _paged_decode_kernel(
                 stage[:, lanes] = (vals * scales[:, first + h:first + h + 1]).astype(q_ref.dtype)
             x = stage[...]
         else:
-            x = buf[slot].reshape(chunk, width)
+            x = buf[slot].reshape(chunk, buf.shape[-1])
         return x if narrow else x.astype(f32)
 
     def dot_pv(p, v):
@@ -978,10 +1064,13 @@ def _paged_decode_kernel(
         l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         return m_new, l, alpha * acc + dot_pv(p, v)
 
+    if sinks:
+        start = (sink_ref[:, :1], jnp.ones((hq, 1), f32))
+    else:
+        start = (jnp.full((hq, 1), neg, f32), jnp.zeros((hq, 1), f32))
     m, l, acc = jax.lax.fori_loop(
         0, turns, turn,
-        (jnp.full((hq, 1), neg, f32), jnp.zeros((hq, 1), f32),
-         jnp.zeros((hq, value_dim if latent else width), f32)),
+        start + (jnp.zeros((hq, value_dim if latent else kvh * dv), f32),),
     )
     slot_ref[0] = (slot0 + turns) % 2  # where the next row's first chunk went
     if latent:
@@ -990,7 +1079,7 @@ def _paged_decode_kernel(
     acc_ref[...] = acc / l
     for g in range(kvh):
         hs = slice(g * groups, (g + 1) * groups)
-        o_ref[0, hs, :] = acc_ref[hs, g * d:(g + 1) * d].astype(o_ref.dtype)
+        o_ref[0, hs, :] = acc_ref[hs, g * dv:(g + 1) * dv].astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -1003,6 +1092,7 @@ def paged_decode_attention(
     value_dim: int | None = None,  # a latent pool's leading lanes that are the value
     k_scale_pool: jax.Array | None = None,  # [num_blocks, block_tokens, kv_heads]
     v_scale_pool: jax.Array | None = None,  # fp32 absmax planes (int8 pool)
+    sinks: jax.Array | None = None,  # [n_heads] float32 sink logits
     scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -1054,7 +1144,18 @@ def paged_decode_attention(
     space, the rotary lanes beside it), the value is lanes ``[0, value_dim)``
     of the same row, and the result is ``[b, n_heads, value_dim]``: all heads
     against one row in the one MXU product a chunk, one copy a block. No int8
-    form."""
+    form.
+
+    **A value pool narrower than the keys'** (``v_pool`` ``[num_blocks,
+    block_tokens, kv_heads * dv]`` with ``dv != head_dim``; MiMo-V2's 192-wide
+    keys and 128-wide values): the values are read at their own width and the
+    result is ``[b, n_heads, dv]``. No int8 form.
+
+    **Sinks** (``[n_heads]``, a learnt logit a query head, `models/mimo_v2.py`'s
+    sliding layers): each query's softmax gains a column whose value is zero,
+    ``p_j = exp(s_j) / (exp(sink_h) + sum_j exp(s_j))``, by starting the
+    running max and sum from ``(sink_h, 1)``. Scores are compared with it
+    after ``scale``."""
     b, hq, d = q.shape
     latent = v_pool is None
     if latent:
@@ -1066,7 +1167,7 @@ def paged_decode_attention(
                 f"value_dim={value_dim}")
     elif value_dim is not None:
         raise ValueError("value_dim is a latent pool's (v_pool=None); got both pools")
-    elif k_pool.ndim != 3 or v_pool.shape != k_pool.shape:
+    elif k_pool.ndim != 3 or v_pool.ndim != 3 or v_pool.shape[:2] != k_pool.shape[:2]:
         raise ValueError(
             f"pools must be [num_blocks, block_tokens, kv_heads * head_dim], "
             f"got {k_pool.shape} and {v_pool.shape}"
@@ -1078,9 +1179,16 @@ def paged_decode_attention(
     kvh = width // d
     if hq % kvh:
         raise ValueError(f"q heads ({hq}) must be a multiple of kv heads ({kvh})")
+    if not latent and v_pool.shape[-1] % kvh:
+        raise ValueError(f"value pool width {v_pool.shape[-1]} is no multiple of {kvh} kv heads")
+    dv = value_dim if latent else v_pool.shape[-1] // kvh
     if (k_scale_pool is None) != (v_scale_pool is None):
         raise ValueError("k_scale_pool and v_scale_pool must be passed together")
     quant = k_scale_pool is not None
+    if quant and dv != d:
+        raise ValueError("an int8 pool keeps values as wide as the keys")
+    if sinks is not None and sinks.shape != (hq,):
+        raise ValueError(f"sinks are one logit a query head, [{hq}], got {sinks.shape}")
     if quant and k_scale_pool.shape != (num_blocks, block_tokens, kvh):
         raise ValueError(
             f"scale pool shape {k_scale_pool.shape} != "
@@ -1090,7 +1198,7 @@ def paged_decode_attention(
         interpret = not _on_tpu()
     vmem_bytes = check_paged_decode_fits(
         kvh, d, q_heads=hq, block_tokens=block_tokens, itemsize=k_pool.dtype.itemsize,
-        value_dim=value_dim)
+        value_dim=value_dim, value_head_dim=None if latent else dv)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     chunk_blocks = min(_paged_decode_chunk_blocks(block_tokens, width, latent),
@@ -1102,12 +1210,12 @@ def paged_decode_attention(
     tables = jnp.minimum(block_tables.astype(jnp.int32), num_blocks - 1)
     lengths = lengths.astype(jnp.int32)
 
-    d_out = value_dim if latent else d
     row_spec, out_spec = (pl.BlockSpec((1, hq, lanes), lambda r, t, l: (r, 0, 0))
-                          for lanes in (d, d_out))
+                          for lanes in (d, dv))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    inputs = [tables, lengths, q, k_pool] + ([] if latent else [v_pool])
-    buffers = [pltpu.VMEM((2, chunk_blocks, block_tokens, width), k_pool.dtype)] * (len(inputs) - 3)
+    pools = [k_pool] + ([] if latent else [v_pool])
+    inputs = [tables, lengths, q] + pools
+    buffers = [pltpu.VMEM((2, chunk_blocks, block_tokens, pool.shape[-1]), pool.dtype) for pool in pools]
     if quant:
         # Mosaic copies no HBM slice whose minor dim is short of a lane tile,
         # so a block's [block_tokens, kv_heads] plane cannot ride beside its
@@ -1124,26 +1232,32 @@ def paged_decode_attention(
         inputs.append(planes)
         buffers.append(pltpu.VMEM((2, planes.shape[1], chunk), jnp.float32))
         buffers += [pltpu.VMEM((chunk, width), q.dtype)] * 2  # dequantised chunk
+    in_specs = [row_spec] + [in_hbm] * (len(inputs) - 3)
+    copied = len(inputs) - 3  # the HBM refs a chunk copies from
+    if sinks is not None:
+        inputs.append(jnp.broadcast_to(sinks.astype(jnp.float32)[:, None], (hq, 128)))
+        in_specs.append(pl.BlockSpec((hq, 128), lambda r, t, l: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[row_spec] + [in_hbm] * (len(inputs) - 3),
+        in_specs=in_specs,
         out_specs=out_spec,
         scratch_shapes=buffers + [
-            pltpu.SemaphoreType.DMA((len(inputs) - 3, 2)),
+            pltpu.SemaphoreType.DMA((copied, 2)),
             pltpu.SMEM((1,), jnp.int32),  # buffer the row's first chunk is in
             pltpu.VMEM((hq, width), jnp.float32),  # Q'
-            pltpu.VMEM((hq, width), jnp.float32),  # the row's output, all lanes
+            pltpu.VMEM((hq, width if latent else kvh * dv), jnp.float32),  # the row's output, all lanes
         ],
     )
     kernel = functools.partial(
         _paged_decode_kernel, block_tokens=block_tokens, chunk_blocks=chunk_blocks,
         scale=scale, groups=hq // kvh, quant=quant, value_dim=value_dim,
+        **({"sinks": True} if sinks is not None else {}),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, d_out), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes),
         interpret=interpret,

@@ -644,6 +644,7 @@ class ServingEngine:
             check_paged_decode_fits(
                 contract.kv_heads // self._mesh_model, contract.head_dim,
                 block_tokens=self._block_tokens, value_dim=contract.value_dim,
+                value_head_dim=contract.value_head_dim,
             )
         # contiguous slot ranges per data replica (the slot dim shards like any
         # leading batch dim: replica i owns rows [i*b/d, (i+1)*b/d)) — 1 when

@@ -9,11 +9,15 @@ one device and inside a four-device jit. It checks that the program builds;
 only a chip run (`chip_smoke.py`) checks what it computes. Marked slow, all
 but the guards at the end: the paged pool's layout (two compiles, about 3 s),
 the latent pool and the two latent-attention models' scopes, the delta
-rule's in-place update, the expert layer's grouped products, and the sampling
-tail's conditional.
+rule's in-place update, the expert layer's grouped products, the sampling
+tail's conditional, the paged and flash kernels' lowered programs pinned to
+their hashes before sinks and split value widths existed (a few seconds), and
+MiMo-V2's decode step and admit at the benchmark cell's shapes (about a
+minute).
 """
 
 import functools
+import hashlib
 import re
 
 import jax
@@ -690,3 +694,149 @@ def test_sampling_tail_guard_sees_a_sort_under_the_vmap(topology):
                    _sds((8,), jnp.int32, s)).as_text()
     always, in_branches = _always_run_wide_sorts(hlo, TAIL_VOCAB)
     assert always and not in_branches
+
+
+# -------------------------------- the paged and flash kernels lower as before
+# sha256 prefixes of the kernels' lowered programs for a described TPU v5e,
+# each computed on the tree before sinks and split value widths existed: with
+# neither option given, the kernels every served model runs lower the same
+PINNED = {
+    "band_grad": "9fb6f046804dd8bb",
+    "band_window": "43f4a3b7f3d9d497",
+    "paged_gpt2_large": "cd00de363e88bf5e",
+    "paged_int8": "cc66989fa8cf83be",
+    "paged_latent": "bdcf12fd9d19403f",
+    "paged_qwen3_next": "47cbb86f27169583",
+    "paged_ring": "284508f39d6a5353",
+    "rect_causal": "34ce4c8c5a23f663",
+    "rect_pad": "7e3c54a8a4edb856",
+    "xla_window": "97187fb96f343135",
+}
+
+
+def _kernel_programs(s):
+    """{name: (function, argument shapes)} of the pinned programs."""
+    from accelerate_tpu.ops.attention import attention
+    from accelerate_tpu.ops.flash_attention import flash_attention, paged_decode_attention
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+
+    def paged(q, k, v, t, n, *scales):
+        kw = dict(k_scale_pool=scales[0], v_scale_pool=scales[1]) if scales else {}
+        return paged_decode_attention(q, k, v, t, n, interpret=False, **kw)
+
+    def flash(**kw):
+        return lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **kw)
+
+    def band_loss(q, k, v):
+        return flash(window=256)(q, k, v).astype(jnp.float32).sum()
+
+    return {
+        "paged_gpt2_large": (paged, sds((32, 20, 64)), sds((2048, 16, 1280)), sds((2048, 16, 1280)),
+                             sds((32, 64), i32), sds((32,), i32)),
+        "paged_int8": (paged, sds((8, 16, 64)), sds((512, 16, 1024), jnp.int8), sds((512, 16, 1024), jnp.int8),
+                       sds((8, 64), i32), sds((8,), i32), sds((512, 16, 16), jnp.float32),
+                       sds((512, 16, 16), jnp.float32)),
+        "paged_qwen3_next": (paged, sds((128, 16, 256)), sds((2048, 16, 512)), sds((2048, 16, 512)),
+                             sds((128, 160), i32), sds((128,), i32)),
+        "paged_latent": (lambda q, k, t, n: paged_decode_attention(q, k, None, t, n, value_dim=512, interpret=False),
+                         sds((256, 64, 640)), sds((1024, 64, 640)), sds((256, 32), i32), sds((256,), i32)),
+        "paged_ring": (paged, sds((128, 64, 128)), sds((128, 128, 1024)), sds((128, 128, 1024)),
+                       sds((128, 1), i32), sds((128,), i32)),
+        "band_window": (flash(window=128), sds((1, 4096, 64, 128)), sds((1, 4096, 8, 128)), sds((1, 4096, 8, 128))),
+        "rect_causal": (flash(), sds((1, 4096, 64, 128)), sds((1, 4096, 8, 128)), sds((1, 4096, 8, 128))),
+        "rect_pad": (flash(), sds((2, 1024, 4, 80)), sds((2, 1024, 4, 80)), sds((2, 1024, 4, 80))),
+        "band_grad": (jax.grad(band_loss, argnums=(0, 1, 2)), *[sds((2, 1024, 16, 64))] * 3),
+        "xla_window": (lambda q, k, v: attention(q, k, v, causal=True, window=128, implementation="xla"),
+                       sds((1, 512, 8, 64)), sds((1, 512, 2, 64)), sds((1, 512, 2, 64))),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_kernels_without_the_new_options_lower_as_before(topology, name):
+    """The paged decode kernel (GPT-2's heads, an int8 pool, Qwen3-Next's
+    grouped heads, Kimi K2's latent pool, K-EXAONE's rings), the band and
+    rectangular flash kernels (a window, causal, a padded head, a band's
+    backward) and the XLA path, lowered for the v5e with no sinks and values
+    as wide as keys: the programs hash as they did before the options."""
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        fn, *args = _kernel_programs(_one_device(topology))[name]
+        text = jax.jit(fn).lower(*args).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    text = re.sub(r"module @[\w.]+", "module", text, count=1)  # the jitted function's own name
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[name]
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_mimo_v2_programs_at_the_cells_shapes(topology, compiled_kernels, program):
+    """MiMo-V2's decode step (128 slots, the full layers' pool of the
+    workload's blocks of 64) and an 8,192-token admit, at the benchmark cell's
+    published widths and cut (seven layers, 16 held experts, 19,072
+    vocabulary rows): the decode step runs the fused kernel on the two full
+    layers' pool (`%attn.N`, keys 4 x 192 and values 4 x 128 apart) and, with
+    the sinks, on each sliding layer's rings (`%window_attn.N`, whose line
+    names the rings `[128, 128, 1536]` and `[128, 128, 1024]` the readers look
+    for); the admit runs the band kernel with the sinks on the five sliding
+    layers (its line names the queries `[1, 64, 8192, 192]`) and causal flash
+    attention on the two full ones; both run the twelve grouped products. The
+    weights, the pool and the rings with the admit's workspace beside them
+    fit the chip. About a minute."""
+    import json
+    import os
+
+    from accelerate_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+
+    here = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks", "chip")
+    with open(os.path.join(here, "configs", "mimo-v2.5.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(here, "workloads", "mimo-v2.5.serve.mixed128.json")) as f:
+        engine = json.load(f)["engine"]
+    s = _one_device(topology)
+    rows, bt, blocks = engine["max_concurrency"], engine["paged_kv"]["block_tokens"], engine["paged_kv"]["num_blocks"]
+    n = cfg["num_hidden_layers"]
+    model_cfg = MiMoV2Config(
+        vocab_size=cfg["vocab_size"], num_hidden_layers=n, experts_held=cfg["n_routed_experts"],
+        n_positions=cfg["n_positions"], kv_cache_per_slot=True, kv_cache_paged=program == "step",
+        kv_num_blocks=blocks, kv_block_tokens=bt, kv_paged_attention="fused")
+    module = MiMoV2ForCausalLM(model_cfg)
+    b, t = (rows, 1) if program == "step" else (engine["admit_batch"], max(engine["prompt_buckets"]))
+    tables = jnp.zeros((b, model_cfg.n_positions // bt), jnp.int32)
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((b, 1), jnp.int32), decode=True, block_tables=tables))
+    place = lambda tree: jax.tree.map(lambda x: _sds(x.shape, x.dtype, s), tree)  # noqa: E731
+
+    def run(params, cache, ids, lens, tables):
+        kw = dict(position_offset=lens, block_tables=tables) if program == "step" \
+            else dict(position_offset=0, cache_write_len=lens)
+        return module.apply({"params": params, "cache": cache}, ids, decode=True, mutable=["cache", "counters"], **kw)
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        place(shapes["params"]), place(shapes["cache"]), _sds((b, t), jnp.int32, s),
+        _sds((b,), jnp.int32, s), _sds(tables.shape, jnp.int32, s)).compile()
+    hlo = compiled.as_text()
+    for scope in ("window_attn", "global_attn", "moe_router", "moe_experts", "dense_mlp"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    kernels = re.findall(r'%([A-Za-z_\-]+)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert kernels.count("gmm") == 12 and "ragged-dot" not in hlo, kernels
+    rings = ("bf16[128,128,1536]", "bf16[128,128,1024]")
+    if program == "step":
+        assert kernels.count("attn") == 2 and kernels.count("window_attn") == 5 and len(kernels) == 19, kernels
+        ring_kernels = re.findall(r"%window_attn(?:\.\d+)? = [^\n]*", hlo)
+        assert all(r in line and "f32[64,128]" in line for line in ring_kernels for r in rings)  # and the sinks
+        pools = re.findall(r"%attn(?:\.\d+)? = [^\n]*", hlo)
+        assert all(f"bf16[{blocks},64,768]" in line and f"bf16[{blocks},64,512]" in line for line in pools)
+    else:
+        assert kernels.count("window_attn") == 5 and kernels.count("global_attn") == 2, kernels
+        band = re.findall(r"%window_attn(?:\.\d+)? = [^\n]*", hlo)
+        assert all("bf16[1,64,8192,192]" in line and "bf16[1,64,8192,128]" in line for line in band)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes \
+        - memory.alias_size_in_bytes
+    if program == "admit":  # the engine's pool and rings stay beside the admit's rows and workspace
+        held += blocks * bt * 2 * 4 * (192 + 128) * 2 + 5 * rows * 128 * 8 * (192 + 128) * 2
+    assert held < 14.6e9, held / 1e9
