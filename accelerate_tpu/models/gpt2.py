@@ -83,11 +83,18 @@ class GPT2Config:
     fp8_recipe: Any = None
 
     def cache_contract(self):
-        """Keys and values only, every head its own (`kv_cache.CacheContract`)."""
+        """Keys and values only, every head its own (`kv_cache.CacheContract`).
+        Every leaf but the LayerNorms' reaches its product through a cast to
+        ``dtype`` (`nn.Dense` promotes kernel and bias, `wte`/`wpe` are cast
+        before the gather and the tied head), so the engine may hold them in
+        ``dtype``. That is declared for the plain `nn.Dense` path only: with
+        an fp8 recipe nothing is, and the params are served as given."""
         from .kv_cache import CacheContract
 
         return CacheContract(kv_heads=self.n_head, head_dim=self.n_embd // self.n_head,
-                             param_rules=gpt2_sharding_rules)
+                             param_rules=gpt2_sharding_rules,
+                             compute_dtype_params=(None if self.fp8_recipe is not None
+                                                   else _outside_layer_norms))
 
     @classmethod
     def small(cls, **kw) -> "GPT2Config":
@@ -105,6 +112,15 @@ class GPT2Config:
     def tiny(cls, **kw) -> "GPT2Config":
         """Test-sized config."""
         return cls(**{**dict(vocab_size=256, n_positions=128, n_embd=64, n_layer=2, n_head=2), **kw})
+
+
+_LAYER_NORMS = frozenset({"ln_1", "ln_2", "ln_f"})
+
+
+def _outside_layer_norms(path: tuple[str, ...]) -> bool:
+    """`CacheContract.compute_dtype_params`: a leaf of no LayerNorm (those
+    compute in float32 from their float32 scale and bias)."""
+    return _LAYER_NORMS.isdisjoint(path)
 
 
 def _dense(cfg: GPT2Config, features: int, name: str) -> nn.Module:

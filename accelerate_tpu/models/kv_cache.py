@@ -60,7 +60,15 @@ class CacheContract:
     K-EXAONE's sliding layers) may keep other layers' caches as state leaves
     at head counts and sizes of their own (per-slot window rings): the
     engine sizes and checks the pool from this contract and counts state
-    leaves by their bytes."""
+    leaves by their bytes.
+
+    ``compute_dtype_params`` names the parameter leaves the module consumes
+    only through a cast to ``config.dtype``: a predicate over a leaf's path
+    of key names, e.g. ``("block_0", "attn", "qkv", "kernel")``. The engine
+    holds each such floating leaf that is wider than ``config.dtype`` in
+    ``config.dtype``, cast once at construction; every product then gets the
+    operand values it got from the wider leaf (`docs/serving.md` "Parameter
+    residency"). ``None`` leaves the param tree as it is given."""
 
     kv_heads: int
     head_dim: int
@@ -69,6 +77,7 @@ class CacheContract:
     param_rules: Callable[[], Any] | None = None
     value_dim: int | None = None
     value_head_dim: int | None = None
+    compute_dtype_params: Callable[[tuple[str, ...]], bool] | None = None
 
 
 LATENT_LEAF = "cached_latent"

@@ -62,7 +62,7 @@ import os
 import time
 from collections import deque
 from pathlib import Path
-from typing import Any, Iterable
+from typing import Any, Callable, Iterable
 
 import jax
 import jax.numpy as jnp
@@ -401,6 +401,33 @@ def _shared_jit(module: Any, kind: str, build):
     return fns[kind]
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves: list, dtype: Any) -> list:
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def _narrow_params(params: Any, declared: Callable[[tuple[str, ...]], bool],
+                   dtype: Any) -> tuple[Any, int]:
+    """Hold in ``dtype`` each floating leaf wider than it that ``declared``
+    (`CacheContract.compute_dtype_params`, a predicate over the leaf's path of
+    key names) names, cast in one jitted program; every other leaf comes back
+    as given. Returns the tree and the bytes the narrowing saved."""
+    dtype = jnp.dtype(dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in flat]
+    picked = [i for i, (path, leaf) in enumerate(flat)
+              if jnp.issubdtype(leaf.dtype, jnp.floating)
+              and leaf.dtype.itemsize > dtype.itemsize
+              and declared(tuple(str(getattr(k, "key", k)) for k in path))]
+    if not picked:
+        return params, 0
+    saved = 0
+    for i, leaf in zip(picked, _cast_leaves([leaves[i] for i in picked], dtype)):
+        saved += int(leaves[i].nbytes) - int(leaf.nbytes)
+        leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves), saved
+
+
 @dataclasses.dataclass
 class RecoveryReport:
     """What `ServingEngine.resume` reconstructed from a journal or snapshot.
@@ -703,6 +730,7 @@ class ServingEngine:
             dense_shardings = infer_param_shardings(
                 params, self.mesh, rules=rules
             )
+        self._param_saved_bytes = 0
         if weight_quant is not None:
             qcfg = weight_quant.quantization_config(
                 getattr(module.config, "param_dtype", None) or jnp.float32)
@@ -712,6 +740,13 @@ class ServingEngine:
             self._admit_module = (
                 module if raw_admit is module.module
                 else _quantized_module(raw_admit, weight_quant.mode))
+        elif contract.compute_dtype_params is not None:
+            # parameter residency (docs/serving.md "Parameter residency"): the
+            # leaves the model consumes only through a cast to its compute
+            # dtype are held in it, cast once here and not every dispatch.
+            # Shapes are unchanged, so are the shardings derived above.
+            params, self._param_saved_bytes = _narrow_params(
+                params, contract.compute_dtype_params, cfg.dtype)
         self.params = params
         if self.mesh is not None:
             if weight_quant is None:
@@ -729,6 +764,7 @@ class ServingEngine:
                     params, dense_shardings, is_leaf=is_qt,
                 )
                 self.params = jax.device_put(params, self._param_shardings)
+        self._param_bytes = int(quantized_nbytes(self.params))
         self.max_len = int(module.config.n_positions)
         self.pipeline_depth = int(pipeline_depth)
         if self.pipeline_depth < 1:
@@ -1681,7 +1717,11 @@ class ServingEngine:
         host simply omits them). Keys are unprefixed — the telemetry exporter
         namespaces them under ``serving/mem/``, except the ``quant/`` group
         (present only when a quantized mode is active — `quant_stats`), which
-        it lifts to the top-level ``serving/quant/`` namespace."""
+        it lifts to the top-level ``serving/quant/`` namespace.
+        ``param_bytes`` (the param tree the programs read) and
+        ``param_narrowed_saved_bytes`` (what holding the contract's
+        ``compute_dtype_params`` in the compute dtype saved) are fixed at
+        construction."""
         stats: dict[str, Any] = {
             "slot_pool_bytes": tree_nbytes(self._cache),
             "slots_total": self.max_concurrency,
@@ -1689,6 +1729,8 @@ class ServingEngine:
             "slots_free": len(self._free),
             "queue_depth": self.scheduler.queue_depth,
             "inflight_dispatches": len(self._inflight),
+            "param_bytes": self._param_bytes,
+            "param_narrowed_saved_bytes": self._param_saved_bytes,
         }
         for dtype, n in tree_bytes_by_dtype(self._cache).items():
             stats[f"slot_pool_bytes/{dtype}"] = n

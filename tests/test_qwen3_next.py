@@ -328,6 +328,20 @@ def test_a_reused_slot_starts_from_its_admission(cfg, model_cfg, params):
         assert out.tokens == alone.tokens
 
 
+def test_engine_holds_the_params_it_is_given(model_cfg, params):
+    """The contract declares no leaf consumed only in the compute dtype, so
+    the engine hands its programs the very tree it was given, even where the
+    compute dtype is narrower than the params (`docs/serving.md` "Parameter
+    residency")."""
+    module = Qwen3NextForCausalLM(dataclasses.replace(model_cfg, dtype=jnp.bfloat16))
+    assert module.config.cache_contract().compute_dtype_params is None
+    engine = engine_for(module, params)
+    assert all(a is b for a, b in zip(jax.tree.leaves(engine.params), jax.tree.leaves(params), strict=True))
+    stats = engine.memory_stats()
+    assert stats["param_narrowed_saved_bytes"] == 0
+    assert stats["param_bytes"] == sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+
+
 def test_step_counters_and_state_gauges(cfg, model_cfg, params):
     engine = engine_for(Qwen3NextForCausalLM(model_cfg), params)
     serve(engine, prompts_of(cfg, (10, 12)), 5)
