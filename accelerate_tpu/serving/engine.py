@@ -67,6 +67,7 @@ from typing import Any, Callable, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..models.kv_cache import (
@@ -156,6 +157,26 @@ def _sample_slot(logits: jax.Array, key: jax.Array, temperature: jax.Array,
         scaled = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
     sampled = jax.random.categorical(key, scaled, axis=-1)
     return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+
+
+def _host_key_data(seed: Any) -> np.ndarray | None:
+    """``jax.random.key_data(jax.random.key(seed))`` as a ``uint32`` row made
+    on the host, or None where only the device gives it exactly.
+
+    Under the default ``threefry2x32`` a key's data is the seed's high and
+    low 32-bit words after `jax.random.key`'s conversion: a Python int is
+    taken as an ``int64`` (outside it `jax.random.key` raises, so it keeps
+    that path), and with ``jax_enable_x64`` off narrowed to ``int32``, which
+    leaves the high word 0 and a negative seed its low word's bits. Another
+    PRNG implementation, a seed offset or a seed that is no Python int
+    returns None."""
+    if (not isinstance(seed, int)
+            or jax.config.jax_default_prng_impl != "threefry2x32"
+            or jax.config.jax_random_seed_offset
+            or not -(1 << 63) <= seed < (1 << 63)):
+        return None
+    bits = seed & ((1 << 64) - 1 if jax.config.jax_enable_x64 else (1 << 32) - 1)
+    return np.array([bits >> 32, bits & 0xFFFFFFFF], np.uint32)
 
 
 def _sample_rows(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
@@ -892,9 +913,10 @@ class ServingEngine:
             )
             self._d_eos = jax.device_put(self._d_eos, self._rep_sharding)
         # per-slot block tables, the ONLY indirection decode follows.
-        # A free slot's row points at num_blocks (out of range): a lagged
-        # step's write for a cancelled tenant DROPS instead of landing in a
-        # freed — possibly re-allocated — block (see _release_slot)
+        # A never-held slot's row points at num_blocks (out of range), and a
+        # cancelled tenant's is pointed there on release: a lagged step's
+        # write for it DROPS instead of landing in a freed — possibly
+        # re-allocated — block (see _release_slot)
         self._d_tables = jnp.full(
             (b, self._blocks_per_slot), self._allocator.num_blocks, jnp.int32)
         if self.mesh is not None:
@@ -1130,7 +1152,7 @@ class ServingEngine:
             # are not written (write_mask), and `_decode_tail` carries their
             # token/pos/budget unchanged — so however far host retirement
             # lags, a finished slot's state is bit-stable until re-admission.
-            # One more drop layer: a released slot's table row points at
+            # One more drop layer: a cancelled slot's table row points at
             # num_blocks, so even a stale dispatch's write cannot land
             logits, mutated = module.apply(
                 {"params": params, "cache": cache}, tokens[:, None], decode=True,
@@ -2610,7 +2632,7 @@ class ServingEngine:
             if fins[i]:
                 reason = (FINISH_EOS if self.eos_token_id is not None
                           and token == self.eos_token_id else FINISH_LENGTH)
-                self._retire(slot, reason, now, finished)
+                self._retire(slot, reason, now, finished, device_finished=True)
 
     def _process_step(self, entry: _Inflight, fetched: tuple, now: float,
                       finished: list[RequestOutput]) -> None:
@@ -2681,7 +2703,7 @@ class ServingEngine:
                 if fins[t, slot]:
                     reason = (FINISH_EOS if self.eos_token_id is not None
                               and token == self.eos_token_id else FINISH_LENGTH)
-                    self._retire(slot, reason, now, finished)
+                    self._retire(slot, reason, now, finished, device_finished=True)
         if appended:
             self.metrics.tokens_per_dispatch.observe(appended)
         if poisoned_any:
@@ -2780,7 +2802,7 @@ class ServingEngine:
                 if fins[t, slot]:
                     reason = (FINISH_EOS if self.eos_token_id is not None
                               and token == self.eos_token_id else FINISH_LENGTH)
-                    self._retire(slot, reason, now, finished)
+                    self._retire(slot, reason, now, finished, device_finished=True)
         if appended:
             self.metrics.tokens_per_dispatch.observe(appended)
             self.metrics.spec_tokens.inc(appended)
@@ -2879,6 +2901,22 @@ class ServingEngine:
                 return  # block-pool backpressure: group requeued
             attrs["admitted"] += len(group)
 
+    def _key_data_row(self, seed: Any, splits: int) -> np.ndarray:
+        """One admitted request's rng key data: made on the host
+        (`_host_key_data`) for a fresh request, else by the device path
+        (another PRNG implementation; a resumed request's chain fast-forwarded
+        past its ``splits`` replayed tokens, so its next sample draws the key
+        the uninterrupted run would) and fetched, bit-equal either way."""
+        row = None if splits else _host_key_data(seed)
+        if row is not None:
+            self.metrics.admit_host_keys.inc()
+            return row
+        self.metrics.admit_device_keys.inc()
+        key = jax.random.key(seed)
+        for _ in range(splits):
+            key = jax.random.split(key)[0]
+        return np.asarray(jax.random.key_data(key))
+
     def _admit_group(self, group: list[Request],
                      finished: list[RequestOutput]) -> bool:
         # reserve BEFORE touching slots: on exhaustion the group goes
@@ -2886,53 +2924,47 @@ class ServingEngine:
         reservation = self._reserve_blocks(group, None)
         if reservation is None:
             return False
-        nb = len(group)
-        slots = [self._free.popleft() for _ in group]
-        bucket = self.scheduler.bucket_for(max(r.prefill_len for r in group))
-        padded = np.zeros((nb, bucket), np.int32)
-        lens = np.zeros(nb, np.int32)
-        temps = np.zeros(nb, np.float32)
-        topks = np.zeros(nb, np.int32)
-        budgets = np.zeros(nb, np.int32)
-        rng_rows = []
-        for i, request in enumerate(group):
-            plen = len(request.prompt)
-            k = len(request.resume_tokens)
-            # a resumed request (crash recovery) prefills prompt + its
-            # already-emitted tokens in ONE continuation pass: same numerics
-            # as the original prefill-then-decode, so the stream stays
-            # bit-identical (tests/test_serving_recovery.py)
-            ptoks = request.prefill_source()
-            padded[i, : plen + k] = ptoks
-            lens[i] = plen + k
-            sp = request.params
-            temps[i] = sp.temperature
-            topks[i] = sp.top_k or 0
-            # the context is fixed-size: cap generation so cache writes stay
-            # inside [0, n_positions). The cap is against the ORIGINAL prompt
-            # (a resumed request keeps the budget it started with, minus the
-            # k tokens it already emitted)
-            budgets[i] = min(int(sp.max_new_tokens), self.max_len - plen) - k
-            # the rng chain advances one split per sampled token; fast-forward
-            # a resumed request's chain past its k replayed tokens so the
-            # next sample draws exactly the key the uninterrupted run would
-            key = jax.random.key(sp.seed)
-            for _ in range(k):
-                key = jax.random.split(key)[0]
-            rng_rows.append(jax.random.key_data(key))
-            if k:
-                self.metrics.replayed_tokens.inc(plen + k)
-        tables_np, dest_np = self._commit_reservation(
-            reservation, group, None, slots)
+        with TraceAnnotation("serve.admit.args"):
+            nb = len(group)
+            slots = [self._free.popleft() for _ in group]
+            bucket = self.scheduler.bucket_for(max(r.prefill_len for r in group))
+            padded = np.zeros((nb, bucket), np.int32)
+            lens = np.zeros(nb, np.int32)
+            temps = np.zeros(nb, np.float32)
+            topks = np.zeros(nb, np.int32)
+            budgets = np.zeros(nb, np.int32)
+            rng = np.zeros((nb,) + self._rng_data.shape[1:], self._rng_data.dtype)
+            for i, request in enumerate(group):
+                plen = len(request.prompt)
+                k = len(request.resume_tokens)
+                # a resumed request (crash recovery) prefills prompt + its
+                # already-emitted tokens in ONE continuation pass: same numerics
+                # as the original prefill-then-decode, so the stream stays
+                # bit-identical (tests/test_serving_recovery.py)
+                ptoks = request.prefill_source()
+                padded[i, : plen + k] = ptoks
+                lens[i] = plen + k
+                sp = request.params
+                temps[i] = sp.temperature
+                topks[i] = sp.top_k or 0
+                # the context is fixed-size: cap generation so cache writes stay
+                # inside [0, n_positions). The cap is against the ORIGINAL prompt
+                # (a resumed request keeps the budget it started with, minus the
+                # k tokens it already emitted)
+                budgets[i] = min(int(sp.max_new_tokens), self.max_len - plen) - k
+                rng[i] = self._key_data_row(sp.seed, k)
+                if k:
+                    self.metrics.replayed_tokens.inc(plen + k)
+            tables_np, dest_np = self._commit_reservation(
+                reservation, group, None, slots)
+            args = jax.device_put((padded, np.asarray(slots, np.int32), lens,
+                                   temps, topks, rng, budgets, dest_np,
+                                   tables_np))
         (self._cache, first, fin0, self._d_tables, self._d_tokens,
          self._d_pos, self._d_temps, self._d_topks, self._d_finished,
          self._d_remaining, self._rng_data) = self._dispatch(
             self._compile_key("admit", bucket, nb), self._admit_fn,
-            self._cache, self.params, jnp.asarray(padded),
-            jnp.asarray(np.asarray(slots, np.int32)), jnp.asarray(lens),
-            jnp.asarray(temps), jnp.asarray(topks),
-            jnp.stack(rng_rows), jnp.asarray(budgets),
-            jnp.asarray(dest_np), jnp.asarray(tables_np),
+            self._cache, self.params, *args,
             self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
             self._d_topks, self._d_finished, self._d_remaining,
             self._rng_data, self._d_eos,
@@ -2971,51 +3003,49 @@ class ServingEngine:
         reservation = self._reserve_blocks(group, matches)
         if reservation is None:
             return False
-        slots = [self._free.popleft() for _ in group]
-        padded = np.zeros((nb, bucket), np.int32)
-        suffix_lens = np.zeros(nb, np.int32)
-        cached_lens = np.zeros(nb, np.int32)
-        temps = np.zeros(nb, np.float32)
-        topks = np.zeros(nb, np.int32)
-        budgets = np.zeros(nb, np.int32)
-        rng_rows = []
-        for i, (request, m) in enumerate(zip(group, matches)):
-            plen = len(request.prompt)
-            suffix = request.prompt[m.tokens:]
-            padded[i, :len(suffix)] = suffix
-            suffix_lens[i] = len(suffix)
-            cached_lens[i] = m.tokens
-            sp = request.params
-            temps[i] = sp.temperature
-            topks[i] = sp.top_k or 0
-            # budget depends on the FULL prompt length — token identity with
-            # the cold path requires the same generation cap either way
-            budgets[i] = min(int(sp.max_new_tokens), self.max_len - plen)
-            rng_rows.append(jax.random.key_data(jax.random.key(sp.seed)))
-            if m.tokens:
-                self.metrics.prefix_hits.inc()
-                self.metrics.prefix_tokens_reused.inc(m.tokens)
-            elif request.cache_prefix:
-                self.metrics.prefix_misses.inc()
-        # the reservation's tables carry the aliased trie blocks up front
-        # and the slot's fresh private blocks after — they serve as BOTH
-        # the gather view (aliased prefix, zero-copy) and the decode
-        # table; dest drops the aliased region so the scatter writes only
-        # the suffix's blocks
-        tables_np, dest_np = self._commit_reservation(
-            reservation, group, matches, slots)
+        with TraceAnnotation("serve.admit.args"):
+            slots = [self._free.popleft() for _ in group]
+            padded = np.zeros((nb, bucket), np.int32)
+            suffix_lens = np.zeros(nb, np.int32)
+            cached_lens = np.zeros(nb, np.int32)
+            temps = np.zeros(nb, np.float32)
+            topks = np.zeros(nb, np.int32)
+            budgets = np.zeros(nb, np.int32)
+            rng = np.zeros((nb,) + self._rng_data.shape[1:], self._rng_data.dtype)
+            for i, (request, m) in enumerate(zip(group, matches)):
+                plen = len(request.prompt)
+                suffix = request.prompt[m.tokens:]
+                padded[i, :len(suffix)] = suffix
+                suffix_lens[i] = len(suffix)
+                cached_lens[i] = m.tokens
+                sp = request.params
+                temps[i] = sp.temperature
+                topks[i] = sp.top_k or 0
+                # budget depends on the FULL prompt length — token identity with
+                # the cold path requires the same generation cap either way
+                budgets[i] = min(int(sp.max_new_tokens), self.max_len - plen)
+                rng[i] = self._key_data_row(sp.seed, 0)
+                if m.tokens:
+                    self.metrics.prefix_hits.inc()
+                    self.metrics.prefix_tokens_reused.inc(m.tokens)
+                elif request.cache_prefix:
+                    self.metrics.prefix_misses.inc()
+            # the reservation's tables carry the aliased trie blocks up front
+            # and the slot's fresh private blocks after — they serve as BOTH
+            # the gather view (aliased prefix, zero-copy) and the decode
+            # table; dest drops the aliased region so the scatter writes only
+            # the suffix's blocks
+            tables_np, dest_np = self._commit_reservation(
+                reservation, group, matches, slots)
+            args = jax.device_put((tables_np, cached_lens, padded, suffix_lens,
+                                   np.asarray(slots, np.int32), temps, topks,
+                                   rng, budgets, dest_np, tables_np))
         (self._cache, first, fin0, self._d_tables, self._d_tokens,
          self._d_pos, self._d_temps, self._d_topks, self._d_finished,
          self._d_remaining, self._rng_data) = self._dispatch(
             self._compile_key("cached_admit", bucket, nb),
             self._cached_admit_fn,
-            self._cache, self.params, jnp.asarray(tables_np),
-            jnp.asarray(cached_lens), jnp.asarray(padded),
-            jnp.asarray(suffix_lens),
-            jnp.asarray(np.asarray(slots, np.int32)),
-            jnp.asarray(temps), jnp.asarray(topks), jnp.stack(rng_rows),
-            jnp.asarray(budgets), jnp.asarray(dest_np),
-            jnp.asarray(tables_np),
+            self._cache, self.params, *args,
             self._d_tables, self._d_tokens, self._d_pos, self._d_temps,
             self._d_topks, self._d_finished, self._d_remaining,
             self._rng_data, self._d_eos,
@@ -3214,7 +3244,8 @@ class ServingEngine:
         return {"slo": slo.name, "attained": bool(attained)}
 
     def _retire(self, slot: int, reason: str, now: float,
-                finished: list[RequestOutput]) -> None:
+                finished: list[RequestOutput],
+                device_finished: bool = False) -> None:
         out = self._slot_out[slot]
         request = self._slot_req[slot]
         out.finish_reason = reason
@@ -3282,7 +3313,7 @@ class ServingEngine:
                 )
                 donated = max(0, n_full - aliased)
                 self._slot_priv[slot] = self._slot_priv[slot][donated:]
-        self._release_slot(slot)
+        self._release_slot(slot, device_finished)
         finished.append(out)
 
     def _count_sample_tail(self, params: SamplingParams, sign: int) -> None:
@@ -3298,7 +3329,7 @@ class ServingEngine:
         self._slot_kv_tokens[slot] += n
         self._held_kv_tokens += n
 
-    def _release_slot(self, slot: int) -> None:
+    def _release_slot(self, slot: int, device_finished: bool = False) -> None:
         """Return a slot to the free pool. Device state needs no touch-up:
         the slot is frozen by its on-device finished mask (or, for a cancel,
         burns out harmlessly against its token budget), lagged in-flight
@@ -3311,7 +3342,11 @@ class ServingEngine:
         self._slot_priv[slot] = []
         self._slot_table_host[slot] = None
         self._slot_aliased[slot] = 0
-        # a CANCELLED slot is not device-finished: dispatches already in
+        # a slot the device finished (`device_finished`: EOS or budget, read
+        # from a fetched finish mask) writes nothing through its table row
+        # until the next admission rewrites it, so its release runs no device
+        # program between the retirement and that admission's dispatch.
+        # A CANCELLED slot is not device-finished: dispatches already in
         # flight — and any issued before the next admission reuses this
         # slot — would keep writing through the stale table row into
         # blocks just freed (and possibly handed to a new tenant). Point
@@ -3320,8 +3355,9 @@ class ServingEngine:
         # BEFORE this update is still safe by device dispatch order —
         # its stale writes execute before any re-allocating admission's
         # scatter can land.
-        self._d_tables = self._d_tables.at[slot].set(
-            jnp.int32(self._allocator.num_blocks))
+        if not device_finished:
+            self._d_tables = self._d_tables.at[slot].set(
+                jnp.int32(self._allocator.num_blocks))
         self._count_sample_tail(self._slot_req[slot].params, -1)
         self._hold_kv_tokens(slot, -self._slot_kv_tokens[slot])
         self._slot_match[slot] = None

@@ -236,6 +236,11 @@ class ServingMetrics:
         # turn or two behind the device), and held slots x n_positions
         self.paged_decode_live_tokens = Counter()
         self.paged_decode_span_tokens = Counter()
+        # where each admitted request's rng key data was made: on the host
+        # (`engine._host_key_data`) or by the device path (a resumed request,
+        # another PRNG implementation)
+        self.admit_host_keys = Counter()
+        self.admit_device_keys = Counter()
         self.ttft_s = Histogram()
         # TTFT split by prefix-cache outcome: the hit histogram is the
         # headline number prefix reuse exists to shrink
@@ -505,6 +510,8 @@ class ServingMetrics:
                 self.paged_decode_live_tokens.value),
             "serving/paged_decode/span_tokens": (
                 self.paged_decode_span_tokens.value),
+            "serving/admit/host_keys": self.admit_host_keys.value,
+            "serving/admit/device_keys": self.admit_device_keys.value,
             "serving/streams_opened": self.streams_opened.value,
             "serving/streams_finished": self.streams_finished.value,
             "serving/stream_events": self.stream_events.value,

@@ -189,14 +189,17 @@ def test_sampled_parity_mixed_per_slot_params(model):
     still each match their solo generate bit-for-bit (the [b]-data sampling
     contract)."""
     module, params = model
-    prompts = _prompts(1, [4, 6, 9])
+    prompts = _prompts(1, [4, 6, 9, 5, 11])
     specs = [
         dict(temperature=1.0, top_k=5, seed=42),
         dict(temperature=0.7, top_k=None, seed=7),
         dict(temperature=0.0, top_k=None, seed=0),  # greedy rides along
+        # seeds past 32 bits and below 0: their key rows are made on the host
+        dict(temperature=0.9, top_k=7, seed=2**32 + 7),
+        dict(temperature=0.8, top_k=None, seed=-3),
     ]
     n_new = 8
-    engine = ServingEngine(module, params, max_concurrency=3,
+    engine = ServingEngine(module, params, max_concurrency=5,
                            prompt_buckets=(16,))
     outs = engine.run([
         Request(p, SamplingParams(max_new_tokens=n_new, **sp))

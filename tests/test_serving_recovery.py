@@ -249,6 +249,9 @@ def test_resume_from_journal_is_crash_exact(model, tmp_path):
     assert {rid: o.tokens for rid, o in final.items()} == refs
     assert b.metrics.requests_resumed.value == len(report.resumed)
     assert b.metrics.replayed_tokens.value > 0
+    # a mid-stream resume splits its key chain on the device; the rest are host rows
+    assert b.metrics.admit_device_keys.value == len(report.resumed)
+    assert b.metrics.admit_host_keys.value == len(report.restored)
     valid = tracer.validate()
     assert valid["clean"], valid["anomalies"]
     streams = request_streams(tracer.events())

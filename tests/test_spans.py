@@ -273,6 +273,55 @@ def test_serving_spans_nest_pair_and_sum_to_step_timings(model, depth, admit):
     assert all(set(s[4]) == {"id", "step"} for s in steps.values())
 
 
+@pytest.mark.parametrize("cached", [False, True], ids=["plain", "prefix_hit"])
+def test_admit_args_annotation_nests_in_its_admit_and_leaves_the_schedule(model, tmp_path,
+                                                                         cached):
+    """`serve.admit.args` is an annotation alone: on a capture each lies inside a
+    `serve.admit` and ends before that admit's own `serve.dispatch` starts; the
+    ring holds none, and `schedule_s` is still the step's stretch to the end of
+    its admit net of the dispatches, fetches and deliveries inside it."""
+    import glob
+
+    module, params = model
+    kw = dict(paged_kv=True, prefix_cache=True) if cached else {}
+    engine = ServingEngine(module, params, max_concurrency=4, prompt_buckets=(16, 32),
+                           admit_batch=4, **kw)
+    prompt = list(range(1, 25))  # every prompt below shares its first block of 16
+    spans.RING.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(2):  # with the prefix cache the second round hits
+            outputs = engine.run([Request(prompt=prompt[:24 - i],
+                                          params=SamplingParams(max_new_tokens=5))
+                                  for i in range(6)])
+            assert all(o.finish_reason == FINISH_LENGTH for o in outputs)
+    ring = spans.RING.snapshot()
+    if cached:
+        assert engine.metrics.prefix_hits.value > 0
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                     for plane in jax.profiler.ProfileData.from_file(path).planes
+                     if plane.name.startswith("/host:") for line in plane.lines
+                     for e in line.events if e.name.startswith("serve.")),
+                    key=lambda e: e[1])
+    kinds = {s[4]["seq"]: s[4]["kind"] for s in ring if s[0] == "serve.dispatch"}
+    args = [e for e in events if e[0] == "serve.admit.args"]
+    admits = [e for e in events if e[0] == "serve.admit"]
+    assert len(args) == sum(1 for k in kinds.values() if k.endswith("admit")) > 0
+    for _, start, end, _ in args:
+        assert any(a[1] <= start and end <= a[2] for a in admits)
+        after = next(e for e in events if e[0] == "serve.dispatch" and e[1] >= start)
+        assert end <= after[1] and kinds[after[3]["seq"]].endswith("admit")
+    assert "serve.admit.args" not in {s[0] for s in ring}
+    ring = [s for s in ring if s[0] != "host.gc"]
+    steps = {s[4]["id"]: s for s in ring if s[0] == "serve.step"}
+    admit_spans = {s[3]: s for s in ring if s[0] == "serve.admit"}
+    inner = [s for s in ring if s[0] in ("serve.dispatch", "serve.fetch", "serve.deliver")]
+    schedule = sum(admit_spans[sid][2] - step[1]
+                   - sum(s[2] - s[1] for s in inner if s[3] == sid and s[2] <= admit_spans[sid][2])
+                   for sid, step in steps.items())
+    assert engine.metrics.step_phase_schedule_s.sum == pytest.approx(schedule, abs=1e-9)
+
+
 @pytest.mark.parametrize("admit", [1, 4])
 def test_one_queue_wait_per_admitted_request_ending_at_its_dispatch(model, admit):
     tracer = Tracer()
